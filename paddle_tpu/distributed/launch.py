@@ -7,6 +7,11 @@ within a host are all driven by one process — unlike the reference's
 process-per-GPU), wires PADDLE_* env vars, supervises children, and kills
 the job when any worker dies.
 
+A chip belongs to one process at a time and nothing here confines a
+child to a device, so ``--nproc_per_node > 1`` on one host is a CPU
+drill (``JAX_PLATFORMS=cpu``): on a TPU host every rank would try to
+own every chip.
+
 Beyond the reference's abort-on-any-failure policy, ``supervise(...)`` /
 ``Supervisor`` adds a relaunch loop: a dead trainer is re-exec'd (after
 exponential backoff with jitter) while a restart budget lasts, composing
@@ -36,7 +41,6 @@ def _worker_env(rank, nranks, endpoints):
         "PADDLE_TRAINERS_NUM": str(nranks),
         "PADDLE_CURRENT_ENDPOINT": endpoints[rank],
         "PADDLE_TRAINER_ENDPOINTS": ",".join(endpoints),
-        "FLAGS_selected_tpus": str(rank),
     })
     return env
 

@@ -79,8 +79,8 @@ define_flag("sp_fallback_warn", True,
 define_flag("flash_short_seq", False,
             "Route 128<=seq<=256 mask-free attention to the "
             "single-block Pallas kernel (direct softmax, one fused bwd "
-            "launch) instead of the XLA dispatch floor. Off until the "
-            "live-TPU A/B (tools/live_tpu_session.py) proves it wins")
+            "launch) instead of the XLA dispatch floor. Off until an "
+            "on-chip A/B proves it wins")
 define_flag("sp_mask_fallback", False,
             "Allow query-dependent attention masks the ring cannot "
             "decompose to fall back to replicated XLA attention instead "

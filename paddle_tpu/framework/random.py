@@ -25,19 +25,12 @@ def prng_impl() -> str:
 
     impl = get_flag("prng_impl")
     if impl == "auto":
-        from .bringup import TPU_PLATFORMS, backends_initialized, default_platform
+        from .bringup import TPU_PLATFORMS
 
-        if backends_initialized():
-            try:
-                platform = jax.default_backend()
-            except Exception:  # broken plugin: survivable (init.cc posture)
-                platform = "unknown"
-        else:
-            # Never let RNG-impl selection be the call that triggers (and
-            # possibly dies on) backend bring-up — guess from config; the
-            # key creation that follows does the real init.
-            platform = default_platform()
-        impl = "rbg" if platform in TPU_PLATFORMS else "threefry2x32"
+        # the live backend decides (this may be the call that initialises
+        # it): a guess from config would answer threefry on the chip
+        impl = ("rbg" if jax.default_backend() in TPU_PLATFORMS
+                else "threefry2x32")
     return impl
 
 
@@ -45,12 +38,8 @@ def make_key(seed: int):
     """Create a PRNG key with the configured implementation.
 
     Key creation is the library's earliest device touch (parameter
-    initializers run before any user Tensor exists), so it goes through
-    the bring-up guard: a broken PJRT plugin degrades to cpu here
-    instead of hanging model construction."""
-    from .bringup import guard_first_touch
-
-    guard_first_touch()
+    initializers run before any user Tensor exists); a backend that
+    fails to initialise raises here."""
     return jax.random.key(seed, impl=prng_impl())
 
 

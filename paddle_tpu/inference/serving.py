@@ -10,7 +10,7 @@ written by ``static.save_inference_model`` (sha256-manifest-verified),
 prunes it to the feed→fetch subgraph, and executes it through the
 static Executor — which pass-optimizes the Program (PR 3 pipeline),
 keeps the params device-resident and DONATED (PR 1 machinery), and
-reuses the persistent compile cache (``PADDLE_COMPILE_CACHE[_DIR]``) so
+reuses the persistent compile cache (``static/compile_cache.py``) so
 a relaunched server pays no cold compile. Execution is compiled at a
 fixed ladder of padded batch-size buckets: every request batch is
 padded up to the nearest bucket, so the engine dispatches against a
@@ -143,8 +143,8 @@ class AnalysisPredictor:
     hot path is one warm XLA dispatch per batch.
 
     ``batch_buckets`` is the padded-batch ladder (ascending); ``warm()``
-    compiles every bucket up front — with ``PADDLE_COMPILE_CACHE_DIR``
-    set, a relaunched server warms from disk instead of re-compiling.
+    compiles every bucket up front — a relaunched server warms from the
+    disk compile cache instead of re-compiling.
     """
 
     def __init__(self, model_dir: str,

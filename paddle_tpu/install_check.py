@@ -5,8 +5,6 @@ from __future__ import annotations
 
 import numpy as np
 
-from .framework.bringup import safe_devices
-
 __all__ = ["run_check"]
 
 
@@ -17,7 +15,7 @@ def run_check():
     from .jit import TrainStep
 
     print("Running verify paddle_tpu program ... ")
-    devices = safe_devices()
+    devices = jax.devices()
     print(f"Found {len(devices)} device(s): "
           f"{[str(d) for d in devices[:4]]}"
           f"{' ...' if len(devices) > 4 else ''}")
@@ -31,7 +29,7 @@ def run_check():
                             parameters=model.parameters())
         step = TrainStep(model, lambda m, x: (m(x) ** 2).mean(), opt,
                          mesh=mesh)
-        rows = max(2, len(safe_devices()))
+        rows = max(2, len(devices))
         x = to_tensor(np.tile(np.array([[1.0, 2.0], [3.0, 4.0]],
                                        np.float32), (rows // 2 + 1, 1))[:rows])
         first = float(step(x))

@@ -216,8 +216,8 @@ class TrainStep:
         self._zero_stage = zero_stage
         self._zero_axis = zero_axis
         self._placed = False
-        # PADDLE_COMPILE_CACHE[_DIR]: route this step's XLA compiles
-        # through the disk-persistent cache too (no-op when unset)
+        self._pinned = None  # (param, slot) shardings of the placement
+        # route this step's XLA compiles through the disk-persistent cache
         from .static.compile_cache import ensure_enabled
         ensure_enabled()
 
@@ -268,6 +268,15 @@ class TrainStep:
                     slots[n] = _tree.tree_map(
                         lambda a, nn=n: jax.device_put(
                             a, slot_sharding(nn, a)), slots[n])
+                # the step counter too: left on one device it comes back
+                # from step 1 replicated over the mesh, and step 2 is
+                # traced and compiled a second time for that
+                self._opt_state["step"] = jax.device_put(
+                    self._opt_state["step"], rep)
+            self._pinned = (
+                {n: a.sharding for n, a in params.items()},
+                _tree.tree_map(lambda a: a.sharding,
+                               self._opt_state["slots"]))
             self._placed = True
         axes = tuple(a for a in self._data_axes if a in mesh.axis_names)
         if axes or self._data_spec is not None:
@@ -304,20 +313,13 @@ class TrainStep:
                 model.load_buffer_pytree(buffers)
                 from contextlib import nullcontext
 
-                from .parallel.mesh import trace_mesh as _trace_mesh_scope
                 from .parallel.ring import sequence_parallel as _sp_scope
 
                 sp_ctx = (_sp_scope(*self._sequence_parallel,
                                     mesh=self._mesh)
                           if self._sequence_parallel else nullcontext())
-                # mark the mesh governing this trace (+ the axes batch
-                # rows shard over) so non-shard_map pallas kernels
-                # (fused_xent) can shard_map themselves or self-gate
-                mesh_ctx = _trace_mesh_scope(self._mesh,
-                                             self._batch_row_axes())
                 try:
-                    with tape_mod.no_grad(), rng_scope(key), sp_ctx, \
-                            mesh_ctx:
+                    with tape_mod.no_grad(), rng_scope(key), sp_ctx:
                         out = loss_fn(model, *[_wrap_in(b) for b in batch])
                     loss = out[0] if isinstance(out, (tuple, list)) else out
                     aux = out[1:] if isinstance(out, (tuple, list)) else ()
@@ -333,10 +335,29 @@ class TrainStep:
                         b._value = saved_b[n]
                 return loss_arr, (new_buffers, aux_arr)
 
-            (loss, (new_buffers, aux)), grads = jax.value_and_grad(
-                loss_of, has_aux=True)(params)
-            new_params, new_opt_state = optimizer.apply_gradients_fn(
-                grads, params, opt_state, lr)
+            from .parallel.mesh import trace_mesh
+
+            # mark the mesh governing this trace (+ the axes batch rows
+            # shard over), for the loss AND the update: Pallas kernels
+            # outside a shard_map consult it to shard_map themselves
+            # (fused_xent) or to self-gate (flash, fused optimizer)
+            with trace_mesh(self._mesh, self._batch_row_axes()):
+                (loss, (new_buffers, aux)), grads = jax.value_and_grad(
+                    loss_of, has_aux=True)(params)
+                new_params, new_opt_state = optimizer.apply_gradients_fn(
+                    grads, params, opt_state, lr)
+            if self._pinned is not None:
+                # hand params and slots back in the placement they came
+                # in with. Left free, XLA returns whatever its sharding
+                # propagation picked and the next call compiles again
+                # for that.
+                p_sh, s_sh = self._pinned
+                new_params = {
+                    n: jax.lax.with_sharding_constraint(v, p_sh[n])
+                    for n, v in new_params.items()}
+                new_opt_state = dict(new_opt_state, slots=_tree.tree_map(
+                    jax.lax.with_sharding_constraint,
+                    new_opt_state["slots"], s_sh))
             return loss, aux, new_params, new_buffers, new_opt_state
 
         # params + optimizer state are donated: XLA updates the (large)

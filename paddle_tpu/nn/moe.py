@@ -298,10 +298,8 @@ def moe_apply_ep(params, x, *, mesh: Optional[Mesh] = None, axis: str = "ep",
         # sharded layout.)
         return jax.vmap(_expert_ffn)(w1, b1, w2, b2, ein)
 
-    from ..parallel.collectives import shard_map_fn
-
     spec_e = PartitionSpec(axis)
-    out_e = shard_map_fn()(
+    out_e = jax.shard_map(
         local, mesh=mesh,
         in_specs=(spec_e, spec_e, spec_e, spec_e, spec_e),
         out_specs=spec_e,

@@ -19,7 +19,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _xla_bag(table, ids, combiner):
@@ -74,15 +75,6 @@ def _bag_kernel(ids_ref, table_blk_ref, out_ref, cnt_ref, *, seq, combiner):
                 out_ref[pl.dslice(off, 1), :] / denom
 
 
-try:  # pallas imports kept lazy-tolerant (cpu wheels without pallas tpu)
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _PALLAS = True
-except Exception:  # pragma: no cover
-    _PALLAS = False
-
-
 def _bag_pallas(table, ids, combiner):
     b, s = ids.shape
     v, d = table.shape
@@ -111,15 +103,20 @@ def _bag_pallas(table, ids, combiner):
 
 def _eligible(table, ids):
     from ...framework.bringup import pallas_enabled
+    from ...parallel.mesh import auto_partitioned_trace
 
-    if not _PALLAS or not pallas_enabled():
+    if not pallas_enabled() or auto_partitioned_trace():
         return False
     v, d = table.shape
     b = ids.shape[0]
     # lane-aligned embedding dim; tiny bags fuse fine in XLA; the 8-row
-    # block layout needs vocab and batch on the sublane modulus
-    return (d % 128 == 0 and ids.shape[1] >= 8
-            and v % 8 == 0 and b % 8 == 0)
+    # block layout needs vocab and batch on the sublane modulus. f32
+    # tables only: a 16-bit table packs two rows per sublane and Mosaic
+    # refuses the kernel's dynamic single-row slice of its block
+    # ("cannot statically prove that index in dimension 0 is a multiple
+    # of 8", v5e, PR 21).
+    return (table.dtype == jnp.float32 and d % 128 == 0
+            and ids.shape[1] >= 8 and v % 8 == 0 and b % 8 == 0)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
@@ -127,20 +124,15 @@ def _bag_core(table, ids, combiner):
     from .counters import bump
 
     if _eligible(table, ids):
-        try:
-            out = _bag_pallas(table, ids, combiner)
-            bump("fused_embedding", "pallas")
-            return out
-        except Exception as e:
-            # counted + optionally logged: this exact silent except hid
-            # a Mosaic tile-rule bug for a full round
-            bump("fused_embedding", "xla",
-                 f"kernel error {type(e).__name__}: {e}")
-    else:
-        bump("fused_embedding", "xla",
-             f"ineligible (table {tuple(table.shape)}, ids "
-             f"{tuple(ids.shape)}: need d%128==0, seq>=8, vocab%8==0, "
-             "batch%8==0, pallas enabled)")
+        # a chosen kernel that fails raises: a silent except here once
+        # hid a Mosaic tile-rule bug for a full round
+        out = _bag_pallas(table, ids, combiner)
+        bump("fused_embedding", "pallas")
+        return out
+    bump("fused_embedding", "xla",
+         f"ineligible (table {tuple(table.shape)} {table.dtype}, ids "
+         f"{tuple(ids.shape)}: need f32, d%128==0, seq>=8, vocab%8==0, "
+         "batch%8==0, pallas enabled, no multi-device GSPMD trace)")
     return _xla_bag(table, ids, combiner)
 
 

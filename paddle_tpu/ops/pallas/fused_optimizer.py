@@ -49,13 +49,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:  # pallas imports kept lazy-tolerant (cpu wheels without pallas tpu)
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _PALLAS = True
-except Exception:  # pragma: no cover
-    _PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["FUSED_OPS", "fused_op_update", "fused_chunk_update",
            "fused_try_rule", "fused_opt_escaped"]
@@ -294,14 +289,16 @@ def _dispatch(op_type: str, n: int, dtype) -> tuple:
         return "xla", f"no fused kernel for {op_type!r}", False
     if fused_opt_escaped():
         return "xla", "disabled (PADDLE_FUSED_OPT=0)", False
-    if not _PALLAS:
-        return "xla", "pallas unavailable in this jax build", False
     interpret = _interpret_forced()
     if not interpret:
         from ...framework.bringup import pallas_enabled
+        from ...parallel.mesh import auto_partitioned_trace
 
         if not pallas_enabled():
             return "xla", "pallas disabled for this backend", False
+        if auto_partitioned_trace():
+            return ("xla", "multi-device GSPMD trace: Mosaic kernels "
+                           "cannot be automatically partitioned", False)
     if jnp.dtype(dtype) != jnp.float32:
         return "xla", f"dtype {jnp.dtype(dtype).name} is not f32", False
     if n < _TILE:
@@ -352,8 +349,9 @@ def _pallas_update(op_type, ins, attrs, interpret, dygraph=False,
                 "VelocityOut": [v_new.reshape(shape).astype(dtype)]}
     b1 = attrs.get("beta1", 0.9)
     b2 = attrs.get("beta2", 0.999)
-    b1p = ins["Beta1Pow"][0].reshape(())
-    b2p = ins["Beta2Pow"][0].reshape(())
+    # the beta-pow accumulators keep their own shape ((1,) in the static
+    # programs), as the XLA reference returns them
+    b1p, b2p = ins["Beta1Pow"][0], ins["Beta2Pow"][0]
     if c1 is None:
         c1, c2 = b1p * b1, b2p * b2
     if op_type == "adam":
@@ -425,15 +423,10 @@ def fused_op_update(op_type, ins, attrs):
     p = ins["Param"][0]
     path, reason, interpret = _dispatch(op_type, p.size, p.dtype)
     if path == "pallas":
-        try:
-            out = _pallas_update(op_type, ins, attrs, interpret)
-            bump("fused_opt", "pallas")
-            return out
-        except Exception as e:
-            bump("fused_opt", "xla",
-                 f"kernel error {type(e).__name__}: {e}")
-    else:
-        bump("fused_opt", "xla", f"{op_type}: {reason}")
+        out = _pallas_update(op_type, ins, attrs, interpret)
+        bump("fused_opt", "pallas")
+        return out
+    bump("fused_opt", "xla", f"{op_type}: {reason}")
     return _XLA[op_type](ins, attrs)
 
 
@@ -494,19 +487,14 @@ def fused_chunk_update(op_type, ins, attrs, *, axis=None,
 
     path, reason, interpret = _dispatch("lamb", c, p.dtype)
     if path == "pallas":
-        try:
-            kern = functools.partial(
-                _lamb_phase1_kernel, b1=b1, b2=b2, eps=eps, wd=wd,
-                dygraph=False)
-            m_new, v_new, r = _run_grid(
-                kern, [_scal(b1p * b1), _scal(b2p * b2)],
-                [p, g, m, v], 3, c, interpret)
-            bump("fused_opt", "pallas")
-        except Exception as e:
-            bump("fused_opt", "xla",
-                 f"kernel error {type(e).__name__}: {e}")
-            path = "xla"
-    if path != "pallas":
+        kern = functools.partial(
+            _lamb_phase1_kernel, b1=b1, b2=b2, eps=eps, wd=wd,
+            dygraph=False)
+        m_new, v_new, r = _run_grid(
+            kern, [_scal(b1p * b1), _scal(b2p * b2)],
+            [p, g, m, v], 3, c, interpret)
+        bump("fused_opt", "pallas")
+    else:
         bump("fused_opt", "xla", f"lamb chunk: {reason}")
         m_new = b1 * m + (1 - b1) * g
         v_new = b2 * v + (1 - b2) * g * g
@@ -566,56 +554,51 @@ def fused_try_rule(opt, g, p, slots, lr, step):
 
     shape, dtype = p.shape, p.dtype
     n = p.size
-    try:
-        if kind == "sgd":
-            (p_new,) = _run_grid(_sgd_kernel, [_scal(lr), _scal(0.0)],
-                                 [p, g], 1, n, interpret)
-            bump("fused_opt", "pallas")
-            return p_new.reshape(shape).astype(dtype), slots
-        if kind == "momentum":
-            kern = functools.partial(_momentum_kernel,
-                                     mu=opt._momentum,
-                                     nesterov=bool(opt._nesterov))
-            p_new, v_new = _run_grid(
-                kern, [_scal(lr), _scal(0.0)],
-                [p, g, slots["velocity"]], 2, n, interpret)
-            bump("fused_opt", "pallas")
-            return (p_new.reshape(shape).astype(dtype),
-                    {"velocity": v_new.reshape(shape).astype(dtype)})
-        b1, b2 = opt._beta1, opt._beta2
-        tf = step.astype(jnp.float32)
-        c1 = (1 - b1 ** tf).astype(jnp.float32)
-        c2 = (1 - b2 ** tf).astype(jnp.float32)
-        if kind == "adam":
-            kern = functools.partial(_adam_kernel, b1=b1, b2=b2,
-                                     eps=opt._eps, dygraph=True)
-            p_new, m_new, v_new = _run_grid(
-                kern, [_scal(lr), _scal(c1), _scal(c2), _scal(0.0)],
-                [p, g, slots["moment1"], slots["moment2"]], 3, n,
-                interpret)
-            bump("fused_opt", "pallas")
-            return (p_new.reshape(shape).astype(dtype),
-                    {"moment1": m_new.reshape(shape).astype(dtype),
-                     "moment2": v_new.reshape(shape).astype(dtype)})
-        # lamb
-        kern = functools.partial(_lamb_phase1_kernel, b1=b1, b2=b2,
-                                 eps=opt._eps, wd=opt._lamb_wd,
-                                 dygraph=True)
-        m_new, v_new, r = _run_grid(
-            kern, [_scal(c1), _scal(c2)],
+    if kind == "sgd":
+        (p_new,) = _run_grid(_sgd_kernel, [_scal(lr), _scal(0.0)],
+                             [p, g], 1, n, interpret)
+        bump("fused_opt", "pallas")
+        return p_new.reshape(shape).astype(dtype), slots
+    if kind == "momentum":
+        kern = functools.partial(_momentum_kernel,
+                                 mu=opt._momentum,
+                                 nesterov=bool(opt._nesterov))
+        p_new, v_new = _run_grid(
+            kern, [_scal(lr), _scal(0.0)],
+            [p, g, slots["velocity"]], 2, n, interpret)
+        bump("fused_opt", "pallas")
+        return (p_new.reshape(shape).astype(dtype),
+                {"velocity": v_new.reshape(shape).astype(dtype)})
+    b1, b2 = opt._beta1, opt._beta2
+    tf = step.astype(jnp.float32)
+    c1 = (1 - b1 ** tf).astype(jnp.float32)
+    c2 = (1 - b2 ** tf).astype(jnp.float32)
+    if kind == "adam":
+        kern = functools.partial(_adam_kernel, b1=b1, b2=b2,
+                                 eps=opt._eps, dygraph=True)
+        p_new, m_new, v_new = _run_grid(
+            kern, [_scal(lr), _scal(c1), _scal(c2), _scal(0.0)],
             [p, g, slots["moment1"], slots["moment2"]], 3, n,
             interpret)
-        pf = p.reshape(-1).astype(jnp.float32)
-        w_norm = jnp.sqrt(jnp.sum(jnp.square(pf)))
-        r_norm = jnp.sqrt(jnp.sum(jnp.square(r)))
-        trust = jnp.where((w_norm > 0) & (r_norm > 0),
-                          w_norm / r_norm, 1.0)
-        p_new = pf - jnp.asarray(lr, jnp.float32) * trust * r
         bump("fused_opt", "pallas")
         return (p_new.reshape(shape).astype(dtype),
                 {"moment1": m_new.reshape(shape).astype(dtype),
                  "moment2": v_new.reshape(shape).astype(dtype)})
-    except Exception as e:
-        bump("fused_opt", "xla",
-             f"dygraph kernel error {type(e).__name__}: {e}")
-        return None
+    # lamb
+    kern = functools.partial(_lamb_phase1_kernel, b1=b1, b2=b2,
+                             eps=opt._eps, wd=opt._lamb_wd,
+                             dygraph=True)
+    m_new, v_new, r = _run_grid(
+        kern, [_scal(c1), _scal(c2)],
+        [p, g, slots["moment1"], slots["moment2"]], 3, n,
+        interpret)
+    pf = p.reshape(-1).astype(jnp.float32)
+    w_norm = jnp.sqrt(jnp.sum(jnp.square(pf)))
+    r_norm = jnp.sqrt(jnp.sum(jnp.square(r)))
+    trust = jnp.where((w_norm > 0) & (r_norm > 0),
+                      w_norm / r_norm, 1.0)
+    p_new = pf - jnp.asarray(lr, jnp.float32) * trust * r
+    bump("fused_opt", "pallas")
+    return (p_new.reshape(shape).astype(dtype),
+            {"moment1": m_new.reshape(shape).astype(dtype),
+             "moment2": v_new.reshape(shape).astype(dtype)})

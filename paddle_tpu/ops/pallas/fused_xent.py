@@ -42,17 +42,30 @@ _BN_CANDIDATES = (1024, 512, 256)
 _BV_CANDIDATES = (512, 384, 256, 128)
 #: pad modulus = the smallest row block we can always fall back to
 _BN_MIN = _BN_CANDIDATES[-1]
-#: per-kernel VMEM budget (bytes) for the block-resident f32 tensors;
-#: v5e has ~16 MB/core — leave headroom for Mosaic's own buffers
+#: per-kernel budget (bytes) for the block-resident f32 tensors as
+#: _fits counts them
 _VMEM_BUDGET = 10 * 1024 * 1024
+#: scoped-VMEM limit handed to Mosaic. _fits counts one f32 copy of each
+#: block; Mosaic also double-buffers every block and keeps more
+#: temporaries live, and against the 16 MiB default the two largest
+#: admitted working sets did not compile (measured on a v5e, PR 21: dW at
+#: bn=1024 bv=128 hd=768 bf16 took 16.02 MB, dh at bn=512 bv=128 hd=2048
+#: f32 18.01 MB — up to 2.1x _fits' count). 3x the budget, of 128 MiB
+#: physical.
+_VMEM_LIMIT = 3 * _VMEM_BUDGET
+
+
+def _compiler_params():
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
 
 
 def _fits(bn, bv, hd):
     """Both backward kernels' block-resident f32 footprints must fit:
     dh holds h + f32 dh accumulator + w tile + s/p pair; dW holds
-    h + w + f32 dW accumulator + s/p pair. Overflow would fail Mosaic
-    at COMPILE time — outside the dispatch try/except — so no
-    over-budget pair may ever be picked."""
+    h + w + f32 dW accumulator + s/p pair. Overflow fails Mosaic at
+    COMPILE time, so no over-budget pair may ever be picked."""
     dh_kernel = 4 * (2 * bn * hd + bv * hd + 2 * bn * bv)
     dw_kernel = 4 * (bn * hd + 2 * bv * hd + 2 * bn * bv)
     return max(dh_kernel, dw_kernel) <= _VMEM_BUDGET
@@ -209,6 +222,7 @@ def _fwd_call(h, w, bias, labels, block_n, block_v):
             _sds((1, n), _F32, h),     # running max (scratch-as-output)
             _sds((1, n), _F32, h),     # running sumexp
         ],
+        compiler_params=_compiler_params(),
     )(h, w, bias[None, :], labels[None, :])
     return lse[0], ll[0]
 
@@ -231,6 +245,7 @@ def _bwd_call(h, w, bias, labels, lse, g, block_n, block_v):
         ],
         out_specs=pl.BlockSpec((block_n, hd), lambda i, j: (i, 0)),
         out_shape=_sds((n, hd), _F32, h),
+        compiler_params=_compiler_params(),
     )(h, w, bias[None, :], labels[None, :], lse[None, :], g[None, :])
     dw, db = pl.pallas_call(
         functools.partial(_bwd_dw_kernel, block_n=block_n,
@@ -252,6 +267,7 @@ def _bwd_call(h, w, bias, labels, lse, g, block_n, block_v):
             _sds((v, hd), _F32, h),
             _sds((1, v), _F32, h),
         ],
+        compiler_params=_compiler_params(),
     )(h, w, bias[None, :], labels[None, :], lse[None, :], g[None, :])
     return dh.astype(h.dtype), dw.astype(w.dtype), db[0]
 
@@ -314,9 +330,18 @@ def _sharded_fused(h2, w, bias, lab, mesh, row_axes, ignore_index):
     explicit instead of asking XLA to infer it."""
     from jax.sharding import PartitionSpec as P
 
-    from ...parallel.ring import _shard_map
+    from ...parallel.ring import _SHARD_MAP_CHECK_VMA, _shard_map
 
     def local(hs, ws, bs, ls):
+        # W and bias arrive replicated over the row axes but each shard's
+        # dW/db is its own partial sum: cast them to varying, so the
+        # custom_vjp's cotangents type-check under check_vma and the
+        # cast's transpose is the psum that adds the partials up. (With
+        # the check off — the interpret-mode tests — nothing is typed
+        # and shard_map's own transpose does that sum.)
+        if _SHARD_MAP_CHECK_VMA[0]:
+            ws, bs = (jax.lax.pcast(a, row_axes, to="varying")
+                      for a in (ws, bs))
         s, c = _fused_xent_sums(hs, ws, bs, ls, ignore_index)
         s = jax.lax.psum(s, row_axes)
         c = jax.lax.psum(c, row_axes)
@@ -378,27 +403,19 @@ def fused_linear_cross_entropy(h, w, bias, labels, ignore_index=-100):
              "value-identical and partitionable)")
     elif plan is not None:
         mesh, row_axes = plan
-        try:
-            out = _sharded_fused(h2, w, bias, lab, mesh, row_axes,
-                                 int(ignore_index))
-            bump("fused_xent", "pallas_sharded")
-            return out
-        except Exception as e:
-            bump("fused_xent", "xla",
-                 f"sharded kernel error {type(e).__name__}: {e}")
+        out = _sharded_fused(h2, w, bias, lab, mesh, row_axes,
+                             int(ignore_index))
+        bump("fused_xent", "pallas_sharded")
+        return out
     elif _eligible(n + pad, hd, w.shape[0]):
-        try:
-            if pad:
-                h2 = jnp.concatenate(
-                    [h2, jnp.zeros((pad, hd), h2.dtype)], 0)
-                lab = jnp.concatenate(
-                    [lab, jnp.full((pad,), ignore_index, lab.dtype)], 0)
-            out = _fused_xent_core(h2, w, bias, lab, int(ignore_index))
-            bump("fused_xent", "pallas")
-            return out
-        except Exception as e:
-            bump("fused_xent", "xla",
-                 f"kernel error {type(e).__name__}: {e}")
+        if pad:
+            h2 = jnp.concatenate(
+                [h2, jnp.zeros((pad, hd), h2.dtype)], 0)
+            lab = jnp.concatenate(
+                [lab, jnp.full((pad,), ignore_index, lab.dtype)], 0)
+        out = _fused_xent_core(h2, w, bias, lab, int(ignore_index))
+        bump("fused_xent", "pallas")
+        return out
     else:
         bump("fused_xent", "xla",
              f"dispatch ineligible (n={n}, w={tuple(w.shape)})")

@@ -23,7 +23,7 @@ straight from the page the table names — the gather never materializes
 a contiguous copy of the context. Online-softmax carries (m, l, acc)
 persist in VMEM scratch across a sequence's page steps; pages past
 ``ceil(seq_len/S)`` are skipped (``pl.when``), which is where the
-ragged win comes from.
+ragged win comes from. (Formulation notes sit above the kernel.)
 
 Dispatch follows the established kernel pattern (flash_attention.py):
 an eligibility gate (``_paged_ok``), per-decision counters
@@ -98,183 +98,153 @@ def _xla_paged_attention_quant(q, k_pages, v_pages, k_scales, v_scales,
 
 # ---------------------------------------------------------------------------
 # Pallas kernel: grid (B, T), page table scalar-prefetched, online
-# softmax carried in VMEM scratch across a sequence's page steps
+# softmax carried in VMEM scratch across a sequence's page steps.
+#
+# Mosaic-friendly formulation: the pool is viewed as (P, S, H*D) so a page
+# is one lane-dense (S, H*D) tile, and the per-head reductions ride the
+# MXU through a 0/1 head-segment matrix instead of in-kernel transposes
+# or batched vector-matrix products (neither lowers):
+#   scores (S, Hp) = (k * q_row) @ seg          seg[c, h] = (c // D == h)
+#   p_exp  (S, HD) = p @ seg^T                  (each head's prob over its D)
+#   acc    (1, HD) += sum_s(p_exp * v)
+# Hp is the head count padded to one 128-lane tile. The three segment
+# matmuls run at HIGHEST precision: they only SUM or COPY f32 values, so
+# the kernel is f32-exact whatever the ambient matmul precision.
 # ---------------------------------------------------------------------------
-def _paged_attn_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
-                       m_sc, l_sc, acc_sc, *, page_size, sm_scale):
-    from jax.experimental import pallas as pl
-
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    num_pages = pl.num_programs(1)
-
-    @pl.when(j == 0)
-    def _init():
-        m_sc[...] = jnp.full_like(m_sc[...], _NEG_INF)
-        l_sc[...] = jnp.zeros_like(l_sc[...])
-        acc_sc[...] = jnp.zeros_like(acc_sc[...])
-
-    length = lens_ref[b]
-
-    @pl.when(j * page_size < length)
-    def _page():
-        q = q_ref[...].astype(_F32) * sm_scale          # (H, D)
-        k = jnp.swapaxes(k_ref[...].astype(_F32), 0, 1)  # (H, S, D)
-        v = jnp.swapaxes(v_ref[...].astype(_F32), 0, 1)  # (H, S, D)
-        H, S = q.shape[0], k.shape[1]
-        # per-head batched q·K^T: (H, D) x (H, S, D) -> (H, S)
-        s = jax.lax.dot_general(q, k, (((1,), (2,)), ((0,), (0,))),
-                                preferred_element_type=_F32)
-        pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (H, S), 1)
-        s = jnp.where(pos < length, s, _NEG_INF)
-        m_prev = m_sc[:, 0]
-        l_prev = l_sc[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_sc[:, 0] = alpha * l_prev + jnp.sum(p, axis=1)
-        m_sc[:, 0] = m_new
-        # (H, S) x (H, S, D) -> (H, D)
-        pv = jax.lax.dot_general(p, v, (((1,), (1,)), ((0,), (0,))),
-                                 preferred_element_type=_F32)
-        acc_sc[...] = acc_sc[...] * alpha[:, None] + pv
-
-    @pl.when(j == num_pages - 1)
-    def _flush():
-        norm = jnp.maximum(l_sc[:, 0], 1e-30)[:, None]
-        o_ref[...] = (acc_sc[...] / norm).astype(o_ref.dtype)
+_HEAD_LANES = 128
+_HI = jax.lax.Precision.HIGHEST
+#: bytes of VMEM the gate lets one grid step hold (v5e's default scoped
+#: limit is 16 MiB; leave headroom for Mosaic's own buffers)
+_VMEM_BUDGET = 12 * 1024 * 1024
 
 
-@functools.partial(jax.jit, static_argnames=())
-def _paged_attention_pallas(q, k_pages, v_pages, page_table, seq_lens):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    B, H, D = q.shape
-    S = k_pages.shape[1]
-    T = page_table.shape[1]
-    sm_scale = 1.0 / math.sqrt(D)
-    # dead/unused table entries route the DMA at a real page (0); the
-    # pl.when page gate skips their compute and the ragged mask keeps
-    # their positions out of the softmax either way
-    safe_table = jnp.maximum(page_table, 0).astype(jnp.int32)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,       # page_table, seq_lens
-        grid=(B, T),
-        in_specs=[
-            pl.BlockSpec((None, H, D), lambda b, j, pt, lens: (b, 0, 0)),
-            pl.BlockSpec((None, S, H, D),
-                         lambda b, j, pt, lens: (pt[b, j], 0, 0, 0)),
-            pl.BlockSpec((None, S, H, D),
-                         lambda b, j, pt, lens: (pt[b, j], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, H, D),
-                               lambda b, j, pt, lens: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((H, 1), _F32),       # running max m
-            pltpu.VMEM((H, 1), _F32),       # running normalizer l
-            pltpu.VMEM((H, D), _F32),       # value accumulator
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_paged_attn_kernel, page_size=S,
-                          sm_scale=sm_scale),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
-    )(safe_table, seq_lens.astype(jnp.int32), q, k_pages, v_pages)
-
-
-def _paged_attn_kernel_quant(pt_ref, lens_ref, q_ref, k_ref, v_ref,
-                             ks_ref, vs_ref, o_ref, m_sc, l_sc, acc_sc,
-                             *, page_size, sm_scale):
-    """Quantized twin of :func:`_paged_attn_kernel`: the page DMA
+def _paged_attn_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
+                       page_size, table_width, sm_scale, quant):
+    """One (sequence, page) grid step. With ``quant`` the page DMA
     brings int8 rows + their per-row f32 scales into VMEM and the
     dequant (one multiply per row) happens right there — the f32 view
-    of a page exists only transiently in registers/VMEM, which is the
-    whole ~4x pool-headroom win."""
+    of a page exists only transiently in VMEM, which is the whole ~4x
+    pool-headroom win."""
     from jax.experimental import pallas as pl
 
+    del pt_ref, table_width                 # consumed by the index maps
+    if quant:
+        ks_ref, vs_ref = rest[0], rest[1]
+        rest = rest[2:]
+    seg_ref, segt_ref, o_ref, m_sc, l_sc, acc_sc = rest
     b = pl.program_id(0)
     j = pl.program_id(1)
-    num_pages = pl.num_programs(1)
 
     @pl.when(j == 0)
     def _init():
-        m_sc[...] = jnp.full_like(m_sc[...], _NEG_INF)
-        l_sc[...] = jnp.zeros_like(l_sc[...])
-        acc_sc[...] = jnp.zeros_like(acc_sc[...])
+        m_sc[...] = jnp.full_like(m_sc, _NEG_INF)
+        l_sc[...] = jnp.zeros_like(l_sc)
+        acc_sc[...] = jnp.zeros_like(acc_sc)
 
     length = lens_ref[b]
 
     @pl.when(j * page_size < length)
     def _page():
-        q = q_ref[...].astype(_F32) * sm_scale          # (H, D)
-        kq = k_ref[...].astype(_F32) * ks_ref[...][:, None, None]
-        vq = v_ref[...].astype(_F32) * vs_ref[...][:, None, None]
-        k = jnp.swapaxes(kq, 0, 1)                      # (H, S, D)
-        v = jnp.swapaxes(vq, 0, 1)                      # (H, S, D)
-        H, S = q.shape[0], k.shape[1]
-        s = jax.lax.dot_general(q, k, (((1,), (2,)), ((0,), (0,))),
-                                preferred_element_type=_F32)
+        q = q_ref[...].astype(_F32) * sm_scale           # (1, HD)
+        k = k_ref[...].astype(_F32)                      # (S, HD)
+        v = v_ref[...].astype(_F32)
+        if quant:
+            k = k * ks_ref[...]                          # (S, 1) scales
+            v = v * vs_ref[...]
+        s = jnp.dot(k * q, seg_ref[...], precision=_HI,
+                    preferred_element_type=_F32)         # (S, Hp)
         pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (H, S), 1)
+            jnp.int32, s.shape, 0)
         s = jnp.where(pos < length, s, _NEG_INF)
-        m_prev = m_sc[:, 0]
-        l_prev = l_sc[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_sc[:, 0] = alpha * l_prev + jnp.sum(p, axis=1)
-        m_sc[:, 0] = m_new
-        pv = jax.lax.dot_general(p, v, (((1,), (1,)), ((0,), (0,))),
-                                 preferred_element_type=_F32)
-        acc_sc[...] = acc_sc[...] * alpha[:, None] + pv
+        m_prev = m_sc[...]                               # (8, Hp) rows equal
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+        p = jnp.exp(s - m_new[0:1, :])                   # (S, Hp)
+        alpha = jnp.exp(m_prev - m_new)                  # (8, Hp)
+        l_sc[...] = alpha * l_sc[...] + jnp.sum(p, axis=0, keepdims=True)
+        m_sc[...] = m_new
+        p_exp = jnp.dot(p, segt_ref[...], precision=_HI,
+                        preferred_element_type=_F32)     # (S, HD)
+        alpha_exp = jnp.dot(alpha, segt_ref[...], precision=_HI,
+                            preferred_element_type=_F32)  # (8, HD)
+        acc_sc[...] = acc_sc[...] * alpha_exp + jnp.sum(
+            p_exp * v, axis=0, keepdims=True)
 
-    @pl.when(j == num_pages - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _flush():
-        norm = jnp.maximum(l_sc[:, 0], 1e-30)[:, None]
-        o_ref[...] = (acc_sc[...] / norm).astype(o_ref.dtype)
+        norm = jnp.dot(jnp.maximum(l_sc[...], 1e-30), segt_ref[...],
+                       precision=_HI, preferred_element_type=_F32)
+        o_ref[...] = (acc_sc[...] / norm)[0:1, :].astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=())
-def _paged_attention_pallas_quant(q, k_pages, v_pages, k_scales,
-                                  v_scales, page_table, seq_lens):
+def _paged_call(q, k_pages, v_pages, page_table, seq_lens, k_scales=None,
+                v_scales=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, D = q.shape
-    S = k_pages.shape[1]
+    P, S = k_pages.shape[:2]
     T = page_table.shape[1]
-    sm_scale = 1.0 / math.sqrt(D)
-    safe_table = jnp.maximum(page_table, 0).astype(jnp.int32)
+    HD = H * D
+    quant = k_scales is not None
+    # dead/unused table entries route the DMA at a real page (0); the
+    # pl.when page gate skips their compute and the ragged mask keeps
+    # their positions out of the softmax either way. Flat 1-D so the
+    # SMEM copy is B*T words, not a lane-padded 2-D tile per row.
+    safe_table = jnp.maximum(page_table, 0).astype(jnp.int32).reshape(-1)
+    seg = (jnp.arange(HD, dtype=jnp.int32)[:, None] // D
+           == jnp.arange(_HEAD_LANES, dtype=jnp.int32)[None, :]
+           ).astype(_F32)                                # (HD, Hp)
+
+    def page_map(b, j, pt, lens):
+        return (pt[b * T + j], 0, 0)
+
+    def row_map(b, j, pt, lens):
+        return (b, 0, 0)
+
+    def const_map(b, j, pt, lens):
+        return (0, 0)
+
+    page_spec = pl.BlockSpec((None, S, HD), page_map)
+    in_specs = [pl.BlockSpec((None, 1, HD), row_map), page_spec, page_spec]
+    operands = [q.reshape(B, 1, HD), k_pages.reshape(P, S, HD),
+                v_pages.reshape(P, S, HD)]
+    if quant:
+        scale_spec = pl.BlockSpec((None, S, 1), page_map)
+        in_specs += [scale_spec, scale_spec]
+        operands += [k_scales.reshape(P, S, 1), v_scales.reshape(P, S, 1)]
+    in_specs += [pl.BlockSpec((HD, _HEAD_LANES), const_map),
+                 pl.BlockSpec((_HEAD_LANES, HD), const_map)]
+    operands += [seg, seg.T]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,       # page_table, seq_lens
         grid=(B, T),
-        in_specs=[
-            pl.BlockSpec((None, H, D), lambda b, j, pt, lens: (b, 0, 0)),
-            pl.BlockSpec((None, S, H, D),
-                         lambda b, j, pt, lens: (pt[b, j], 0, 0, 0)),
-            pl.BlockSpec((None, S, H, D),
-                         lambda b, j, pt, lens: (pt[b, j], 0, 0, 0)),
-            pl.BlockSpec((None, S), lambda b, j, pt, lens: (pt[b, j], 0)),
-            pl.BlockSpec((None, S), lambda b, j, pt, lens: (pt[b, j], 0)),
-        ],
-        out_specs=pl.BlockSpec((None, H, D),
-                               lambda b, j, pt, lens: (b, 0, 0)),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((None, 1, HD), row_map),
         scratch_shapes=[
-            pltpu.VMEM((H, 1), _F32),
-            pltpu.VMEM((H, 1), _F32),
-            pltpu.VMEM((H, D), _F32),
+            pltpu.VMEM((8, _HEAD_LANES), _F32),   # running max m
+            pltpu.VMEM((8, _HEAD_LANES), _F32),   # running normalizer l
+            pltpu.VMEM((8, HD), _F32),            # value accumulator
         ],
     )
-    return pl.pallas_call(
-        functools.partial(_paged_attn_kernel_quant, page_size=S,
-                          sm_scale=sm_scale),
+    out = pl.pallas_call(
+        functools.partial(_paged_attn_kernel, page_size=S, table_width=T,
+                          sm_scale=1.0 / math.sqrt(D), quant=quant),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
-    )(safe_table, seq_lens.astype(jnp.int32), q, k_pages, v_pages,
-      k_scales, v_scales)
+        out_shape=jax.ShapeDtypeStruct((B, 1, HD), q.dtype),
+    )(safe_table, seq_lens.astype(jnp.int32), *operands)
+    return out.reshape(B, H, D)
+
+
+@jax.jit
+def _paged_attention_pallas(q, k_pages, v_pages, page_table, seq_lens):
+    return _paged_call(q, k_pages, v_pages, page_table, seq_lens)
+
+
+@jax.jit
+def _paged_attention_pallas_quant(q, k_pages, v_pages, k_scales,
+                                  v_scales, page_table, seq_lens):
+    return _paged_call(q, k_pages, v_pages, page_table, seq_lens,
+                       k_scales, v_scales)
 
 
 # ---------------------------------------------------------------------------
@@ -287,11 +257,13 @@ def _paged_ok(q, k_pages) -> bool:
         return False
     B, H, D = q.shape
     S = k_pages.shape[1]
-    # S % 128: the score tile's lane dim is the page; D % 64 / <= 256
-    # mirrors the flash kernel's head-dim contract; the H*S + H*D
-    # scratch stays far inside VMEM at these ceilings
-    return (S % 128 == 0 and D % 64 == 0 and D <= 256 and
-            H <= 128 and S <= 1024)
+    # S % 128 / (H*D) % 128: a page is one (S, H*D) tile, sublane- and
+    # lane-aligned for the segment matmuls; D % 64 / <= 256 mirrors the
+    # flash kernel's head-dim contract; H <= 128: heads pad to one lane
+    # tile. The VMEM bound counts the double-buffered K and V page
+    # blocks plus the kernel's (S, H*D) f32 temporaries, ~8 tiles.
+    return (S % 128 == 0 and D % 64 == 0 and D <= 256 and H <= 128 and
+            (H * D) % 128 == 0 and 8 * S * H * D * 4 <= _VMEM_BUDGET)
 
 
 def _escape_pinned() -> bool:
@@ -326,19 +298,14 @@ def paged_attention(q, k_pages, v_pages, page_table, seq_lens,
                                     seq_lens)
     if quant:
         if _paged_ok(q, k_pages):
-            try:
-                out = _paged_attention_pallas_quant(
-                    q, k_pages, v_pages, k_scales, v_scales,
-                    page_table, seq_lens)
-                bump("paged_attention", "pallas")
-                return out
-            except Exception as e:
-                bump("paged_attention", "xla",
-                     f"kernel error {type(e).__name__}: {e}")
-        else:
-            bump("paged_attention", "xla",
-                 f"dispatch ineligible (q {tuple(q.shape)}, page "
-                 f"{k_pages.shape[1]}; gate in _paged_ok)")
+            out = _paged_attention_pallas_quant(
+                q, k_pages, v_pages, k_scales, v_scales,
+                page_table, seq_lens)
+            bump("paged_attention", "pallas")
+            return out
+        bump("paged_attention", "xla",
+             f"dispatch ineligible (q {tuple(q.shape)}, page "
+             f"{k_pages.shape[1]}; gate in _paged_ok)")
         return _xla_paged_attention_quant(q, k_pages, v_pages, k_scales,
                                           v_scales, page_table, seq_lens)
     if _paged_ok(q, k_pages):
@@ -349,18 +316,13 @@ def paged_attention(q, k_pages, v_pages, page_table, seq_lens,
             bump("paged_attention", "xla", "autotuned: xla wins this shape")
             return _xla_paged_attention(q, k_pages, v_pages, page_table,
                                         seq_lens)
-        try:
-            out = _paged_attention_pallas(q, k_pages, v_pages,
-                                          page_table, seq_lens)
-            bump("paged_attention", "pallas")
-            return out
-        except Exception as e:
-            bump("paged_attention", "xla",
-                 f"kernel error {type(e).__name__}: {e}")
-    else:
-        bump("paged_attention", "xla",
-             f"dispatch ineligible (q {tuple(q.shape)}, page "
-             f"{k_pages.shape[1]}; gate in _paged_ok)")
+        out = _paged_attention_pallas(q, k_pages, v_pages,
+                                      page_table, seq_lens)
+        bump("paged_attention", "pallas")
+        return out
+    bump("paged_attention", "xla",
+         f"dispatch ineligible (q {tuple(q.shape)}, page "
+         f"{k_pages.shape[1]}; gate in _paged_ok)")
     return _xla_paged_attention(q, k_pages, v_pages, page_table, seq_lens)
 
 

@@ -59,11 +59,16 @@ def _xla_sample(logits, noise, temperature, top_k, top_p):
 
 
 # ---------------------------------------------------------------------------
-# Pallas kernel: grid (B,), one logits row per step, fused
-# scale + top-k threshold + Gumbel add + argmax
+# Pallas kernel: grid (B/8,), one sublane tile of 8 logits rows per step
+# ((1, V) row blocks do not lower: Mosaic needs the second-to-last block
+# dim % 8), fused scale + top-k threshold + Gumbel add + argmax
 # ---------------------------------------------------------------------------
+_ROWS = 8
+_LANES = 128
+
+
 def _sample_kernel(l_ref, n_ref, o_ref, *, temperature, top_k):
-    x = l_ref[...].astype(_F32) / temperature          # (1, V)
+    x = l_ref[...].astype(_F32) / temperature          # (8, V)
     if top_k:
         # k-th max by top_k unrolled max+mask rounds (k is static and
         # small — the _sample_ok ceiling)
@@ -76,29 +81,34 @@ def _sample_kernel(l_ref, n_ref, o_ref, *, temperature, top_k):
     y = x + n_ref[...].astype(_F32)
     m = jnp.max(y, axis=1, keepdims=True)
     # first-max index (argmax tie rule) via 2D iota — 1D iota fails on
-    # TPU (pallas guide)
-    idx = jax.lax.broadcasted_iota(jnp.int32, y.shape, 1)
-    cand = jnp.where(y >= m, idx, jnp.int32(2147483647))
-    o_ref[0, 0] = jnp.min(cand)
+    # TPU (pallas guide). The min runs in f32: vocab ids are exact there
+    # (V <= 2^24 by the _sample_ok ceiling) and float lane reductions
+    # lower everywhere integer ones may not.
+    idx = jax.lax.broadcasted_iota(jnp.int32, y.shape, 1).astype(_F32)
+    first = jnp.min(jnp.where(y >= m, idx, 3e38), axis=1, keepdims=True)
+    # lane-dense store: every lane of a row carries that row's token
+    o_ref[...] = jnp.broadcast_to(first, o_ref.shape).astype(jnp.int32)
 
 
 def _fused_sample_pallas(logits, noise, temperature, top_k):
     from jax.experimental import pallas as pl
 
     B, V = logits.shape
+    pad = (-B) % _ROWS
+    if pad:   # dead rows: their draws are sliced away below
+        logits = jnp.pad(logits, ((0, pad), (0, 0)))
+        noise = jnp.pad(noise, ((0, pad), (0, 0)))
+    rows = pl.BlockSpec((_ROWS, V), lambda i: (i, 0))
     out = pl.pallas_call(
         functools.partial(_sample_kernel,
                           temperature=float(temperature),
                           top_k=int(top_k)),
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, V), lambda b: (b, 0)),
-            pl.BlockSpec((1, V), lambda b: (b, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda b: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, 1), jnp.int32),
+        grid=((B + pad) // _ROWS,),
+        in_specs=[rows, rows],
+        out_specs=pl.BlockSpec((_ROWS, _LANES), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((B + pad, _LANES), jnp.int32),
     )(logits, noise)
-    return out[:, 0]
+    return out[:B, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -143,15 +153,10 @@ def fused_sample(logits, noise, temperature, top_k: int = 0,
         if choice == "xla":
             bump("fused_sample", "xla", "autotuned: xla wins this shape")
             return _xla_sample(logits, noise, temperature, top_k, top_p)
-        try:
-            out = _fused_sample_pallas(logits, noise, temperature, top_k)
-            bump("fused_sample", "pallas")
-            return out
-        except Exception as e:
-            bump("fused_sample", "xla",
-                 f"kernel error {type(e).__name__}: {e}")
-    else:
-        bump("fused_sample", "xla",
-             f"dispatch ineligible (logits {tuple(logits.shape)}, "
-             f"top_k={top_k}, top_p={top_p}; gate in _sample_ok)")
+        out = _fused_sample_pallas(logits, noise, temperature, top_k)
+        bump("fused_sample", "pallas")
+        return out
+    bump("fused_sample", "xla",
+         f"dispatch ineligible (logits {tuple(logits.shape)}, "
+         f"top_k={top_k}, top_p={top_p}; gate in _sample_ok)")
     return _xla_sample(logits, noise, temperature, top_k, top_p)

@@ -132,46 +132,20 @@ def quant_decode(payload, scales, codec: str, block: int = QUANT_BLOCK):
     return (qb * scales.reshape(-1, 1)).reshape(-1)
 
 
-# ---------------------------------------------------------------------------
-# shard_map compat (jax.shard_map landed after 0.4; check_rep/check_vma
-# renamed across versions — one resolver, reused by ring.py)
-# ---------------------------------------------------------------------------
-
-
-def shard_map_fn():
+def shard_map_nocheck(fn, mesh, in_specs, out_specs):
+    """shard_map with vma checking OFF: the quantized ring produces
+    outputs that are bitwise-replicated by construction (identical
+    decodes of identical forwarded payloads) but not PROVABLY replicated
+    to jax's vma type system."""
     import jax
 
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn
-    return fn
-
-
-def shard_map_nocheck(fn, mesh, in_specs, out_specs):
-    """shard_map with replication/vma checking OFF: the quantized ring
-    produces outputs that are bitwise-replicated by construction
-    (identical decodes of identical forwarded payloads) but not
-    PROVABLY replicated to jax's rep/vma type system."""
-    sm = shard_map_fn()
-    try:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    except TypeError:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------
 # the quantized ring all-reduce (inside shard_map)
 # ---------------------------------------------------------------------------
-
-
-def _axis_size(axis_name) -> int:
-    import jax
-
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
 
 
 def _pad_to(x, n: int):
@@ -208,7 +182,7 @@ def allreduce_start(x, axis_name: str, *, codec: str = "int8",
     import jax
     import jax.numpy as jnp
 
-    g = axis_size if axis_size is not None else _axis_size(axis_name)
+    g = axis_size if axis_size is not None else jax.lax.axis_size(axis_name)
     shape, dtype = x.shape, x.dtype
     n = int(np.prod(shape)) if shape else 1
     total = padded_len(n, g, block)
@@ -328,7 +302,7 @@ def all_gather(chunk, axis_name: str, *, codec: str = "f32",
     ``quantized_allreduce``'s gather phase."""
     import jax.numpy as jnp
 
-    g = axis_size if axis_size is not None else _axis_size(axis_name)
+    g = axis_size if axis_size is not None else jax.lax.axis_size(axis_name)
     flat = chunk.reshape(-1).astype(jnp.float32)
     n = flat.shape[0] * g
     tag = "done1" if g == 1 else "rs"

@@ -17,8 +17,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from ..framework.bringup import safe_devices as _safe_devices
-
 _global_mesh: list = [None]
 
 AXES = ("dp", "pp", "tp", "sp", "ep")
@@ -38,7 +36,7 @@ def create_mesh(mesh_shape: Optional[Dict[str, int]] = None,
     DCN-reaching axes should be listed first (outermost) so XLA keeps
     high-traffic collectives on ICI.
     """
-    devices = list(devices if devices is not None else _safe_devices())
+    devices = list(devices if devices is not None else jax.devices())
     mesh_shape = dict(mesh_shape or {})
     sized = {k: v for k, v in mesh_shape.items() if v and v > 1}
     total = int(np.prod(list(sized.values()))) if sized else 1
@@ -99,6 +97,20 @@ def active_trace_row_axes() -> tuple:
     return _trace_mesh[0][1]
 
 
+def auto_partitioned_trace() -> bool:
+    """True while tracing a multi-device TrainStep OUTSIDE a full-manual
+    shard_map — where XLA, not the program, partitions each op. A Pallas
+    TPU kernel cannot lower there (jax: "Mosaic kernels cannot be
+    automatically partitioned. Please wrap the call in a shard_map"), so
+    the kernel gates route such dispatches to XLA; inside a shard_map
+    over every mesh axis the same kernels are fine."""
+    mesh = active_trace_mesh()
+    if mesh is None or mesh.size <= 1:
+        return False
+    manual = jax.sharding.get_abstract_mesh().manual_axes
+    return set(manual) != set(mesh.axis_names)
+
+
 def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, PartitionSpec())
 
@@ -134,7 +146,7 @@ def mesh_for_shape(mesh_shape: Dict[str, int],
     prod(sizes) local (or given) devices, cached — repeated calls with
     the same shape return the SAME Mesh object and never touch the
     ambient global mesh."""
-    devices = list(devices if devices is not None else _safe_devices())
+    devices = list(devices if devices is not None else jax.devices())
     sized = {str(k): int(v) for k, v in (mesh_shape or {}).items()
              if int(v) > 1}
     if not sized:

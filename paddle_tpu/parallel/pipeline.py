@@ -121,10 +121,8 @@ def pipeline_apply(stage_fn: Callable, stage_params, x, *,
             jnp.where(s == n_stages - 1, outs, 0.0 * outs), axis)
         return outs
 
-    from .collectives import shard_map_fn
-
-    outs = shard_map_fn()(local, mesh=mesh, in_specs=(p_spec, x_spec),
-                          out_specs=x_spec)(stage_params, xm)
+    outs = jax.shard_map(local, mesh=mesh, in_specs=(p_spec, x_spec),
+                         out_specs=x_spec)(stage_params, xm)
     return outs.reshape(batch, *x.shape[1:])
 
 
@@ -444,17 +442,10 @@ def _build_1f1b_step(stage_fn, first_fn, last_fn, mesh, axis, mb, ba):
             shard_map's varying-axes type, and stage-local values
             genuinely differ per rank. Already-varying axes pass through
             (pcast rejects re-casting them)."""
-            typeof = getattr(jax, "typeof", None)
-            pcast = getattr(jax.lax, "pcast", None)
-            if typeof is None or pcast is None:
-                # jax < 0.7: no varying-manual-axes typing, so there is
-                # nothing to re-cast — values are already usable
-                return t
-
             def one(a):
-                have = set(getattr(typeof(a), "vma", ()))
+                have = jax.typeof(a).vma
                 need = tuple(ax for ax in want_axes if ax not in have)
-                return pcast(a, need, to="varying") if need else a
+                return jax.lax.pcast(a, need, to="varying") if need else a
             return jax.tree_util.tree_map(one, t)
 
         zero_h = vary(jnp.zeros(h_struct.shape, h_struct.dtype))
@@ -561,9 +552,7 @@ def _build_1f1b_step(stage_fn, first_fn, last_fn, mesh, axis, mb, ba):
                 lambda a: jax.lax.pmean(a, ba), (gf, gb, gl))
         return loss, gf, gb, gl
 
-    from .collectives import shard_map_fn
-
-    sharded = shard_map_fn()(
+    sharded = jax.shard_map(
         local, mesh=mesh,
         in_specs=(repl_spec, blocks_spec, repl_spec, data_spec, data_spec),
         out_specs=(repl_spec, repl_spec, blocks_spec, repl_spec))

@@ -47,13 +47,7 @@ define_flag("ring_flash", True,
             "Route each ring-attention step's local block compute through "
             "the Pallas flash kernel (SURVEY hard part f). Eligible shapes "
             "only; False keeps the einsum online-softmax walk everywhere "
-            "(the A/B arm for tools/live_tpu_session.py)")
-
-
-def _axis_size(axis_name):
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+            "(the A/B arm)")
 
 
 # Tests flip this to run interpret-mode Pallas under shard_map: the hlo
@@ -64,17 +58,9 @@ _SHARD_MAP_CHECK_VMA = [True]
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    from .collectives import shard_map_fn
-
-    sm = shard_map_fn()  # jax.shard_map, or the pre-0.6 experimental home
-    if _SHARD_MAP_CHECK_VMA[0]:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    try:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    except TypeError:   # pre-vma jax spells it check_rep
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs,
+                         check_vma=_SHARD_MAP_CHECK_VMA[0])
 
 
 # ---------------------------------------------------------------------------
@@ -275,27 +261,21 @@ def ring_attention_local(q, k, v, axis_name: str, is_causal: bool = False,
     kernels (_ring_flash, FLAGS_ring_flash); the einsum online-softmax
     walk below is the exact fallback for everything else.
     """
-    size = axis_size if axis_size is not None else _axis_size(axis_name)
+    size = (axis_size if axis_size is not None
+            else jax.lax.axis_size(axis_name))
+    from ..ops.pallas.counters import bump
+
     if _ring_flash_eligible(q, k, is_causal):
-        from ..ops.pallas.counters import bump
-
-        try:
-            bias = (jnp.where(kv_mask.astype(jnp.bool_), 0.0,
-                              _NEG_INF).astype(jnp.float32)
-                    if kv_mask is not None else jnp.zeros((), jnp.float32))
-            out = _ring_flash(q, k, v, bias, axis_name, size, is_causal,
-                              kv_mask is not None)
-            bump("ring_attention", "pallas")
-            return out
-        except Exception as e:  # trace/lowering failure: exact fallback
-            bump("ring_attention", "xla",
-                 f"flash-ring error {type(e).__name__}: {e}")
-    else:
-        from ..ops.pallas.counters import bump
-
-        bump("ring_attention", "xla",
-             f"dispatch ineligible (q {tuple(q.shape)}, causal="
-             f"{is_causal}; modulus/shape gate in _ring_flash_eligible)")
+        bias = (jnp.where(kv_mask.astype(jnp.bool_), 0.0,
+                          _NEG_INF).astype(jnp.float32)
+                if kv_mask is not None else jnp.zeros((), jnp.float32))
+        out = _ring_flash(q, k, v, bias, axis_name, size, is_causal,
+                          kv_mask is not None)
+        bump("ring_attention", "pallas")
+        return out
+    bump("ring_attention", "xla",
+         f"dispatch ineligible (q {tuple(q.shape)}, causal="
+         f"{is_causal}; modulus/shape gate in _ring_flash_eligible)")
     idx = jax.lax.axis_index(axis_name)
 
     orig_dtype = q.dtype

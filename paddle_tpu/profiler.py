@@ -79,7 +79,7 @@ _state = {
 #   amp_loss_scaled    fp16 static loss scaling wired through the
 #                      check_finite_and_unscale kernel (1 per build)
 #   disk_cache_hits / disk_cache_misses  jax persistent-compilation-cache
-#                      traffic (PADDLE_COMPILE_CACHE[_DIR]); process
+#                      traffic (static/compile_cache.py); process
 #                      events, merged into exe.counters like the fault
 #                      counters below
 #
@@ -106,8 +106,8 @@ _state = {
 #   pp_stages          GAUGE: pipeline stage count of the last
 #                      pipelined (GPipe-scheduled) build
 #   autotune_disk_hits flash autotune verdicts served from the
-#                      persistent disk cache (PADDLE_COMPILE_CACHE_DIR
-#                      co-location; ops/pallas/autotune.py)
+#                      persistent disk cache (<compile cache>/autotune;
+#                      ops/pallas/autotune.py)
 #   xla_temp_bytes / xla_peak_bytes / xla_argument_bytes /
 #   xla_output_bytes   GAUGES (set_counter, not accumulated): the last
 #                      built executable's compiled.memory_analysis() —
@@ -198,7 +198,7 @@ ELASTIC_COUNTER_NAMES = (
 # process-level compile-cache counters merged into Executor.counters
 # (bumped by the jax monitoring listener in static/compile_cache.py;
 # autotune_disk_hits by ops/pallas/autotune.py — tuned kernel configs
-# persist alongside compiled steps under PADDLE_COMPILE_CACHE_DIR)
+# persist alongside compiled steps under compile_cache.cache_dir())
 COMPILE_COUNTER_NAMES = ("disk_cache_hits", "disk_cache_misses",
                          "autotune_disk_hits")
 

@@ -1,18 +1,34 @@
-"""Disk-persistent XLA compile cache, gated by PADDLE_COMPILE_CACHE[_DIR].
+"""Disk-persistent XLA compile cache, on by default.
 
 The reference pays its 89 IR passes + kernel selection on every process
 start; our executor pays an XLA compile instead. This module makes that
-cost once-per-machine rather than once-per-process: it points jax's
-persistent compilation cache at a directory, so a relaunched trainer
+cost once-per-machine rather than once-per-process: jax's persistent
+compilation cache holds every executable, so a relaunched trainer
 (launch.supervise restart, PR 2) resumes without the cold compile —
 ``lower()`` still traces, but ``compile()`` becomes a disk read.
 
-Knobs:
-  PADDLE_COMPILE_CACHE      "1"/"true" enables with the default dir,
-                            "0"/"false"/"off" force-disables
-  PADDLE_COMPILE_CACHE_DIR  cache directory (implies enable)
+One resolution of where it lives (:func:`cache_dir`), shared with the
+Pallas autotune verdicts (``<dir>/autotune``):
 
-Default dir: ~/.cache/paddle_tpu/xla_cache.
+- ``JAX_COMPILATION_CACHE_DIR`` set: jax already reads it; this module
+  leaves jax's cache-directory config alone.
+- unset: ``<checkout>/.jax_cache`` — a fixed path, because the path is
+  part of what a later process must find again (never ``~/.cache``, a
+  temp dir, a pid or a time).
+
+``JAX_ENABLE_COMPILATION_CACHE=0`` (jax's own switch) turns it off.
+
+Arming the cache also strips the debug locations from every Pallas TPU
+kernel before it is serialized (:func:`_strip_kernel_locations`). jax
+strips locations from the StableHLO it hashes into the cache key, but a
+Mosaic kernel body travels inside the custom call's ``backend_config``,
+which is hashed as is — and its locations name the call site of whichever
+trace first filled jax's inner-jit caches (``jnp.where`` inside a kernel,
+say). A process whose autotuner timed a kernel before the real step was
+traced therefore keyed the same step differently from the next process,
+which read the verdict from disk: measured on a v5e (PR 21), the second
+run missed exactly the steps that held tuned kernels (~50 s of compile
+each), with full tracebacks in locations and with one frame alike.
 
 Cache traffic is observable: a jax monitoring listener bumps the
 profiler counters ``disk_cache_hits`` / ``disk_cache_misses``, which
@@ -22,67 +38,66 @@ reports per row.
 from __future__ import annotations
 
 import os
-from typing import Optional
 
-_state = {"resolved": False, "enabled": False, "dir": None,
-          "listener": False}
+_ENV = "JAX_COMPILATION_CACHE_DIR"
+_CHECKOUT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
-_DISABLE_VALUES = ("0", "false", "off", "no")
-
-
-def cache_dir() -> Optional[str]:
-    """The active cache directory, or None when the cache is off."""
-    return _state["dir"] if _state["enabled"] else None
+_armed = [False]
 
 
-def is_enabled() -> bool:
-    return bool(_state["enabled"])
+def cache_dir() -> str:
+    """The directory compiled executables persist in."""
+    return os.environ.get(_ENV) or _CHECKOUT_DIR
 
 
-def ensure_enabled() -> bool:
-    """Resolve the env knobs once and (maybe) turn the cache on.
+def ensure_enabled() -> None:
+    """Arm the disk cache, once per process.
 
-    Called from Executor/TrainStep construction — every jit compiled
-    after the first executor benefits, including the dygraph TrainStep
-    path. Returns whether the disk cache is active.
-    """
-    if _state["resolved"]:
-        return _state["enabled"]
-    _state["resolved"] = True
-    flag = os.environ.get("PADDLE_COMPILE_CACHE")
-    cdir = os.environ.get("PADDLE_COMPILE_CACHE_DIR")
-    if flag is not None and flag.strip().lower() in _DISABLE_VALUES:
-        return False
-    if flag is None and not cdir:
-        return False
-    cdir = cdir or os.path.join(os.path.expanduser("~"), ".cache",
-                                "paddle_tpu", "xla_cache")
-    try:
-        os.makedirs(cdir, exist_ok=True)
-        import jax
+    Called from Executor/TrainStep/substrate construction — every jit
+    compiled after the first of them benefits."""
+    if _armed[0]:
+        return
+    _armed[0] = True
+    import jax
 
-        jax.config.update("jax_compilation_cache_dir", cdir)
-        # default thresholds skip everything that compiles in under a
-        # second — exactly the small-step regime tests and relaunch
-        # drills live in; cache unconditionally
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:
-        return False
+    if not os.environ.get(_ENV):
+        jax.config.update("jax_compilation_cache_dir", _CHECKOUT_DIR)
+    _strip_kernel_locations()
+    # default thresholds skip everything that compiles in under a
+    # second — exactly the small-step regime warm-ups and relaunch
+    # drills live in; cache unconditionally
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     _install_listener()
-    _state.update(enabled=True, dir=cdir)
-    return True
+
+
+def _strip_kernel_locations() -> None:
+    """Run ``strip-debuginfo`` over each Mosaic module on its way into a
+    ``tpu_custom_call`` (see the module docstring). jax 0.9.0 has no
+    switch for this, so the serializer — a private name — is wrapped; if
+    an upgrade moves it, this raises instead of quietly keying on
+    locations again."""
+    from jax._src import tpu_custom_call
+    from jax._src.lib.mlir import passmanager
+
+    serialize = tpu_custom_call._lower_mosaic_module_to_asm
+
+    def serialize_stripped(module, **kwargs):
+        with module.context:
+            passmanager.PassManager.parse(
+                "builtin.module(strip-debuginfo)").run(module.operation)
+        return serialize(module, **kwargs)
+
+    tpu_custom_call._lower_mosaic_module_to_asm = serialize_stripped
 
 
 def _install_listener() -> None:
     """Bridge jax's /jax/compilation_cache/* monitoring events into the
     profiler counter table (secrets-free: event names only)."""
-    if _state["listener"]:
-        return
-    try:
-        from jax._src import monitoring
-    except Exception:
-        return
+    from jax._src import monitoring
+
     from .. import profiler
 
     def _on_event(event: str, **kwargs) -> None:
@@ -92,12 +107,3 @@ def _install_listener() -> None:
             profiler.bump_counter("disk_cache_misses")
 
     monitoring.register_event_listener(_on_event)
-    _state["listener"] = True
-
-
-def _reset_for_tests() -> None:
-    """Re-arm env resolution (tests flip PADDLE_COMPILE_CACHE* between
-    cases; the listener stays — re-registering would double-count)."""
-    _state["resolved"] = False
-    _state["enabled"] = False
-    _state["dir"] = None

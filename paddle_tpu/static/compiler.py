@@ -18,7 +18,6 @@ from typing import Optional
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from ..framework.bringup import safe_devices as _safe_devices
 from .ir import Program
 
 
@@ -213,7 +212,7 @@ class CompiledProgram:
         self._mesh = get_mesh()
         if self._mesh is None or not any(
                 a in self._mesh.axis_names for a in DATA_AXIS_NAMES):
-            n = len(places) if places else len(_safe_devices())
+            n = len(places) if places else len(jax.devices())
             self._mesh = create_mesh({"data": n})
         return self
 

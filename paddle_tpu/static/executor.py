@@ -23,9 +23,9 @@ sharding, donation), held in a process-global table — so
 Program.clone()/parse_from_string() copies, and a second Executor in the
 same process, all hit the same entry (the reference's
 ExecutorPrepareContext cache was per-executor and identity-keyed). A
-per-program weak-keyed fast path avoids re-hashing on every step. With
-PADDLE_COMPILE_CACHE[_DIR] set, compilation additionally goes through
-jax's disk-persistent cache (compile_cache.py), so a relaunched trainer
+per-program weak-keyed fast path avoids re-hashing on every step.
+Compilation additionally goes through jax's disk-persistent cache
+(compile_cache.py), so a relaunched trainer
 skips the cold compile; the executor AOT-splits jit into lower()
 (trace_ms) and compile() (compile_ms) so both phases are measurable.
 """
@@ -387,7 +387,7 @@ class Executor:
         self._cache = weakref.WeakKeyDictionary()
         self._step = 0
         from .compile_cache import ensure_enabled
-        ensure_enabled()  # PADDLE_COMPILE_CACHE[_DIR] disk cache, once
+        ensure_enabled()  # disk compile cache, once
         self._donate = bool(donate_state)
         # last executable this executor dispatched — memory_stats() and
         # the xla_*_bytes gauges read its compiled.memory_analysis()
@@ -1054,7 +1054,7 @@ class Executor:
         """AOT-compile one step: jit -> lower() (trace_ms) -> compile()
         (compile_ms). The split makes trace vs XLA-compile time
         measurable, and compile() goes through jax's persistent
-        compilation cache when PADDLE_COMPILE_CACHE[_DIR] is set — a
+        compilation cache (compile_cache.py) — a
         relaunched trainer's cold build becomes a disk read
         (disk_cache_hits in exe.counters).
 

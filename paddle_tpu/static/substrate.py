@@ -13,8 +13,7 @@ engine's prefill/decode executables — funnels through
   and explicit in/out shardings (GSPMD boundary maps, PR 10)
 - the lower()/compile() AOT split, so trace time and XLA-compile time
   stay separately measurable (``trace_ms`` / ``compile_ms`` counters)
-- the persistent disk compile cache (``PADDLE_COMPILE_CACHE[_DIR]``,
-  compile_cache.py) armed before the first compile, so a relaunched
+- the persistent disk compile cache (compile_cache.py) armed before the first compile, so a relaunched
   process pays a disk read instead of a cold build
 """
 from __future__ import annotations
@@ -81,7 +80,7 @@ def aot_compile(step_fn: Callable, example_args: Tuple[Any, ...], *,
 
     from .compile_cache import ensure_enabled
 
-    ensure_enabled()  # PADDLE_COMPILE_CACHE[_DIR] disk cache, idempotent
+    ensure_enabled()  # disk compile cache, idempotent
     jit_kwargs = {}
     if donate_argnums:
         jit_kwargs["donate_argnums"] = tuple(donate_argnums)

@@ -21,8 +21,6 @@ def summary_env(print_out: bool = False):
     try:
         import jax
 
-        from ..framework.bringup import safe_devices as _safe_devices
-
         info["jax"] = jax.__version__
         try:
             import jaxlib
@@ -31,7 +29,7 @@ def summary_env(print_out: bool = False):
         except ImportError:
             pass
         try:
-            devs = _safe_devices()
+            devs = jax.devices()
             info["backend"] = jax.default_backend()
             info["devices"] = ", ".join(
                 f"{d.platform}:{d.id}({getattr(d, 'device_kind', '?')})"

@@ -1,7 +1,7 @@
 """Attention dispatch autotune (FLAGS_cudnn_exhaustive_search parity):
-selection, caching, fallback, and dispatch wiring. Real on-device
-timing is exercised by tools/live_tpu_session.py; here the timer is
-stubbed and kernels run in interpret mode."""
+selection, caching, failure, and dispatch wiring. Real on-device
+timing is exercised by chip_smoke.py; here the timer is stubbed and
+kernels run in interpret mode."""
 import functools
 
 import jax
@@ -19,7 +19,7 @@ def _reset(monkeypatch, tmp_path):
     # point the persistent verdict cache at a per-test dir so a warm
     # disk cache from a previous run can't satisfy a lookup the test
     # expects to re-time
-    monkeypatch.setenv("PADDLE_TPU_AUTOTUNE_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     autotune.reset()
     counters.reset()
     yield
@@ -57,7 +57,7 @@ def test_selection_picks_min_and_caches(monkeypatch, interpret_pallas):
     # short, xla
     times = iter([3.0, 1.0])
 
-    def fake_timeit(fn, *args, iters=0, vary_arg=-1):
+    def fake_timeit(fn, *args, iters=0):
         calls.append(fn)
         return next(times)
 
@@ -77,13 +77,17 @@ def test_selection_picks_min_and_caches(monkeypatch, interpret_pallas):
     assert autotune.short_window_choice(q2, q2, False, 0.0) == "short"
 
 
-def test_failed_candidates_are_skipped(monkeypatch, interpret_pallas):
+def test_failed_candidate_raises_and_pins_nothing(monkeypatch,
+                                                  interpret_pallas):
+    """Every candidate passed its shape gate, so one that fails to
+    compile is an error — not a loss to XLA — and no verdict is cached
+    in memory or on disk."""
     import paddle_tpu.utils.timing as timing
 
     monkeypatch.setattr(bringup, "pallas_enabled", lambda: True)
     monkeypatch.setattr(bringup, "TPU_PLATFORMS", ("cpu", "tpu"))
 
-    def exploding_timeit(fn, *args, iters=0, vary_arg=-1):
+    def exploding_timeit(fn, *args, iters=0):
         if exploding_timeit.n == 0:
             exploding_timeit.n += 1
             raise RuntimeError("mosaic says no")
@@ -92,7 +96,12 @@ def test_failed_candidates_are_skipped(monkeypatch, interpret_pallas):
     exploding_timeit.n = 0
     monkeypatch.setattr(timing, "timeit", exploding_timeit)
     q = _q(l=128)
-    assert autotune.short_window_choice(q, q, False, 0.0) == "xla"
+    with pytest.raises(RuntimeError, match="mosaic says no"):
+        autotune.short_window_choice(q, q, False, 0.0)
+    assert autotune.cached_choices() == {}
+    import os
+
+    assert not os.path.exists(autotune._disk_path())
 
 
 def test_dispatch_routes_on_choice(monkeypatch, interpret_pallas):
@@ -116,16 +125,6 @@ def test_dispatch_routes_on_choice(monkeypatch, interpret_pallas):
     assert snap.get("flash_attention.xla", 0) == 1
     np.testing.assert_allclose(np.asarray(out2), np.asarray(ref),
                                rtol=1e-6)
-
-
-def test_autotune_error_keeps_static_dispatch(monkeypatch):
-    monkeypatch.setattr(bringup, "pallas_enabled", lambda: True)
-    monkeypatch.setattr(bringup, "TPU_PLATFORMS", ("cpu", "tpu"))
-    monkeypatch.setattr(
-        autotune, "best_short_window_impl",
-        lambda *a, **k: (_ for _ in ()).throw(RuntimeError("boom")))
-    q = _q(l=128)
-    assert autotune.short_window_choice(q, q, False, 0.0) is None
 
 
 def test_disk_persistence_skips_retiming(monkeypatch, interpret_pallas):
@@ -181,28 +180,32 @@ def test_disk_cache_survives_corruption(monkeypatch, interpret_pallas,
     assert autotune.short_window_choice(q, q, False, 0.0) == "xla"
 
 
-def test_all_failed_leaves_cache_empty(monkeypatch, interpret_pallas):
-    import paddle_tpu.utils.timing as timing
-
+def test_tuner_runs_under_an_outer_jit_trace(monkeypatch, interpret_pallas):
+    """Dispatch decisions are taken at trace time: the tuner must run
+    its candidates eagerly on concrete inputs even while the dispatch
+    site is being traced (a ConcretizationTypeError here used to be
+    swallowed into 'static dispatch keeps')."""
     monkeypatch.setattr(bringup, "pallas_enabled", lambda: True)
     monkeypatch.setattr(bringup, "TPU_PLATFORMS", ("cpu", "tpu"))
+    seen = []
 
-    def always_fail(fn, *args, **kw):
-        raise RuntimeError("tunnel blip")
+    @jax.jit
+    def f(q):
+        seen.append(autotune.short_window_choice(q, q, False, 0.0))
+        return q
 
-    monkeypatch.setattr(timing, "timeit", always_fail)
-    q = _q(l=128)
-    assert autotune.short_window_choice(q, q, False, 0.0) is None
-    assert autotune.cached_choices() == {}, (
-        "a transient failure must not pin a process-wide verdict")
+    f(_q(l=128))
+    assert seen and seen[0] in ("short", "xla")
+    assert autotune.stats()["timed"] == 1
 
 
 def test_compile_cache_dir_colocates_and_counts(monkeypatch,
                                                interpret_pallas,
                                                tmp_path):
-    """With no explicit autotune dir, verdicts persist under
-    PADDLE_COMPILE_CACHE_DIR/autotune — tuned configs relaunch alongside
-    the compiled steps — and a disk hit bumps the process-global
+    """Verdicts persist under <compile cache dir>/autotune — tuned
+    configs relaunch alongside the compiled steps, under
+    JAX_COMPILATION_CACHE_DIR when set and the fixed in-checkout
+    directory otherwise — and a disk hit bumps the process-global
     autotune_disk_hits counter (COMPILE_COUNTER_NAMES slice)."""
     import os
 
@@ -211,8 +214,12 @@ def test_compile_cache_dir_colocates_and_counts(monkeypatch,
 
     monkeypatch.setattr(bringup, "pallas_enabled", lambda: True)
     monkeypatch.setattr(bringup, "TPU_PLATFORMS", ("cpu", "tpu"))
-    monkeypatch.delenv("PADDLE_TPU_AUTOTUNE_CACHE_DIR", raising=False)
-    monkeypatch.setenv("PADDLE_COMPILE_CACHE_DIR",
+    from paddle_tpu.static import compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert autotune._cache_dir() == os.path.join(
+        compile_cache._CHECKOUT_DIR, "autotune")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
                        str(tmp_path / "xla_cache"))
     autotune.reset()
     assert autotune._cache_dir() == str(tmp_path / "xla_cache" /

@@ -1,7 +1,7 @@
 """In-kernel dropout flash attention — TPU-only checks (the Pallas PRNG
-has no CPU interpreter path; tests/conftest.py forces CPU, so this file
-self-gates and is exercised by running pytest with the default TPU env:
-`PYTHONPATH=/root/repo python -m pytest tests/test_flash_dropout_tpu.py`).
+has no CPU interpreter path). Self-gates; runs on a chip with
+`JAX_PLATFORMS=tpu python -m pytest tests/test_flash_dropout_tpu.py`
+(tests/conftest.py forces the CPU otherwise).
 """
 import numpy as np
 import pytest

@@ -1,8 +1,8 @@
 """Short single-block flash kernel — TPU-only hardware checks (the
 in-kernel PRNG dropout has no CPU interpreter path, and real-Mosaic
 lowering is exactly what the r3 fused-embedding bug showed interpret
-mode cannot vouch for). Self-gates; run with the default TPU env:
-`PYTHONPATH=/root/repo python -m pytest tests/test_flash_short_tpu.py`.
+mode cannot vouch for). Self-gates; runs on a chip with
+`JAX_PLATFORMS=tpu python -m pytest tests/test_flash_short_tpu.py`.
 """
 import numpy as np
 import pytest

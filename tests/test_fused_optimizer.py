@@ -21,7 +21,7 @@ def _reset(monkeypatch, tmp_path):
     # hermetic dispatch: no stale escape env, per-test autotune cache
     monkeypatch.delenv("PADDLE_FUSED_OPT", raising=False)
     monkeypatch.delenv("PADDLE_FUSED_OPT_INTERPRET", raising=False)
-    monkeypatch.setenv("PADDLE_TPU_AUTOTUNE_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     autotune.reset()
     counters.reset()
     yield

@@ -129,7 +129,7 @@ def test_ineligible_vocab_falls_back(monkeypatch):
     assert counters.snapshot().get("fused_sample.xla", 0) == 1
 
 
-def test_kernel_error_falls_back(monkeypatch):
+def test_kernel_error_propagates(monkeypatch):
     monkeypatch.setattr(bringup, "pallas_enabled", lambda: True)
 
     def boom(*a, **k):
@@ -137,10 +137,9 @@ def test_kernel_error_falls_back(monkeypatch):
 
     monkeypatch.setattr(sm, "_fused_sample_pallas", boom)
     logits, noise = _rows()
-    out = np.asarray(sm.fused_sample(logits, noise, 0.8, top_k=2))
-    ref = np.asarray(sm._xla_sample(logits, noise, 0.8, 2, 1.0))
-    assert (out == ref).all()
-    assert counters.snapshot().get("fused_sample.xla", 0) == 1
+    with pytest.raises(RuntimeError, match="mosaic said no"):
+        sm.fused_sample(logits, noise, 0.8, top_k=2)
+    assert counters.snapshot().get("fused_sample.xla", 0) == 0
 
 
 def test_escape_env_pins_xla_bitwise(monkeypatch):

@@ -26,6 +26,9 @@ def interp(monkeypatch):
     monkeypatch.setattr(pl, "pallas_call",
                         functools.partial(pl.pallas_call, interpret=True))
     monkeypatch.setattr(bringup, "pallas_enabled", lambda: True)
+    # the open backend gate would also engage the fused optimizer kernels
+    # in the TrainStep tests, and those pass interpret=False explicitly
+    monkeypatch.setenv("PADDLE_FUSED_OPT", "0")
     counters.reset()
     yield
     counters.reset()
@@ -320,3 +323,54 @@ def test_multi_device_trainstep_shards_fused_path(interp, monkeypatch):
     loss_single = float(build(None)(ids, tt, mlm, nsp).numpy())
     assert counters.snapshot().get("fused_xent.pallas", 0) >= 1
     np.testing.assert_allclose(loss_dp, loss_single, rtol=1e-4)
+
+
+def test_sharded_wrapper_under_check_vma(monkeypatch):
+    """The shard_map wrapper with check_vma ON (what a real chip runs —
+    the interpret-mode tests above switch it off): W and bias enter
+    replicated over the row axes, each shard's dW/db is a partial sum,
+    and the custom_vjp must type-check AND add the partials up. The two
+    pallas_call plumbing functions are stood in by jnp math so the
+    wrapper itself is what runs."""
+    from jax.sharding import Mesh
+
+    from paddle_tpu.ops.pallas import fused_xent as fx
+
+    def fwd_call(h, w, bias, labels, bn, bv):
+        logits = h @ w.T + bias
+        hit = jnp.arange(w.shape[0])[None, :] == labels[:, None]
+        return (jax.scipy.special.logsumexp(logits, axis=-1),
+                jnp.sum(jnp.where(hit, logits, 0.0), axis=1))
+
+    def bwd_call(h, w, bias, labels, lse, g, bn, bv):
+        hit = jnp.arange(w.shape[0])[None, :] == labels[:, None]
+        p = (jnp.exp(h @ w.T + bias - lse[:, None]) - hit) * g[:, None]
+        return p @ w, p.T @ h, jnp.sum(p, 0)
+
+    monkeypatch.setattr(fx, "_fwd_call", fwd_call)
+    monkeypatch.setattr(fx, "_bwd_call", bwd_call)
+    n, hd, v = 1024, 128, 256
+    h = jax.random.normal(jax.random.key(0), (n, hd)) * 0.3
+    w = jax.random.normal(jax.random.key(1), (v, hd)) * 0.3
+    b = jax.random.normal(jax.random.key(2), (v,)) * 0.1
+    lab = jax.random.randint(jax.random.key(3), (n,), 0, v)
+    lab = lab.at[::5].set(-100)
+
+    def ref(h, w, b):
+        logits = h @ w.T + b
+        valid = lab != -100
+        lse = jax.scipy.special.logsumexp(logits, -1)
+        ll = jnp.take_along_axis(
+            logits, jnp.where(valid, lab, 0)[:, None], 1)[:, 0]
+        return jnp.sum(jnp.where(valid, lse - ll, 0)) / jnp.sum(valid)
+
+    want = jax.value_and_grad(ref, argnums=(0, 1, 2))(h, w, b)
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("dp", "tp"))
+    for axes in (("dp",), ("dp", "tp")):
+        got = jax.jit(lambda h, w, b: jax.value_and_grad(
+            lambda h, w, b: fx._sharded_fused(h, w, b, lab, mesh, axes,
+                                              -100),
+            argnums=(0, 1, 2))(h, w, b))(h, w, b)
+        np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+        for g, r in zip(got[1], want[1]):
+            np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-6)

@@ -1,7 +1,8 @@
 """Fused linear+cross-entropy — TPU-only hardware checks: real Mosaic
 lowering of the 2D-grid reduction idiom (output-ref accumulators
 revisited across the inner vocab axis) and fwd+bwd numerics at the
-real MLM-head scale. Self-gates; run with the default TPU env."""
+real MLM-head scale. Self-gates; runs on a chip with
+`JAX_PLATFORMS=tpu python -m pytest tests/test_fused_xent_tpu.py`."""
 import numpy as np
 import pytest
 

@@ -424,7 +424,8 @@ print("COUNTERS", c.get("disk_cache_hits", 0), c.get("disk_cache_misses", 0))
 def test_disk_cache_warm_process_hits(tmp_path):
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["PADDLE_COMPILE_CACHE_DIR"] = str(tmp_path / "xla")
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "xla")
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "1"   # conftest turns it off
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
 
     def run():

@@ -7,7 +7,8 @@ One real chip cannot rotate a >1 ring, so the shard_map here is a
 1-device mesh: the custom_vjp, the switch diagonal branch, and both
 backward kernels still lower and execute for real; multi-device
 numerics are pinned by tests/test_ring_flash.py on the 8-device CPU
-mesh. Self-gates; run with the default TPU env.
+mesh. Self-gates; runs on a chip with
+`JAX_PLATFORMS=tpu python -m pytest tests/test_ring_flash_tpu.py`.
 """
 import numpy as np
 import pytest

@@ -3,6 +3,10 @@
 parameter-server kill-a-primary (ISSUE 8, ``--ps``), and fleet decode
 serving kill-an-engine (ISSUE 17, ``--fleet``).
 
+All three start several JAX processes on one host, so they are CPU
+drills (children default to ``JAX_PLATFORMS=cpu``): a chip belongs to
+one process at a time and nothing here confines a child to a device.
+
 Fleet drill (``--fleet``): N decode engines come up as subprocesses,
 each behind its ``DecodeEngineServer`` HTTP surface; a ``FleetRouter``
 sprays deterministic traffic over them, then SIGKILLs the engine a

@@ -25,10 +25,10 @@ import numpy as np
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-def _timeit(fn, *args, iters=20, vary=-1):
-    from tools._timing import timeit
+def _timeit(fn, *args, iters=20):
+    from paddle_tpu.utils.timing import timeit
 
-    return timeit(fn, *args, iters=iters, vary_arg=vary)
+    return timeit(fn, *args, iters=iters)
 
 
 def bench_matmul(smoke):
@@ -215,7 +215,7 @@ def bench_optimizer_update(smoke):
         up, state = opt.update(g, state, p)
         return optax.apply_updates(p, up), state
 
-    ms = _timeit(step, p, g, state, iters=10, vary=1)  # vary the grads
+    ms = _timeit(step, p, g, state, iters=10)
     return {"op": "adamw_update", "shape": f"{n}", "ms": ms,
             "gbps": p.nbytes * 5 / (ms / 1e3) / 1e9}
 
@@ -292,12 +292,10 @@ def run_benches(ops=None, smoke=None):
     if smoke is None:
         smoke = os.environ.get("BENCH_SMOKE") == "1"
 
-    from paddle_tpu.framework.bringup import ensure_backend
-
-    backend = ensure_backend()
     global jax
     import jax
 
+    backend = jax.default_backend()
     kind = jax.devices()[0].device_kind
     rows = []
     for name in (ops or list(BENCHES)):

@@ -106,13 +106,14 @@ def main():
                          "table to (e.g. XPLANE_SUMMARY.md)")
     args = ap.parse_args()
 
-    from paddle_tpu.framework.bringup import TPU_PLATFORMS, ensure_backend
+    import jax
 
-    backend = ensure_backend()
+    from paddle_tpu.framework.bringup import TPU_PLATFORMS
+
+    backend = jax.default_backend()
     if backend not in TPU_PLATFORMS:
         print(f"backend {backend!r}: profiling a CPU run is not useful")
         return 1
-    import jax
 
     import bench
 

@@ -17,21 +17,16 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from paddle_tpu.framework.bringup import TPU_PLATFORMS, ensure_backend  # noqa: E402
+from paddle_tpu.framework.bringup import TPU_PLATFORMS  # noqa: E402
 
 import jax  # noqa: E402  (importing jax does not init a backend)
 import jax.numpy as jnp  # noqa: E402
 
 
 def _time(fn, args, steps):
-    # shared methodology (tools/_timing.py): host-fetch completion
-    # forcing + per-iteration value-distinct inputs — the remote plugin
-    # neither honors block_until_ready nor reliably re-executes
-    # value-identical dispatches. q is the varied argument (the seed, if
-    # present, is a constant int and immune to perturbation).
-    from tools._timing import timeit
+    from paddle_tpu.utils.timing import timeit
 
-    return timeit(fn, *args, iters=steps, vary_arg=0)
+    return timeit(fn, *args, iters=steps)
 
 
 def main():
@@ -50,7 +45,7 @@ def main():
                          "at seq 512 though it loses the fwd-only race)")
     ns = ap.parse_args()
 
-    backend = ensure_backend()
+    backend = jax.default_backend()
     if backend not in TPU_PLATFORMS:
         print(json.dumps({"error": f"needs a TPU backend, got {backend}"}))
         return
