@@ -301,8 +301,11 @@ class TrainStep:
         loss_fn = self.loss_fn
         optimizer = self.optimizer
         model = self.model
+        from .ops.pallas import counters
 
-        def pure_step(params, buffers, opt_state, lr, batch):
+        # the name is the XLA module's (``jit_train_step``), the key of
+        # compile_cache.seconds_by_function() and of counters.step_work()
+        def train_step(params, buffers, opt_state, lr, batch):
             step_idx = opt_state["step"]
 
             def loss_of(params):
@@ -319,7 +322,8 @@ class TrainStep:
                                     mesh=self._mesh)
                           if self._sequence_parallel else nullcontext())
                 try:
-                    with tape_mod.no_grad(), rng_scope(key), sp_ctx:
+                    with tape_mod.no_grad(), rng_scope(key), sp_ctx, \
+                            jax.named_scope("loss"):
                         out = loss_fn(model, *[_wrap_in(b) for b in batch])
                     loss = out[0] if isinstance(out, (tuple, list)) else out
                     aux = out[1:] if isinstance(out, (tuple, list)) else ()
@@ -341,11 +345,18 @@ class TrainStep:
             # shard over), for the loss AND the update: Pallas kernels
             # outside a shard_map consult it to shard_map themselves
             # (fused_xent) or to self-gate (flash, fused optimizer)
-            with trace_mesh(self._mesh, self._batch_row_axes()):
-                (loss, (new_buffers, aux)), grads = jax.value_and_grad(
-                    loss_of, has_aux=True)(params)
-                new_params, new_opt_state = optimizer.apply_gradients_fn(
-                    grads, params, opt_state, lr)
+            # this body runs when jax traces it: once for each compiled
+            # step, so what the kernels' dispatches declare inside is the
+            # work of one execution (counters.step_work("train_step"))
+            with trace_mesh(self._mesh, self._batch_row_axes()), \
+                    counters.capture("train_step"):
+                with counters.differentiated():
+                    (loss, (new_buffers, aux)), grads = jax.value_and_grad(
+                        loss_of, has_aux=True)(params)
+                with jax.named_scope("optimizer"):
+                    new_params, new_opt_state = \
+                        optimizer.apply_gradients_fn(
+                            grads, params, opt_state, lr)
             if self._pinned is not None:
                 # hand params and slots back in the placement they came
                 # in with. Left free, XLA returns whatever its sharding
@@ -364,9 +375,11 @@ class TrainStep:
         # parameter/moment buffers in place instead of allocating a fresh
         # set per step. donate=False keeps every input buffer readable.
         jit_kwargs = {"donate_argnums": (0, 2)} if self._donate else {}
-        self._compiled = jax.jit(pure_step, **jit_kwargs)
+        self._compiled = jax.jit(train_step, **jit_kwargs)
 
-    def __call__(self, *batch):
+    def _inputs(self, batch):
+        """(params, buffers, lr, batch arrays) of a call on ``batch``;
+        builds the step and the optimizer state the first time."""
         model = self.model
         params = {n: p.value for n, p in model.named_parameters()
                   if p.trainable}
@@ -385,11 +398,31 @@ class TrainStep:
         if self._lr_cache is None or self._lr_cache[0] != lr_val:
             self._lr_cache = (lr_val, jnp.asarray(lr_val, jnp.float32))
             profiler.bump_counter("h2d_bytes", 4)
-        lr = self._lr_cache[1]
         batch_arrays = tuple(
             _tree.tree_map(_unwrap_out, b,
                            is_leaf=lambda x: isinstance(x, Tensor))
             for b in batch)
+        return params, buffers, self._lr_cache[1], batch_arrays
+
+    def lower(self, *batch):
+        """The ``jax.stages.Lowered`` of this step for ``batch``, nothing
+        run or donated. Its ``as_text(debug_info=True)`` holds the named
+        scopes (``loss``, ``optimizer``, the layers' names); its
+        ``compile().as_text()`` the device's instruction names, each with
+        the ``op_name`` it came from (``tools/profile_step.py`` joins a
+        device trace on them)."""
+        params, buffers, lr, batch_arrays = self._inputs(batch)
+        if self._mesh is not None:
+            params, buffers, batch_arrays = self._place_spmd(
+                params, buffers, batch_arrays)
+        return self._compiled.lower(params, buffers, self._opt_state, lr,
+                                    batch_arrays)
+
+    def __call__(self, *batch):
+        from . import profiler
+
+        model = self.model
+        params, buffers, lr, batch_arrays = self._inputs(batch)
         sig = tuple(
             (tuple(getattr(a, "shape", ())), str(getattr(a, "dtype", "")))
             for a in _tree.tree_leaves(batch_arrays))

@@ -51,7 +51,7 @@ class LayerList(Layer):
         layers.insert(index, sublayer)
         self._sub_layers.clear()
         for i, l in enumerate(layers):
-            self._sub_layers[str(i)] = l
+            self.add_sublayer(str(i), l)
 
     def extend(self, sublayers):
         for l in sublayers:
@@ -64,7 +64,7 @@ class LayerList(Layer):
         return self._sub_layers[str(idx if idx >= 0 else len(self) + idx)]
 
     def __setitem__(self, idx, layer):
-        self._sub_layers[str(idx)] = layer
+        self.add_sublayer(str(idx), layer)
 
     def __len__(self):
         return len(self._sub_layers)

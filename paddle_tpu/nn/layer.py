@@ -65,6 +65,15 @@ class Parameter(Tensor):
 _name_counters = {}
 
 
+def _scope_name(name) -> str:
+    """The ``jax.named_scope`` of a sublayer registered under ``name``:
+    the name itself, and ``layer`` for an index in a container
+    (``layers.3``), so that a profile groups the twelve encoder layers
+    under one ``encoder/layer`` and no scope holds a layer index."""
+    name = str(name)
+    return "layer" if name.isdigit() else name
+
+
 def _unique_name(prefix):
     n = _name_counters.get(prefix, 0)
     _name_counters[prefix] = n + 1
@@ -105,6 +114,8 @@ class Layer:
 
     def add_sublayer(self, name, sublayer):
         self._sub_layers[str(name)] = sublayer
+        if sublayer is not None:
+            object.__setattr__(sublayer, "_scope", _scope_name(name))
         return sublayer
 
     def register_buffer(self, name, tensor, persistable=True):
@@ -126,6 +137,7 @@ class Layer:
         elif isinstance(value, Layer) and layers is not None:
             layers[name] = value
             params.pop(name, None)
+            object.__setattr__(value, "_scope", _scope_name(name))
         else:
             if params is not None and name in params and value is None:
                 params[name] = None
@@ -156,7 +168,15 @@ class Layer:
             result = hook(self, inputs)
             if result is not None:
                 inputs = result if isinstance(result, tuple) else (result,)
-        out = self.forward(*inputs, **kwargs)
+        # the name its parent registered it under: metadata of the ops
+        # traced inside (``bert/encoder/layer/self_attn``), nothing at
+        # step time; a root layer has none
+        scope = self.__dict__.get("_scope")
+        if scope is None:
+            out = self.forward(*inputs, **kwargs)
+        else:
+            with jax.named_scope(scope):
+                out = self.forward(*inputs, **kwargs)
         for hook in self._forward_post_hooks.values():
             result = hook(self, inputs, out)
             if result is not None:

@@ -23,6 +23,7 @@ import json
 import os
 import sys
 import tempfile
+import time
 from typing import Callable, Dict, Tuple
 
 from ...framework.flags import define_flag, get_flag
@@ -64,7 +65,7 @@ _ITERS = 8
 # autotune_disk_hits profiler counter. Write-through on every new
 # verdict.
 _disk: Dict[str, str] | None = None
-_stats = {"mem_hits": 0, "disk_hits": 0, "timed": 0}
+_stats = {"mem_hits": 0, "disk_hits": 0, "timed": 0, "timed_s": 0.0}
 
 
 def _cache_dir() -> str:
@@ -122,16 +123,18 @@ def cached_choices() -> Dict[tuple, str]:
     return dict(_cache)
 
 
-def stats() -> Dict[str, int]:
-    """Hit/miss counters for bench rows: 'timed' is the number of
-    on-chip timing rounds this process actually paid for."""
+def stats() -> Dict[str, float]:
+    """Hit/miss counters: 'timed' is the number of on-chip timing
+    rounds this process actually paid for and 'timed_s' their wall
+    seconds, candidates' compiles included (part of a run's set-up: the
+    benchmark reads it as ``autotune_s.train``)."""
     return dict(_stats)
 
 
 def reset(disk: bool = False) -> None:
     global _disk
     _cache.clear()
-    _stats.update(mem_hits=0, disk_hits=0, timed=0)
+    _stats.update(mem_hits=0, disk_hits=0, timed=0, timed_s=0.0)
     _disk = None
     if disk:
         try:
@@ -163,14 +166,19 @@ def _verdict(key: tuple, label: str, impls: Tuple[str, ...],
         return hit
 
     from ...utils.timing import timeit
+    from .counters import capture
 
     # eval_context, not ensure_compile_time_eval: the latter also
     # constant-folds INSIDE the candidates' own traces, where a Pallas
-    # kernel body may not capture the folded constants
-    with jax.core.eval_context():
+    # kernel body may not capture the folded constants. capture(None):
+    # the candidates run beside the step being traced, not in it, so
+    # their work stays out of that step's ledger
+    t0 = time.perf_counter()
+    with jax.core.eval_context(), capture(None):
         candidates, arg = build()
         times = {name: timeit(fn, arg, iters=_ITERS)
                  for name, fn in candidates.items()}
+    _stats["timed_s"] += time.perf_counter() - t0
     winner = min(times, key=times.get)
     sys.stderr.write(
         f"{label} autotune {key}: "

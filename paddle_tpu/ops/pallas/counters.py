@@ -1,23 +1,39 @@
-"""Trace-time dispatch counters for the custom Pallas kernels.
+"""Trace-time dispatch counters, kernel names and the work ledger of the
+custom Pallas kernels.
 
-VERDICT r3 weak #4/#8: the silent try/except fallback around the fused
-embedding kernel hid a real lowering bug for a full round, and bench.py
-had no way to report whether the flash kernel actually engaged. Every
-kernel dispatch site now bumps a counter — ``<kernel>.pallas`` when the
-custom kernel runs, ``<kernel>.xla`` (with a reason) when the XLA path
-is taken — and ``FLAGS_log_pallas_fallback=True`` additionally writes
-each fallback to stderr.
+Every kernel dispatch site bumps a counter — ``<kernel>.pallas`` when
+the custom kernel runs, ``<kernel>.xla`` (with a reason) when the XLA
+path is taken — and ``FLAGS_log_pallas_fallback=True`` additionally
+writes each fallback to stderr. A silent try/except fallback once hid a
+real lowering bug for a whole round; nothing falls back silently now.
 
 Counts are per DISPATCH DECISION (trace time under jit — once per
-compilation, not per step; every call in eager mode). bench.py snapshots
-before/after a config and reports the delta, so ``pallas_fallback`` in
-its rows reflects reality rather than only compile exceptions.
+compilation, not per step; every call in eager mode). The benchmark
+prints ``snapshot()`` in the log of every run (``benchmarks/run.py``,
+the ``pallas counters`` line), and ``chip_smoke.py`` reports deltas.
+
+**Names.** A kernel is launched through :func:`kernel_call` under its
+ROLE name (``fused_xent_fwd``, ``flash_attention_short_bwd``,
+``fused_adamw``, ...). That name is what a device trace shows
+(``kernel:<role>``) and what the benchmark's readers key on, so it holds
+the kernel's family and never a layer index.
+
+**Work.** The dispatch site also declares the work the call requires:
+matmul FLOPs of the mathematical operation (recomputation not counted,
+every row the kernel is handed counted) and the bytes it must read and
+write once, per role, from the shapes in hand. While a compiled step is
+traced inside :func:`capture`, the declared work is summed by role and
+kept as the work of ONE execution of that step:
+``step_work("train_step")`` -> ``{role: {"calls", "flops", "bytes"}}``.
+Kernel time from a device trace over that work is the kernel's roofline
+share (``benchmarks/kernel_rows.py``).
 """
 from __future__ import annotations
 
 import collections
+import contextlib
 import sys
-from typing import Dict
+from typing import Dict, Mapping, Optional, Tuple
 
 from ...framework.flags import define_flag, get_flag
 
@@ -25,16 +41,102 @@ define_flag("log_pallas_fallback", False,
             "Log every Pallas-kernel fallback to the XLA path with its "
             "reason (dispatch decisions are trace-time)")
 
+#: {role: (flops, bytes)} of one call
+Work = Mapping[str, Tuple[float, float]]
+
 _COUNTS: collections.Counter = collections.Counter()
+#: the capture in progress: role -> [calls, flops, bytes]; None outside
+_capture: Optional[Dict[str, list]] = None
+#: whether the trace being captured is differentiated right now
+_differentiated = False
+#: step name -> the work of one execution, from its latest trace
+_STEP_WORK: Dict[str, Dict[str, Dict[str, float]]] = {}
 
 
-def bump(kernel: str, path: str, reason: str = "") -> None:
+def bump(kernel: str, path: str, reason: str = "",
+         work: Optional[Work] = None,
+         grad_work: Optional[Work] = None) -> None:
+    """Count one dispatch decision. ``work`` is what the call requires,
+    ``grad_work`` what its backward requires on top when the enclosing
+    trace differentiates it (:func:`differentiated`); both only count
+    inside a :func:`capture`."""
     _COUNTS[f"{kernel}.{path}"] += 1
     if path != "pallas" and get_flag("log_pallas_fallback"):
         msg = f"pallas-fallback: {kernel} -> {path}"
         if reason:
             msg += f" ({reason})"
         sys.stderr.write(msg + "\n")
+    if _capture is None:
+        return
+    for part in (work, grad_work if _differentiated else None):
+        for role, (flops, moved) in (part or {}).items():
+            row = _capture.setdefault(role, [0, 0.0, 0.0])
+            row[0] += 1
+            row[1] += flops
+            row[2] += moved
+
+
+@contextlib.contextmanager
+def capture(step: Optional[str]):
+    """Sum the work that dispatches declare while ``step`` is traced and
+    keep it as the work of one execution of it (the latest trace wins).
+    ``capture(None)`` drops what is declared inside: the autotuner times
+    its candidates on the side of whichever trace asked for a verdict."""
+    global _capture
+    outer, _capture = _capture, {}
+    try:
+        yield
+        if step is not None:
+            _STEP_WORK[step] = {
+                role: {"calls": c, "flops": f, "bytes": b}
+                for role, (c, f, b) in _capture.items()}
+    finally:
+        _capture = outer
+
+
+@contextlib.contextmanager
+def differentiated():
+    """Inside, a dispatch's ``grad_work`` counts: the caller takes the
+    gradient of what is traced here."""
+    global _differentiated
+    outer, _differentiated = _differentiated, True
+    try:
+        yield
+    finally:
+        _differentiated = outer
+
+
+def step_work(step: str) -> Dict[str, Dict[str, float]]:
+    """{role: {"calls", "flops", "bytes"}} of ONE execution of the
+    compiled step ``step``; empty when it was never traced or launched
+    no kernel that declares work."""
+    return {role: dict(row) for role, row in _STEP_WORK.get(step, {}).items()}
+
+
+def nbytes(*arrays) -> int:
+    """Bytes of the arrays (or shape structs) as they are."""
+    return sum(int(a.size) * a.dtype.itemsize for a in arrays)
+
+
+def kernel_call(role: str, kernel, **kwargs):
+    """``pl.pallas_call`` under the kernel's role name.
+
+    XLA names a Mosaic custom call after the innermost scope of its
+    ``op_name``, and jax folds the first scope inside ``jvp(...)`` /
+    ``transpose(...)`` into the transform's own name, so a kernel called
+    straight under ``jax.grad`` would show as ``jvp_<role>_``. The plain
+    ``pallas`` scope above the role keeps the row ``<role>`` wherever
+    the call sits (seen in the HLO compiled for a v5e, PR 24)."""
+    import jax
+    from jax.experimental import pallas as pl
+
+    call = pl.pallas_call(kernel, name=role, **kwargs)
+
+    def named(*operands):
+        with jax.named_scope("pallas"):
+            return call(*operands)
+
+    return named
 
 
 def snapshot() -> Dict[str, int]:
@@ -48,3 +150,4 @@ def delta(before: Dict[str, int]) -> Dict[str, int]:
 
 def reset() -> None:
     _COUNTS.clear()
+    _STEP_WORK.clear()

@@ -21,6 +21,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+from .counters import kernel_call, nbytes
+
 _NEG_INF = -1e30
 _F32 = jnp.float32
 
@@ -298,7 +300,8 @@ def _fwd_call(qm, km, vm, causal, block_q, block_kv, sm_scale,
     if dropout_p > 0.0:
         in_specs.append(pl.BlockSpec((1, 1), lambda i, j: (0, 0)))
         operands.append(seed)
-    out, lse = pl.pallas_call(
+    out, lse = kernel_call(
+        "flash_attention_stream_fwd",
         functools.partial(_flash_fwd_kernel, kv_len=kl, block_kv=block_kv,
                           sm_scale=sm_scale, causal=causal, q_block=block_q,
                           masked=masked, dropout_p=dropout_p),
@@ -354,7 +357,9 @@ def _bwd_call(qm, km, vm, dom, lse, delta, causal, block_q, block_kv,
     if dropout_p > 0.0:
         dq_specs.append(pl.BlockSpec((1, 1), lambda i, j: (0, 0)))
         dq_ops.append(seed)
-    dq = pl.pallas_call(
+    # dq and dk/dv under the one role: a trace sums them
+    dq = kernel_call(
+        "flash_attention_stream_bwd",
         functools.partial(_flash_bwd_dq_kernel, kv_len=kl,
                           block_kv=block_kv, sm_scale=sm_scale,
                           causal=causal, q_block=block_q, masked=masked,
@@ -382,7 +387,8 @@ def _bwd_call(qm, km, vm, dom, lse, delta, causal, block_q, block_kv,
     if dropout_p > 0.0:
         dkv_specs.append(pl.BlockSpec((1, 1), lambda i, j: (0, 0)))
         dkv_ops.append(seed)
-    dk, dv = pl.pallas_call(
+    dk, dv = kernel_call(
+        "flash_attention_stream_bwd",
         functools.partial(_flash_bwd_dkv_kernel, q_len=ql, block_q=block_q,
                           sm_scale=sm_scale, causal=causal,
                           kv_block=block_kv, masked=masked,
@@ -610,7 +616,8 @@ def _flash_attention_core_short_fwd(q, k, v, seed, causal, dropout_p):
     ops = [qm, km, vm]
     if dropout_p > 0.0:
         ops.append(seed)
-    out_m, lse = pl.pallas_call(
+    out_m, lse = kernel_call(
+        "flash_attention_short_fwd",
         functools.partial(_short_fwd_kernel, sm_scale=sm_scale,
                           causal=causal, dropout_p=dropout_p),
         grid=(bh,),
@@ -647,7 +654,8 @@ def _flash_attention_core_short_bwd(causal, dropout_p, res, dout):
     if dropout_p > 0.0:
         specs.append(pl.BlockSpec((1, 1), lambda i: (0, 0)))
         ops.append(seed)
-    dq, dk, dv = pl.pallas_call(
+    dq, dk, dv = kernel_call(
+        "flash_attention_short_bwd",
         functools.partial(_short_bwd_kernel, sm_scale=sm_scale,
                           causal=causal, dropout_p=dropout_p),
         grid=(bh,),
@@ -798,6 +806,24 @@ def _rng_seed_arr(key_rng):
     return jax.lax.bitcast_convert_type(bits, jnp.int32)
 
 
+def _work(kind, q, k, v, causal):
+    """``work=`` / ``grad_work=`` of one call of the ``kind`` ("short" or
+    "stream") kernels, for the ledger in ``counters``. Forward: Q K^T
+    and P V, 4 B A Sq Sk D; backward: dV, dP, dQ, dK, 8 B A Sq Sk D (the
+    recomputed scores not counted); half of each under a causal mask.
+    Bytes: q, k, v read, the output and the f32 logsumexp written;
+    backward reads those with the output's cotangent and writes dq, dk,
+    dv."""
+    b, ql, a, d = q.shape
+    mm = (0.5 if causal else 1.0) * b * a * ql * k.shape[1] * d
+    qkv, lse = nbytes(q, k, v), 4 * b * a * ql
+    return {
+        "work": {f"flash_attention_{kind}_fwd":
+                 (4.0 * mm, qkv + nbytes(q) + lse)},
+        "grad_work": {f"flash_attention_{kind}_bwd":
+                      (8.0 * mm, 2 * qkv + 2 * nbytes(q) + lse)}}
+
+
 def _local_attention(q, k, v, is_causal):
     """Best single-device mask-free attention: Pallas when eligible,
     else XLA. Used directly and as ring_attention's fallback."""
@@ -807,7 +833,8 @@ def _local_attention(q, k, v, is_causal):
     choice = _short_choice(q, k, is_causal, 0.0)
     if choice == "short":
         out = _flash_attention_pallas_short(q, k, v, causal=is_causal)
-        bump("flash_attention", "pallas")
+        bump("flash_attention", "pallas",
+             **_work("short", q, k, v, is_causal))
         return out
     if choice == "xla":
         bump("flash_attention", "xla", "autotuned: xla wins this shape")
@@ -815,7 +842,8 @@ def _local_attention(q, k, v, is_causal):
     # choice == "stream" or no autotune verdict: static streaming path
     if _pallas_ok(q, k, is_causal):
         out = _flash_attention_pallas(q, k, v, causal=is_causal)
-        bump("flash_attention", "pallas")
+        bump("flash_attention", "pallas",
+             **_work("stream", q, k, v, is_causal))
         return out
     bump("flash_attention", "xla",
          f"dispatch ineligible (q {tuple(q.shape)}, causal="
@@ -943,7 +971,8 @@ def flash_attention_or_fallback(q, k, v, mask=None, dropout_p=0.0,
             out = _flash_attention_pallas_short(
                 q, k, v, seed=_rng_seed_arr(key_rng),
                 causal=is_causal, dropout_p=dropout_p)
-            bump("flash_attention", "pallas")
+            bump("flash_attention", "pallas",
+                 **_work("short", q, k, v, is_causal))
             return out
         if choice == "xla":
             bump("flash_attention", "xla",
@@ -962,7 +991,8 @@ def flash_attention_or_fallback(q, k, v, mask=None, dropout_p=0.0,
         # b32/s512)
         out = _flash_attention_pallas_dropout(
             q, k, v, _rng_seed_arr(key_rng), dropout_p, causal=is_causal)
-        bump("flash_attention", "pallas")
+        bump("flash_attention", "pallas",
+             **_work("stream", q, k, v, is_causal))
         return out
     if mask is not None and dropout_p == 0.0 and _pallas_ok(q, k, is_causal):
         # key-padding masks ride the Pallas kernel as an additive kv bias;
@@ -971,7 +1001,8 @@ def flash_attention_or_fallback(q, k, v, mask=None, dropout_p=0.0,
         if bias is not None:
             out = _flash_attention_pallas_masked(q, k, v, bias,
                                                  causal=is_causal)
-            bump("flash_attention", "pallas")
+            bump("flash_attention", "pallas",
+                 **_work("stream", q, k, v, is_causal))
             return out
     bump("flash_attention", "xla",
          f"dropout/mask dispatch ineligible (q {tuple(q.shape)}, mask="

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .counters import kernel_call, nbytes
+
 
 def _xla_bag(table, ids, combiner):
     """Reference path: masked gather + pooled reduce (what XLA fuses)."""
@@ -92,7 +94,8 @@ def _bag_pallas(table, ids, combiner):
         scratch_shapes=[pltpu.SMEM((8,), jnp.float32)],
     )
     kernel = functools.partial(_bag_kernel, seq=s, combiner=combiner)
-    out = pl.pallas_call(
+    out = kernel_call(
+        "fused_embedding",
         kernel,
         grid_spec=grid_spec,
         # f32 accumulator output; cast back to the table dtype at the end
@@ -127,7 +130,11 @@ def _bag_core(table, ids, combiner):
         # a chosen kernel that fails raises: a silent except here once
         # hid a Mosaic tile-rule bug for a full round
         out = _bag_pallas(table, ids, combiner)
-        bump("fused_embedding", "pallas")
+        # a gather and a sum: no matmul; the ids and one table row per
+        # id read, the pooled rows written
+        bump("fused_embedding", "pallas", work={"fused_embedding": (
+            0.0, nbytes(ids, out)
+            + ids.size * table.shape[1] * table.dtype.itemsize)})
         return out
     bump("fused_embedding", "xla",
          f"ineligible (table {tuple(table.shape)} {table.dtype}, ids "

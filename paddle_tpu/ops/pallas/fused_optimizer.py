@@ -52,6 +52,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .counters import bump, kernel_call
+
 __all__ = ["FUSED_OPS", "fused_op_update", "fused_chunk_update",
            "fused_try_rule", "fused_opt_escaped"]
 
@@ -259,15 +261,28 @@ def _pad_flat(x, n_pad):
         [flat, jnp.zeros((n_pad - flat.shape[0],), jnp.float32)])
 
 
-def _run_grid(kernel, scalars, tensors, n_outs, n, interpret):
-    """Common pallas_call: scalars as SMEM (1,1) refs, tensors padded
-    to whole (8, 128) tiles and blocked (block_rows, 128) over a 1-D
-    grid. Returns the outputs sliced back to ``n`` flat elements."""
+#: f32 streams a fused update reads and writes once, by rule kind
+_STREAMS = {"sgd": 3, "momentum": 5, "adam": 7, "lamb": 7}
+
+
+def _engaged(role: str, kind: str, n: int) -> None:
+    """Count an engaged kernel and declare its work to the ledger: no
+    matmul; param, grad and moments read, param and moments written."""
+    bump("fused_opt", "pallas",
+         work={role: (0.0, 4.0 * _STREAMS[kind] * n)})
+
+
+def _run_grid(role, kernel, scalars, tensors, n_outs, n, interpret):
+    """Common pallas_call under the kernel's ``role`` name: scalars as
+    SMEM (1,1) refs, tensors padded to whole (8, 128) tiles and blocked
+    (block_rows, 128) over a 1-D grid. Returns the outputs sliced back
+    to ``n`` flat elements."""
     n_pad = -(-n // _TILE) * _TILE
     rows = n_pad // _LANE
     br = _block_rows(rows)
     blk = pl.BlockSpec((br, _LANE), lambda i: (i, 0))
-    outs = pl.pallas_call(
+    outs = kernel_call(
+        role,
         kernel,
         grid=(rows // br,),
         in_specs=([pl.BlockSpec(memory_space=pltpu.SMEM)
@@ -327,6 +342,7 @@ def _pallas_update(op_type, ins, attrs, interpret, dygraph=False,
                    c1=None, c2=None):
     """The fused kernel leg. c1/c2 override the beta-pow scalars for
     the dygraph variant (bias-correction by step count)."""
+    role = "fused_" + op_type
     p = _pick(ins, "Param")
     shape, dtype = p.shape, p.dtype
     n = p.size
@@ -334,7 +350,7 @@ def _pallas_update(op_type, ins, attrs, interpret, dygraph=False,
     skip = _found_scal(ins)
     if op_type == "sgd":
         (p_new,) = _run_grid(
-            _sgd_kernel, [lr, skip], [p, _pick(ins, "Grad")], 1, n,
+            role, _sgd_kernel, [lr, skip], [p, _pick(ins, "Grad")], 1, n,
             interpret)
         return {"ParamOut": [p_new.reshape(shape).astype(dtype)]}
     if op_type == "momentum":
@@ -342,7 +358,7 @@ def _pallas_update(op_type, ins, attrs, interpret, dygraph=False,
             _momentum_kernel, mu=attrs.get("mu", 0.9),
             nesterov=bool(attrs.get("use_nesterov", False)))
         p_new, v_new = _run_grid(
-            kern, [lr, skip],
+            role, kern, [lr, skip],
             [p, _pick(ins, "Grad"), _pick(ins, "Velocity")], 2, n,
             interpret)
         return {"ParamOut": [p_new.reshape(shape).astype(dtype)],
@@ -359,7 +375,7 @@ def _pallas_update(op_type, ins, attrs, interpret, dygraph=False,
             _adam_kernel, b1=b1, b2=b2,
             eps=attrs.get("epsilon", 1e-8), dygraph=dygraph)
         p_new, m_new, v_new = _run_grid(
-            kern, [lr, _scal(c1), _scal(c2), skip],
+            role, kern, [lr, _scal(c1), _scal(c2), skip],
             [p, _pick(ins, "Grad"), _pick(ins, "Moment1"),
              _pick(ins, "Moment2")], 3, n, interpret)
         return _gate_scalars(ins, {
@@ -373,7 +389,7 @@ def _pallas_update(op_type, ins, attrs, interpret, dygraph=False,
         eps=attrs.get("epsilon", 1e-6),
         wd=attrs.get("weight_decay", 0.01), dygraph=dygraph)
     m_new, v_new, r = _run_grid(
-        kern, [_scal(c1), _scal(c2)],
+        role, kern, [_scal(c1), _scal(c2)],
         [p, _pick(ins, "Grad"), _pick(ins, "Moment1"),
          _pick(ins, "Moment2")], 3, n, interpret)
     pf = p.reshape(-1).astype(jnp.float32)
@@ -418,13 +434,11 @@ def fused_op_update(op_type, ins, attrs):
     convention as static/kernels.py. Ineligible / escaped dispatches
     run the verbatim XLA reference (bitwise with the pre-fusion ops);
     an engaged kernel is counted ``fused_opt.pallas``."""
-    from .counters import bump
-
     p = ins["Param"][0]
     path, reason, interpret = _dispatch(op_type, p.size, p.dtype)
     if path == "pallas":
         out = _pallas_update(op_type, ins, attrs, interpret)
-        bump("fused_opt", "pallas")
+        _engaged("fused_" + op_type, op_type, p.size)
         return out
     bump("fused_opt", "xla", f"{op_type}: {reason}")
     return _XLA[op_type](ins, attrs)
@@ -470,8 +484,6 @@ def fused_chunk_update(op_type, ins, attrs, *, axis=None,
     if op_type != "lamb":
         return fused_op_update(op_type, ins, attrs)
 
-    from .counters import bump
-
     p = ins["Param"][0].reshape(-1)
     g = ins["Grad"][0].reshape(-1)
     m = ins["Moment1"][0].reshape(-1)
@@ -491,9 +503,9 @@ def fused_chunk_update(op_type, ins, attrs, *, axis=None,
             _lamb_phase1_kernel, b1=b1, b2=b2, eps=eps, wd=wd,
             dygraph=False)
         m_new, v_new, r = _run_grid(
-            kern, [_scal(b1p * b1), _scal(b2p * b2)],
+            "fused_lamb", kern, [_scal(b1p * b1), _scal(b2p * b2)],
             [p, g, m, v], 3, c, interpret)
-        bump("fused_opt", "pallas")
+        _engaged("fused_lamb", "lamb", c)
     else:
         bump("fused_opt", "xla", f"lamb chunk: {reason}")
         m_new = b1 * m + (1 - b1) * g
@@ -550,23 +562,22 @@ def fused_try_rule(opt, g, p, slots, lr, step):
     if path != "pallas":
         return None
 
-    from .counters import bump
-
+    # the kernel shows under the optimizer's own name (``fused_adamw``)
+    role = "fused_" + type(opt).__name__.lower()
     shape, dtype = p.shape, p.dtype
     n = p.size
+    _engaged(role, kind, n)
     if kind == "sgd":
-        (p_new,) = _run_grid(_sgd_kernel, [_scal(lr), _scal(0.0)],
+        (p_new,) = _run_grid(role, _sgd_kernel, [_scal(lr), _scal(0.0)],
                              [p, g], 1, n, interpret)
-        bump("fused_opt", "pallas")
         return p_new.reshape(shape).astype(dtype), slots
     if kind == "momentum":
         kern = functools.partial(_momentum_kernel,
                                  mu=opt._momentum,
                                  nesterov=bool(opt._nesterov))
         p_new, v_new = _run_grid(
-            kern, [_scal(lr), _scal(0.0)],
+            role, kern, [_scal(lr), _scal(0.0)],
             [p, g, slots["velocity"]], 2, n, interpret)
-        bump("fused_opt", "pallas")
         return (p_new.reshape(shape).astype(dtype),
                 {"velocity": v_new.reshape(shape).astype(dtype)})
     b1, b2 = opt._beta1, opt._beta2
@@ -577,10 +588,9 @@ def fused_try_rule(opt, g, p, slots, lr, step):
         kern = functools.partial(_adam_kernel, b1=b1, b2=b2,
                                  eps=opt._eps, dygraph=True)
         p_new, m_new, v_new = _run_grid(
-            kern, [_scal(lr), _scal(c1), _scal(c2), _scal(0.0)],
+            role, kern, [_scal(lr), _scal(c1), _scal(c2), _scal(0.0)],
             [p, g, slots["moment1"], slots["moment2"]], 3, n,
             interpret)
-        bump("fused_opt", "pallas")
         return (p_new.reshape(shape).astype(dtype),
                 {"moment1": m_new.reshape(shape).astype(dtype),
                  "moment2": v_new.reshape(shape).astype(dtype)})
@@ -589,7 +599,7 @@ def fused_try_rule(opt, g, p, slots, lr, step):
                              eps=opt._eps, wd=opt._lamb_wd,
                              dygraph=True)
     m_new, v_new, r = _run_grid(
-        kern, [_scal(c1), _scal(c2)],
+        role, kern, [_scal(c1), _scal(c2)],
         [p, g, slots["moment1"], slots["moment2"]], 3, n,
         interpret)
     pf = p.reshape(-1).astype(jnp.float32)
@@ -598,7 +608,6 @@ def fused_try_rule(opt, g, p, slots, lr, step):
     trust = jnp.where((w_norm > 0) & (r_norm > 0),
                       w_norm / r_norm, 1.0)
     p_new = pf - jnp.asarray(lr, jnp.float32) * trust * r
-    bump("fused_opt", "pallas")
     return (p_new.reshape(shape).astype(dtype),
             {"moment1": m_new.reshape(shape).astype(dtype),
              "moment2": v_new.reshape(shape).astype(dtype)})

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from ...framework.flags import define_flag
+from .counters import kernel_call, nbytes
 from .flash_attention import _dot, _sds
 
 define_flag("fused_vocab_xent", True,
@@ -201,7 +202,8 @@ def _fwd_call(h, w, bias, labels, block_n, block_v):
     n, hd = h.shape
     v = w.shape[0]
     num_v = v // block_v
-    lse, ll, _m, _l = pl.pallas_call(
+    lse, ll, _m, _l = kernel_call(
+        "fused_xent_fwd",
         functools.partial(_fwd_kernel, num_v=num_v, block_v=block_v),
         grid=(n // block_n, num_v),
         in_specs=[
@@ -232,7 +234,9 @@ def _bwd_call(h, w, bias, labels, lse, g, block_n, block_v):
 
     n, hd = h.shape
     v = w.shape[0]
-    dh = pl.pallas_call(
+    # both backward kernels under the one role: a trace sums them
+    dh = kernel_call(
+        "fused_xent_bwd",
         functools.partial(_bwd_dh_kernel, block_v=block_v),
         grid=(n // block_n, v // block_v),
         in_specs=[
@@ -247,7 +251,8 @@ def _bwd_call(h, w, bias, labels, lse, g, block_n, block_v):
         out_shape=_sds((n, hd), _F32, h),
         compiler_params=_compiler_params(),
     )(h, w, bias[None, :], labels[None, :], lse[None, :], g[None, :])
-    dw, db = pl.pallas_call(
+    dw, db = kernel_call(
+        "fused_xent_bwd",
         functools.partial(_bwd_dw_kernel, block_n=block_n,
                           block_v=block_v),
         grid=(v // block_v, n // block_n),
@@ -382,6 +387,21 @@ def _eligible(n, hd, v):
             hd % 128 == 0 and hd <= 2048)
 
 
+def _work(h2, w, bias, lab):
+    """``work=`` / ``grad_work=`` of one call on these rows, for the
+    ledger in ``counters``: the logits matmul forward (2 N H V); dh and dW
+    backward (4 N H V, the recomputed logits not counted). Bytes: h, W,
+    bias and labels read, lse and the label logit written; backward
+    reads those with lse and the row cotangent and writes dh, dW, db."""
+    n, hd = h2.shape
+    v = w.shape[0]
+    read = nbytes(h2, w, bias, lab)
+    return {
+        "work": {"fused_xent_fwd": (2.0 * n * hd * v, read + 8 * n)},
+        "grad_work": {"fused_xent_bwd": (
+            4.0 * n * hd * v, read + 8 * n + nbytes(h2, w, bias))}}
+
+
 def fused_linear_cross_entropy(h, w, bias, labels, ignore_index=-100):
     """mean softmax-xent of (h @ w^T + bias) against labels, streaming
     the vocab axis so the logits never land in HBM. h: (..., H); w:
@@ -405,7 +425,7 @@ def fused_linear_cross_entropy(h, w, bias, labels, ignore_index=-100):
         mesh, row_axes = plan
         out = _sharded_fused(h2, w, bias, lab, mesh, row_axes,
                              int(ignore_index))
-        bump("fused_xent", "pallas_sharded")
+        bump("fused_xent", "pallas_sharded", **_work(h2, w, bias, lab))
         return out
     elif _eligible(n + pad, hd, w.shape[0]):
         if pad:
@@ -414,7 +434,7 @@ def fused_linear_cross_entropy(h, w, bias, labels, ignore_index=-100):
             lab = jnp.concatenate(
                 [lab, jnp.full((pad,), ignore_index, lab.dtype)], 0)
         out = _fused_xent_core(h2, w, bias, lab, int(ignore_index))
-        bump("fused_xent", "pallas")
+        bump("fused_xent", "pallas", **_work(h2, w, bias, lab))
         return out
     else:
         bump("fused_xent", "xla",

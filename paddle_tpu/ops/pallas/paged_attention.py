@@ -41,6 +41,8 @@ import os
 import jax
 import jax.numpy as jnp
 
+from .counters import kernel_call
+
 _NEG_INF = -1e30
 _F32 = jnp.float32
 
@@ -226,7 +228,8 @@ def _paged_call(q, k_pages, v_pages, page_table, seq_lens, k_scales=None,
             pltpu.VMEM((8, HD), _F32),            # value accumulator
         ],
     )
-    out = pl.pallas_call(
+    out = kernel_call(
+        "paged_attention",
         functools.partial(_paged_attn_kernel, page_size=S, table_width=T,
                           sm_scale=1.0 / math.sqrt(D), quant=quant),
         grid_spec=grid_spec,
@@ -287,6 +290,8 @@ def paged_attention(q, k_pages, v_pages, page_table, seq_lens,
     traffic, not comparable)."""
     from .counters import bump
 
+    # no work is declared to the ledger here: what a decode step must
+    # read follows the live lengths, which are values, not shapes
     quant = k_scales is not None
     if _escape_pinned():
         bump("paged_attention", "xla", "PADDLE_PAGED_ATTENTION=0 pin")

@@ -27,6 +27,8 @@ import os
 import jax
 import jax.numpy as jnp
 
+from .counters import kernel_call, nbytes
+
 _NEG_INF = -1e30
 _F32 = jnp.float32
 
@@ -99,7 +101,8 @@ def _fused_sample_pallas(logits, noise, temperature, top_k):
         logits = jnp.pad(logits, ((0, pad), (0, 0)))
         noise = jnp.pad(noise, ((0, pad), (0, 0)))
     rows = pl.BlockSpec((_ROWS, V), lambda i: (i, 0))
-    out = pl.pallas_call(
+    out = kernel_call(
+        "gumbel_sampling",
         functools.partial(_sample_kernel,
                           temperature=float(temperature),
                           top_k=int(top_k)),
@@ -154,7 +157,9 @@ def fused_sample(logits, noise, temperature, top_k: int = 0,
             bump("fused_sample", "xla", "autotuned: xla wins this shape")
             return _xla_sample(logits, noise, temperature, top_k, top_p)
         out = _fused_sample_pallas(logits, noise, temperature, top_k)
-        bump("fused_sample", "pallas")
+        # no matmul: logits and noise read once, one token id a row
+        bump("fused_sample", "pallas", work={"gumbel_sampling": (
+            0.0, nbytes(logits, noise, out))})
         return out
     bump("fused_sample", "xla",
          f"dispatch ineligible (logits {tuple(logits.shape)}, "

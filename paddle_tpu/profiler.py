@@ -39,8 +39,13 @@ _state = {
 # The reference profiler only times host events; the quantities that decide
 # TPU step-loop health — did the step recompile, did state bounce through
 # host memory, were parameter buffers donated — are invisible to a timer.
-# Every executor (static Executor, jit.TrainStep) bumps these; bench.py
-# snapshots before/after a config and reports the delta in its rows.
+# Every executor (static Executor, jit.TrainStep) bumps these; a reader
+# takes counters_snapshot() before and counters_delta() after what it
+# watches (chip_smoke.py does). The kernels' own tables live with the
+# kernels: ops.pallas.counters (dispatch counts, printed on the
+# ``pallas counters`` line of every benchmarks/run.py log, and the work
+# ledger counters.step_work), ops.pallas.autotune.stats() and
+# static.compile_cache.seconds_by_function().
 #
 # Names in use:
 #   compile_cache_hits / compile_cache_misses  per-step executable lookup
@@ -57,8 +62,8 @@ _state = {
 # IR pass pipeline + compile cache counters (static/passes.py,
 # static/executor.py, static/compile_cache.py):
 #   ir_ops_before / ir_ops_after  block-0 op counts entering/leaving the
-#                      pass pipeline (cumulative over builds; the delta
-#                      over a bench config is what its row reports)
+#                      pass pipeline (cumulative over builds; read
+#                      as a delta)
 #   ir_pass_ms         total pipeline wall-time (ms, float)
 #   ir_vars_dropped    unused VarDescs dropped by the cleanup pass
 #   pass_<name>_removed_ops / pass_<name>_ms  per-pass movement

@@ -32,12 +32,16 @@ each), with full tracebacks in locations and with one frame alike.
 
 Cache traffic is observable: a jax monitoring listener bumps the
 profiler counters ``disk_cache_hits`` / ``disk_cache_misses``, which
-Executor.counters merges (profiler.COMPILE_COUNTER_NAMES) and bench.py
-reports per row.
+Executor.counters merges (profiler.COMPILE_COUNTER_NAMES). The same
+listener sums what each jitted function cost this process to trace,
+lower and compile or load (:func:`seconds_by_function`); the benchmark
+reads the train step's share of a run's set-up from it
+(``step_compile_s.train``).
 """
 from __future__ import annotations
 
 import os
+from typing import Dict
 
 _ENV = "JAX_COMPILATION_CACHE_DIR"
 _CHECKOUT_DIR = os.path.join(
@@ -45,6 +49,17 @@ _CHECKOUT_DIR = os.path.join(
         os.path.abspath(__file__)))), ".jax_cache")
 
 _armed = [False]
+
+#: jax's duration events that carry a ``fun_name``: tracing to a jaxpr,
+#: lowering it to a module, and compiling the module or loading it from
+#: the disk cache
+_STAGE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                 "/jax/core/compile/jaxpr_to_mlir_module_duration",
+                 "/jax/core/compile/backend_compile_duration")
+_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+#: the key under which the disk reads are summed
+CACHE_RETRIEVAL = "(cache_retrieval)"
+_seconds: Dict[str, float] = {}
 
 
 def cache_dir() -> str:
@@ -106,4 +121,29 @@ def _install_listener() -> None:
         elif event.endswith("/cache_misses"):
             profiler.bump_counter("disk_cache_misses")
 
+    def _on_duration(event: str, secs: float, **kwargs) -> None:
+        if event in _STAGE_EVENTS:
+            # tracing reports the function's name, the later stages the
+            # name jax wraps it in (``jit(<name>)``)
+            name = str(kwargs.get("fun_name", "?"))
+            if name.startswith("jit(") and name.endswith(")"):
+                name = name[len("jit("):-1]
+        elif event == _RETRIEVAL_EVENT:
+            name = CACHE_RETRIEVAL
+        else:
+            return
+        _seconds[name] = _seconds.get(name, 0.0) + float(secs)
+
     monitoring.register_event_listener(_on_event)
+    monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+def seconds_by_function() -> Dict[str, float]:
+    """function name -> the seconds this process spent tracing, lowering
+    and compiling-or-loading it, summed over its compilations, since the
+    cache was armed (``seconds_by_function()["train_step"]``). A function
+    traced inside another counts in both, and so do autotune timings a
+    dispatch ran while its step was traced. jax names no function on a
+    disk read: those seconds, a part of the compile stage above, are
+    summed under ``CACHE_RETRIEVAL``."""
+    return dict(_seconds)

@@ -57,56 +57,104 @@ def test_default_captures_path_is_repo_root():
             os.environ["BENCH_CAPTURES_PATH"] = old
 
 
-def test_profile_trace_summarizer(tmp_path):
-    """tools/profile_step.summarize_trace turns a chrome trace into the
-    committed device-time-by-op table (synthetic trace; the real one
-    needs the live chip)."""
-    import gzip
+def _profile_step():
     import importlib.util
-    import json
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     spec = importlib.util.spec_from_file_location(
         "profile_step", os.path.join(repo, "tools", "profile_step.py"))
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
+    return mod
 
-    trace = {"traceEvents": [
-        {"ph": "M", "name": "process_name", "pid": 7,
-         "args": {"name": "/device:TPU:0"}},
-        {"ph": "M", "name": "process_name", "pid": 1,
-         "args": {"name": "python"}},
-        {"ph": "M", "name": "thread_name", "pid": 7, "tid": 2,
-         "args": {"name": "XLA Ops"}},
-        {"ph": "M", "name": "thread_name", "pid": 7, "tid": 3,
-         "args": {"name": "XLA Modules"}},
-        {"ph": "X", "pid": 7, "tid": 2, "name": "fusion.12",
-         "dur": 3000.0},
-        {"ph": "X", "pid": 7, "tid": 2, "name": "fusion.13",
-         "dur": 1000.0},
-        {"ph": "X", "pid": 7, "tid": 2, "name": "copy-start.1",
-         "dur": 500.0},
-        # module span == sum of the ops under it: counting it would
-        # double the total (the r5 review catch)
-        {"ph": "X", "pid": 7, "tid": 3, "name": "jit_train_step",
-         "dur": 4500.0},
-        {"ph": "X", "pid": 1, "tid": 9, "name": "host-stuff",
-         "dur": 9999.0},
-    ]}
-    d = tmp_path / "plugins"
-    d.mkdir()
-    with gzip.open(d / "t.trace.json.gz", "wt") as f:
-        json.dump(trace, f)
-    out = tmp_path / "XPLANE_SUMMARY.md"
-    ok = mod.summarize_trace(str(tmp_path), "bert512",
-                             {"value": 1.0, "unit": "tok/s",
-                              "device_kind": "fake-v5e", "mfu": 0.5},
-                             str(out))
-    assert ok
-    text = out.read_text()
-    assert "| fusion | 4.00 |" in text          # instances folded
-    assert "88.9%" in text                      # 4000/4500 device time
-    assert "host-stuff" not in text             # host track excluded
-    assert "jit_train_step" not in text         # module line excluded
-    assert "| TOTAL (all ops) | 4.50 |" in text  # no double count
-    assert "bert512 @ fake-v5e" in text
+
+#: a small recorded event list in the shapes a v5e trace gives them:
+#: (instruction name, op_name, seconds)
+_STEP = "jit(train_step)/"
+_EVENTS = [
+    ("fusion.12", _STEP + "jvp(loss)/bert/encoder/layer/linear1/"
+     "dot_general", 3.0),
+    ("fusion.13", _STEP + "transpose(jvp(loss))/bert/encoder/layer/"
+     "linear1/dot_general", 5.0),
+    ("multiply_reduce_fusion.2", _STEP + "transpose(jvp(loss))/bert/"
+     "encoder/layer/norm1/mul", 2.0),
+    ("kernel:flash_attention_short_fwd.4", _STEP + "jvp(loss)/bert/encoder/"
+     "layer/self_attn/jit(_flash_attention_pallas_short)/pallas/"
+     "flash_attention_short_fwd/pallas_call", 1.0),
+    ("kernel:fused_xent_bwd.1", _STEP + "transpose(jvp(loss))/pallas/"
+     "fused_xent_bwd/pallas_call", 4.0),
+    ("kernel:fused_xent_bwd.2", _STEP + "transpose(jvp(loss))/pallas/"
+     "fused_xent_bwd/pallas_call", 2.0),
+    ("kernel:fused_adamw.9", _STEP + "optimizer/pallas/fused_adamw/"
+     "pallas_call", 1.5),
+    ("copy.3", None, 1.0),
+    ("bitcast_convert_fusion", _STEP + "optimizer/convert_element_type",
+     0.5),
+]
+
+
+def test_profile_step_splits_an_op_name_into_phase_and_scope():
+    split = _profile_step().split_op_name
+    assert split(_EVENTS[0][1]) == (
+        "forward", ["loss", "bert", "encoder", "layer", "linear1"])
+    assert split(_EVENTS[1][1])[0] == "backward"
+    # the jit wrapper, the guard scope and the primitive are not scopes
+    assert split(_EVENTS[3][1]) == (
+        "forward", ["loss", "bert", "encoder", "layer", "self_attn",
+                    "flash_attention_short_fwd"])
+    assert split(_EVENTS[6][1]) == ("optimizer",
+                                    ["optimizer", "fused_adamw"])
+    assert split(_STEP + "optimizer/reshape;" + _STEP + "optimizer/mul") \
+        == ("optimizer", ["optimizer"])
+    assert split(None) == ("unattributed", [])
+    assert split("jit(train_step)/add") == ("other", [])
+
+
+def test_profile_step_sums_by_phase_scope_and_kernel_role():
+    mod = _profile_step()
+    out = mod.summarize(_EVENTS, depth=4)
+    assert out["total_s"] == 20.0
+    assert dict(out["by_phase"]) == {
+        "forward": 4.0, "backward": 13.0, "optimizer": 2.0,
+        "unattributed": 1.0}
+    scopes = dict(out["by_scope"])
+    # cut at depth 4: the layer's parts fold into encoder/layer
+    assert scopes["loss/bert/encoder/layer"] == 11.0
+    assert scopes["loss/fused_xent_bwd"] == 6.0
+    assert scopes[mod.NO_SCOPE] == 1.0
+    assert sum(scopes.values()) == 20.0
+    # numeric suffixes merged: both backward kernels are one role
+    assert dict(out["kernels"]) == {
+        "fused_xent_bwd": 6.0, "fused_adamw": 1.5,
+        "flash_attention_short_fwd": 1.0}
+    top = out["owners"][0]
+    assert top[0] == "fusion" and top[1] == 8.0
+    assert top[2] == [("loss/bert/encoder/layer", 8.0)]
+    text = mod.render(out, steps=10)
+    assert "| backward | 13.0000 | 65.0% |" in text
+    assert "| fused_xent_bwd | 6.0000 | 30.0% |" in text
+
+
+def test_profile_step_joins_instructions_with_the_compiled_text():
+    mod = _profile_step()
+    hlo = """HloModule jit_train_step, is_scheduled=true
+%fused_computation.1 (p: f32[8]) -> f32[8] {
+  ROOT %add.5 = f32[8]{0} add(%p, %p), metadata={op_name="jit(train_step)/jvp(loss)/nsp/add" source_file="x.py"}
+}
+ENTRY %main.9 (a: f32[8]) -> f32[8] {
+  %fusion.12 = f32[8]{0} fusion(%a), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(train_step)/jvp(loss)/bert/pooler/dot_general" source_file="x.py" source_line=3}
+  %fused_xent_fwd.1 = f32[8]{0} custom-call(%fusion.12), custom_call_target="tpu_custom_call", metadata={op_name="jit(train_step)/jvp(loss)/pallas/fused_xent_fwd/pallas_call"}
+  ROOT %copy.3 = f32[8]{0} copy(%fused_xent_fwd.1)
+}
+"""
+    scopes = mod.scopes_from_text(hlo)
+    assert scopes["fusion.12"].endswith("bert/pooler/dot_general")
+    assert scopes["fused_xent_fwd.1"].endswith("fused_xent_fwd/pallas_call")
+    assert "copy.3" not in scopes
+    events = [(n, scopes.get(n.removeprefix("kernel:")), s) for n, s in
+              (("fusion.12", 2.0), ("kernel:fused_xent_fwd.1", 1.0),
+               ("copy.3", 1.0))]
+    out = mod.summarize(events)
+    assert dict(out["by_scope"]) == {
+        "loss/bert/pooler": 2.0, "loss/fused_xent_fwd": 1.0,
+        mod.NO_SCOPE: 1.0}

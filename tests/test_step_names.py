@@ -1,0 +1,215 @@
+"""What the compiled train step says about itself (PR 24): the module
+and its scopes carry the program's own names, every Pallas kernel runs
+under its role name, the work ledger of one step equals the closed
+forms, and jax's compile events are summed by function. CPU, tiny
+sizes, Pallas in interpret mode: names and counts, never a time."""
+import functools
+import re
+
+import jax
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+import paddle_tpu.framework.bringup as bringup
+from paddle_tpu import nn, optimizer
+from paddle_tpu.framework.flags import get_flag, set_flags
+from paddle_tpu.jit import TrainStep
+from paddle_tpu.models.bert import BertConfig, BertForPretraining
+from paddle_tpu.nn.layer import _scope_name
+from paddle_tpu.ops.pallas import autotune, counters
+from paddle_tpu.static import compile_cache
+
+V, H, L, A, I = 512, 128, 2, 2, 256
+B, S = 2, 128
+N, D = B * S, H // A
+
+
+def _batch(seed=0):
+    rng = np.random.default_rng(seed)
+    ids = rng.integers(0, V, (B, S)).astype("int32")
+    mlm = np.where(rng.random((B, S)) < 0.15, ids, -100).astype("int32")
+    return [paddle.to_tensor(a) for a in (
+        ids, np.zeros((B, S), "int32"), mlm,
+        rng.integers(0, 2, (B,)).astype("int32"))]
+
+
+def _train_step():
+    model = BertForPretraining(BertConfig(
+        vocab_size=V, hidden_size=H, num_hidden_layers=L,
+        num_attention_heads=A, intermediate_size=I,
+        max_position_embeddings=S, hidden_dropout_prob=0.0,
+        attention_probs_dropout_prob=0.0))
+    model.eval()
+    opt = optimizer.AdamW(learning_rate=1e-4,
+                          parameters=model.parameters())
+    return TrainStep(
+        model, lambda m, ids, tt, mlm, nsp: m.loss(ids, tt, mlm, nsp), opt)
+
+
+@pytest.fixture(scope="module")
+def forced():
+    """One tiny BERT step with every kernel of the main path forced on
+    (interpret mode), run once: (step, batch, seconds before, after)."""
+    from jax.experimental import pallas as pl
+
+    short = get_flag("flash_short_seq")
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(pl, "pallas_call",
+                   functools.partial(pl.pallas_call, interpret=True))
+        mp.setattr(bringup, "pallas_enabled", lambda: True)
+        mp.setenv("PADDLE_FUSED_OPT_INTERPRET", "1")
+        set_flags({"flash_short_seq": True})
+        counters.reset()
+        try:
+            step, batch = _train_step(), _batch()
+            before = compile_cache.seconds_by_function().get(
+                "train_step", 0.0)
+            step(*batch)
+            after = compile_cache.seconds_by_function()["train_step"]
+            yield step, batch, before, after
+        finally:
+            set_flags({"flash_short_seq": short})
+            counters.reset()
+
+
+@pytest.fixture(scope="module")
+def lowered(forced):
+    step, batch, _, _ = forced
+    return step.lower(*batch).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("scope", [
+    "jvp(loss)/bert/encoder/layer/self_attn",
+    "transpose(jvp(loss))/bert/encoder/layer/self_attn",
+    "bert/embeddings/word_embeddings", "optimizer/"])
+def test_lowered_step_holds_the_scopes(lowered, scope):
+    assert f"jit(train_step)/{scope}" in lowered or \
+        f"/{scope}" in lowered
+
+
+def test_no_scope_holds_a_container_index(lowered):
+    names = set(re.findall(r'loc\("(jit\(train_step\)[^"]*)"', lowered))
+    assert names
+    for name in names:
+        assert not re.search(r"(^|/|\(|\.)\d+(/|\)|$)", name), name
+        assert "layers" not in name.split("/")
+
+
+def test_module_is_named_train_step(lowered):
+    assert "\nmodule @jit_train_step " in lowered
+
+
+@pytest.mark.parametrize("role", [
+    "fused_xent_fwd", "fused_xent_bwd", "flash_attention_short_fwd",
+    "flash_attention_short_bwd", "fused_adamw"])
+def test_every_kernel_runs_under_its_role(lowered, role):
+    # the role is the innermost scope (what XLA names the custom call
+    # after), under the plain ``pallas`` scope that keeps jvp()/
+    # transpose() from wrapping it
+    assert f"pallas/{role}/pallas_call" in lowered
+
+
+def test_step_work_equals_the_closed_forms(forced):
+    work = counters.step_work("train_step")
+    xent = work["fused_xent_fwd"]["flops"] + work["fused_xent_bwd"]["flops"]
+    # logits forward 2 N H V; dh and dW backward 4 N H V
+    assert xent == 6 * N * H * V
+    assert work["fused_xent_fwd"]["calls"] == 1
+    attn = (work["flash_attention_short_fwd"]["flops"]
+            + work["flash_attention_short_bwd"]["flops"])
+    # per layer: QK^T and PV forward 4 B A S^2 D; dV, dP, dQ, dK 8 B A S^2 D
+    assert attn == 12 * B * A * S * S * D * L
+    assert work["flash_attention_short_fwd"]["calls"] == L
+    # q, k, v read and the output written in float32, plus the logsumexp
+    assert work["flash_attention_short_fwd"]["bytes"] == \
+        L * (4 * B * S * A * D * 4 + 4 * B * A * S)
+    # every f32 leaf of at least one (8, 128) tile: 7 streams of 4 bytes
+    adam = work["fused_adamw"]
+    assert adam["flops"] == 0 and adam["bytes"] % 28 == 0
+    assert adam["calls"] == counters.snapshot()["fused_opt.pallas"]
+
+
+def test_step_work_is_of_one_execution(forced):
+    step, batch, _, _ = forced
+    work = counters.step_work("train_step")
+    step(*batch)
+    step(*batch)
+    assert counters.step_work("train_step") == work
+
+
+def test_an_autotune_timing_leaves_the_ledger_alone(forced, monkeypatch,
+                                                   tmp_path):
+    import paddle_tpu.utils.timing as timing
+
+    work = counters.step_work("train_step")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    monkeypatch.setattr(timing, "timeit", lambda fn, *a, iters=0: 1.0)
+    autotune.reset()
+
+    def build():
+        # a candidate that dispatches like the program does
+        counters.bump("flash_attention", "pallas",
+                      work={"flash_attention_short_fwd": (1e9, 1e6)})
+        return {"only": lambda x: x}, 0
+
+    try:
+        with counters.capture("train_step_2"):
+            counters.bump("fused_opt", "pallas",
+                          work={"fused_adamw": (0.0, 28.0)})
+            assert autotune._verdict(("t", 1), "test", ("only",),
+                                     build) == "only"
+        stats = autotune.stats()
+        assert stats["timed"] == 1 and stats["timed_s"] > 0
+        assert counters.step_work("train_step_2") == {
+            "fused_adamw": {"calls": 1, "flops": 0.0, "bytes": 28.0}}
+        assert counters.step_work("train_step") == work
+    finally:
+        autotune.reset()
+
+
+def test_grad_work_counts_only_where_the_trace_differentiates():
+    declared = dict(work={"k_fwd": (2.0, 3.0)},
+                    grad_work={"k_bwd": (4.0, 5.0)})
+    counters.bump("k", "pallas", **declared)       # no capture: dropped
+    with counters.capture("s"):
+        counters.bump("k", "pallas", **declared)
+        with counters.differentiated():
+            counters.bump("k", "pallas", **declared)
+    assert counters.step_work("s") == {
+        "k_fwd": {"calls": 2, "flops": 4.0, "bytes": 6.0},
+        "k_bwd": {"calls": 1, "flops": 4.0, "bytes": 5.0}}
+    assert counters.step_work("never traced") == {}
+
+
+def test_compile_seconds_are_summed_by_function(forced):
+    step, batch, before, after = forced
+    assert after > before                  # the first call compiled
+    now = compile_cache.seconds_by_function()["train_step"]
+    step(*batch)
+    assert compile_cache.seconds_by_function()["train_step"] == now
+
+
+def test_a_sublayer_scope_is_its_registered_name_without_an_index():
+    assert _scope_name("self_attn") == "self_attn"
+    assert _scope_name("3") == _scope_name(11) == "layer"
+    net = nn.Sequential(nn.Linear(4, 4), nn.ReLU())
+    tail = nn.LayerList([nn.Linear(4, 4)])
+    tail.insert(0, nn.Linear(4, 4))
+    tail[1] = nn.Linear(4, 2)
+    for layer in list(net) + list(tail):
+        assert layer._scope == "layer"
+
+    class Net(nn.Layer):
+        def __init__(self):
+            super().__init__()
+            self.head = nn.Linear(4, 2)
+
+        def forward(self, x):
+            return self.head(x)
+
+    model = Net()
+    assert model.head._scope == "head" and "_scope" not in model.__dict__
+    text = jax.jit(lambda x: model(paddle.to_tensor(x)).value).lower(
+        np.zeros((2, 4), "float32")).as_text(debug_info=True)
+    assert "/head/" in text

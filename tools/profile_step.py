@@ -1,131 +1,232 @@
-"""Capture an XPlane/TensorBoard profile of one bench config's train
-step on the live chip (jax.profiler), for offline bottleneck analysis —
-the resnet config sits at ~20% MFU vs BERT's 41%, and only a hardware
-trace can say where the time goes.
+"""Where one benchmark cell's train step spends its device time, by the
+program's own names: phase (``loss`` forward, backward, ``optimizer``),
+module scope (``loss/bert/encoder/layer/self_attn``) and Pallas kernel
+role (``fused_xent_fwd``). The one operator's reader of the scopes that
+``nn.Layer.__call__`` and ``jit.TrainStep`` put on the compiled step.
 
-Usage: python tools/profile_step.py [--config resnet] [--out DIR]
+    python tools/profile_step.py --workload <cell> --out DIR
+        [--seed N] [--steps 10] [--depth 5]
+
+Builds the cell's ``Loop`` through ``benchmarks.harness.context`` and the
+cell's driver, warms it, traces ``--steps`` steps on the chip and keeps
+the ``.xplane.pb`` under DIR. The ``XLA Ops`` events of a v5e profile
+name an instruction and carry no ``op_name`` (looked for in their stats,
+PR 24), so an event's scope comes from joining its instruction name with
+the ``metadata={op_name=...}`` of the compiled step's text
+(``TrainStep.lower(...).compile().as_text()``). A fusion has the op_name
+of one of the operations fused into it: a weight's AdamW update that XLA
+fused into the matmul of its gradient counts under that layer's backward.
+Chip only, like the benchmark; :func:`summarize` is plain arithmetic and
+is tested on a small recorded event list.
 """
 from __future__ import annotations
 
 import argparse
+import json
 import os
+import re
 import sys
+from typing import Dict, Iterable, List, Optional, Tuple
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
+#: an event with no op_name: copies and transfers the compiler added
+NO_SCOPE = "(no scope)"
+_TRANSFORM = re.compile(r"^(jvp|transpose|vmap|remat|checkpoint|"
+                        r"custom_jvp|custom_vjp|shard_map)\((.*)\)$")
+_JIT = re.compile(r"^p?jit\(.*\)$")
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*?op_name=\"([^\"]+)\"")
+_NUM_SUFFIX = re.compile(r"(\.\d+)+$")
 
-def summarize_trace(out_dir: str, config: str, row: dict,
-                    summary_path: str, top_k: int = 25) -> bool:
-    """Aggregate the chrome-trace events jax.profiler wrote under
-    `out_dir` into a committed markdown table: total device time by op
-    name, top offenders first — the offline 'where does the non-MXU
-    time go' answer VERDICT r4 #2 asks for, without needing the
-    tensorboard profile plugin in the image."""
-    import glob
-    import gzip
-    import json
-    from collections import defaultdict
 
-    traces = sorted(glob.glob(
-        os.path.join(out_dir, "**", "*.trace.json.gz"), recursive=True))
-    if not traces:
-        print(f"no .trace.json.gz under {out_dir}; summary skipped")
-        return False
-    with gzip.open(traces[-1], "rt") as f:
-        data = json.load(f)
-    events = data.get("traceEvents", [])
-    pid_names = {e.get("pid"): e.get("args", {}).get("name", "")
-                 for e in events if e.get("name") == "process_name"}
-    device_pids = {p for p, n in pid_names.items()
-                   if "TPU" in str(n) or "/device" in str(n).lower()}
-    # a device pid carries several thread lines ("XLA Modules", "Steps",
-    # "XLA Ops"); module/step spans equal the SUM of the op events below
-    # them, so summing across tids double-counts — keep op-level only
-    tid_names = {(e.get("pid"), e.get("tid")):
-                 str(e.get("args", {}).get("name", ""))
-                 for e in events if e.get("name") == "thread_name"}
-    # explicit op-line match: a substring like "op" also hits
-    # "TensorFlow Name Scope" (sc-op-e), whose hierarchical spans
-    # already contain every op under them — double counting
-    op_tids = {k for k, n in tid_names.items()
-               if k[0] in device_pids and "xla ops" in n.lower()}
+def scopes_from_text(hlo_text: str) -> Dict[str, str]:
+    """instruction name -> op_name, from a compiled module's text."""
+    out = {}
+    for line in hlo_text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m:
+            out[m.group(1)] = m.group(2)
+    return out
 
-    per_tid = defaultdict(lambda: defaultdict(float))
-    counts = defaultdict(int)
-    for e in events:
-        if e.get("ph") != "X" or e.get("pid") not in device_pids:
-            continue
-        key = (e.get("pid"), e.get("tid"))
-        if op_tids and key not in op_tids:
-            continue
-        dur = float(e.get("dur", 0.0))   # microseconds
-        name = str(e.get("name", "?"))
-        # fold fusion instances: fusion.123 -> fusion; keep op kind
-        base = name.split(".")[0] if name.split(".")[-1].isdigit() else name
-        per_tid[key][base] += dur
-        counts[key] += 1
-    if not per_tid:
-        print("trace had no device events; summary skipped")
-        return False
-    if op_tids:
-        # merge the explicit op-level threads (one per core)
-        agg = defaultdict(float)
-        for t in per_tid.values():
-            for k, v in t.items():
-                agg[k] += v
+
+def split_op_name(op_name: Optional[str]) -> Tuple[str, List[str]]:
+    """(phase, scope path) of an op_name such as
+    ``jit(train_step)/transpose(jvp(loss))/bert/encoder/layer/mul``:
+    jax wraps the first scope inside a differentiated region in
+    ``jvp(...)`` and, for the backward ops, ``transpose(jvp(...))``. The
+    path drops the module, inner ``jit(...)`` calls, the ``pallas`` guard
+    above a kernel's role and the primitive at the end."""
+    if not op_name:
+        return "unattributed", []
+    # an instruction XLA merged from several carries their op_names
+    # joined by ';': the first stands for it
+    parts = op_name.split(";")[0].split("/")
+    if parts and _JIT.match(parts[0]):
+        parts = parts[1:]
+    parts = parts[:-1]                       # the primitive
+    transforms, path = set(), []
+    for part in parts:
+        m = _TRANSFORM.match(part)
+        while m:
+            transforms.add(m.group(1))
+            part = m.group(2)
+            m = _TRANSFORM.match(part)
+        if part and not _JIT.match(part) and part != "pallas":
+            path.append(part)
+    if "transpose" in transforms:
+        phase = "backward"
+    elif "jvp" in transforms:
+        phase = "forward"
+    elif path and path[0] == "optimizer":
+        phase = "optimizer"
     else:
-        # no thread_name metadata: the op line has by far the most
-        # events (module/step lines have a handful of giant spans)
-        agg = per_tid[max(counts, key=counts.get)]
-    total = sum(agg.values())
-    rows = sorted(agg.items(), key=lambda kv: -kv[1])[:top_k]
-    from tools._captures import git_sha
-
-    with open(summary_path, "a") as f:
-        f.write(f"\n## {config} @ {row.get('device_kind', '?')} "
-                f"(sha {git_sha()}, {row.get('value')} {row.get('unit')}"
-                f", mfu {row.get('mfu')})\n\n")
-        f.write("| op | device ms | % of device time |\n|---|---|---|\n")
-        for name, us in rows:
-            f.write(f"| {name} | {us / 1e3:.2f} | "
-                    f"{100.0 * us / total:.1f}% |\n")
-        f.write(f"| TOTAL (all ops) | {total / 1e3:.2f} | 100% |\n")
-    print(f"summary appended to {summary_path} "
-          f"({len(rows)} rows, total {total / 1e3:.1f} ms device time)")
-    return True
+        phase = "other"
+    return phase, path
 
 
-def main():
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--config", default="resnet")
-    ap.add_argument("--out", default="/tmp/paddle_tpu_profile")
-    ap.add_argument("--steps", type=int, default=5)
-    ap.add_argument("--summary", default=None,
-                    help="markdown file to append a device-time-by-op "
-                         "table to (e.g. XPLANE_SUMMARY.md)")
-    args = ap.parse_args()
+def summarize(events: Iterable[Tuple[str, Optional[str], float]],
+              depth: int = 5, top: int = 12) -> dict:
+    """``events``: (instruction name, op_name or None, seconds) of one
+    device's operations. Seconds by phase, by module scope cut at
+    ``depth``, by Pallas kernel role (``kernel:`` names, numeric suffix
+    merged), and for the ``top`` operation families the scopes that own
+    them."""
+    by_phase: Dict[str, float] = {}
+    by_scope: Dict[str, float] = {}
+    kernels: Dict[str, float] = {}
+    by_op: Dict[str, Dict[str, float]] = {}
+    total = 0.0
+    for name, op_name, seconds in events:
+        phase, path = split_op_name(op_name)
+        scope = "/".join(path[:depth]) or NO_SCOPE
+        family = _NUM_SUFFIX.sub("", name)
+        total += seconds
+        by_phase[phase] = by_phase.get(phase, 0.0) + seconds
+        by_scope[scope] = by_scope.get(scope, 0.0) + seconds
+        owners = by_op.setdefault(family, {})
+        owners[scope] = owners.get(scope, 0.0) + seconds
+        if family.startswith("kernel:"):
+            role = family[len("kernel:"):]
+            kernels[role] = kernels.get(role, 0.0) + seconds
 
+    def ranked(table):
+        return sorted(table.items(), key=lambda kv: -kv[1])
+
+    ops = sorted(by_op.items(), key=lambda kv: -sum(kv[1].values()))[:top]
+    return {"total_s": total, "by_phase": ranked(by_phase),
+            "by_scope": ranked(by_scope), "kernels": ranked(kernels),
+            "owners": [[op, sum(t.values()), ranked(t)] for op, t in ops]}
+
+
+def device_events(xplane_path: str) -> List[Tuple[str, float]]:
+    """[(instruction name, seconds)] of the first TPU plane's ``XLA Ops``
+    line, named as ``benchmarks/trace_reduce.py`` names them."""
+    from jax.profiler import ProfileData
+
+    from benchmarks import trace_reduce
+
+    for plane in ProfileData.from_file(xplane_path).planes:
+        if trace_reduce._DEVICE_PLANE.match(plane.name):
+            return [(trace_reduce.short_name(ev.name),
+                     float(ev.duration_ns) / 1e9)
+                    for line in plane.lines
+                    if line.name == trace_reduce._OP_LINE
+                    for ev in line.events]
+    return []
+
+
+def render(summary: dict, steps: int) -> str:
+    total = summary["total_s"]
+    lines = [f"device seconds in {steps} traced steps: {total:.4f}"]
+
+    def table(title, rows):
+        lines.append(f"\n{title}")
+        lines.append("| | s | share |")
+        lines.append("| --- | --- | --- |")
+        for name, s in rows:
+            lines.append(f"| {name} | {s:.4f} | {100 * s / total:.1f}% |")
+
+    table("by phase", summary["by_phase"])
+    table("by module scope", summary["by_scope"])
+    table("Pallas kernel roles", summary["kernels"])
+    lines.append("\nwho owns the largest operation families")
+    for op, s, owners in summary["owners"]:
+        own = ", ".join(f"{scope} {100 * t / s:.0f}%"
+                        for scope, t in owners[:4])
+        lines.append(f"| {op} | {s:.4f} | {100 * s / total:.1f}% | {own} |")
+    return "\n".join(lines)
+
+
+def profile(workload: str, out: str, seed: int = 1, steps: int = 10,
+            depth: int = 5, warm: int = 3, root: Optional[str] = None,
+            check_device: bool = True) -> dict:
+    """Trace ``steps`` steps of the cell's loop and reduce the trace."""
     import jax
 
-    from paddle_tpu.framework.bringup import TPU_PLATFORMS
+    from benchmarks import harness, trace_reduce, traffic
 
-    backend = jax.default_backend()
-    if backend not in TPU_PLATFORMS:
-        print(f"backend {backend!r}: profiling a CPU run is not useful")
-        return 1
+    ctx, driver, info = harness.context(
+        workload, seed, 0.0, root or harness.ROOT, check_device)
+    if not hasattr(driver, "Loop"):
+        raise harness.Refused(f"the driver of {workload} has no Loop: "
+                              "only training cells have a step to profile")
+    cfg, cell = ctx.config, ctx.cell
+    batches = traffic.train_batches(cell["traffic"], cfg["vocab_size"], seed)
+    loop = driver.Loop(cfg, cell, driver.make_params(cfg, seed), seed)
+    loss = None
+    for _ in range(warm):
+        loss = loop.feed_and_step(batches[loop.steps % len(batches)])
+    float(loss)                              # a fetch: the device is done
+    os.makedirs(out, exist_ok=True)
+    jax.profiler.start_trace(out)
+    for _ in range(steps):
+        loss = loop.feed_and_step(batches[loop.steps % len(batches)])
+    float(loss)
+    jax.profiler.stop_trace()
+    path = trace_reduce.find_xplane(out)
+    if path is None:
+        raise harness.Refused(f"the profiler wrote no .xplane.pb under {out}")
+    events = device_events(path)
+    if not events:
+        raise harness.Refused("the trace holds no device operation")
+    import paddle_tpu as paddle
 
-    import bench
+    batch = [paddle.to_tensor(a) for a in batches[0]]
+    scopes = scopes_from_text(loop.step.lower(*batch).compile().as_text())
+    events = [(n, scopes.get(n.removeprefix("kernel:")), s)
+              for n, s in events]
+    summary = summarize(events, depth)
+    summary.update(workload=workload, steps=steps, device=info, xplane=path)
+    print(f"{workload} on {info}: trace kept at {path} "
+          f"({os.path.getsize(path)} bytes)")
+    print(render(summary, steps))
+    return summary
 
-    os.environ.setdefault("BENCH_STEPS", str(args.steps))
-    os.makedirs(args.out, exist_ok=True)
-    with jax.profiler.trace(args.out):
-        row = bench.CONFIGS[args.config](False)
-    bench.attach_mfu(row)
-    print({k: row.get(k) for k in ("value", "unit", "dt", "steps", "mfu")})
-    print(f"trace written under {args.out} (tensorboard --logdir {args.out})")
-    if args.summary:
-        summarize_trace(args.out, args.config, row, args.summary)
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True,
+                    help="a training cell of BENCHMARK.json")
+    ap.add_argument("--out", required=True,
+                    help="directory for the trace (.xplane.pb) and "
+                         "summary.json")
+    ap.add_argument("--seed", type=int, default=1)
+    ap.add_argument("--steps", type=int, default=10)
+    ap.add_argument("--depth", type=int, default=5,
+                    help="module scopes are cut at this depth")
+    args = ap.parse_args(argv)
+    from benchmarks import harness
+
+    try:
+        summary = profile(args.workload, args.out, args.seed, args.steps,
+                          args.depth)
+    except harness.Refused as e:
+        print(f"REFUSED: {e}", file=sys.stderr)
+        return 2
+    with open(os.path.join(args.out, "summary.json"), "w") as f:
+        json.dump(summary, f, indent=1)
     return 0
 
 
