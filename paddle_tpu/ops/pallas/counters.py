@@ -25,6 +25,9 @@ write once, per role, from the shapes in hand. While a compiled step is
 traced inside :func:`capture`, the declared work is summed by role and
 kept as the work of ONE execution of that step:
 ``step_work("train_step")`` -> ``{role: {"calls", "flops", "bytes"}}``.
+Where a dispatch picks among kernels on the device (the row-capacity
+ladder of ``fused_xent``), every role it may run is listed with its own
+work and one of them runs in an execution; a device trace shows which.
 Kernel time from a device trace over that work is the kernel's roofline
 share (``benchmarks/kernel_rows.py``).
 """
@@ -61,7 +64,7 @@ def bump(kernel: str, path: str, reason: str = "",
     trace differentiates it (:func:`differentiated`); both only count
     inside a :func:`capture`."""
     _COUNTS[f"{kernel}.{path}"] += 1
-    if path != "pallas" and get_flag("log_pallas_fallback"):
+    if path == "xla" and get_flag("log_pallas_fallback"):
         msg = f"pallas-fallback: {kernel} -> {path}"
         if reason:
             msg += f" ({reason})"
@@ -109,7 +112,8 @@ def differentiated():
 def step_work(step: str) -> Dict[str, Dict[str, float]]:
     """{role: {"calls", "flops", "bytes"}} of ONE execution of the
     compiled step ``step``; empty when it was never traced or launched
-    no kernel that declares work."""
+    no kernel that declares work. Roles under a data-dependent branch
+    are all listed, and one of them runs."""
     return {role: dict(row) for role, row in _STEP_WORK.get(step, {}).items()}
 
 

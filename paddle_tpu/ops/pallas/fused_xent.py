@@ -1,17 +1,27 @@
 """Fused linear + softmax cross-entropy for large vocabularies.
 
 The BERT MLM head computes logits = h @ W^T + b with W the tied
-(vocab, hidden) embedding table, then softmax-xent over vocab. At
-bert512 bench shapes the logits tensor is (32*512, 30592) — ~1 GB in
-bf16 — written to HBM by the matmul, read back by the softmax, and the
-same again for dlogits in the backward. That HBM traffic is pure
-overhead: these Pallas kernels stream W in vocab tiles over a 2D grid
-(rows-block outer, vocab-block inner — the inner axis revisits the
-same output block, the canonical Pallas reduction idiom), carrying an
-online max/sumexp + label-logit forward and recomputing the logit
-blocks in the backward for dh and dW/db (the flash trick: p =
-exp(s - lse) needs only the saved lse). Logits never land in HBM in
-either direction.
+(vocab, hidden) embedding table, then softmax-xent over vocab. At the
+benchmark's BERT-base cells (64 x 512 rows, 5,120 of them labelled,
+vocab 30592) the logits of the 8,192 rows the kernels are handed would
+be 0.5 GB in bf16 (2 GB for all 32,768) — written to HBM by the matmul,
+read back by the softmax, and the same again for dlogits in the
+backward. That HBM traffic is pure overhead: these Pallas kernels
+stream W in vocab tiles over a 2D grid (rows-block outer, vocab-block
+inner — the inner axis revisits the same output block, the canonical
+Pallas reduction idiom), carrying an online max/sumexp + label-logit
+forward and recomputing the logit blocks in the backward for dh and
+dW/db (the flash trick: p = exp(s - lse) needs only the saved lse).
+Logits never land in HBM in either direction.
+
+Rows whose label is ignored add nothing to the loss or to any gradient,
+so the single-device path leaves them out: it counts the labelled rows
+on the device, orders the rows labelled-first and runs the kernels on
+the first K of them, K the smallest rung of a short ladder of static
+row capacities (``_ladder``) that holds the count, picked with
+``lax.switch``. Each rung's kernels run under a role name of their own
+(``_tag``), so a device trace shows which rung ran and the work
+ledger charges exactly that rung's rows.
 
 Reference analog: softmax_with_cross_entropy_op.cu fuses softmax+xent
 (but not the matmul); the matmul fusion is the TPU-native extension
@@ -75,9 +85,9 @@ def _fits(bn, bv, hd):
 def _pick_blocks(n, hd, v):
     """Joint (block_n, block_v) choice, LARGEST bn first: every grid
     row-block streams the ENTIRE weight table once (47 MB for BERT),
-    so bn — not bv — sets the dominant HBM traffic; at bert512
-    (n=16384, hd=768) 1024-row blocks read W 16x (~0.75 GB) vs 64x
-    (~3 GB) at 256. A greedy-large bv that forced a smaller bn under
+    so bn — not bv — sets the dominant HBM traffic; at the benchmark's
+    rung (n=8192, hd=768) 1024-row blocks read W 8x (~0.38 GB) vs 32x
+    (~1.5 GB) at 256. A greedy-large bv that forced a smaller bn under
     the VMEM cap would double exactly that traffic, so bv concedes
     first. Returns None when nothing divides + fits (dispatch falls
     back to XLA via _eligible). Vocab lane modulus 128: BERT's 30592
@@ -196,14 +206,14 @@ def _bwd_dw_kernel(h_ref, w_ref, b_ref, lab_ref, lse_ref, g_ref,
 # ---------------------------------------------------------------------------
 
 
-def _fwd_call(h, w, bias, labels, block_n, block_v):
+def _fwd_call(h, w, bias, labels, block_n, block_v, tag=""):
     from jax.experimental import pallas as pl
 
     n, hd = h.shape
     v = w.shape[0]
     num_v = v // block_v
     lse, ll, _m, _l = kernel_call(
-        "fused_xent_fwd",
+        f"fused_xent_{tag}fwd",
         functools.partial(_fwd_kernel, num_v=num_v, block_v=block_v),
         grid=(n // block_n, num_v),
         in_specs=[
@@ -229,14 +239,14 @@ def _fwd_call(h, w, bias, labels, block_n, block_v):
     return lse[0], ll[0]
 
 
-def _bwd_call(h, w, bias, labels, lse, g, block_n, block_v):
+def _bwd_call(h, w, bias, labels, lse, g, block_n, block_v, tag=""):
     from jax.experimental import pallas as pl
 
     n, hd = h.shape
     v = w.shape[0]
     # both backward kernels under the one role: a trace sums them
     dh = kernel_call(
-        "fused_xent_bwd",
+        f"fused_xent_{tag}bwd",
         functools.partial(_bwd_dh_kernel, block_v=block_v),
         grid=(n // block_n, v // block_v),
         in_specs=[
@@ -252,7 +262,7 @@ def _bwd_call(h, w, bias, labels, lse, g, block_n, block_v):
         compiler_params=_compiler_params(),
     )(h, w, bias[None, :], labels[None, :], lse[None, :], g[None, :])
     dw, db = kernel_call(
-        "fused_xent_bwd",
+        f"fused_xent_{tag}bwd",
         functools.partial(_bwd_dw_kernel, block_n=block_n,
                           block_v=block_v),
         grid=(v // block_v, n // block_n),
@@ -277,49 +287,109 @@ def _bwd_call(h, w, bias, labels, lse, g, block_n, block_v):
     return dh.astype(h.dtype), dw.astype(w.dtype), db[0]
 
 
-def _fused_xent_core(h, w, bias, labels, ignore_index):
-    """mean loss = sum / clamp(count): derived from the ONE sum-form
-    custom_vjp below (autodiff of the division supplies the 1/count
-    the hand-written mean backward used to hard-code — r5 review
-    dedup)."""
-    s, c = _fused_xent_sums(h, w, bias, labels, ignore_index)
-    return s / jnp.maximum(c, 1.0)
+def _ladder(n, block_n):
+    """The static row capacities a dispatch on ``n`` rows may run at:
+    n/8, n/4, n/2 and n, each rounded up to whole row blocks, ascending
+    (one rung when n is one block). Powers of two of the row count, so
+    no capacity is a constant someone tuned; below n/8 the head is a few
+    percent of a step."""
+    return tuple(sorted({-(-n // (d * block_n)) * block_n
+                         for d in (8, 4, 2, 1)}))
 
 
-# -- the single custom_vjp: per-shard (loss_sum, valid_count), so the
-# shard_map'd multi-device path can psum BEFORE the mean --------------------
+def _tag(k, n):
+    """What sets a rung's role names apart: ``fused_xent_rows<K>_fwd`` /
+    ``_bwd`` below the top, today's ``fused_xent_fwd`` / ``_bwd`` at it.
+    No rung's name holds another's, so a trace reader that matches rows
+    by role (``benchmarks/kernel_rows.py``) charges a rung's seconds to
+    that rung's declared work alone."""
+    return "" if k == n else f"rows{k}_"
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _fused_xent_sums(h, w, bias, labels, ignore_index):
-    (s, c), _ = _fused_xent_sums_fwd(h, w, bias, labels, ignore_index)
-    return s, c
-
-
-def _fused_xent_sums_fwd(h, w, bias, labels, ignore_index):
-    valid = labels != ignore_index
-    # rows with ignored labels still flow through the kernel; clamp the
-    # label so the in-kernel hit-test never matches, zero the loss after
-    safe = jnp.where(valid, labels, -1).astype(jnp.int32)
+def _blocks(h, w):
     blocks = _pick_blocks(h.shape[0], h.shape[1], w.shape[0])
     if blocks is None:
         raise ValueError(
             f"fused_xent: no (block_n, block_v) divides+fits h "
             f"{h.shape} x w {w.shape} — dispatch should have taken the "
             "XLA path (_eligible)")
-    bn, bv = blocks
-    lse, ll = _fwd_call(h, w, bias, safe, bn, bv)
-    s = jnp.sum(jnp.where(valid, lse - ll, 0.0))
-    c = jnp.sum(valid.astype(_F32))
-    return (s, c), (h, w, bias, safe, valid, lse)
+    return blocks
 
 
-def _fused_xent_sums_bwd(ignore_index, res, ct):
+def _fused_xent_core(h, w, bias, labels, ignore_index):
+    """mean loss = sum / clamp(count): derived from the ONE sum-form
+    custom_vjp below (autodiff of the division supplies the 1/count
+    the hand-written mean backward used to hard-code — r5 review
+    dedup)."""
+    rungs = _ladder(h.shape[0], _blocks(h, w)[0])
+    s, c = _fused_xent_sums(h, w, bias, labels, ignore_index, rungs)
+    return s / jnp.maximum(c, 1.0)
+
+
+# -- the single custom_vjp: per-shard (loss_sum, valid_count), so the
+# shard_map'd multi-device path can psum BEFORE the mean. ``rungs`` are
+# the row capacities it may run at, the last one all of its rows --------
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _fused_xent_sums(h, w, bias, labels, ignore_index, rungs):
+    (s, c), _ = _fused_xent_sums_fwd(h, w, bias, labels, ignore_index, rungs)
+    return s, c
+
+
+def _fused_xent_sums_fwd(h, w, bias, labels, ignore_index, rungs):
+    n = h.shape[0]
+    valid = labels != ignore_index
+    # an unlabelled row that rides along in a rung (or fills the top one)
+    # gets a label the in-kernel hit-test never matches; its loss is
+    # masked here and its cotangent is zero in the backward
+    safe = jnp.where(valid, labels, -1).astype(jnp.int32)
+    bn, bv = _blocks(h, w)
+    count = jnp.sum(valid, dtype=jnp.int32)
+    # labelled rows first: the first K of this order are distinct, in
+    # range and hold every labelled row whenever count <= K
+    order = jnp.argsort(~valid, stable=True) if len(rungs) > 1 else None
+    rung = jnp.sum(count > jnp.asarray(rungs[:-1], jnp.int32))
+
+    def at(k):
+        def run(h, safe, valid):
+            if k == n:
+                lse, ll = _fwd_call(h, w, bias, safe, bn, bv)
+            else:
+                rows = order[:k]
+                h, safe, valid = h[rows], safe[rows], valid[rows]
+                lse, ll = _fwd_call(h, w, bias, safe, bn, bv, _tag(k, n))
+            # lse stays in the rung's own row order, for its backward
+            return (jnp.sum(jnp.where(valid, lse - ll, 0.0)),
+                    jnp.pad(lse, (0, n - k)))
+        return run
+
+    s, lse = jax.lax.switch(rung, [at(k) for k in rungs], h, safe, valid)
+    return (s, count.astype(_F32)), (h, w, bias, safe, valid, lse, order,
+                                     rung)
+
+
+def _fused_xent_sums_bwd(ignore_index, rungs, res, ct):
     ds, _dc = ct   # count is a step function of int labels: no grad path
-    h, w, bias, safe, valid, lse = res
+    h, w, bias, safe, valid, lse, order, rung = res
+    n = h.shape[0]
     g = jnp.where(valid, ds, 0.0).astype(_F32)
-    bn, bv = _pick_blocks(h.shape[0], h.shape[1], w.shape[0])  # fwd validated
-    dh, dw, db = _bwd_call(h, w, bias, safe, lse, g, bn, bv)
+    bn, bv = _blocks(h, w)
+
+    def at(k):
+        def run(h, safe, lse, g):
+            if k == n:
+                return _bwd_call(h, w, bias, safe, lse, g, bn, bv)
+            rows = order[:k]
+            dh, dw, db = _bwd_call(h[rows], w, bias, safe[rows], lse[:k],
+                                   g[rows], bn, bv, _tag(k, n))
+            # the rows left out are unlabelled: their dh is exactly zero
+            return (jnp.zeros_like(h).at[rows].set(dh, unique_indices=True),
+                    dw, db)
+        return run
+
+    dh, dw, db = jax.lax.switch(rung, [at(k) for k in rungs], h, safe, lse,
+                                g)
     return dh, dw, db.astype(bias.dtype), None
 
 
@@ -347,7 +417,10 @@ def _sharded_fused(h2, w, bias, lab, mesh, row_axes, ignore_index):
         if _SHARD_MAP_CHECK_VMA[0]:
             ws, bs = (jax.lax.pcast(a, row_axes, to="varying")
                       for a in (ws, bs))
-        s, c = _fused_xent_sums(hs, ws, bs, ls, ignore_index)
+        # every shard on all of its rows: the ladder is the single-device
+        # path's (PERF.md §7)
+        s, c = _fused_xent_sums(hs, ws, bs, ls, ignore_index,
+                                (hs.shape[0],))
         s = jax.lax.psum(s, row_axes)
         c = jax.lax.psum(c, row_axes)
         return s / jnp.maximum(c, 1.0)
@@ -387,19 +460,25 @@ def _eligible(n, hd, v):
             hd % 128 == 0 and hd <= 2048)
 
 
-def _work(h2, w, bias, lab):
-    """``work=`` / ``grad_work=`` of one call on these rows, for the
-    ledger in ``counters``: the logits matmul forward (2 N H V); dh and dW
-    backward (4 N H V, the recomputed logits not counted). Bytes: h, W,
-    bias and labels read, lse and the label logit written; backward
+def _work(h2, w, bias, lab, rungs):
+    """``work=`` / ``grad_work=`` of one call that runs at one of the row
+    capacities ``rungs``, for the ledger in ``counters``, each rung under
+    its own roles: the logits matmul forward (2 K H V); dh and dW backward
+    (4 K H V, the recomputed logits not counted). Bytes: K rows of h and
+    labels, W and bias read, lse and the label logit written; backward
     reads those with lse and the row cotangent and writes dh, dW, db."""
     n, hd = h2.shape
     v = w.shape[0]
-    read = nbytes(h2, w, bias, lab)
-    return {
-        "work": {"fused_xent_fwd": (2.0 * n * hd * v, read + 8 * n)},
-        "grad_work": {"fused_xent_bwd": (
-            4.0 * n * hd * v, read + 8 * n + nbytes(h2, w, bias))}}
+    work, grad_work = {}, {}
+    for k in rungs:
+        rows = nbytes(h2, lab) * k // n
+        read = rows + nbytes(w, bias)
+        tag = _tag(k, n)
+        work[f"fused_xent_{tag}fwd"] = (2.0 * k * hd * v, read + 8 * k)
+        grad_work[f"fused_xent_{tag}bwd"] = (
+            4.0 * k * hd * v,
+            read + 8 * k + nbytes(h2) * k // n + nbytes(w, bias))
+    return {"work": work, "grad_work": grad_work}
 
 
 def fused_linear_cross_entropy(h, w, bias, labels, ignore_index=-100):
@@ -425,7 +504,8 @@ def fused_linear_cross_entropy(h, w, bias, labels, ignore_index=-100):
         mesh, row_axes = plan
         out = _sharded_fused(h2, w, bias, lab, mesh, row_axes,
                              int(ignore_index))
-        bump("fused_xent", "pallas_sharded", **_work(h2, w, bias, lab))
+        bump("fused_xent", "pallas_sharded",
+             **_work(h2, w, bias, lab, (n,)))
         return out
     elif _eligible(n + pad, hd, w.shape[0]):
         if pad:
@@ -434,7 +514,10 @@ def fused_linear_cross_entropy(h, w, bias, labels, ignore_index=-100):
             lab = jnp.concatenate(
                 [lab, jnp.full((pad,), ignore_index, lab.dtype)], 0)
         out = _fused_xent_core(h2, w, bias, lab, int(ignore_index))
-        bump("fused_xent", "pallas", **_work(h2, w, bias, lab))
+        rungs = _ladder(n + pad, _blocks(h2, w)[0])
+        bump("fused_xent", "pallas", **_work(h2, w, bias, lab, rungs))
+        if len(rungs) > 1:
+            bump("fused_xent", "ladder")
         return out
     else:
         bump("fused_xent", "xla",

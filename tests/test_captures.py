@@ -135,6 +135,16 @@ def test_profile_step_sums_by_phase_scope_and_kernel_role():
     assert "| fused_xent_bwd | 6.0000 | 30.0% |" in text
 
 
+def test_profile_step_counts_an_enclosing_operation_once():
+    # a cond that ran a gather and a kernel, then a fusion after it
+    got = _profile_step().self_seconds([
+        ("fusion.2", 8e9, 3e9), ("kernel:fused_xent_rows512_fwd", 1.5e9, 6e9),
+        ("cond", 0.0, 8e9), ("gather_fusion", 0.5e9, 1e9)])
+    assert got == [("cond", 1.0), ("gather_fusion", 1.0),
+                   ("kernel:fused_xent_rows512_fwd", 6.0), ("fusion.2", 3.0)]
+    assert sum(s for _, s in got) == 11.0
+
+
 def test_profile_step_joins_instructions_with_the_compiled_text():
     mod = _profile_step()
     hlo = """HloModule jit_train_step, is_scheduled=true

@@ -143,6 +143,69 @@ def test_bf16_grads_accumulate_in_f32(interp):
                                rtol=2e-2, atol=2e-3)
 
 
+# -- the row-capacity ladder: 2048 rows in blocks of 256 may run at 256,
+# 512, 1024 or all 2048 rows --------------------------------------------
+LN, LBN, LK = 2048, 256, 512
+RUNGS = (256, 512, 1024, 2048)
+
+
+@pytest.fixture
+def ladder(interp, monkeypatch):
+    monkeypatch.setattr(fx, "_BN_CANDIDATES", (LBN,))
+    assert fx._ladder(LN, LBN) == RUNGS
+    assert fx._ladder(LBN, LBN) == (LBN,)     # one block: today's path
+
+
+def _labelled(count, where, seed):
+    """(h, w, b, labels) with exactly ``count`` labelled rows of LN."""
+    h, w, b, lab = _data(n=LN, v=512, seed=seed, ignore_frac=0.0)
+    rng = np.random.RandomState(seed + 100)
+    keep = (rng.permutation(LN)[:count] if where == "scattered"
+            else np.arange(LN - count, LN))
+    mask = np.zeros(LN, bool)
+    mask[keep] = True
+    return h, w, b, jnp.where(jnp.asarray(mask), lab, -100)
+
+
+@pytest.mark.parametrize("where", ["scattered", "bunched_at_end"])
+@pytest.mark.parametrize("count", [0, 1, LK - 1, LK, LK + 1, LN])
+def test_ladder_matches_reference(ladder, count, where):
+    """Whatever rung the label count lands on — empty, one row, either
+    side of a capacity, the full rows — loss and gradients are the
+    reference's, and an unlabelled row's dh is exactly zero."""
+    h, w, b, lab = _labelled(count, where, seed=count % 7)
+    loss, (gh, gw, gb) = jax.jit(jax.value_and_grad(
+        lambda *a: fx.fused_linear_cross_entropy(*a, lab),
+        argnums=(0, 1, 2)))(h, w, b)
+    snap = counters.snapshot()
+    assert snap["fused_xent.pallas"] == 1 and snap["fused_xent.ladder"] == 1
+    ref, g_r = jax.value_and_grad(
+        lambda *a: _ref_loss(*a, lab), argnums=(0, 1, 2))(h, w, b)
+    np.testing.assert_allclose(float(loss), float(ref), rtol=2e-5)
+    for a, r in zip((gh, gw, gb), g_r):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r),
+                                   rtol=1e-4, atol=2e-5)
+    assert not np.asarray(gh)[np.asarray(lab) == -100].any()
+    # the smallest capacity that holds the count is the one that ran
+    rung = jax.jit(lambda: fx._fused_xent_sums_fwd(
+        h, w, b, lab, -100, RUNGS)[1][-1])()
+    assert RUNGS[int(rung)] == min(k for k in RUNGS if k >= count)
+
+
+def test_full_labels_run_todays_kernels_under_todays_names(ladder):
+    """A feed whose labels fill the rows (causal LM) lands on the top
+    rung: the kernels on all rows, under ``fused_xent_fwd`` / ``_bwd``;
+    every lower rung's kernels have names of their own."""
+    h, w, b, lab = _labelled(LN, "scattered", seed=3)
+    text = jax.jit(jax.grad(
+        lambda *a: fx.fused_linear_cross_entropy(*a, lab),
+        argnums=(0, 1, 2))).lower(h, w, b).as_text(debug_info=True)
+    for role in ["fused_xent_fwd", "fused_xent_bwd"] + [
+            f"fused_xent_rows{k}_{d}" for k in RUNGS[:-1]
+            for d in ("fwd", "bwd")]:
+        assert f"pallas/{role}/pallas_call" in text, role
+
+
 def test_ineligible_vocab_falls_back(interp):
     h, w, b, lab = _data(v=100, seed=3)   # 100 % 512 != 0
     out = fx.fused_linear_cross_entropy(h, w, b, lab)

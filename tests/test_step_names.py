@@ -25,20 +25,20 @@ B, S = 2, 128
 N, D = B * S, H // A
 
 
-def _batch(seed=0):
+def _batch(seed=0, b=B, s=S):
     rng = np.random.default_rng(seed)
-    ids = rng.integers(0, V, (B, S)).astype("int32")
-    mlm = np.where(rng.random((B, S)) < 0.15, ids, -100).astype("int32")
+    ids = rng.integers(0, V, (b, s)).astype("int32")
+    mlm = np.where(rng.random((b, s)) < 0.15, ids, -100).astype("int32")
     return [paddle.to_tensor(a) for a in (
-        ids, np.zeros((B, S), "int32"), mlm,
-        rng.integers(0, 2, (B,)).astype("int32"))]
+        ids, np.zeros((b, s), "int32"), mlm,
+        rng.integers(0, 2, (b,)).astype("int32"))]
 
 
-def _train_step():
+def _train_step(positions=S):
     model = BertForPretraining(BertConfig(
         vocab_size=V, hidden_size=H, num_hidden_layers=L,
         num_attention_heads=A, intermediate_size=I,
-        max_position_embeddings=S, hidden_dropout_prob=0.0,
+        max_position_embeddings=positions, hidden_dropout_prob=0.0,
         attention_probs_dropout_prob=0.0))
     model.eval()
     opt = optimizer.AdamW(learning_rate=1e-4,
@@ -128,6 +128,76 @@ def test_step_work_equals_the_closed_forms(forced):
     adam = work["fused_adamw"]
     assert adam["flops"] == 0 and adam["bytes"] % 28 == 0
     assert adam["calls"] == counters.snapshot()["fused_opt.pallas"]
+
+
+@pytest.fixture(scope="module")
+def laddered():
+    """(fused-xent rows of the ledger, lowered text) of a tiny BERT step
+    whose 1024 MLM rows, in blocks of 256, may run at 256, 512 or 1024
+    rows: traced once, not run, the module's own ledger left as it was."""
+    from jax.experimental import pallas as pl
+    from paddle_tpu.ops.pallas import fused_xent as fx
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(pl, "pallas_call",
+                   functools.partial(pl.pallas_call, interpret=True))
+        mp.setattr(bringup, "pallas_enabled", lambda: True)
+        mp.setenv("PADDLE_FUSED_OPT", "0")
+        mp.setattr(fx, "_BN_CANDIDATES", (256,))
+        mp.setattr(counters, "_STEP_WORK", {})
+        text = _train_step(positions=256).lower(
+            *_batch(b=4, s=256)).as_text(debug_info=True)
+        work = counters.step_work("train_step")
+    return {role: w for role, w in work.items()
+            if "fused_xent" in role}, text
+
+
+def test_every_rung_has_roles_of_its_own(laddered):
+    work, text = laddered
+    assert sorted(work) == sorted(
+        ["fused_xent_fwd", "fused_xent_bwd"] + [
+            f"fused_xent_rows{k}_{d}" for k in (256, 512)
+            for d in ("fwd", "bwd")])
+    for role in work:
+        assert f"pallas/{role}/pallas_call" in text
+        # a trace reader matches rows by "role in row name": no rung's
+        # seconds may be charged to another rung's work
+        assert not [other for other in work
+                    if other != role and role in other], role
+
+
+@pytest.mark.parametrize("k,tag", [(256, "rows256_"), (512, "rows512_"),
+                                   (1024, "")])
+def test_a_rung_declares_the_work_of_its_rows(laddered, k, tag):
+    work, _ = laddered
+    fwd, bwd = work[f"fused_xent_{tag}fwd"], work[f"fused_xent_{tag}bwd"]
+    assert fwd["flops"] == 2 * k * H * V and bwd["flops"] == 4 * k * H * V
+    assert fwd["calls"] == bwd["calls"] == 1
+    # float32 here: K rows of h, the table and the bias, K int32 labels,
+    # then lse and the label logit out
+    read = 4 * (k * H + V * H + V + k)
+    assert fwd["bytes"] == read + 8 * k
+    assert bwd["bytes"] == read + 8 * k + 4 * (k * H + V * H + V)
+
+
+def test_a_trace_of_one_rung_is_read_against_that_rung_alone(laddered,
+                                                             monkeypatch):
+    """The reading that decides ``impossible_reading``: the rung that ran
+    is the only one with ``kernel:`` rows, and only its work counts."""
+    from benchmarks import kernel_rows
+
+    monkeypatch.setattr(counters, "_STEP_WORK", {"train_step": laddered[0]})
+    peaks = {"bf16_flops_per_s": 1e9, "hbm_bytes_per_s": 1e12}
+    run = {"peaks": peaks, "cell": {"traffic": {"loss_fetch_every": 10}},
+           "trace": {"busy_s": 9.0, "device_ops": [
+               ["fusion", 5.0], ["conditional", 3.0],
+               ["kernel:fused_xent_rows512_bwd", 2.0],
+               ["kernel:fused_xent_rows512_fwd", 1.0]]}}
+    least = 10 * 6 * 512 * H * V / peaks["bf16_flops_per_s"]
+    assert kernel_rows.roofline_pct(run, "fused_xent") == \
+        pytest.approx(100 * least / 3.0)
+    assert kernel_rows.device_share_pct(run, "fused_xent") == \
+        pytest.approx(100 * 3.0 / 9.0)
 
 
 def test_step_work_is_of_one_execution(forced):

@@ -120,20 +120,43 @@ def summarize(events: Iterable[Tuple[str, Optional[str], float]],
             "owners": [[op, sum(t.values()), ranked(t)] for op, t in ops]}
 
 
+def self_seconds(events: Iterable[Tuple[str, float, float]]
+                 ) -> List[Tuple[str, float]]:
+    """``events``: (name, start, duration) of one device line, in the
+    trace's whole nanoseconds (sums of seconds round, and an operation
+    would then seem to start inside the one before it). An operation
+    that encloses others (the ``cond`` around the branch it ran) keeps
+    only the time its direct children do not cover, so the seconds add
+    up to the device's busy time."""
+    out: List[List] = []
+    open_: List[Tuple[int, float]] = []     # (index in out, end)
+    for name, start, duration in sorted(events,
+                                        key=lambda e: (e[1], -e[2])):
+        while open_ and open_[-1][1] <= start:
+            open_.pop()
+        if open_:
+            out[open_[-1][0]][1] -= duration
+        open_.append((len(out), start + duration))
+        out.append([name, duration])
+    return [(name, ns / 1e9) for name, ns in out]
+
+
 def device_events(xplane_path: str) -> List[Tuple[str, float]]:
-    """[(instruction name, seconds)] of the first TPU plane's ``XLA Ops``
-    line, named as ``benchmarks/trace_reduce.py`` names them."""
+    """[(instruction name, self seconds)] of the first TPU plane's
+    ``XLA Ops`` line, named as ``benchmarks/trace_reduce.py`` names
+    them."""
     from jax.profiler import ProfileData
 
     from benchmarks import trace_reduce
 
     for plane in ProfileData.from_file(xplane_path).planes:
         if trace_reduce._DEVICE_PLANE.match(plane.name):
-            return [(trace_reduce.short_name(ev.name),
-                     float(ev.duration_ns) / 1e9)
-                    for line in plane.lines
-                    if line.name == trace_reduce._OP_LINE
-                    for ev in line.events]
+            return self_seconds(
+                (trace_reduce.short_name(ev.name), ev.start_ns,
+                 ev.duration_ns)
+                for line in plane.lines
+                if line.name == trace_reduce._OP_LINE
+                for ev in line.events)
     return []
 
 
