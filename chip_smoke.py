@@ -464,6 +464,33 @@ def _kernel_checks():
           xla(), (32, 512, 12, 64), bf16,
           lambda q, k: fa._pallas_ok(q, k, False))
 
+    def flash_mla(fails, shape=(1, 8192, 4), qk=192, vd=128):
+        """MLA's widths at the streaming kernels' sequence ceiling: keys
+        and queries 192 wide, values 128, causal, bfloat16."""
+        q, k = (rnd(s, shape + (qk,), bf16) for s in (1, 2))
+        v = rnd(3, shape + (vd,), bf16)
+        w = rnd(4, shape + (vd,), f32)
+        if not fa._pallas_ok(q, k, True, v=v):
+            fails.append(f"flash stream MLA widths: {shape} is outside "
+                         "its gate")
+            return
+
+        def run(f):
+            return jax.jit(jax.value_and_grad(
+                lambda q, k, v: jnp.sum(f(q, k, v).astype(f32) * w),
+                argnums=(0, 1, 2)))(q, k, v)
+
+        kernel = lambda q, k, v: fa._flash_attention_pallas(  # noqa: E731
+            q, k, v, causal=True)
+        (_, got), (_, want) = run(kernel), run(xla(causal=True))
+        _close("flash stream MLA out", jax.jit(kernel)(q, k, v),
+               jax.jit(xla(causal=True))(q, k, v), tol_of(bf16), fails)
+        for g, r, nm in zip(got, want, "qkv"):
+            _close(f"flash stream MLA d{nm}", g, r, tol_of(bf16), fails)
+    # four heads: the XLA reference keeps (heads, 8192, 8192) scores
+    checks.append(("flash stream causal, MLA widths (1, 8192, 4, 192 / "
+                   "128)", flash_mla))
+
     def flash_masked(fails, shape=(8, 512, 12, 64)):
         b, l = shape[:2]    # ragged key-padding: row i keeps l - 37 i keys
         keep = jnp.arange(l)[None, :] < l - 37 * jnp.arange(b)[:, None]
@@ -552,6 +579,105 @@ def _kernel_checks():
     xent(1024, 2048, 32768, f32, "gate edge")
     xent(2048, 1024, 32768, f32, "gate edge (largest _fits count)")
     xent(256, 2048, 128, bf16, "gate edge small")
+    # a causal LM's head: every row but the last labelled, the top rung
+    xent(8192, 2304, 20480, f32, "causal-LM head, hidden 2304, top rung",
+         unlabelled=lambda i: i == 8191)
+
+    # -- KDA chunk kernels against the same chunk formulas under XLA ---------
+    def kda_chunks(fails, shape=(1, 8192, 32, 128)):
+        from paddle_tpu.ops.pallas import kda
+
+        def unit(x):
+            return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+
+        q, k = unit(rnd(1, shape)) * shape[-1] ** -0.5, unit(rnd(2, shape))
+        v, w = rnd(3, shape), rnd(4, shape)
+        # log decays of the family's start: -A softplus(.), A in [1, 16]
+        g = -jax.random.uniform(jax.random.key(5), shape, f32, 1e-3, 1.6)
+        beta = jax.nn.sigmoid(rnd(6, shape[:3]))
+        if not kda._kernel_takes(q, v, kda.CHUNK):
+            fails.append(f"kda chunk: {shape} is outside its gate")
+            return
+
+        def run(kernel):
+            return jax.jit(jax.value_and_grad(
+                lambda *a: jnp.sum(kda._chunk_kda(*a, kda.CHUNK, kernel)
+                                   * w), argnums=(0, 1, 2, 3, 4)))(
+                q, k, v, g, beta)
+
+        (lk, got), (lx, want) = run(True), run(False)
+        _close("kda chunk sum(o w)", lk, lx, tol_of(f32), fails)
+        for a, r, nm in zip(got, want, ("q", "k", "v", "g", "beta")):
+            _close(f"kda chunk d{nm}", a, r, tol_of(f32), fails)
+    checks.append(("kda chunk fwd + bwd (1, 8192, 32, 128)", kda_chunks))
+
+    # -- the dropless expert layer, each rung against a dense loop -----------
+    def experts(held, dtype, rungs, t=8192, d=2304, f=1024,
+                num_experts=256, top_k=8, scaling=2.446):
+        """The new cell's layer shapes, one compiled layer through each of
+        its rungs. ``rungs`` maps a number of held experts that the
+        correction bias makes every token's picks to the rows that must
+        then run: the count of pairs decides the rung, ``ragged_dot`` on
+        a lower one, on the top one where more experts are held than a
+        token picks, every token through every expert where not."""
+        from paddle_tpu.nn.moe import sparse_moe
+
+        name = f"sparse experts {held} of {num_experts} held, rungs " \
+               f"{sorted(rungs.values())}, {jnp.dtype(dtype).name}"
+
+        def layer(x, router, wg, wu, wd, bias, w):
+            out, routing = sparse_moe.raw_fn(
+                x, router, bias, wg, wu, wd, top_k=top_k, scaling=scaling)
+            return jnp.sum(out * w), (out, routing)
+
+        def loop(x, router, wg, wu, wd, bias, w):
+            scores = jax.nn.sigmoid(jnp.matmul(
+                x.astype(f32), router, precision=jax.lax.Precision.HIGHEST))
+            _, picked = jax.lax.top_k(scores + bias, top_k)
+            weight = jnp.take_along_axis(scores, picked, axis=1)
+            weight = scaling * weight / jnp.sum(weight, axis=1,
+                                                keepdims=True)
+            out = jnp.zeros((t, d), f32)
+            for e in range(held):
+                y = jnp.matmul(jax.nn.silu(jnp.matmul(x, wg[e]))
+                               * jnp.matmul(x, wu[e]), wd[e])
+                mine = jnp.sum(jnp.where(picked == e, weight, 0.0), 1)
+                out = out + y.astype(f32) * mine[:, None]
+            return jnp.sum(out * w), (out, None)
+
+        def check(fails):
+            x = rnd(1, (t, d), dtype)
+            router = rnd(2, (d, num_experts), f32, d ** -0.5)
+            wg, wu = (rnd(s, (held, d, f), dtype, d ** -0.5) for s in (3, 4))
+            wd = rnd(5, (held, f, d), dtype, f ** -0.5)
+            w = rnd(6, (t, d), f32)
+            layer_, loop_ = (jax.jit(jax.value_and_grad(
+                fn, argnums=(0, 1, 2, 3, 4), has_aux=True))
+                for fn in (layer, loop))
+            for forced, rows in rungs.items():
+                bias = jnp.zeros((num_experts,), f32).at[:forced].set(10.0)
+                args = (x, router, wg, wu, wd, bias, w)
+                # bfloat16 operands at the step's own precision: the
+                # TPU's ragged_dot refuses them under the phase's "highest"
+                with jax.default_matmul_precision(
+                        "highest" if dtype == f32 else "default"):
+                    ((_, (got, routing)), dgot) = layer_(*args)
+                    ((_, (want, _)), dwant) = loop_(*args)
+                pairs, ran = (int(v) for v in np.asarray(routing))
+                if ran != rows or not 0 < pairs <= rows:
+                    fails.append(f"{name}: {pairs} pairs ran on {ran} rows, "
+                                 f"not on {rows}")
+                _close(f"{name} {rows} out", got, want, tol_of(dtype), fails)
+                for a, r, nm in zip(dgot, dwant,
+                                    ("x", "router", "gate", "up", "down")):
+                    _close(f"{name} {rows} d{nm}", a, r, tol_of(dtype),
+                           fails)
+        checks.append((name, check))
+
+    # the cell's share in the step's type, and a share that holds more
+    # experts than a token picks, exactly
+    experts(8, bf16, {0: 16384, 8: 65536})
+    experts(16, f32, {0: 32768, 8: 65536})
 
     # -- fused embedding bag --------------------------------------------------
     def bag(vocab, d, b, s, dtype, combiner):

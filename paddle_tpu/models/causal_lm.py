@@ -1,0 +1,170 @@
+"""A decoder-only causal LM assembled from a model's own config keys.
+
+``CausalLM.from_config(cfg)`` reads a Hugging Face style ``config.json``
+dict and builds: token embedding, ``num_hidden_layers`` pre-norm residual
+blocks (``x += Mix(RMSNorm(x))``, ``x += FFN(RMSNorm(x))``), a final
+RMSNorm and an untied head. Which token mixer and which feed-forward a
+block gets is decided per layer from the config, through two small
+tables below: a new architecture is a config file plus, at most, a new
+layer kind registered there.
+
+    mixer   ``linear_attn_config.kda_layers`` (1-based) -> ``kda``
+            (``nn.KimiDeltaAttention``); every other layer -> ``mla``
+            (``nn.MLAttention``, NoPE)
+    ffn     the first ``first_k_dense_replace`` layers -> ``dense``
+            (``nn.GatedFFN`` of ``intermediate_size``); the others ->
+            ``moe`` (``nn.SparseMoELayer``: ``num_experts_per_token`` of
+            the router's experts, ``num_shared_experts`` shared)
+
+A chip's share of an expert-parallel deployment is said with
+``experts_held`` / ``expert_offset`` (the router keeps all its outputs).
+``loss`` goes through the fused vocabulary cross-entropy, so the
+``(tokens, vocabulary)`` logits never exist; ``recompute=True`` keeps
+only each block's input for the backward and runs the block again there.
+"""
+from __future__ import annotations
+
+import jax.numpy as jnp
+
+from .. import nn
+from ..framework.tensor import Tensor
+from ..nn import functional as F
+
+
+def _mixer_kda(cfg):
+    lin = cfg["linear_attn_config"]
+    return nn.KimiDeltaAttention(
+        cfg["hidden_size"], lin["num_heads"], lin["head_dim"],
+        conv_size=lin["short_conv_kernel_size"],
+        epsilon=cfg["rms_norm_eps"])
+
+
+def _mixer_mla(cfg):
+    if cfg.get("q_lora_rank") is not None:
+        raise NotImplementedError("MLA with a low-rank query projection")
+    return nn.MLAttention(
+        cfg["hidden_size"], cfg["num_attention_heads"],
+        cfg["qk_nope_head_dim"], cfg["qk_rope_head_dim"],
+        cfg["v_head_dim"], cfg["kv_lora_rank"],
+        epsilon=cfg["rms_norm_eps"], rotary=not cfg["mla_use_nope"])
+
+
+def _ffn_dense(cfg):
+    return nn.GatedFFN(cfg["hidden_size"], cfg["intermediate_size"],
+                       activation=cfg["hidden_act"])
+
+
+def _ffn_moe(cfg):
+    if cfg.get("moe_router_activation_func", "sigmoid") != "sigmoid":
+        raise NotImplementedError("only sigmoid router scores are built")
+    if cfg.get("num_expert_group", 1) != 1 or cfg.get("topk_group", 1) != 1:
+        raise NotImplementedError("group-limited routing")
+    held = cfg.get("experts_held", cfg["num_experts"])
+    shared = cfg.get("num_shared_experts", 0) * cfg["moe_intermediate_size"]
+    return nn.SparseMoELayer(
+        cfg["hidden_size"], cfg["moe_intermediate_size"],
+        cfg["num_experts"], cfg["num_experts_per_token"],
+        experts_held=held, expert_offset=cfg.get("expert_offset", 0),
+        scaling=cfg.get("routed_scaling_factor", 1.0),
+        renormalize=cfg.get("moe_renormalize", True),
+        shared_width=shared or None)
+
+
+MIXERS = {"kda": _mixer_kda, "mla": _mixer_mla}
+FFNS = {"dense": _ffn_dense, "moe": _ffn_moe}
+
+
+def mixer_kind(cfg, layer: int) -> str:
+    """``layer`` counts from 1, as the config's layer lists do."""
+    lin = cfg.get("linear_attn_config") or {}
+    return "kda" if layer in lin.get("kda_layers", ()) else "mla"
+
+
+def ffn_kind(cfg, layer: int) -> str:
+    dense = layer <= cfg.get("first_k_dense_replace", 0) \
+        or "num_experts" not in cfg \
+        or (layer - 1) % cfg.get("moe_layer_freq", 1) != 0
+    return "dense" if dense else "moe"
+
+
+class DecoderBlock(nn.Layer):
+    def __init__(self, cfg, layer: int):
+        super().__init__()
+        eps = cfg["rms_norm_eps"]
+        self.mixer_kind, self.ffn_kind = (mixer_kind(cfg, layer),
+                                          ffn_kind(cfg, layer))
+        self.input_norm = nn.RMSNorm(cfg["hidden_size"], epsilon=eps)
+        self.mixer = MIXERS[self.mixer_kind](cfg)
+        self.post_norm = nn.RMSNorm(cfg["hidden_size"], epsilon=eps)
+        self.ffn = FFNS[self.ffn_kind](cfg)
+
+    def forward(self, x):
+        """(x, routing): ``routing`` is the expert layer's [pairs on held
+        experts, rows of the rung that ran], zeros for a dense block."""
+        from .. import ops
+
+        x = x + self.mixer(self.input_norm(x))
+        x = x + self.ffn(self.post_norm(x))
+        routing = self.ffn.last_routing if self.ffn_kind == "moe" \
+            else ops.zeros([2], "float32")
+        return x, routing
+
+
+class CausalLM(nn.Layer):
+    """See the module docstring. ``cfg`` is kept as ``self.config``."""
+
+    def __init__(self, cfg: dict, recompute: bool = False):
+        super().__init__()
+        self.config = dict(cfg)
+        self.recompute = bool(recompute)
+        if cfg.get("tie_word_embeddings", False):
+            raise NotImplementedError("a head tied to the embedding")
+        from ..nn.initializer import Normal
+
+        init = nn.ParamAttr(initializer=Normal(
+            0.0, cfg.get("initializer_range", 0.02)))
+        self.embed = nn.Embedding(cfg["vocab_size"], cfg["hidden_size"],
+                                  weight_attr=init)
+        self.layers = nn.LayerList([
+            DecoderBlock(cfg, n + 1)
+            for n in range(cfg["num_hidden_layers"])])
+        self.final_norm = nn.RMSNorm(cfg["hidden_size"],
+                                     epsilon=cfg["rms_norm_eps"])
+        # (vocabulary, hidden): the layout the fused cross-entropy streams
+        self.head = self.create_parameter(
+            [cfg["vocab_size"], cfg["hidden_size"]], attr=init)
+        # the fused cross-entropy takes a bias; this head has none
+        self._no_bias = Tensor(jnp.zeros((cfg["vocab_size"],), jnp.float32))
+
+    @classmethod
+    def from_config(cls, cfg: dict, recompute: bool = False) -> "CausalLM":
+        return cls(cfg, recompute=recompute)
+
+    def hidden(self, input_ids):
+        """(final hidden states, per-layer routing (L, 2))."""
+        from .. import ops
+        from ..optimizer.meta import recompute
+
+        x = self.embed(input_ids)
+        routing = []
+        for block in self.layers:
+            x, r = recompute(block, x) if self.recompute else block(x)
+            routing.append(r)
+        return self.final_norm(x), ops.stack(routing, axis=0)
+
+    def forward(self, input_ids):
+        from .. import ops
+
+        h, _ = self.hidden(input_ids)
+        return ops.matmul(h, self.head, transpose_y=True)
+
+    def loss(self, input_ids, labels, ignore_index=-100,
+             return_routing=False):
+        """Mean cross-entropy of each position's logits against
+        ``labels`` (already the next token; ``ignore_index`` where there
+        is none). With ``return_routing``, also the (L, 2) routing
+        counters of this call."""
+        h, routing = self.hidden(input_ids)
+        loss = F.fused_linear_cross_entropy(
+            h, self.head, self._no_bias, labels, ignore_index=ignore_index)
+        return (loss, routing) if return_routing else loss

@@ -4,7 +4,7 @@ from . import initializer  # noqa: F401
 from .layer import Layer, Parameter, ParamAttr  # noqa: F401
 from .container import Sequential, LayerList, LayerDict, ParameterList  # noqa: F401
 from .common import (  # noqa: F401
-    Identity, Linear, Embedding, Dropout, Dropout2D, Dropout3D, AlphaDropout,
+    Identity, Linear, GatedFFN, Embedding, Dropout, Dropout2D, Dropout3D, AlphaDropout,
     Flatten, Upsample, UpsamplingBilinear2D, UpsamplingNearest2D,
     PixelShuffle, Pad1D, Pad2D, Pad3D, CosineSimilarity, Bilinear,
     ReLU, ReLU6, LeakyReLU, ELU, CELU, SELU, GELU, Silu, Swish, Mish,
@@ -18,7 +18,7 @@ from .conv import (  # noqa: F401
 )
 from .norm import (  # noqa: F401
     BatchNorm, BatchNorm1D, BatchNorm2D, BatchNorm3D, SyncBatchNorm,
-    LayerNorm, GroupNorm, InstanceNorm1D, InstanceNorm2D, InstanceNorm3D,
+    LayerNorm, RMSNorm, GroupNorm, InstanceNorm1D, InstanceNorm2D, InstanceNorm3D,
     LocalResponseNorm, SpectralNorm,
 )
 from .pooling import (  # noqa: F401
@@ -45,7 +45,11 @@ from .decode import (  # noqa: F401
     BasicDecoder,
 )
 from .clip import ClipGradByValue, ClipGradByNorm, ClipGradByGlobalNorm  # noqa: F401
-from .moe import MoELayer, moe_apply_ep, MOE_EP_RULES  # noqa: F401
+from .moe import (  # noqa: F401
+    MoELayer, SparseMoELayer, moe_apply_ep, MOE_EP_RULES,
+)
+from .linear_attention import KimiDeltaAttention  # noqa: F401
+from .latent_attention import MLAttention  # noqa: F401
 from .crf import LinearChainCRF, crf_decoding, linear_chain_crf  # noqa: F401,E402
 
 # 2.0-alpha surface parity: pre-rename spellings + functional re-exports

@@ -34,6 +34,21 @@ class Linear(Layer):
         return f"in_features={self._in_features}, out_features={self._out_features}"
 
 
+class GatedFFN(Layer):
+    """(act(x W_gate) * x W_up) W_down, no biases: the feed-forward of
+    the gated decoder blocks (dense layers, shared and routed experts)."""
+
+    def __init__(self, d_model, d_hidden, activation="silu"):
+        super().__init__()
+        self.gate_proj = Linear(d_model, d_hidden, bias_attr=False)
+        self.up_proj = Linear(d_model, d_hidden, bias_attr=False)
+        self.down_proj = Linear(d_hidden, d_model, bias_attr=False)
+        self._act = getattr(F, activation)
+
+    def forward(self, x):
+        return self.down_proj(self._act(self.gate_proj(x)) * self.up_proj(x))
+
+
 class Embedding(Layer):
     """Reference lookup_table_v2_op.cc; rows gathered via jnp.take."""
 

@@ -1,0 +1,145 @@
+"""Kimi Delta Attention: a linear-attention token mixer whose per-head
+state follows the gated delta rule with a decay per key channel.
+
+    q_t = L2norm(SiLU(ShortConv(x W_q)))_t / sqrt(d_k)
+    k_t = L2norm(SiLU(ShortConv(x W_k)))_t       v_t = SiLU(ShortConv(x W_v))_t
+    g_t = -exp(A_log_h) * softplus((x W_f1 W_f2)_t + dt_bias)     (log decay)
+    beta_t = sigmoid(x W_b)_t
+    S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
+    o_t = S_t^T q_t
+    y_t = [RMSNorm_head(o_t) * sigmoid((x W_g1 W_g2 + b_g)_t)] W_o
+
+``ShortConv`` is a causal depthwise convolution along the sequence. The
+recurrence runs chunk by chunk in ``ops/pallas/kda.py``; everything
+between the projections and that call is float32 whatever the autocast
+level (the norms, the decay and the state are precision-sensitive).
+"""
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..framework.op import primitive
+from .common import Linear
+from .layer import Layer
+
+__all__ = ["KimiDeltaAttention", "kda_mix"]
+
+_F32 = jnp.float32
+#: added to the squared norm under the L2 normalisation of q and k
+L2_EPS = 1e-6
+
+
+def _short_conv(x, taps):
+    """Causal depthwise convolution: x (B, T, C), taps (W, C); the last
+    tap multiplies the current token."""
+    width = taps.shape[0]
+    t = x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
+    return sum(padded[:, j:j + t] * taps[j] for j in range(width))
+
+
+def _l2norm(x):
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
+
+
+@primitive("kda_mix")
+def kda_mix(q, k, v, q_taps, k_taps, v_taps, decay, a_log, dt_bias,
+            beta_logits, gate, norm_weight, num_heads, epsilon=1e-5):
+    """Everything of the mixer between its input projections and its
+    output projection. q, k, v, decay, gate: (B, T, H * D) projections of
+    the block's input; beta_logits: (B, T, H). Returns (B, T, H * D),
+    float32."""
+    from ..ops.pallas.kda import chunk_kda
+
+    b, t, width = q.shape
+    d = width // num_heads
+
+    def heads(x):
+        return x.reshape(b, t, num_heads, d)
+
+    def mixed(x, taps):
+        return heads(jax.nn.silu(_short_conv(x.astype(_F32),
+                                             taps.astype(_F32))))
+
+    # the float32 element-wise chains on either side of the recurrence
+    # are recomputed in the backward from their (autocast-typed) inputs:
+    # kept, they are a dozen (B, T, H * D) float32 arrays a layer
+    @jax.checkpoint
+    def before(q, k, v, decay, beta_logits, q_taps, k_taps, v_taps, a_log,
+               dt_bias):
+        g = -jnp.exp(a_log.astype(_F32))[:, None] * heads(
+            jax.nn.softplus(decay.astype(_F32) + dt_bias.astype(_F32)))
+        return (_l2norm(mixed(q, q_taps)) * (d ** -0.5),
+                _l2norm(mixed(k, k_taps)), mixed(v, v_taps), g,
+                jax.nn.sigmoid(beta_logits.astype(_F32)))
+
+    @jax.checkpoint
+    def after(o, gate, norm_weight):
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                              + epsilon)
+        return o * norm_weight.astype(_F32) * heads(
+            jax.nn.sigmoid(gate.astype(_F32)))
+
+    o = chunk_kda(*before(q, k, v, decay, beta_logits, q_taps, k_taps,
+                          v_taps, a_log, dt_bias))
+    return after(o, gate, norm_weight).reshape(b, t, width)
+
+
+class KimiDeltaAttention(Layer):
+    """The mixer above as a layer. The decay's and the gate's low-rank
+    projections are ``head_dim`` wide inside (the family's convention)."""
+
+    def __init__(self, hidden_size, num_heads, head_dim, conv_size=4,
+                 epsilon=1e-5):
+        super().__init__()
+        from .initializer import Assign, Constant, Normal
+
+        self.num_heads = num_heads
+        self.head_dim = head_dim
+        self._epsilon = epsilon
+        width, low_rank = num_heads * head_dim, head_dim
+
+        def proj(i, o, bias=False):
+            return Linear(i, o, bias_attr=None if bias else False)
+
+        self.q_proj = proj(hidden_size, width)
+        self.k_proj = proj(hidden_size, width)
+        self.v_proj = proj(hidden_size, width)
+        taps = Normal(0.0, 1.0 / math.sqrt(conv_size))
+        self.q_conv = self.create_parameter([conv_size, width],
+                                            default_initializer=taps)
+        self.k_conv = self.create_parameter([conv_size, width],
+                                            default_initializer=taps)
+        self.v_conv = self.create_parameter([conv_size, width],
+                                            default_initializer=taps)
+        self.f_a_proj = proj(hidden_size, low_rank)
+        self.f_b_proj = proj(low_rank, width)
+        # the family's start: decay rates A in [1, 16], time steps dt in
+        # [1e-3, 1e-1] (log-uniform), dt_bias their inverse softplus
+        rng = np.random.default_rng(0)
+        self.A_log = self.create_parameter(
+            [num_heads], default_initializer=Assign(
+                np.log(rng.uniform(1.0, 16.0, num_heads)).astype("float32")))
+        dt = np.exp(rng.uniform(math.log(1e-3), math.log(1e-1), width))
+        self.dt_bias = self.create_parameter(
+            [width], default_initializer=Assign(
+                (dt + np.log(-np.expm1(-dt))).astype("float32")))
+        self.b_proj = proj(hidden_size, num_heads)
+        self.g_a_proj = proj(hidden_size, low_rank)
+        self.g_b_proj = proj(low_rank, width, bias=True)
+        self.o_norm = self.create_parameter(
+            [head_dim], default_initializer=Constant(1.0))
+        self.o_proj = proj(width, hidden_size)
+
+    def forward(self, x):
+        mixed = kda_mix(
+            self.q_proj(x), self.k_proj(x), self.v_proj(x),
+            self.q_conv, self.k_conv, self.v_conv,
+            self.f_b_proj(self.f_a_proj(x)), self.A_log, self.dt_bias,
+            self.b_proj(x), self.g_b_proj(self.g_a_proj(x)), self.o_norm,
+            num_heads=self.num_heads, epsilon=self._epsilon)
+        return self.o_proj(mixed)

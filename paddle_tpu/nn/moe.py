@@ -1,4 +1,19 @@
-"""Mixture-of-Experts with REAL expert parallelism (the "ep" mesh axis).
+"""Mixture-of-Experts layers. Two of them, for two routing contracts:
+
+- :class:`SparseMoELayer` is the DROPLESS layer: sigmoid scores, top-k
+  of any k, renormalised and scaled weights, an optional shared expert,
+  gated (SwiGLU) experts. It is told which experts it holds
+  (``experts_held`` from ``expert_offset``), routes over all of them and
+  computes its own experts' part; no token is ever dropped and there is
+  no capacity factor. Sorted (token, expert) pairs go through a grouped
+  matrix product (``jax.lax.ragged_dot``).
+- :class:`MoELayer` is the CAPACITY layer: GShard top-2 softmax gating
+  into a static ``(tokens, experts, capacity)`` grid that drops what
+  overflows, with the explicit expert-parallel exchange below.
+
+The rest of this docstring is about the capacity layer.
+
+Mixture-of-Experts with REAL expert parallelism (the "ep" mesh axis).
 
 The reference framework predates MoE entirely (SURVEY §2.6: EP absent) —
 this is a TPU-first design, not a port. Tokens are routed top-2 by a
@@ -47,8 +62,9 @@ from jax.sharding import Mesh, PartitionSpec
 from ..framework.op import primitive
 from .layer import Layer
 
-__all__ = ["MoELayer", "moe_apply_ep", "MOE_EP_RULES", "top2_gating",
-           "moe_route_stats", "moe_a2a_nbytes"]
+__all__ = ["MoELayer", "SparseMoELayer", "sparse_moe", "moe_apply_ep",
+           "MOE_EP_RULES", "top2_gating", "moe_route_stats",
+           "moe_a2a_nbytes"]
 
 # parameter sharding rules: expert-stacked weights shard over "ep"
 MOE_EP_RULES = [
@@ -363,3 +379,178 @@ class MoELayer(Layer):
     @property
     def aux_loss(self):
         return self._last_aux_loss
+
+
+# ---------------------------------------------------------------------------
+# the dropless layer
+# ---------------------------------------------------------------------------
+def _row_ladder(pairs: int, experts_held: int, num_experts: int) -> tuple:
+    """The static row capacities the grouped product may run at,
+    ascending, in whole 256-row blocks. The top rung holds every (token,
+    expert) pair there can be, so nothing is ever dropped; the lowest is
+    eight times what even routing would send to the experts held, and
+    each rung is four times the one below: uneven routing (an expert's
+    load follows its tokens' frequencies) then moves the rung seldom,
+    and a step's time hardly depends on its data, at the price of rows
+    that hold no pair. All of it follows from the shapes."""
+    top = -(-pairs // 256) * 256
+    rung = -(-8 * pairs * experts_held // (num_experts * 256)) * 256
+    rungs = []
+    while rung < top:
+        rungs.append(rung)
+        rung *= 4
+    return tuple(rungs) + (top,)
+
+
+def _every_pair_ffn(x, weight_by_expert, w_gate, w_up, w_down):
+    """The top rung: every token through every expert held, one expert
+    at a time, weighted by ``weight_by_expert`` (T, H) — zero where the
+    token did not pick the expert. The same rows as sorting all T x H
+    pairs would give the grouped product, without their T x H x D
+    gathered copy."""
+    rows = x.astype(w_gate.dtype)
+
+    def one(acc, expert):
+        gate, up, down, w = expert
+        y = jnp.matmul(jax.nn.silu(jnp.matmul(rows, gate))
+                       * jnp.matmul(rows, up), down)
+        return acc + y.astype(jnp.float32) * w[:, None], None
+
+    out, _ = jax.lax.scan(one, jnp.zeros(x.shape, jnp.float32),
+                          (w_gate, w_up, w_down, weight_by_expert.T))
+    return out
+
+
+def _grouped_ffn(x, tokens, weights, sizes, w_gate, w_up, w_down, n_tokens):
+    """Gated FFN of each expert on its own run of the sorted rows, then
+    the weighted scatter-add back to the tokens. What ``ragged_dot``
+    leaves in the rows past the last run is not specified (zeros on the
+    CPU, not on the TPU), forward or transposed: those rows are selected
+    away after every product, so that neither they nor their cotangents
+    reach a token."""
+    live = (jnp.arange(tokens.shape[0]) < jnp.sum(sizes))[:, None]
+
+    def runs(a):
+        return jnp.where(live, a, jnp.zeros((), a.dtype))
+
+    rows = runs(x[tokens].astype(w_gate.dtype))
+    hidden = runs(jax.nn.silu(jax.lax.ragged_dot(rows, w_gate, sizes))
+                  * jax.lax.ragged_dot(rows, w_up, sizes))
+    out = runs(jax.lax.ragged_dot(hidden, w_down, sizes))
+    out = out.astype(jnp.float32) * weights[:, None]
+    return jnp.zeros((n_tokens, x.shape[-1]), jnp.float32).at[tokens].add(out)
+
+
+@primitive("sparse_moe")
+def sparse_moe(x, router_w, router_bias, w_gate, w_up, w_down, top_k,
+               expert_offset=0, scaling=1.0, renormalize=True):
+    """The routed part of the dropless layer on tokens ``x`` (T, D).
+
+    ``router_w`` (D, E) scores ALL E experts; ``w_gate`` / ``w_up``
+    (H, D, F) and ``w_down`` (H, F, D) are the H experts held here,
+    experts ``expert_offset`` .. ``expert_offset + H - 1``. Returns the
+    sum over the picked AND held experts of weight * expert(x), float32,
+    and ``[pairs on held experts, rows of the rung that ran]``. The
+    renormalisation is over all ``top_k`` picks, held or not; the pick
+    itself passes no gradient. Routing is float32 at full precision
+    whatever the autocast level (a rounded score flips picks); the
+    experts' products run in the autocast type."""
+    from ..amp import amp_dtype, amp_enabled
+
+    t = x.shape[0]
+    num_experts, held = router_w.shape[1], w_gate.shape[0]
+    scores = jax.nn.sigmoid(jnp.matmul(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, picked = jax.lax.top_k(scores + router_bias, top_k)       # (T, k)
+    weight = jnp.take_along_axis(scores, picked, axis=1)
+    if renormalize:
+        weight = weight / jnp.sum(weight, axis=1, keepdims=True)
+    weight = (scaling * weight).reshape(-1)
+    local = picked.reshape(-1) - expert_offset
+    mine = (local >= 0) & (local < held)
+    # a pair's expert here, or ``held`` for an expert that is elsewhere
+    slot = jnp.where(mine, local, held)
+    # pairs on held experts first, expert by expert (a stable sort keeps
+    # the tokens of an expert in order)
+    order = jnp.argsort(slot, stable=True)
+    token = jnp.arange(t * top_k, dtype=jnp.int32) // top_k
+    # (a one-hot sum, not a bincount: a TPU scatter walks its updates)
+    sizes = jnp.sum(jax.nn.one_hot(slot, held + 1, dtype=jnp.int32),
+                    axis=0)[:held]
+    count = jnp.sum(sizes)
+    rungs = _row_ladder(t * min(top_k, held), held, num_experts)
+    if amp_enabled():
+        w_gate, w_up, w_down = (w.astype(amp_dtype())
+                                for w in (w_gate, w_up, w_down))
+
+    def at(rows):
+        def run(x, weight, w_gate, w_up, w_down):
+            take = order[:rows]
+            return _grouped_ffn(
+                x, token[take], jnp.where(mine[take], weight[take], 0.0),
+                sizes, w_gate, w_up, w_down, t)
+        return run
+
+    def every_pair(x, weight, w_gate, w_up, w_down):
+        by_expert = jnp.zeros((t, held + 1), jnp.float32).at[
+            token, slot].add(weight)
+        return _every_pair_ffn(x, by_expert[:, :held], w_gate, w_up, w_down)
+
+    # with no more experts here than a token picks, every pair is every
+    # token through every expert: no sort, no gathered copy of the rows
+    top = every_pair if held <= top_k else at(rungs[-1])
+    rung = jnp.sum(count > jnp.asarray(rungs[:-1], jnp.int32))
+    out = jax.lax.switch(rung, [at(r) for r in rungs[:-1]] + [top],
+                         x, weight, w_gate, w_up, w_down)
+    ran = jnp.asarray(rungs, jnp.float32)[rung]
+    return out, jnp.stack([count.astype(jnp.float32), ran])
+
+
+class SparseMoELayer(Layer):
+    """Dropless top-k mixture of gated experts (see the module docstring).
+
+    ``experts_held`` of the ``num_experts`` experts live here, from
+    ``expert_offset``; with all of them held this is the whole layer. A
+    share's output leaves out what the absent experts would have added;
+    ``shared_width`` adds one always-on expert that every share computes
+    alike. ``forward`` keeps ``[pairs on held experts, rows of the rung
+    that ran]`` of its last call in ``last_routing``."""
+
+    def __init__(self, d_model, d_expert, num_experts, top_k,
+                 experts_held=None, expert_offset=0, scaling=1.0,
+                 renormalize=True, shared_width=None):
+        super().__init__()
+        from .common import GatedFFN, Linear
+
+        held = num_experts if experts_held is None else int(experts_held)
+        if not 0 <= expert_offset <= num_experts - held:
+            raise ValueError(
+                f"experts {expert_offset}..{expert_offset + held - 1} are "
+                f"not among {num_experts}")
+        self.top_k, self.expert_offset = int(top_k), int(expert_offset)
+        self.scaling, self.renormalize = float(scaling), bool(renormalize)
+        self.router = Linear(d_model, num_experts, bias_attr=False)
+        # the router's correction bias: a buffer, moved by a balancing
+        # rule outside the loss and not by its gradient
+        self.register_buffer("router_bias",
+                             jnp.zeros((num_experts,), jnp.float32))
+        self.experts_gate = self.create_parameter([held, d_model, d_expert])
+        self.experts_up = self.create_parameter([held, d_model, d_expert])
+        self.experts_down = self.create_parameter([held, d_expert, d_model])
+        self.shared = GatedFFN(d_model, shared_width) if shared_width \
+            else None
+        self.last_routing = None
+
+    def forward(self, x):
+        from .. import ops
+
+        shape = x.shape
+        routed, self.last_routing = sparse_moe(
+            ops.reshape(x, [-1, shape[-1]]), self.router.weight,
+            self.router_bias, self.experts_gate, self.experts_up,
+            self.experts_down, top_k=self.top_k,
+            expert_offset=self.expert_offset, scaling=self.scaling,
+            renormalize=self.renormalize)
+        out = ops.reshape(routed, list(shape))
+        return out if self.shared is None else out + self.shared(x)

@@ -145,6 +145,21 @@ class LayerNorm(Layer):
         return f"normalized_shape={self._normalized_shape}"
 
 
+class RMSNorm(Layer):
+    """x / rms(x) * weight over the last axis; no mean, no bias."""
+
+    def __init__(self, hidden_size, epsilon=1e-6, weight_attr=None,
+                 name=None):
+        super().__init__()
+        self._epsilon = epsilon
+        self.weight = self.create_parameter(
+            [int(hidden_size)], attr=weight_attr,
+            default_initializer=I.Constant(1.0))
+
+    def forward(self, x):
+        return F.rms_norm(x, self.weight, self._epsilon)
+
+
 class GroupNorm(Layer):
     def __init__(self, num_groups, num_channels, epsilon=1e-5,
                  weight_attr=None, bias_attr=None, data_format="NCHW",

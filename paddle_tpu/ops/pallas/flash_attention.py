@@ -267,6 +267,20 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 # ---------------------------------------------------------------------------
 
 
+def _stream_params(rows, d, dv, itemsize):
+    """Scoped-VMEM limit of the streaming kernels: each keeps one head's
+    whole K and V (the dk/dv kernel: Q and dO) resident, double-buffered,
+    beside its blocks and float32 temporaries. Mosaic's 16 MiB default
+    holds that up to about 8192 rows of 128 bfloat16 columns; 8192 x
+    (192 + 128) needed 17.7 MB (seen compiling for a v5e, PR 26). Of 128
+    MiB physical."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    resident = 2 * rows * (d + dv) * itemsize
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=max(16 << 20, min(resident + (12 << 20), 96 << 20)))
+
+
 def _mergeheads(x):
     b, l, h, d = x.shape
     return jnp.swapaxes(x, 1, 2).reshape(b * h, l, d)
@@ -282,13 +296,13 @@ def _fwd_call(qm, km, vm, causal, block_q, block_kv, sm_scale,
     from jax.experimental import pallas as pl
 
     bh, ql, d = qm.shape
-    kl = km.shape[1]
+    kl, dv = km.shape[1], vm.shape[2]      # values may be narrower (MLA)
     grid = (bh, ql // block_q)
     masked = mask_bias is not None
     in_specs = [
         pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
         pl.BlockSpec((None, kl, d), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((None, kl, d), lambda i, j: (i, 0, 0)),
+        pl.BlockSpec((None, kl, dv), lambda i, j: (i, 0, 0)),
     ]
     operands = [qm, km, vm]
     if masked:
@@ -308,13 +322,14 @@ def _fwd_call(qm, km, vm, causal, block_q, block_kv, sm_scale,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((None, block_q, dv), lambda i, j: (i, j, 0)),
             pl.BlockSpec((None, 1, block_q), lambda i, j: (i, 0, j)),
         ],
         out_shape=[
-            _sds((bh, ql, d), qm.dtype, qm),
+            _sds((bh, ql, dv), qm.dtype, qm),
             _sds((bh, 1, ql), _F32, qm),
         ],
+        compiler_params=_stream_params(kl, d, dv, km.dtype.itemsize),
     )(*operands)
     return out, lse
 
@@ -338,14 +353,14 @@ def _bwd_call(qm, km, vm, dom, lse, delta, causal, block_q, block_kv,
     from jax.experimental import pallas as pl
 
     bh, ql, d = qm.shape
-    kl = km.shape[1]
+    kl, dv = km.shape[1], vm.shape[2]
     masked = mask_bias is not None
 
     dq_specs = [
         pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
         pl.BlockSpec((None, kl, d), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((None, kl, d), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
+        pl.BlockSpec((None, kl, dv), lambda i, j: (i, 0, 0)),
+        pl.BlockSpec((None, block_q, dv), lambda i, j: (i, j, 0)),
         pl.BlockSpec((None, 1, block_q), lambda i, j: (i, 0, j)),
         pl.BlockSpec((None, 1, block_q), lambda i, j: (i, 0, j)),
     ]
@@ -368,13 +383,14 @@ def _bwd_call(qm, km, vm, dom, lse, delta, causal, block_q, block_kv,
         in_specs=dq_specs,
         out_specs=pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
         out_shape=_sds((bh, ql, d), qm.dtype, qm),
+        compiler_params=_stream_params(kl, d, dv, km.dtype.itemsize),
     )(*dq_ops)
 
     dkv_specs = [
         pl.BlockSpec((None, ql, d), lambda i, j: (i, 0, 0)),
         pl.BlockSpec((None, block_kv, d), lambda i, j: (i, j, 0)),
-        pl.BlockSpec((None, block_kv, d), lambda i, j: (i, j, 0)),
-        pl.BlockSpec((None, ql, d), lambda i, j: (i, 0, 0)),
+        pl.BlockSpec((None, block_kv, dv), lambda i, j: (i, j, 0)),
+        pl.BlockSpec((None, ql, dv), lambda i, j: (i, 0, 0)),
         pl.BlockSpec((None, 1, ql), lambda i, j: (i, 0, 0)),
         pl.BlockSpec((None, 1, ql), lambda i, j: (i, 0, 0)),
     ]
@@ -397,12 +413,13 @@ def _bwd_call(qm, km, vm, dom, lse, delta, causal, block_q, block_kv,
         in_specs=dkv_specs,
         out_specs=[
             pl.BlockSpec((None, block_kv, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((None, block_kv, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((None, block_kv, dv), lambda i, j: (i, j, 0)),
         ],
         out_shape=[
             _sds((bh, kl, d), km.dtype, qm),
-            _sds((bh, kl, d), vm.dtype, qm),
+            _sds((bh, kl, dv), vm.dtype, qm),
         ],
+        compiler_params=_stream_params(ql, d, dv, qm.dtype.itemsize),
     )(*dkv_ops)
     return dq, dk, dv
 
@@ -757,7 +774,7 @@ def _kv_mask_bias(mask, batch, kv_len):
     return jnp.where(m, 0.0, _NEG_INF).astype(_F32)
 
 
-def _pallas_ok(q, k, causal, seq_floor=256):
+def _pallas_ok(q, k, causal, seq_floor=256, v=None):
     from ...framework.bringup import pallas_enabled
     from ...parallel.mesh import auto_partitioned_trace
 
@@ -765,6 +782,10 @@ def _pallas_ok(q, k, causal, seq_floor=256):
         return False
     b, ql, h, d = q.shape
     kl = k.shape[1]
+    # a value width of its own (MLA: keys 192, values 128) is the plain
+    # streaming kernels' alone, under the same lane rule as the keys
+    if v is not None and not (v.shape[-1] % 64 == 0 and v.shape[-1] <= d):
+        return False
     # 128 is the hard tile modulus (the wrappers fall back to 128-wide
     # blocks when 256 doesn't divide); seq_floor is a pure perf floor —
     # where the kernel beats XLA (short sequences fuse fine in XLA).
@@ -815,13 +836,21 @@ def _work(kind, q, k, v, causal):
     backward reads those with the output's cotangent and writes dq, dk,
     dv."""
     b, ql, a, d = q.shape
-    mm = (0.5 if causal else 1.0) * b * a * ql * k.shape[1] * d
+    # Q K^T is d wide and P V is v's width (the same, but for MLA)
+    mm = (0.5 if causal else 1.0) * b * a * ql * k.shape[1] \
+        * (d + v.shape[-1]) / 2
+    out = b * ql * a * v.shape[-1] * q.dtype.itemsize
     qkv, lse = nbytes(q, k, v), 4 * b * a * ql
     return {
         "work": {f"flash_attention_{kind}_fwd":
-                 (4.0 * mm, qkv + nbytes(q) + lse)},
+                 (4.0 * mm, qkv + out + lse)},
         "grad_work": {f"flash_attention_{kind}_bwd":
-                      (8.0 * mm, 2 * qkv + 2 * nbytes(q) + lse)}}
+                      (8.0 * mm, 2 * qkv + 2 * out + lse)}}
+
+
+def _one_width(q, v):
+    """The short, masked and dropout kernels take one head width."""
+    return v.shape[-1] == q.shape[-1]
 
 
 def _local_attention(q, k, v, is_causal):
@@ -830,7 +859,8 @@ def _local_attention(q, k, v, is_causal):
     from .counters import bump
 
     # a kernel that was chosen and then fails raises (no except -> XLA)
-    choice = _short_choice(q, k, is_causal, 0.0)
+    choice = _short_choice(q, k, is_causal, 0.0) if _one_width(q, v) \
+        else None
     if choice == "short":
         out = _flash_attention_pallas_short(q, k, v, causal=is_causal)
         bump("flash_attention", "pallas",
@@ -840,7 +870,7 @@ def _local_attention(q, k, v, is_causal):
         bump("flash_attention", "xla", "autotuned: xla wins this shape")
         return _xla_attention(q, k, v, None, 0.0, is_causal, None)
     # choice == "stream" or no autotune verdict: static streaming path
-    if _pallas_ok(q, k, is_causal):
+    if _pallas_ok(q, k, is_causal, v=v):
         out = _flash_attention_pallas(q, k, v, causal=is_causal)
         bump("flash_attention", "pallas",
              **_work("stream", q, k, v, is_causal))
@@ -965,7 +995,8 @@ def flash_attention_or_fallback(q, k, v, mask=None, dropout_p=0.0,
             return _local_attention(q, k, v, is_causal)
     from .counters import bump
 
-    if mask is None and dropout_p > 0.0 and key_rng is not None:
+    if mask is None and dropout_p > 0.0 and key_rng is not None \
+            and _one_width(q, v):
         choice = _short_choice(q, k, is_causal, dropout_p)
         if choice == "short":
             out = _flash_attention_pallas_short(
@@ -981,7 +1012,7 @@ def flash_attention_or_fallback(q, k, v, mask=None, dropout_p=0.0,
                                   key_rng)
         # choice == "stream"/None: static streaming dispatch below
     if (mask is None and dropout_p > 0.0 and key_rng is not None and
-            q.shape[0] * q.shape[2] < (1 << 15) and
+            q.shape[0] * q.shape[2] < (1 << 15) and _one_width(q, v) and
             _pallas_ok(q, k, is_causal)):
         # dropout rides the kernel's hardware PRNG — no HBM mask tensor
         # (the XLA path materialises (B, H, L, L) keep masks). Floor is
@@ -994,7 +1025,8 @@ def flash_attention_or_fallback(q, k, v, mask=None, dropout_p=0.0,
         bump("flash_attention", "pallas",
              **_work("stream", q, k, v, is_causal))
         return out
-    if mask is not None and dropout_p == 0.0 and _pallas_ok(q, k, is_causal):
+    if mask is not None and dropout_p == 0.0 and _one_width(q, v) \
+            and _pallas_ok(q, k, is_causal):
         # key-padding masks ride the Pallas kernel as an additive kv bias;
         # per-query masks keep the XLA path
         bias = _kv_mask_bias(jnp.asarray(mask), q.shape[0], k.shape[1])

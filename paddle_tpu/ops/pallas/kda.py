@@ -1,0 +1,453 @@
+"""Chunked gated delta rule with channel-wise decay (Kimi Delta
+Attention), forward and backward.
+
+Per head, with keys ``k_t`` and queries ``q_t`` in R^K, values ``v_t``
+in R^V, a log-decay ``g_t <= 0`` per key channel and a write strength
+``beta_t`` in (0, 1), the recurrence over a state ``S`` (K x V, zero at
+the start of a row) is
+
+    S' = Diag(exp(g_t)) S_{t-1}
+    S_t = S' + beta_t k_t (v_t - S'^T k_t)^T          o_t = S_t^T q_t
+
+Nothing here walks the tokens. A row is cut into chunks of ``C`` tokens;
+inside a chunk the decays are accumulated in log space (``G``: the
+inclusive cumulative sum of ``g``), the rank-one updates of the chunk
+are resolved at once by one unit-lower-triangular system
+
+    (I + M B) U = V - (K * e^G) S_0,   M_ri = sum_c k_rc k_ic e^(G_rc - G_ic)  (i < r)
+
+(``B = Diag(beta)``, ``U`` the chunk's corrected values), and one state
+is carried from chunk to chunk:
+
+    O = (Q * e^G) S_0 + (P B) U,       P_ri as M_ri with q_r, i <= r
+    S_C = Diag(e^(G_C)) S_0 + (K * e^(G_C - G) B)^T U
+
+``e^(G_r - G_i)`` is never split into ``e^(G_r)`` and ``e^(-G_i)``, which
+overflows under strong decay: rows are taken ``_SUB`` at a time, each
+group against the cumulative decay just before its first row, so every
+exponent is either non-positive or spans less than one group.
+
+The backward walks the chunks in reverse with the state's cotangent as
+its carry; it reads the state each chunk started from (saved by the
+forward, ``C`` times smaller than per-token states) and recomputes the
+chunk's triangular system.
+
+One set of chunk formulas (:func:`_chunk_fwd`, :func:`_chunk_bwd`, plain
+``jax.numpy`` on 2-D blocks) serves both paths: under ``lax.scan`` they
+are the XLA form — the CPU path and the kernels' parity oracle — and as
+the bodies of the two Pallas kernels, launched under the roles
+``kda_chunk_fwd`` and ``kda_chunk_bwd``, they are the TPU path. The
+token-by-token recurrence lives only in the tests and in the
+benchmark's reference.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from .counters import bump, kernel_call
+from .flash_attention import _sds
+
+_F32 = jnp.float32
+_HIGHEST = jax.lax.Precision.HIGHEST
+#: tokens in a chunk, and rows in a group that shares a reference decay
+CHUNK = 64
+_SUB = 16
+#: exponents of decays that a mask discards anyway are held under this
+_EXP_CAP = 80.0
+
+
+def _dot(a, b, trans_b=False):
+    """float32 matrix product at full precision: the triangular system
+    amplifies what a bfloat16 pass would round away."""
+    dims = (((1,), (1,)), ((), ())) if trans_b else (((1,), (0,)), ((), ()))
+    return jax.lax.dot_general(a, b, dims, precision=_HIGHEST,
+                               preferred_element_type=_F32)
+
+
+def _iota(shape, axis):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _unit_lower_inverse(n):
+    """(I + N)^-1 for a strictly lower triangular ``n`` (nilpotent):
+    (I - N)(I + N^2)(I + N^4)... — log2(C) squarings, all on the MXU."""
+    c = n.shape[0]
+    eye = (_iota((c, c), 0) == _iota((c, c), 1)).astype(_F32)
+    inv, power, span = eye - n, n, 1
+    while 2 * span < c:
+        power = _dot(power, power)
+        inv = inv + _dot(inv, power)
+        span *= 2
+    return inv
+
+
+def _groups(c):
+    return [(lo, min(lo + _SUB, c)) for lo in range(0, c, _SUB)]
+
+
+def _intra(q, k, gc):
+    """P (q rows against k columns, i <= r) and M (k against k, i < r)
+    of one chunk, with what the backward needs again per group of rows:
+    (lo, hi, E, F) — E = e^(G_r - ref) on the group's rows, F =
+    e^(ref - G_i) on the columns up to the group's last."""
+    c = q.shape[0]
+    row = _iota((c, 1), 0)
+    parts, p_rows, m_rows = [], [], []
+    for lo, hi in _groups(c):
+        ref = gc[lo - 1:lo] if lo else jnp.zeros_like(gc[:1])
+        e = jnp.exp(gc[lo:hi] - ref)
+        f = jnp.where(row < hi,
+                      jnp.exp(jnp.minimum(ref - gc, _EXP_CAP)), 0.0)
+        both = jnp.concatenate([q[lo:hi] * e, k[lo:hi] * e], axis=0)
+        a = _dot(both, k * f, trans_b=True)              # (2 sub, C)
+        p_rows.append(a[:hi - lo])
+        m_rows.append(a[hi - lo:])
+        parts.append((lo, hi, e, f, both))
+    r, i = _iota((c, c), 0), _iota((c, c), 1)
+    p = jnp.where(r >= i, jnp.concatenate(p_rows, axis=0), 0.0)
+    m = jnp.where(r > i, jnp.concatenate(m_rows, axis=0), 0.0)
+    return p, m, parts
+
+
+def _chunk_fwd(q, k, v, gc, beta, st):
+    """One chunk of one head. q, k, gc: (C, K); v: (C, V); beta: (1, C);
+    st: the state TRANSPOSED, (V, K). Returns (o (C, V), next state)."""
+    p, m, _ = _intra(q, k, gc)
+    gam = jnp.exp(gc)
+    u = _dot(_unit_lower_inverse(m * beta),
+             v - _dot(k * gam, st, trans_b=True))
+    o = _dot(q * gam, st, trans_b=True) + _dot(p * beta, u)
+    last = gc[-1:]
+    st_next = st * jnp.exp(last) + _dot(u.T * beta, k * jnp.exp(last - gc))
+    return o, st_next
+
+
+def _chunk_bwd(q, k, v, gc, beta, st, do, dst_next):
+    """Cotangents of one chunk: (dq, dk, dv, dgc, dbeta (1, C), dst)."""
+    c = q.shape[0]
+    p, m, parts = _intra(q, k, gc)
+    gam = jnp.exp(gc)
+    qt, kt = q * gam, k * gam
+    inv = _unit_lower_inverse(m * beta)
+    u = _dot(inv, v - _dot(kt, st, trans_b=True))
+    last = gc[-1:]
+    decay_out = jnp.exp(last - gc)
+    kbar = k * decay_out
+    ubt = u.T * beta                                      # (V, C)
+
+    # the state that leaves the chunk: st * e^last + ubt @ kbar
+    dubt = _dot(dst_next, kbar, trans_b=True)             # (V, C)
+    dkbar = _dot(ubt.T, dst_next)                         # (C, K)
+    dbeta = jnp.sum(dubt * u.T, axis=0, keepdims=True)
+    dlast = jnp.sum(dst_next * st, axis=0, keepdims=True) * jnp.exp(last)
+    # the output: qt @ st^T + (p * beta) @ u
+    du = _dot((p * beta).T, do) + (dubt * beta).T
+    dpb = _dot(do, u, trans_b=True)
+    dbeta = dbeta + jnp.sum(dpb * p, axis=0, keepdims=True)
+    dqt = _dot(do, st)
+    # u = inv @ (v - kt @ st^T)
+    dr = _dot(inv.T, du)
+    dmb = -_dot(dr, u, trans_b=True)
+    r, i = _iota((c, c), 0), _iota((c, c), 1)
+    dmb = jnp.where(r > i, dmb, 0.0)
+    dpb = jnp.where(r >= i, dpb, 0.0)
+    dbeta = dbeta + jnp.sum(dmb * m, axis=0, keepdims=True)
+    dkt = -_dot(dr, st)
+    dst = _dot(do.T, qt) + dst_next * jnp.exp(last) - _dot(dr.T, kt)
+
+    dq = dqt * gam
+    dk = dkt * gam + dkbar * decay_out
+    dkbar_g = dkbar * kbar
+    dgc = dqt * qt + dkt * kt - dkbar_g
+    dlast = dlast + jnp.sum(dkbar_g, axis=0, keepdims=True)
+    row = _iota((c, 1), 0)
+    dgc = dgc + jnp.where(row == c - 1, dlast, 0.0)
+    # P and M, a group of rows at a time
+    dp, dm = dpb * beta, dmb * beta
+    dq_rows, dk_rows, dg_rows = [], [], []
+    for lo, hi, e, f, both in parts:
+        da = jnp.concatenate([dp[lo:hi], dm[lo:hi]], axis=0)   # (2 sub, C)
+        dboth = _dot(da, k * f)
+        dkf = _dot(da.T, both)                                 # (C, K)
+        dq_rows.append(dboth[:hi - lo] * e)
+        dk_rows.append(dboth[hi - lo:] * e)
+        dge = (dboth[:hi - lo] * q[lo:hi] + dboth[hi - lo:] * k[lo:hi]) * e
+        dg_rows.append(dge)
+        dgf = dkf * k * f
+        dk = dk + dkf * f
+        dgc = dgc - dgf
+        if lo:
+            dref = jnp.sum(dgf, axis=0, keepdims=True) \
+                - jnp.sum(dge, axis=0, keepdims=True)
+            dgc = dgc + jnp.where(row == lo - 1, dref, 0.0)
+    dq = dq + jnp.concatenate(dq_rows, axis=0)
+    dk = dk + jnp.concatenate(dk_rows, axis=0)
+    dgc = dgc + jnp.concatenate(dg_rows, axis=0)
+    return dq, dk, dr, dgc, dbeta, dst
+
+
+# ---------------------------------------------------------------------------
+# the XLA form: the same chunk formulas under lax.scan, heads under vmap
+# ---------------------------------------------------------------------------
+def _by_chunk(x, chunk):
+    """(B, T, H, D) -> (NC, B, H, C, D)."""
+    b, t, h, d = x.shape
+    return x.reshape(b, t // chunk, chunk, h, d).transpose(1, 0, 3, 2, 4)
+
+
+def _from_chunks(x):
+    """(NC, B, H, C, D) -> (B, T, H, D)."""
+    nc, b, h, c, d = x.shape
+    return x.transpose(1, 0, 3, 2, 4).reshape(b, nc * c, h, d)
+
+
+def _beta_rows(beta, chunk):
+    """(B, T, H) -> (B, H, NC, 1, C): a (1, C) row per head and chunk."""
+    b, t, h = beta.shape
+    return beta.reshape(b, t // chunk, chunk, h).transpose(
+        0, 3, 1, 2)[:, :, :, None, :]
+
+
+def _beta_from_rows(rows):
+    """(B, H, NC, 1, C) -> (B, T, H)."""
+    b, h, nc, _, c = rows.shape
+    return rows[:, :, :, 0, :].transpose(0, 2, 3, 1).reshape(b, nc * c, h)
+
+
+_heads = functools.partial(jax.vmap, in_axes=0)
+
+
+def _xla_fwd(q, k, v, gc, beta, chunk):
+    b, _, h, kd = q.shape
+    vd = v.shape[-1]
+    step = _heads(_heads(_chunk_fwd))
+
+    def body(st, xs):
+        o, st_next = step(*xs, st)
+        return st_next, (o, st)
+
+    xs = tuple(_by_chunk(a, chunk) for a in (q, k, v, gc)) \
+        + (jnp.moveaxis(_beta_rows(beta, chunk), 2, 0),)
+    _, (o, states) = jax.lax.scan(body, jnp.zeros((b, h, vd, kd), _F32), xs)
+    return _from_chunks(o), states
+
+
+def _xla_bwd(q, k, v, gc, beta, states, do, chunk):
+    b, _, h, kd = q.shape
+    vd = v.shape[-1]
+    step = _heads(_heads(_chunk_bwd))
+
+    def body(dst, xs):
+        dq, dk, dv, dgc, dbeta, dst = step(*xs, dst)
+        return dst, (dq, dk, dv, dgc, dbeta)
+
+    xs = tuple(_by_chunk(a, chunk) for a in (q, k, v, gc)) \
+        + (jnp.moveaxis(_beta_rows(beta, chunk), 2, 0), states,
+           _by_chunk(do, chunk))
+    _, (dq, dk, dv, dgc, dbeta) = jax.lax.scan(
+        body, jnp.zeros((b, h, vd, kd), _F32), xs, reverse=True)
+    dbeta = _beta_from_rows(jnp.moveaxis(dbeta, 0, 2))
+    return tuple(_from_chunks(a) for a in (dq, dk, dv, dgc)) + (dbeta,)
+
+
+# ---------------------------------------------------------------------------
+# the Pallas kernels: grid (batch, head, chunk), chunks in order, the
+# state (its cotangent, backward) in a VMEM scratch across them
+# ---------------------------------------------------------------------------
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, st_ref, carry):
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(2) == 0)
+    def _start():
+        carry[...] = jnp.zeros_like(carry)
+
+    st = carry[...]
+    st_ref[...] = st
+    o, st_next = _chunk_fwd(q_ref[...], k_ref[...], v_ref[...], g_ref[...],
+                            b_ref[...], st)
+    o_ref[...] = o
+    carry[...] = st_next
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, st_ref, do_ref,
+                dq_ref, dk_ref, dv_ref, dg_ref, db_ref, carry):
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(2) == 0)
+    def _start():
+        carry[...] = jnp.zeros_like(carry)
+
+    dq, dk, dv, dgc, dbeta, dst = _chunk_bwd(
+        q_ref[...], k_ref[...], v_ref[...], g_ref[...], b_ref[...],
+        st_ref[...], do_ref[...], carry[...])
+    dq_ref[...] = dq
+    dk_ref[...] = dk
+    dv_ref[...] = dv
+    dg_ref[...] = dgc
+    db_ref[...] = dbeta
+    carry[...] = dst
+
+
+def _flat(x):
+    b, t, h, d = x.shape
+    return x.reshape(b, t, h * d)
+
+
+def _specs(h, kd, vd, chunk, nc, reverse):
+    """Block specs by kind: a (C, D) block of the (B, T, H*D) arrays at
+    head h (no transposed copy of q, k, v in HBM), beta's (1, C) row and
+    a chunk's (V, K) state."""
+    from jax.experimental import pallas as pl
+
+    def at(c):
+        return nc - 1 - c if reverse else c
+
+    def tokens(d):
+        return pl.BlockSpec((None, chunk, d), lambda b, h, c: (b, at(c), h))
+
+    row = pl.BlockSpec((None, None, None, 1, chunk),
+                       lambda b, h, c: (b, h, at(c), 0, 0))
+    state = pl.BlockSpec((None, None, None, vd, kd),
+                         lambda b, h, c: (b, h, at(c), 0, 0))
+    return tokens(kd), tokens(vd), row, state
+
+
+def _compiler_params():
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+
+def _pallas_fwd(q, k, v, gc, beta, chunk):
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, t, h, kd = q.shape
+    vd = v.shape[-1]
+    nc = t // chunk
+    keys, vals, row, state = _specs(h, kd, vd, chunk, nc, reverse=False)
+    o, states = kernel_call(
+        "kda_chunk_fwd", _fwd_kernel, grid=(b, h, nc),
+        in_specs=[keys, keys, vals, keys, row],
+        out_specs=[vals, state],
+        out_shape=[_sds((b, t, h * vd), _F32, q),
+                   _sds((b, h, nc, vd, kd), _F32, q)],
+        scratch_shapes=[pltpu.VMEM((vd, kd), _F32)],
+        compiler_params=_compiler_params(),
+    )(_flat(q), _flat(k), _flat(v), _flat(gc), _beta_rows(beta, chunk))
+    return o.reshape(b, t, h, vd), states
+
+
+def _pallas_bwd(q, k, v, gc, beta, states, do, chunk):
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, t, h, kd = q.shape
+    vd = v.shape[-1]
+    nc = t // chunk
+    keys, vals, row, state = _specs(h, kd, vd, chunk, nc, reverse=True)
+    dq, dk, dv, dgc, dbeta = kernel_call(
+        "kda_chunk_bwd", _bwd_kernel, grid=(b, h, nc),
+        in_specs=[keys, keys, vals, keys, row, state, vals],
+        out_specs=[keys, keys, vals, keys, row],
+        out_shape=[_sds((b, t, h * kd), _F32, q),
+                   _sds((b, t, h * kd), _F32, q),
+                   _sds((b, t, h * vd), _F32, q),
+                   _sds((b, t, h * kd), _F32, q),
+                   _sds((b, h, nc, 1, chunk), _F32, q)],
+        scratch_shapes=[pltpu.VMEM((vd, kd), _F32)],
+        compiler_params=_compiler_params(),
+    )(_flat(q), _flat(k), _flat(v), _flat(gc), _beta_rows(beta, chunk),
+      states, _flat(do))
+    return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
+            dgc.reshape(q.shape), _beta_from_rows(dbeta))
+
+
+# ---------------------------------------------------------------------------
+# dispatch + custom_vjp
+# ---------------------------------------------------------------------------
+def _kernel_takes(q, v, chunk):
+    """The kernels take lane-dense heads (128-wide keys and values) on a
+    single-device TPU trace; everything else runs the XLA form."""
+    from ...framework.bringup import pallas_enabled
+    from ...parallel.mesh import auto_partitioned_trace
+
+    return (pallas_enabled() and not auto_partitioned_trace()
+            and q.shape[-1] % 128 == 0 and v.shape[-1] % 128 == 0
+            and chunk % _SUB == 0 and chunk % 8 == 0)
+
+
+def _cumulate(g, chunk):
+    """Inclusive cumulative log-decay inside each chunk."""
+    b, t, h, d = g.shape
+    return jnp.cumsum(g.reshape(b, t // chunk, chunk, h, d),
+                      axis=2).reshape(g.shape)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _chunk_kda(q, k, v, g, beta, chunk, kernel):
+    return _chunk_kda_fwd(q, k, v, g, beta, chunk, kernel)[0]
+
+
+def _chunk_kda_fwd(q, k, v, g, beta, chunk, kernel):
+    gc = _cumulate(g, chunk)
+    o, states = (_pallas_fwd if kernel else _xla_fwd)(q, k, v, gc, beta,
+                                                      chunk)
+    return o, (q, k, v, gc, beta, states)
+
+
+def _chunk_kda_bwd(chunk, kernel, res, do):
+    q, k, v, gc, beta, states = res
+    dq, dk, dv, dgc, dbeta = (_pallas_bwd if kernel else _xla_bwd)(
+        q, k, v, gc, beta, states, do, chunk)
+    b, t, h, d = dgc.shape
+    # the cumulative sum's transpose: a reversed cumulative sum per chunk
+    dg = jnp.flip(jnp.cumsum(jnp.flip(
+        dgc.reshape(b, t // chunk, chunk, h, d), 2), axis=2), 2)
+    return dq, dk, dv, dg.reshape(dgc.shape), dbeta
+
+
+_chunk_kda.defvjp(_chunk_kda_fwd, _chunk_kda_bwd)
+
+
+def kda_work(b, t, h, kd, vd):
+    """``work=`` / ``grad_work=`` of one call: the recurrence's own
+    operations (decay, read, rank-one write and query of a K x V state:
+    6 K V a token and head; twice that backward) and the bytes it cannot
+    avoid — q, k, v, g (float32) and beta read and o written once; the
+    same again plus o's cotangent read and five cotangents written."""
+    tokens = b * t * h
+    moved = 4 * tokens * (3 * kd + 2 * vd + 1)
+    return {
+        "work": {"kda_chunk_fwd": (6.0 * tokens * kd * vd, moved)},
+        "grad_work": {"kda_chunk_bwd": (
+            12.0 * tokens * kd * vd,
+            2 * moved)}}
+
+
+def chunk_kda(q, k, v, g, beta, chunk=CHUNK):
+    """Gated delta rule over each row of a batch from a zero state.
+
+    q, k, g: (B, T, H, K); v: (B, T, H, V); beta: (B, T, H); ``g`` is the
+    per-token, per-channel LOG decay (<= 0). Returns o (B, T, H, V),
+    float32. A length that is no multiple of ``chunk`` is padded with
+    tokens that neither write nor decay."""
+    q, k, v, g, beta = (a.astype(_F32) for a in (q, k, v, g, beta))
+    b, t, h, kd = q.shape
+    vd = v.shape[-1]
+    pad = (-t) % chunk
+    if pad:
+        q, k, v, g = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                      for a in (q, k, v, g))
+        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
+    kernel = _kernel_takes(q, v, chunk)
+    if kernel:
+        bump("kda_chunk", "pallas", **kda_work(b, t + pad, h, kd, vd))
+    else:
+        bump("kda_chunk", "xla",
+             f"dispatch ineligible (q {tuple(q.shape)}, v {tuple(v.shape)}"
+             f", chunk {chunk}; backend or 128-lane heads)")
+    o = _chunk_kda(q, k, v, g, beta, chunk, kernel)
+    return o[:, :t] if pad else o

@@ -196,8 +196,12 @@ def profile(workload: str, out: str, seed: int = 1, steps: int = 10,
         raise harness.Refused(f"the driver of {workload} has no Loop: "
                               "only training cells have a step to profile")
     cfg, cell = ctx.config, ctx.cell
-    batches = traffic.train_batches(cell["traffic"], cfg["vocab_size"], seed)
-    loop = driver.Loop(cfg, cell, driver.make_params(cfg, seed), seed)
+    if hasattr(driver, "loop_and_batches"):     # a driver with a feed of
+        loop, batches = driver.loop_and_batches(ctx)        # its own
+    else:
+        batches = traffic.train_batches(cell["traffic"], cfg["vocab_size"],
+                                        seed)
+        loop = driver.Loop(cfg, cell, driver.make_params(cfg, seed), seed)
     loss = None
     for _ in range(warm):
         loss = loop.feed_and_step(batches[loop.steps % len(batches)])
