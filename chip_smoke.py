@@ -502,8 +502,13 @@ def _kernel_checks():
             lambda q, k: fa._pallas_ok(q, k, False))(fails)
     checks.append(("flash stream masked (8, 512, 12, 64)", flash_masked))
 
+    # packed (B, L, H*D) blocks: two 64-wide heads a block (the seq512
+    # cell's own shape), one 128- or 256-wide head a block; an odd head
+    # count at 64 keeps the transposing wrapper
     for shape, dtype, tag in (((128, 128, 12, 64), bf16, "bert128"),
-                              ((32, 512, 12, 64), bf16, "bert512"),
+                              ((64, 512, 12, 64), bf16, "bert512"),
+                              ((8, 512, 4, 128), bf16, "one head a block"),
+                              ((8, 512, 3, 64), bf16, "odd heads, merged"),
                               ((2, 512, 2, 256), f32, "gate edge")):
         ok = lambda q, k: fa._short_ok(q, k, False)
         flash(f"flash short {tag} {shape}",
@@ -513,6 +518,56 @@ def _kernel_checks():
               lambda q, k, v: fa._flash_attention_pallas_short(
                   q, k, v, seed=seed, dropout_p=p0),
               xla(), shape, dtype, ok)
+
+    def flash_short_dropout_live(fails, p=0.25):
+        """Dropout on in a block of two heads (float32, so that a mask is
+        the only thing that can tell runs apart). The second head is a
+        copy of the first: the same q, k, v must come out different,
+        because each head seeds a mask of its own, and as they come out
+        of the merged layout, where a head is a row and its index the
+        program's. The backward must draw the forward's masks again: the
+        output is linear in v, so <out, w> = <v, dv> for the mask the
+        forward drew and for no other, and the slopes along a direction
+        in q and k are those of the forward run twice on one seed."""
+        b, l, h, d = 4, 256, 2, 64
+        q, k, v, w, u = (jnp.repeat(rnd(s, (b, l, 1, d)), h, axis=2)
+                         for s in (1, 2, 3, 4, 5))
+
+        def short(q, k, v, s=seed):
+            return fa._flash_attention_pallas_short(q, k, v, seed=s,
+                                                    dropout_p=p)
+        out = jax.jit(short)(q, k, v)
+        if not bool(jnp.all(out == jax.jit(short)(q, k, v))) or \
+                bool(jnp.all(out == short(q, k, v, seed + 1))):
+            fails.append("flash short dropout: not a function of its seed")
+        if bool(jnp.all(out[:, :, 0] == out[:, :, 1])):
+            fails.append("flash short dropout: the two heads of a block "
+                         "drew one mask")
+        plain = fa._flash_attention_pallas_short(q, k, v)
+        if not bool(jnp.all(plain[:, :, 0] == plain[:, :, 1])):
+            fails.append("flash short: equal heads of a block differ "
+                         "with dropout off")
+        # one head a row: (b*h, l, 1, d) takes the merged layout, and
+        # row b*h + a is head a of batch b
+        rows = [jnp.swapaxes(x, 1, 2).reshape(b * h, l, 1, d)
+                for x in (q, k, v)]
+        merged = jnp.swapaxes(short(*rows).reshape(b, h, l, d), 1, 2)
+        _close("flash short dropout: packed heads against merged rows",
+               out, merged, 2e-4, fails)
+
+        loss = lambda q, k, v: jnp.sum(short(q, k, v) * w)  # noqa: E731
+        val, (dq, dk, dv) = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2)))(q, k, v)
+        _close("flash short dropout <out, w> = <v, dv>", val,
+               jnp.sum(v * dv), 2e-4, fails)
+        eps = 1e-2
+        for nm, g, at in (("q", dq, lambda t: (q + t * u, k, v)),
+                          ("k", dk, lambda t: (q, k + t * u, v))):
+            slope = (loss(*at(eps)) - loss(*at(-eps))) / (2 * eps)
+            _close(f"flash short dropout slope along d{nm}",
+                   jnp.sum(g * u), slope, 2e-2, fails)
+    checks.append(("flash short dropout p=0.25, two heads a block "
+                   "(4, 256, 2, 64)", flash_short_dropout_live))
 
     def flash_dropout_live(fails):
         """p = 0.1: finite, reproducible for a seed, different across

@@ -201,34 +201,48 @@ def best_short_window_impl(b, l, h, d, dtype, causal, dropout_p) -> str:
     from . import flash_attention as fa
 
     def build():
-        q = jax.random.normal(jax.random.key(0), (b, l, h, d),
-                              jnp.float32).astype(dtype)
+        # q, k, v as three projections write them, (B, L, H*D) each and
+        # seen as heads inside the timed function, and a cotangent with
+        # values of its own: a candidate pays for the layout change it
+        # needs and for no other, and XLA finds no equal operands and no
+        # constant cotangent to fold, as it finds none in a model (with
+        # one array for all three and grad(sum), XLA attention read 2.1 ms
+        # against the short kernels' 2.3 at (256, 128, 12, 64); with these
+        # 3.0 against 2.2, and BERT at seq 128 ran 5% faster on the
+        # kernels: chip runs, PR 28)
+        *qkv, w = (jax.random.normal(jax.random.key(i), (b, l, h * d),
+                                     jnp.float32).astype(dtype)
+                   for i in range(4))
+        heads = jax.ShapeDtypeStruct((b, l, h, d), dtype)
         seed = jnp.asarray([[17]], jnp.int32) if dropout_p > 0.0 else None
 
         def train_like(impl):
             # fwd+bwd through the impl's custom vjp: training is what the
             # headline measures, and fwd-only and train prefer different
             # kernels (the r3 block sweeps showed exactly that)
-            return jax.jit(jax.grad(lambda a: jnp.sum(impl(a))))
+            def loss(qkv, w):
+                out = impl(*(a.reshape(heads.shape) for a in qkv))
+                return jnp.sum(out.reshape(w.shape).astype(jnp.float32) * w)
+            return jax.jit(lambda arg: jax.grad(loss)(*arg))
 
         candidates = {"short": train_like(
-            lambda a: fa._flash_attention_core_short(
-                a, a, a, seed, causal, dropout_p))}
-        if fa._pallas_ok(q, q, causal):
+            lambda q, k, v: fa._flash_attention_core_short(
+                q, k, v, seed, causal, dropout_p))}
+        if fa._pallas_ok(heads, heads, causal):
             blocks = fa._pick_blocks(l, l, 512, 512)
             if dropout_p > 0.0:
                 candidates["stream"] = train_like(
-                    lambda a: fa._flash_attention_core_dropout(
-                        a, a, a, seed, causal, *blocks, dropout_p))
+                    lambda q, k, v: fa._flash_attention_core_dropout(
+                        q, k, v, seed, causal, *blocks, dropout_p))
             else:
                 candidates["stream"] = train_like(
-                    lambda a: fa._flash_attention_core(
-                        a, a, a, causal, *blocks))
+                    lambda q, k, v: fa._flash_attention_core(
+                        q, k, v, causal, *blocks))
         candidates["xla"] = train_like(
-            lambda a: fa._xla_attention(
-                a, a, a, None, dropout_p, causal,
+            lambda q, k, v: fa._xla_attention(
+                q, k, v, None, dropout_p, causal,
                 jax.random.key(3) if dropout_p > 0.0 else None))
-        return candidates, q
+        return candidates, (tuple(qkv), w)
 
     key = (b, l, h, d, str(dtype), bool(causal), round(float(dropout_p), 4))
     return _verdict(key, "flash", ("short", "stream", "xla"), build)

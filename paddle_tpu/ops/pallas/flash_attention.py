@@ -529,19 +529,70 @@ _flash_attention_core_dropout.defvjp(_flash_attention_core_dropout_fwd,
 
 
 # ---------------------------------------------------------------------------
-# short-sequence single-block kernels (seq <= _SHORT_SEQ_MAX): the whole
-# (L, L) score tile lives in VMEM, so softmax is computed directly (no
-# online-softmax carry/rescale machinery) and the ENTIRE backward — dq,
-# dk and dv — is one kernel launch recomputing the scores once, versus
-# the streaming path's two launches recomputing them twice. This is the
-# candidate for beating XLA below the seq-256 dispatch floor
-# (r3 review, weak #3); FLAGS_flash_short_seq gates dispatch until an
-# on-chip A/B proves it.
-# The 512 ceiling includes the bert512 shape on purpose: per program the
-# fused bwd holds ~4x(512,512) f32 intermediates (~5 MB).
+# short-sequence single-block kernels (seq <= _SHORT_SEQ_MAX): a head's
+# whole (L, L) score tile lives in VMEM, so softmax is computed directly
+# (no online-softmax carry) and the whole backward (delta, dq, dk, dv) is
+# one launch that recomputes the scores once.
+#
+# Layout: the kernels take q, k, v, the output and their cotangents as
+# (B, L, H*D), the layout the projections write and the output
+# projection reads (from (B, L, H, D) a reshape of contiguous trailing
+# axes: nothing moves), and address heads inside it: grid (B, H*D / W),
+# blocks (L, W) at lane block j. W (_short_block_width) is the smallest
+# multiple of 128 lanes that holds whole heads: one head a step where
+# D % 128 == 0, two where D == 64 and H is even. Two 64-wide heads share
+# a block because a block narrower than the 128 lanes of a vreg can be
+# neither cut from a wider array nor stored densely, and sharing costs
+# the MXU nothing: a 64-deep contraction and a 64-wide result occupy a
+# 128 x 128 pass anyway. Per head the operands are masked to the head's
+# lanes (_head_lanes), so every product adds only exact zeros to what the
+# head alone would give, and the heads' results, zero outside their own
+# lanes, sum to one lane-dense store. Any other (H, D) (odd H at 64,
+# D = 192) moves the heads beside the batch first (_mergeheads, one
+# transposing copy each way) and runs the same kernels on (B*H, L, D), one
+# head a row.
+# The 512 ceiling includes the bert512 shape on purpose; what a block's
+# heads keep in VMEM is counted in _short_call.
 # ---------------------------------------------------------------------------
 
 _SHORT_SEQ_MAX = 512
+
+
+def _short_block_width(h, d):
+    """Lanes of one block of the packed (B, L, H*D) layout, or None where
+    no multiple of 128 lanes holds whole heads of this width."""
+    if d % 128 == 0:
+        return d
+    if d == 64 and h % 2 == 0:
+        return 128
+    return None
+
+
+def _short_pack(x):
+    """(B, L, H, D) -> what the short kernels address, (rows, L, H' * D)
+    with H' heads a row: the packed layout itself, or the heads merged
+    into the rows where it has no block width."""
+    b, l, h, d = x.shape
+    if _short_block_width(h, d) is None:
+        return _mergeheads(x)
+    return x.reshape(b, l, h * d)
+
+
+def _short_unpack(x, shape):
+    """Back from :func:`_short_pack`'s layout to ``shape`` (B, L, H, D)."""
+    b, l, h, d = shape
+    if _short_block_width(h, d) is None:
+        return _splitheads(x, b, h)
+    return x.reshape(shape)
+
+
+def _head_lanes(x, a, d):
+    """x with every lane outside head a's d zeroed (x itself where the
+    block is one head wide)."""
+    if x.shape[1] == d:
+        return x
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where((lane >= a * d) & (lane < (a + 1) * d), x, 0.0)
 
 
 def _short_scores(q, k, sm_scale, causal):
@@ -554,66 +605,120 @@ def _short_scores(q, k, sm_scale, causal):
     return s
 
 
-def _short_fwd_kernel(q_ref, k_ref, v_ref, *rest, sm_scale, causal,
-                      dropout_p=0.0):
+def _first_head(heads, group):
+    """Index, over batch and heads, of the first head of this grid step:
+    each head seeds a dropout mask of its own from its index."""
     from jax.experimental import pallas as pl
 
+    return pl.program_id(0) * heads + pl.program_id(1) * group
+
+
+def _short_fwd_kernel(q_ref, k_ref, v_ref, *rest, sm_scale, causal, d,
+                      heads, dropout_p=0.0):
     rest = list(rest)
     seed_ref = rest.pop(0) if dropout_p > 0.0 else None
     o_ref, lse_ref = rest
     q = q_ref[...].astype(_F32)
     k = k_ref[...].astype(_F32)
     v = v_ref[...].astype(_F32)
-    s = _short_scores(q, k, sm_scale, causal)
-    m = jnp.max(s, axis=1)
-    p = jnp.exp(s - m[:, None])
-    l = jnp.sum(p, axis=1)
-    p = p / l[:, None]
-    if dropout_p > 0.0:
-        keep = _keep_mask(seed_ref[0, 0], pl.program_id(0), 0, 0,
-                          p.shape, dropout_p)
-        p = jnp.where(keep, p / (1.0 - dropout_p), 0.0)
-    o_ref[...] = _dot(p, v).astype(o_ref.dtype)
-    lse_ref[...] = (m + jnp.log(jnp.maximum(l, 1e-30)))[None, :]
+    group = q.shape[1] // d                          # heads in this block
+    out = jnp.zeros_like(q)
+    for a in range(group):
+        s = _short_scores(_head_lanes(q, a, d), k, sm_scale, causal)
+        m = jnp.max(s, axis=1)
+        p = jnp.exp(s - m[:, None])
+        l = jnp.sum(p, axis=1)
+        p = p / l[:, None]
+        if dropout_p > 0.0:
+            keep = _keep_mask(seed_ref[0, 0], _first_head(heads, group) + a,
+                              0, 0, p.shape, dropout_p)
+            p = jnp.where(keep, p / (1.0 - dropout_p), 0.0)
+        out = out + _dot(p, _head_lanes(v, a, d))
+        lse_ref[a] = (m + jnp.log(jnp.maximum(l, 1e-30)))[None, :]
+    o_ref[...] = out.astype(o_ref.dtype)
 
 
-def _short_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      *rest, sm_scale, causal, dropout_p=0.0):
-    from jax.experimental import pallas as pl
-
+def _short_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
+                      sm_scale, causal, d, heads, dropout_p=0.0):
     rest = list(rest)
     seed_ref = rest.pop(0) if dropout_p > 0.0 else None
     dq_ref, dk_ref, dv_ref = rest
     q = q_ref[...].astype(_F32) * sm_scale
     k = k_ref[...].astype(_F32)
     v = v_ref[...].astype(_F32)
+    o = o_ref[...].astype(_F32)
     do = do_ref[...].astype(_F32)
-    lse = lse_ref[0, :]
-    delta = delta_ref[0, :]
-    s = _short_scores(q, k, 1.0, causal)             # q pre-scaled
-    p = jnp.exp(s - lse[:, None])                    # (L, L)
-    dp = _dot(do, v, trans_b=True)
-    if dropout_p > 0.0:
-        keep = _keep_mask(seed_ref[0, 0], pl.program_id(0), 0, 0,
-                          p.shape, dropout_p)
-        inv = 1.0 / (1.0 - dropout_p)
-        dv_ref[...] = _dot(jnp.where(keep, p * inv, 0.0).T,
-                           do).astype(dv_ref.dtype)
-        dp = jnp.where(keep, dp * inv, 0.0)
-    else:
-        dv_ref[...] = _dot(p.T, do).astype(dv_ref.dtype)
-    ds = p * (dp - delta[:, None])
-    dq_ref[...] = (_dot(ds, k) * sm_scale).astype(dq_ref.dtype)
-    dk_ref[...] = _dot(ds.T, q).astype(dk_ref.dtype)
+    group = q.shape[1] // d
+    dq, dk, dv = jnp.zeros_like(q), jnp.zeros_like(k), jnp.zeros_like(v)
+    for a in range(group):
+        qa, doa = _head_lanes(q, a, d), _head_lanes(do, a, d)
+        # rowsum(do * out) over the head: with dropout on it already
+        # equals <dp_dropped, p>
+        delta = jnp.sum(doa * o, axis=1)
+        s = _short_scores(qa, k, 1.0, causal)        # q pre-scaled
+        p = jnp.exp(s - lse_ref[a, 0, :][:, None])   # (L, L)
+        dp = _dot(doa, v, trans_b=True)
+        if dropout_p > 0.0:
+            keep = _keep_mask(seed_ref[0, 0], _first_head(heads, group) + a,
+                              0, 0, p.shape, dropout_p)
+            inv = 1.0 / (1.0 - dropout_p)
+            dv = dv + _dot(jnp.where(keep, p * inv, 0.0).T, doa)
+            dp = jnp.where(keep, dp * inv, 0.0)
+        else:
+            dv = dv + _dot(p.T, doa)
+        ds = p * (dp - delta[:, None])
+        dq = dq + _dot(ds, _head_lanes(k, a, d))
+        dk = dk + _dot(ds.T, qa)
+    dq_ref[...] = (dq * sm_scale).astype(dq_ref.dtype)
+    dk_ref[...] = dk.astype(dk_ref.dtype)
+    dv_ref[...] = dv.astype(dv_ref.dtype)
 
 
-def _short_call_specs(bh, L, d, dropout):
+def _short_call(role, kernel, blocks, lse, seed, d, causal, dropout_p):
+    """One launch of a short kernel on (rows, L, H' * D) operands
+    ``blocks``: grid (rows, H' * D / W), one (L, W) block of every operand
+    a step beside the logsumexp rows of the block's heads. The forward
+    (``lse`` None) writes the output and the logsumexp; the backward
+    reads ``lse`` and writes dq, dk, dv. ``seed`` is read with dropout
+    on."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    specs = [pl.BlockSpec((None, L, d), lambda i: (i, 0, 0))] * 3
-    if dropout:
-        specs.append(pl.BlockSpec((1, 1), lambda i: (0, 0)))
-    return specs
+    rows, L, hd = blocks[0].shape
+    heads = hd // d
+    width = _short_block_width(heads, d) or d        # merged rows: H' = 1
+    group = width // d
+    block = pl.BlockSpec((None, L, width), lambda i, j: (i, 0, j))
+    lse_spec = pl.BlockSpec((None, group, 1, L), lambda i, j: (i, j, 0, 0))
+    like = jax.ShapeDtypeStruct(blocks[0].shape, blocks[0].dtype)
+    operands, in_specs = list(blocks), [block] * len(blocks)
+    if lse is None:
+        out_specs = [block, lse_spec]
+        out_shape = [like, jax.ShapeDtypeStruct((rows, heads, 1, L), _F32)]
+    else:
+        operands.append(lse)
+        in_specs.append(lse_spec)
+        out_specs, out_shape = [block] * 3, [like] * 3
+    if dropout_p > 0.0:
+        operands.append(seed)
+        in_specs.append(pl.BlockSpec((1, 1), lambda i, j: (0, 0)))
+    # scoped VMEM: a head of the block keeps about eight (L, L) float32
+    # intermediates in the backward (scores, probabilities, dP, dS, two
+    # transposes, a 'highest' product's split operands) beside sixteen
+    # (L, W) blocks and their float32 copies. Mosaic's 16 MiB default fell
+    # short by 0.1 MB at (512, 2 x 64) with dropout and by 2.4 MB at
+    # (512, 256) float32, both under 'highest' (compiling for a v5e,
+    # PR 28); twice the estimate, of 128 MiB physical
+    need = 4 * L * (8 * L * group + 16 * width)
+    return kernel_call(
+        role,
+        functools.partial(kernel, sm_scale=1.0 / math.sqrt(d), causal=causal,
+                          d=d, heads=heads, dropout_p=dropout_p),
+        grid=(rows, hd // width),
+        in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=max(16 << 20, min(2 * need, 96 << 20))),
+    )(*operands)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
@@ -624,66 +729,28 @@ def _flash_attention_core_short(q, k, v, seed, causal, dropout_p):
 
 
 def _flash_attention_core_short_fwd(q, k, v, seed, causal, dropout_p):
-    from jax.experimental import pallas as pl
-
-    b, L, h, d = q.shape
-    sm_scale = 1.0 / math.sqrt(d)
-    qm, km, vm = _mergeheads(q), _mergeheads(k), _mergeheads(v)
-    bh = qm.shape[0]
-    ops = [qm, km, vm]
-    if dropout_p > 0.0:
-        ops.append(seed)
-    out_m, lse = kernel_call(
-        "flash_attention_short_fwd",
-        functools.partial(_short_fwd_kernel, sm_scale=sm_scale,
-                          causal=causal, dropout_p=dropout_p),
-        grid=(bh,),
-        in_specs=_short_call_specs(bh, L, d, dropout_p > 0.0),
-        out_specs=[
-            pl.BlockSpec((None, L, d), lambda i: (i, 0, 0)),
-            pl.BlockSpec((None, 1, L), lambda i: (i, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, L, d), qm.dtype),
-            jax.ShapeDtypeStruct((bh, 1, L), _F32),
-        ],
-    )(*ops)
-    return _splitheads(out_m, b, h), (qm, km, vm, out_m, lse, seed, b, h)
+    qp, kp, vp = _short_pack(q), _short_pack(k), _short_pack(v)
+    out_p, lse = _short_call(
+        "flash_attention_short_fwd", _short_fwd_kernel, (qp, kp, vp), None,
+        seed, q.shape[-1], causal, dropout_p)
+    return _short_unpack(out_p, q.shape), (qp, kp, vp, out_p, lse, seed)
 
 
 def _flash_attention_core_short_bwd(causal, dropout_p, res, dout):
     import numpy as np
 
-    from jax.experimental import pallas as pl
-
-    qm, km, vm, out_m, lse, seed, b, h = res
-    bh, L, d = qm.shape
-    sm_scale = 1.0 / math.sqrt(d)
-    # same constant-cotangent Mosaic guard as the streaming dropout bwd
-    dom = _mergeheads(jax.lax.optimization_barrier(dout))
-    delta = jnp.sum(dom.astype(_F32) * out_m.astype(_F32),
-                    axis=-1)[:, None, :]
-    specs = [pl.BlockSpec((None, L, d), lambda i: (i, 0, 0))] * 4 + [
-        pl.BlockSpec((None, 1, L), lambda i: (i, 0, 0)),
-        pl.BlockSpec((None, 1, L), lambda i: (i, 0, 0)),
-    ]
-    ops = [qm, km, vm, dom, lse, delta]
-    if dropout_p > 0.0:
-        specs.append(pl.BlockSpec((1, 1), lambda i: (0, 0)))
-        ops.append(seed)
-    dq, dk, dv = kernel_call(
-        "flash_attention_short_bwd",
-        functools.partial(_short_bwd_kernel, sm_scale=sm_scale,
-                          causal=causal, dropout_p=dropout_p),
-        grid=(bh,),
-        in_specs=specs,
-        out_specs=[pl.BlockSpec((None, L, d), lambda i: (i, 0, 0))] * 3,
-        out_shape=[jax.ShapeDtypeStruct((bh, L, d), qm.dtype)] * 3,
-    )(*ops)
+    qp, kp, vp, out_p, lse, seed = res
+    # same constant-cotangent Mosaic guard as the streaming dropout bwd;
+    # on the packed array, where it does not stand between the kernel's
+    # layout and the product that writes the cotangent
+    dop = jax.lax.optimization_barrier(_short_pack(dout))
+    grads = _short_call(
+        "flash_attention_short_bwd", _short_bwd_kernel,
+        (qp, kp, vp, out_p, dop), lse, seed, dout.shape[-1], causal,
+        dropout_p)
     dseed = None if seed is None else np.zeros(seed.shape,
                                                jax.dtypes.float0)
-    return (_splitheads(dq, b, h), _splitheads(dk, b, h),
-            _splitheads(dv, b, h), dseed)
+    return (*(_short_unpack(g, dout.shape) for g in grads), dseed)
 
 
 _flash_attention_core_short.defvjp(_flash_attention_core_short_fwd,
@@ -848,6 +915,17 @@ def _work(kind, q, k, v, causal):
                       (8.0 * mm, 2 * qkv + 2 * out + lse)}}
 
 
+def _bump_short(q, k, v, causal):
+    """Count one dispatch to the short kernels, and whether they took the
+    projections' layout as it is (``short_packed``) or behind the
+    transposing wrapper."""
+    from .counters import bump
+
+    bump("flash_attention", "pallas", **_work("short", q, k, v, causal))
+    if _short_block_width(q.shape[2], q.shape[3]) is not None:
+        bump("flash_attention", "short_packed")
+
+
 def _one_width(q, v):
     """The short, masked and dropout kernels take one head width."""
     return v.shape[-1] == q.shape[-1]
@@ -863,8 +941,7 @@ def _local_attention(q, k, v, is_causal):
         else None
     if choice == "short":
         out = _flash_attention_pallas_short(q, k, v, causal=is_causal)
-        bump("flash_attention", "pallas",
-             **_work("short", q, k, v, is_causal))
+        _bump_short(q, k, v, is_causal)
         return out
     if choice == "xla":
         bump("flash_attention", "xla", "autotuned: xla wins this shape")
@@ -1002,8 +1079,7 @@ def flash_attention_or_fallback(q, k, v, mask=None, dropout_p=0.0,
             out = _flash_attention_pallas_short(
                 q, k, v, seed=_rng_seed_arr(key_rng),
                 causal=is_causal, dropout_p=dropout_p)
-            bump("flash_attention", "pallas",
-                 **_work("short", q, k, v, is_causal))
+            _bump_short(q, k, v, is_causal)
             return out
         if choice == "xla":
             bump("flash_attention", "xla",

@@ -77,6 +77,34 @@ def test_selection_picks_min_and_caches(monkeypatch, interpret_pallas):
     assert autotune.short_window_choice(q2, q2, False, 0.0) == "short"
 
 
+def test_probe_hands_every_candidate_the_projections_layout(
+        monkeypatch, interpret_pallas):
+    """What the probe times: forward and backward from three distinct
+    (B, L, H*D) arrays, as a model's projections write them, under a
+    cotangent with values of its own. Every candidate gets the same
+    operands and returns the same three gradients."""
+    import paddle_tpu.utils.timing as timing
+
+    monkeypatch.setattr(bringup, "pallas_enabled", lambda: True)
+    monkeypatch.setattr(bringup, "TPU_PLATFORMS", ("cpu", "tpu"))
+    grads = []
+
+    def run_once(fn, *args, iters=0):
+        grads.append(fn(*args))
+        return float(len(grads))            # the first candidate wins
+
+    monkeypatch.setattr(timing, "timeit", run_once)
+    q = _q(l=256, b=1, h=2, d=64)
+    assert autotune.short_window_choice(q, q, False, 0.0) == "short"
+    assert len(grads) == 3                  # short, stream, xla
+    for got in grads:
+        assert [g.shape for g in got] == [(1, 256, 128)] * 3
+        assert not np.allclose(got[0], got[1], atol=1e-3)   # dq is not dk
+        for a, b in zip(got, grads[-1]):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-4, atol=2e-4)
+
+
 def test_failed_candidate_raises_and_pins_nothing(monkeypatch,
                                                   interpret_pallas):
     """Every candidate passed its shape gate, so one that fails to

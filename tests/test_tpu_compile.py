@@ -84,3 +84,48 @@ def test_fused_xent_compiles_at_hidden_2304_on_the_top_rung(one_chip):
                    ((n,), jnp.int32))
     assert (bn, bv) == (256, 256)
     assert "fused_xent_bwd" in out.as_text()
+
+
+@pytest.mark.parametrize("precision", [None, "highest"])
+@pytest.mark.parametrize("dropout_p", [0.0, 0.1])
+@pytest.mark.parametrize("shape, dtype", [
+    ((64, 512, 12, 64), BF16),      # the seq512 cell's: two heads a block
+    ((8, 512, 4, 128), BF16),       # one head a block
+    ((8, 512, 3, 64), BF16),        # odd heads: the transposing wrapper
+    ((2, 512, 2, 256), F32),        # the gate's widest head
+])
+def test_short_flash_compiles_in_the_projections_layout(
+        one_chip, shape, dtype, dropout_p, precision):
+    """The short kernels through the (B, L, H*D) layout the projections
+    write, forward and backward. Where that layout has a block width the
+    compiled module holds the two kernels and not one `copy` or
+    `transpose` of its own beside them: the reshapes cancel, and the
+    barrier on the cotangent does not stand in the layout's way. Under
+    `highest` (as `chip_smoke.py kernels` runs them) a float32 product
+    splits its operands and the backward needs more scoped VMEM than
+    Mosaic's default (PR 28)."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    b, l, h, d = shape
+    seed = [((1, 1), jnp.int32)] if dropout_p else []
+
+    def attention(q, k, v, *seed):
+        return fa._flash_attention_core_short(
+            *(x.reshape(shape) for x in (q, k, v)),
+            seed[0] if seed else None, False, dropout_p
+        ).reshape(b, l, h * d)
+
+    def fwd_bwd(q, k, v, w, *seed):
+        out, vjp = jax.vjp(lambda q, k, v: attention(q, k, v, *seed),
+                           q, k, v)
+        return (out,) + vjp(w)
+
+    with jax.default_matmul_precision(precision or "default"):
+        text = _compile(fwd_bwd, one_chip, *[((b, l, h * d), dtype)] * 4,
+                        *seed).as_text()
+    assert "flash_attention_short_fwd" in text
+    assert "flash_attention_short_bwd" in text
+    moved = [line for line in text.splitlines()
+             if (" copy(" in line or " transpose(" in line)
+             and "s32[1,1]" not in line]         # the seed, to scalar memory
+    assert bool(moved) == (fa._short_block_width(h, d) is None), moved
