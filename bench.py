@@ -1210,28 +1210,6 @@ def _shard_probe_main(n_devices=8, steps=3):
     bs_z.zero_stage = 2
     z_losses, _dt_z, zc, _ = run(bs_z, **zkw)
     z_dispatches = _pk.snapshot().get("zero.zero", 0) - z_snap0
-    # fused-optimizer dual leg (ISSUE 19): the same ZeRO-2 int8 step
-    # with the fused Pallas chunk update pinned OFF (PADDLE_FUSED_OPT=0,
-    # the bitwise XLA reference) vs ON via interpret mode (CPU has no
-    # Pallas backend; interpret-mode timing is a smoke signal, the real
-    # win needs a TPU — fused_opt_note says so)
-    def _zero_leg(envs):
-        bs = static.BuildStrategy()
-        bs.mesh_shape = {"dp": n_devices}
-        bs.comm_quant = "int8"
-        bs.zero_stage = 2
-        for k, v in envs.items():
-            os.environ[k] = v
-        try:
-            return run(bs, **zkw)
-        finally:
-            for k in envs:
-                os.environ.pop(k, None)
-
-    fx_losses, dt_fx, _, _ = _zero_leg({"PADDLE_FUSED_OPT": "0"})
-    f_snap0 = _pk.snapshot().get("fused_opt.pallas", 0)
-    ff_losses, dt_ff, _, _ = _zero_leg({"PADDLE_FUSED_OPT_INTERPRET": "1"})
-    fused_hits = _pk.snapshot().get("fused_opt.pallas", 0) - f_snap0
     # expert-parallel MoE leg (ISSUE 19): dense oracle vs the explicit
     # all_to_all exchange on an ep x dp mesh (same loss — global gating
     # makes the explicit path numerically the dense path), plus the
@@ -1322,15 +1300,6 @@ def _shard_probe_main(n_devices=8, steps=3):
         "comm_buckets": int(qc.get("comm_buckets", 0)),
         "allreduce_overlap_frac": float(
             qc.get("allreduce_overlap_frac", 0.0)),
-        "fused_opt_step_ms": round(1000.0 * dt_ff / steps, 3),
-        "fused_opt_xla_step_ms": round(1000.0 * dt_fx / steps, 3),
-        "fused_opt_dispatches": int(fused_hits),
-        "fused_opt_loss_delta": max(
-            abs(a - b) for a, b in zip(fx_losses, ff_losses)),
-        "fused_opt_note": (
-            "fused leg runs the Pallas kernel in interpret mode (CPU "
-            "host has no Pallas backend); step-time is a smoke signal "
-            "only — the HBM-bandwidth win needs a real TPU"),
         "moe_tokens_per_sec": round(T * steps / dt_me, 2),
         "moe_parity_delta": max(
             abs(a - b) for a, b in zip(moe_dense, moe_ep)),

@@ -212,7 +212,6 @@ def train_phase(cfg, shapes=TRAIN_SHAPES, results=None) -> list:
         _engaged(fails, what, counts, "flash_attention", "flash",
                  xla_ok=True)
         _engaged(fails, what, counts, "fused_xent", None, xla_ok=False)
-        _engaged(fails, what, counts, "fused_opt", "fused_opt")
     from paddle_tpu.ops.pallas import autotune
 
     log(f"  autotune stats {autotune.stats()}; verdicts {_verdicts()}")
@@ -398,7 +397,6 @@ def _kernel_checks():
     from paddle_tpu.ops.pallas import autotune  # noqa: F401 (its flags)
     from paddle_tpu.ops.pallas import flash_attention as fa
     from paddle_tpu.ops.pallas import fused_embedding as fe
-    from paddle_tpu.ops.pallas import fused_optimizer as fo
     from paddle_tpu.ops.pallas import fused_xent as fx
     from paddle_tpu.ops.pallas import paged_attention as pa
     from paddle_tpu.ops.pallas import sampling as sp
@@ -754,42 +752,6 @@ def _kernel_checks():
     bag(4096, 128, 64, 16, f32, "sum")
     bag(100000, 1024, 256, 64, f32, "mean")
     bag(8192, 256, 8, 8, f32, "sqrtn")
-
-    # -- fused optimizer updates ----------------------------------------------
-    def opt(op_type, n):
-        name = f"fused optimizer {op_type} n={n}"
-
-        def check(fails):
-            import paddle_tpu as paddle
-
-            # _dispatch is the family's one gate; its autotune leg would
-            # time (and compile) both candidates per size just to be asked
-            paddle.set_flags({"fused_opt_autotune": False})
-            try:
-                path, reason, _ = fo._dispatch(op_type, n, f32)
-            finally:
-                paddle.set_flags({"fused_opt_autotune": True})
-            if path != "pallas":
-                fails.append(f"{name}: outside its gate ({reason})")
-                return
-            g = rnd(1, (n,), f32)
-            ins = {"Param": [g * 0.5 + 0.1], "Grad": [g],
-                   "LearningRate": [jnp.asarray(1e-3, f32)],
-                   "Velocity": [g * 0.1], "Moment1": [g * 0.1],
-                   "Moment2": [g * g * 0.1],
-                   "Beta1Pow": [jnp.asarray([0.9], f32)],
-                   "Beta2Pow": [jnp.asarray([0.999], f32)]}
-            got = jax.jit(lambda i: fo._pallas_update(
-                op_type, i, {}, False))(ins)
-            want = jax.jit(lambda i: fo._XLA[op_type](i, {}))(ins)
-            for slot in want:
-                _close(f"{name} {slot}", got[slot][0], want[slot][0],
-                       1e-5, fails)
-        checks.append((name, check))
-
-    for op_type in fo.FUSED_OPS:
-        for n in (1024, 768 * 3072 + 1, 30592 * 768):
-            opt(op_type, n)
 
     # -- paged attention (f32 and int8 pools) ---------------------------------
     def paged(b, h, d, s, t, quant, tag):

@@ -40,13 +40,6 @@ define_flag("sample_autotune", True,
             "dispatch the winner (persisted in the same disk cache as "
             "the flash/paged verdicts). TPU only")
 
-define_flag("fused_opt_autotune", True,
-            "Time the fused Pallas optimizer update kernel (sgd / "
-            "momentum / adam / lamb) against the unfused XLA update "
-            "once per (op, n, dtype) flat size and dispatch the winner "
-            "(persisted in the same disk cache as the flash/paged "
-            "verdicts). TPU only")
-
 define_flag("paged_autotune", True,
             "Time the ragged paged-attention Pallas kernel against the "
             "XLA gather path once per (batch, pages, page_size, heads, "
@@ -331,45 +324,6 @@ def best_sample_impl(b, v, dtype, top_k) -> str:
                     ("pallas", "xla"), build)
 
 
-def fused_opt_cache_key(op_type, n, dtype) -> tuple:
-    """The fused-optimizer verdict key, namespaced like the paged and
-    sample keys in the ONE memo/disk cache."""
-    return ("fused_opt", str(op_type), int(n), str(dtype))
-
-
-def best_fused_opt_impl(op_type, n, dtype) -> str:
-    """'pallas' | 'xla' for this (op, flat size), timed on the device.
-    Must only be called with fused-eligible sizes on a TPU backend."""
-    import jax
-    import jax.numpy as jnp
-
-    from . import fused_optimizer as fo
-
-    def _ins(gg):
-        ins = {"Param": [gg * 0.5], "Grad": [gg],
-               "LearningRate": [jnp.asarray(1e-3, jnp.float32)]}
-        if op_type == "momentum":
-            ins["Velocity"] = [gg * 0.1]
-        elif op_type in ("adam", "lamb"):
-            ins["Moment1"] = [gg * 0.1]
-            ins["Moment2"] = [gg * gg * 0.1]
-            ins["Beta1Pow"] = [jnp.asarray([0.9], jnp.float32)]
-            ins["Beta2Pow"] = [jnp.asarray([0.999], jnp.float32)]
-        return ins
-
-    def build():
-        g = jax.random.normal(jax.random.key(7), (n,), jnp.float32)
-        return {
-            "pallas": jax.jit(lambda gg: fo._pallas_update(
-                op_type, _ins(gg), {}, False)["ParamOut"][0]),
-            "xla": jax.jit(lambda gg: fo._XLA[op_type](
-                _ins(gg), {})["ParamOut"][0]),
-        }, g
-
-    return _verdict(fused_opt_cache_key(op_type, n, dtype), "fused_opt",
-                    ("pallas", "xla"), build)
-
-
 def _tuning(flag: str) -> bool:
     """Autotuning applies: its flag is on and the live backend is a TPU."""
     from ...framework.bringup import TPU_PLATFORMS
@@ -379,15 +333,6 @@ def _tuning(flag: str) -> bool:
     import jax
 
     return jax.default_backend() in TPU_PLATFORMS
-
-
-def fused_opt_choice(op_type, n, dtype) -> str | None:
-    """The fused-optimizer dispatch entry: the tuned impl name, or None
-    when autotuning does not apply (not TPU / flag off) — None keeps
-    the static dispatch (the kernel)."""
-    if not _tuning("fused_opt_autotune"):
-        return None
-    return best_fused_opt_impl(op_type, n, dtype)
 
 
 def fused_sample_choice(logits, top_k) -> str | None:

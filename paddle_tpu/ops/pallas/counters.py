@@ -14,7 +14,7 @@ the ``pallas counters`` line), and ``chip_smoke.py`` reports deltas.
 
 **Names.** A kernel is launched through :func:`kernel_call` under its
 ROLE name (``fused_xent_fwd``, ``flash_attention_short_bwd``,
-``fused_adamw``, ...). That name is what a device trace shows
+``kda_chunk_fwd``, ...). That name is what a device trace shows
 (``kernel:<role>``) and what the benchmark's readers key on, so it holds
 the kernel's family and never a layer index.
 

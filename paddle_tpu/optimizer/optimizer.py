@@ -112,6 +112,13 @@ class Optimizer:
                 new_p.append(p)
                 new_s.append(s)
                 continue
+            # the gradient is whole before its update reads it. Without
+            # the barrier XLA merges the update into the fusion that
+            # forms a weight's gradient, and that merged fusion is slower
+            # than the product and the update apart: BERT's step lost
+            # 3.2% to it on a v5e, the Kimi cell 0.4% (PERF.md section 6,
+            # PR 29). Arithmetic and memory peak are unchanged.
+            g = jax.lax.optimization_barrier(g)
             master = s.get("__master__") if isinstance(s, dict) else None
             if master is not None:
                 # multi-precision: update the f32 master, cast down for
@@ -119,8 +126,8 @@ class Optimizer:
                 # touches f32 state
                 lr32 = jnp.asarray(lr, master.dtype)
                 sub = {k: v for k, v in s.items() if k != "__master__"}
-                m2, s2 = self._fused_or_rule(g.astype(master.dtype),
-                                             master, sub, lr32, step)
+                m2, s2 = self.rule(g.astype(master.dtype), master, sub,
+                                   lr32, step)
                 if self._l2_coeff and self.DECOUPLED_WD:
                     m2 = m2 - lr32 * self._l2_coeff * master
                 s2 = dict(s2)
@@ -128,8 +135,7 @@ class Optimizer:
                 new_p.append(m2.astype(p.dtype))
                 new_s.append(s2)
                 continue
-            p2, s2 = self._fused_or_rule(g, p, s,
-                                         jnp.asarray(lr, p.dtype), step)
+            p2, s2 = self.rule(g, p, s, jnp.asarray(lr, p.dtype), step)
             if self._l2_coeff and self.DECOUPLED_WD:
                 p2 = p2 - jnp.asarray(lr, p.dtype) * self._l2_coeff * p
             new_p.append(p2)
@@ -163,20 +169,6 @@ class Optimizer:
 
     def init_slot(self, p):
         return {}
-
-    def _fused_or_rule(self, g, p, slots, lr, t):
-        """ISSUE 19: try the fused Pallas update first — one grid pass
-        over the flat param instead of the rule's 5-8 XLA elementwise
-        ops. fused_try_rule returns None whenever the kernel does not
-        ENGAGE (CPU, non-f32, tiny param, PADDLE_FUSED_OPT=0, an
-        optimizer class without a fused form), so every non-engaging
-        path runs the reference rule bitwise-unchanged."""
-        from ..ops.pallas.fused_optimizer import fused_try_rule
-
-        fused = fused_try_rule(self, g, p, slots, lr, t)
-        if fused is not None:
-            return fused
-        return self.rule(g, p, slots, lr, t)
 
     def rule(self, g, p, slots, lr, t):
         raise NotImplementedError

@@ -311,17 +311,14 @@ def _exec_cache_put(key: str, entry: _ExecEntry) -> None:
 
 
 def _escape_env_signature() -> tuple:
-    """Kernel escape hatches read the environment at TRACE time (fused
-    optimizer / explicit MoE exchange), so two content-identical
-    programs traced under different toggles are different executables —
-    the toggles must join both cache keys or a cached leg silently
-    defangs the env pin (the bench's dual fused-vs-xla legs hit exactly
-    this)."""
+    """A kernel escape hatch reads the environment at TRACE time (the
+    explicit MoE exchange), so two content-identical programs traced
+    under different toggles are different executables — the toggle
+    must join both cache keys or a cached leg silently defangs the
+    env pin."""
     import os
 
-    return tuple((k, os.environ.get(k, "")) for k in
-                 ("PADDLE_FUSED_OPT", "PADDLE_FUSED_OPT_INTERPRET",
-                  "PADDLE_MOE_A2A"))
+    return (("PADDLE_MOE_A2A", os.environ.get("PADDLE_MOE_A2A", "")),)
 
 
 def _content_key(opt_program, feed_sig, fetch_names, persist_names,

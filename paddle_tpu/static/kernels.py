@@ -711,38 +711,79 @@ def _gate_update(ins, outs):
             for slot, vals in outs.items()}
 
 
-# sgd/momentum/adam/lamb delegate to the fused Pallas update (ISSUE
-# 19): one grid pass reads grad+param+moments once and writes them
-# once, instead of the 5-8 separate XLA elementwise ops each rule used
-# to lower to. Every ineligible dispatch (non-f32, tiny param, pallas
-# unavailable, PADDLE_FUSED_OPT=0) runs fused_optimizer's XLA
-# reference, whose math is VERBATIM the pre-fusion bodies — bitwise.
 @kernel("sgd")
 def _sgd(ins, attrs, ctx):
-    from ..ops.pallas.fused_optimizer import fused_op_update
-
-    return fused_op_update("sgd", ins, attrs)
+    p, g, lr = ins["Param"][0], ins["Grad"][0], ins["LearningRate"][0]
+    return _gate_update(ins, {"ParamOut": [p - lr * g]})
 
 
 @kernel("momentum")
 def _momentum(ins, attrs, ctx):
-    from ..ops.pallas.fused_optimizer import fused_op_update
-
-    return fused_op_update("momentum", ins, attrs)
+    p, g, v = ins["Param"][0], ins["Grad"][0], ins["Velocity"][0]
+    lr = ins["LearningRate"][0]
+    mu = attrs.get("mu", 0.9)
+    use_nesterov = attrs.get("use_nesterov", False)
+    v_new = mu * v + g
+    if use_nesterov:
+        p_new = p - (g + mu * v_new) * lr
+    else:
+        p_new = p - lr * v_new
+    return _gate_update(ins, {"ParamOut": [p_new],
+                              "VelocityOut": [v_new]})
 
 
 @kernel("adam")
 def _adam(ins, attrs, ctx):
-    from ..ops.pallas.fused_optimizer import fused_op_update
+    p, g = ins["Param"][0], ins["Grad"][0]
+    m, v = ins["Moment1"][0], ins["Moment2"][0]
+    b1p, b2p = ins["Beta1Pow"][0], ins["Beta2Pow"][0]
+    lr = ins["LearningRate"][0]
+    b1 = attrs.get("beta1", 0.9)
+    b2 = attrs.get("beta2", 0.999)
+    eps = attrs.get("epsilon", 1e-8)
+    m_new = b1 * m + (1 - b1) * g
+    v_new = b2 * v + (1 - b2) * g * g
+    lr_t = lr * jnp.sqrt(1 - b2p * b2) / (1 - b1p * b1)
+    p_new = p - lr_t * m_new / (jnp.sqrt(v_new) + eps)
+    return _gate_update(ins, {
+        "ParamOut": [p_new], "Moment1Out": [m_new],
+        "Moment2Out": [v_new], "Beta1PowOut": [b1p * b1],
+        "Beta2PowOut": [b2p * b2]})
 
-    return fused_op_update("adam", ins, attrs)
+
+def _lamb_moments(p, g, m, v, b1p, b2p, attrs):
+    """Lamb's element-wise phase: the advanced moments and the
+    trust-ratio numerator ``r``. What follows needs the norms of ``p``
+    and ``r`` over a whole parameter: the op below takes them of the
+    arrays in hand, a ZeRO bucket (``stepplan.chunk_update``) of
+    its chunk's segments, summed over the devices."""
+    b1 = attrs.get("beta1", 0.9)
+    b2 = attrs.get("beta2", 0.999)
+    eps = attrs.get("epsilon", 1e-6)
+    wd = attrs.get("weight_decay", 0.01)
+    m_new = b1 * m + (1 - b1) * g
+    v_new = b2 * v + (1 - b2) * g * g
+    b1p_new, b2p_new = b1p * b1, b2p * b2
+    m_hat = m_new / (1 - b1p_new)
+    v_hat = v_new / (1 - b2p_new)
+    r = m_hat / (jnp.sqrt(v_hat) + eps) + wd * p
+    return m_new, v_new, r, b1p_new, b2p_new
 
 
 @kernel("lamb")
 def _lamb(ins, attrs, ctx):
-    from ..ops.pallas.fused_optimizer import fused_op_update
-
-    return fused_op_update("lamb", ins, attrs)
+    p = ins["Param"][0]
+    m_new, v_new, r, b1p_new, b2p_new = _lamb_moments(
+        p, ins["Grad"][0], ins["Moment1"][0], ins["Moment2"][0],
+        ins["Beta1Pow"][0], ins["Beta2Pow"][0], attrs)
+    lr = ins["LearningRate"][0]
+    p_norm = jnp.linalg.norm(p)
+    r_norm = jnp.linalg.norm(r)
+    trust = jnp.where((p_norm > 0) & (r_norm > 0), p_norm / r_norm, 1.0)
+    return _gate_update(ins, {
+        "ParamOut": [p - lr * trust * r], "Moment1Out": [m_new],
+        "Moment2Out": [v_new], "Beta1PowOut": [b1p_new],
+        "Beta2PowOut": [b2p_new]})
 
 
 @kernel("check_finite_and_unscale")

@@ -46,7 +46,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..framework import dtype as dtype_mod
-from .kernels import KERNELS, ExecContext
+from .kernels import (KERNELS, ExecContext, _gate_update,
+                      _lamb_moments)
 
 __all__ = [
     "StepPlan", "build_plan", "build_step_fn", "plan_kind", "PLAN_KINDS",
@@ -996,23 +997,88 @@ def _comm_step_fn(plan, block, feed_keys, fetch_names, persist_names,
 
 # optimizer ops that run on a (chunk,) shard. sgd/momentum/adam are
 # ELEMENTWISE, so they commute with the concat/pad/chunk reshuffle
-# unchanged. lamb (ISSUE 19) rides the fused kernel's TWO-PHASE trust
-# plan: per-chunk partial per-param sq-norms -> one tiny psum over the
-# dp axis -> the elementwise finish consumes the global norms — so its
-# global-param-norm trust ratio no longer blocks sharding (it is
+# unchanged and a bucket's update IS the op's kernel. lamb runs the
+# TWO-PHASE trust plan of :func:`chunk_update`: per-chunk partial
+# per-param sq-norms -> one tiny psum over the dp axis -> the
+# elementwise finish consumes the global norms — so its
+# global-param-norm trust ratio does not block sharding (it is
 # tolerance-parity vs the unsharded op: the norm sum reassociates
 # across devices).
 ZERO_OPT_OPS = ("sgd", "momentum", "adam", "lamb")
 
 # per-op state slots that shard into (g, chunk) rows, and the scalar
-# accumulators that stay replicated per-var (the fused kernel call
-# updates them through its own gated Beta*PowOut rule)
+# accumulators that stay replicated per-var (the bucket's update
+# returns them through the op's own gated Beta*PowOut rule)
 _ZERO_ROLES = {"sgd": (), "momentum": ("Velocity",),
                "adam": ("Moment1", "Moment2"),
                "lamb": ("Moment1", "Moment2")}
 _ZERO_SCALARS = {"sgd": (), "momentum": (),
                  "adam": ("Beta1Pow", "Beta2Pow"),
                  "lamb": ("Beta1Pow", "Beta2Pow")}
+
+
+def _chunk_segments(param_elems, position, c):
+    """Per-element segment ids of a (c,) chunk inside the bucket's
+    padded concat buffer: element j of param i maps to segment i, the
+    padding tail to the sentinel segment len(param_elems)."""
+    ends = np.cumsum(np.asarray(param_elems, np.int64))
+    pos = position + jnp.arange(c, dtype=jnp.int32)
+    return jnp.searchsorted(jnp.asarray(ends, jnp.int32), pos,
+                            side="right")
+
+
+def chunk_update(op_type, ins, attrs, ctx, *, axis=None, param_elems=None,
+                 position=None):
+    """One ZeRO bucket's per-device (chunk,) update.
+
+    sgd/momentum/adam are elementwise-closed on the chunk — they ARE
+    the op's own kernel. lamb needs the per-param trust ratio, a
+    GLOBAL norm over buffers this device only holds 1/g of — the
+    two-phase plan:
+
+    1. segment the chunk by ``param_elems`` (static per-param element
+       counts; ``position`` is this device's traced flat offset) and
+       reduce per-segment partial sq-norms of the param chunk and the
+       lamb ``r`` numerator (``kernels._lamb_moments``, the op's own
+       elementwise phase)
+    2. one tiny ``lax.psum`` of the two (n_params+1,) partials over
+       ``axis`` (None: the chunk is the whole bucket) -> global
+       per-param norms -> per-element trust gathered back through the
+       segment ids -> elementwise finish.
+
+    Parity vs the unsharded lamb op is TOLERANCE, not bitwise: the
+    sq-norm sum reassociates across devices (documented; the ZeRO
+    parity gate is the same amp-style loss tolerance the int8 ring
+    uses)."""
+    if op_type != "lamb":
+        return KERNELS[op_type](ins, attrs, ctx)
+
+    p = ins["Param"][0].reshape(-1)
+    m = ins["Moment1"][0].reshape(-1)
+    v = ins["Moment2"][0].reshape(-1)
+    lr = ins["LearningRate"][0].reshape(())
+    m_new, v_new, r, b1p_new, b2p_new = _lamb_moments(
+        p, ins["Grad"][0].reshape(-1), m, v,
+        ins["Beta1Pow"][0].reshape(()), ins["Beta2Pow"][0].reshape(()),
+        attrs)
+
+    n_seg = len(param_elems) + 1
+    seg = _chunk_segments(param_elems, position, p.shape[0])
+    sq_p = jax.ops.segment_sum(p * p, seg, num_segments=n_seg)
+    sq_r = jax.ops.segment_sum(r * r, seg, num_segments=n_seg)
+    if axis is not None:
+        sq_p = jax.lax.psum(sq_p, axis)
+        sq_r = jax.lax.psum(sq_r, axis)
+    p_norm = jnp.sqrt(sq_p)
+    r_norm = jnp.sqrt(sq_r)
+    trust = jnp.where((p_norm > 0) & (r_norm > 0),
+                      p_norm / jnp.where(r_norm > 0, r_norm, 1.0), 1.0)
+    p_new = p - lr * trust[seg] * r
+    return _gate_update(
+        {**ins, "Param": [p], "Moment1": [m], "Moment2": [v]},
+        {"ParamOut": [p_new], "Moment1Out": [m_new],
+         "Moment2Out": [v_new], "Beta1PowOut": [b1p_new],
+         "Beta2PowOut": [b2p_new]})
 
 
 def _zero_row_sources(stage, bucket):
@@ -1036,13 +1102,13 @@ def zero_eligibility(program, block, zero, comm, comm_plan, shard_cfg,
 
     ZeRO rides the ENGAGED quantized comm plan: the bucketed all-reduce
     decomposes into reduce-scatter + all-gather and the optimizer
-    region collapses to one fused elementwise kernel call per bucket on
+    region collapses to one update per bucket on
     this device's (chunk,) shard. Eligible means: the comm plan is
     engaged, every bucket's params are updated by allowlisted
     chunk-shardable optimizer ops (:data:`ZERO_OPT_OPS`; lamb via the
-    fused kernel's two-phase trust-ratio plan) with ONE uniform
-    type/attrs/lr/gate per bucket (the fused call synthesizes a single
-    op), params and grads are f32 (a chunked f32 update of a bf16
+    two-phase trust-ratio plan) with ONE uniform
+    type/attrs/lr/gate per bucket (the bucket's update synthesizes a
+    single op), params and grads are f32 (a chunked f32 update of a bf16
     param would drift from the reference kernel's native-dtype math),
     no surviving post-region op reads the merged gradient / sharded
     moments / stage-3 params (never materialized), and no fetch asks
@@ -1129,7 +1195,7 @@ def zero_eligibility(program, block, zero, comm, comm_plan, shard_cfg,
                 sig = s
             elif s != sig:
                 return verdict(None, f"mixed optimizer configs inside "
-                                     f"comm bucket {bi} — the fused "
+                                     f"comm bucket {bi} — the "
                                      "chunk update needs one uniform "
                                      "type/attrs/lr per bucket")
             params.append(pn)
@@ -1309,7 +1375,8 @@ def _zero_step_fn(plan, block, feed_keys, fetch_names, persist_names,
       codec='f32' the step is bitwise the replicated comm step for
       the elementwise rules — lamb is tolerance-parity: its segment
       norms psum across devices, which reassociates the sum)
-    - ONE fused elementwise kernel call per bucket updates the param
+    - ONE update per bucket (the op's own kernel on the chunk;
+      :func:`chunk_update` for lamb) advances the param
       chunk (stage 2: sliced from the replicated param concat at the
       ring-owned position; stage 3: this device's param row) against
       the moment rows — eligibility guaranteed uniform op
@@ -1320,14 +1387,13 @@ def _zero_step_fn(plan, block, feed_keys, fetch_names, persist_names,
       skips that gather entirely and the NEXT step's pre-forward
       gather serves the params
     - scalar accumulators (adam beta-pows) stay replicated per var,
-      updated through the kernel's own gated Beta*PowOut rule
+      updated through the op's own gated Beta*PowOut rule
     - surviving post-region ops (lr schedules, counters) run in
       original op order around the replaced optimizer ops, each
-      bucket's fused update firing at its first replaced index
+      bucket's update firing at its first replaced index
     """
     from jax.sharding import PartitionSpec as P
 
-    from ..ops.pallas.fused_optimizer import fused_chunk_update
     from ..parallel.collectives import (
         all_gather, quant_decode, quant_encode, reduce_scatter,
         shard_map_nocheck)
@@ -1533,14 +1599,11 @@ def _zero_step_fn(plan, block, feed_keys, fetch_names, persist_names,
                 ins[srole] = [env[names[0]]]
             if b["found"] is not None:
                 ins["FoundInfinite"] = [env[b["found"]]]
-            # ONE fused kernel call per bucket (ISSUE 19): the Pallas
-            # grid pass reads the chunk's grad/param/moments once; the
-            # ineligible path is the verbatim static-op math. lamb
-            # threads the per-param element layout + this device's
-            # ring position so its two-phase trust plan can psum the
-            # segment norms over the dp axis.
-            outs = fused_chunk_update(
-                b["op_type"], ins, b["attrs"], axis=axis,
+            # lamb threads the per-param element layout + this
+            # device's ring position so its two-phase trust plan can
+            # psum the segment norms over the dp axis
+            outs = chunk_update(
+                b["op_type"], ins, b["attrs"], ctx, axis=axis,
                 param_elems=tuple(
                     int(np.prod(shp or (1,)))
                     for shp in b["param_shapes"]),

@@ -340,21 +340,13 @@ def test_bench_smoke_row_contract(tmp_path):
     assert last["zero_state_bytes_saved_pct"] >= 40.0, last
     assert last["zero_loss_delta"] <= 1e-2, last
     assert last["zero_dispatches"] >= 1, last
-    # kernel MFU push contract (ISSUE 19): the fused Pallas optimizer
-    # engages on the ZeRO int8 leg (interpret-forced on CPU) and stays
-    # inside the quant gate vs its PADDLE_FUSED_OPT=0 XLA twin; the
-    # MoE probe's explicit all_to_all path is parity-gated vs the dense
-    # oracle with its wire bytes charged in the cost model
-    for key in ("fused_opt_step_ms", "fused_opt_xla_step_ms",
-                "fused_opt_dispatches", "fused_opt_loss_delta",
-                "fused_opt_note", "moe_tokens_per_sec",
+    # the MoE probe's explicit all_to_all path is parity-gated vs the
+    # dense oracle with its wire bytes charged in the cost model
+    for key in ("moe_tokens_per_sec",
                 "moe_parity_delta", "moe_int8_loss_delta",
                 "moe_capacity_drop_pct", "moe_a2a_dispatches",
                 "moe_a2a_bytes", "moe_a2a_bytes_saved_pct"):
         assert key in last, f"bench row missing {key!r}"
-    assert last["fused_opt_step_ms"] > 0, last
-    assert last["fused_opt_dispatches"] >= 1, last
-    assert last["fused_opt_loss_delta"] <= 1e-2, last
     assert last["moe_tokens_per_sec"] > 0, last
     assert last["moe_parity_delta"] <= 1e-5, last
     assert last["moe_int8_loss_delta"] <= 1e-2, last
@@ -464,7 +456,7 @@ def test_compile_cache_dir_resolution(tmp_path):
 
 
 @pytest.mark.parametrize("family", ["flash", "fused_xent", "fused_embedding",
-                                    "fused_opt"])
+                                    "kda_chunk"])
 def test_chosen_pallas_kernel_failure_propagates(monkeypatch, family):
     """A kernel that passed its gate and then fails raises; it is not
     counted as ``<family>.xla`` and served from the XLA reference.
@@ -474,8 +466,7 @@ def test_chosen_pallas_kernel_failure_propagates(monkeypatch, family):
 
     import paddle_tpu.framework.bringup as bringup
     from paddle_tpu.ops.pallas import (counters, flash_attention,
-                                       fused_embedding, fused_optimizer,
-                                       fused_xent)
+                                       fused_embedding, fused_xent, kda)
 
     def boom(*a, **k):
         raise RuntimeError("mosaic said no")
@@ -496,11 +487,9 @@ def test_chosen_pallas_kernel_failure_propagates(monkeypatch, family):
         call = lambda: fused_embedding.fused_embedding_seq_pool(
             jnp.zeros((64, 128)), jnp.zeros((8, 8), jnp.int32))
     else:
-        monkeypatch.setattr(fused_optimizer, "_pallas_update", boom)
-        p = jnp.zeros((2048,), jnp.float32)
-        call = lambda: fused_optimizer.fused_op_update(
-            "sgd", {"Param": [p], "Grad": [p],
-                    "LearningRate": [jnp.float32(0.1)]}, {})
+        monkeypatch.setattr(kda, "_pallas_fwd", boom)
+        x = jnp.zeros((1, 64, 1, 128), jnp.float32)
+        call = lambda: kda.chunk_kda(x, x, x, x, jnp.zeros((1, 64, 1)))
     with pytest.raises(RuntimeError, match="mosaic said no"):
         call()
     assert not [k for k in counters.snapshot() if k.endswith(".xla")]

@@ -43,7 +43,6 @@ def interp(monkeypatch):
     monkeypatch.setattr(pl, "pallas_call",
                         functools.partial(pl.pallas_call, interpret=True))
     monkeypatch.setattr(bringup, "pallas_enabled", lambda: True)
-    monkeypatch.setenv("PADDLE_FUSED_OPT", "0")
     counters.reset()
     yield
     counters.reset()

@@ -26,9 +26,6 @@ def interp(monkeypatch):
     monkeypatch.setattr(pl, "pallas_call",
                         functools.partial(pl.pallas_call, interpret=True))
     monkeypatch.setattr(bringup, "pallas_enabled", lambda: True)
-    # the open backend gate would also engage the fused optimizer kernels
-    # in the TrainStep tests, and those pass interpret=False explicitly
-    monkeypatch.setenv("PADDLE_FUSED_OPT", "0")
     counters.reset()
     yield
     counters.reset()

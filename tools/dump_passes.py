@@ -334,72 +334,6 @@ def _moe_table(optimized, ep):
     return "\n".join(lines)
 
 
-def _fused_opt_table(optimized, strategy, zero_stage):
-    """Per-update-op (and per-ZeRO-bucket) kernel-vs-xla dispatch table
-    — the same ``_dispatch`` gate the compiled step funnels through, so
-    the table shows exactly which params ride the fused Pallas kernel
-    on this backend/env and the refusal reason for the rest."""
-    from paddle_tpu.ops.pallas.fused_optimizer import _dispatch
-
-    blk = optimized.global_block
-    update_ops = ("sgd", "momentum", "adam", "adamw", "lamb",
-                  "rmsprop", "adagrad")
-    rows = [(i, op) for i, op in enumerate(blk.ops)
-            if op.type in update_ops]
-    if not rows:
-        return "(no optimizer update ops in the optimized block)"
-    lines = [f"{'#':>3} {'op':<10}{'param':<22}{'elems':>9} "
-             f"{'dtype':<9}{'path':<8}reason"]
-    import numpy as np
-
-    for i, op in rows:
-        pname = (op.inputs.get("Param") or ["?"])[0]
-        v = blk.vars.get(pname)
-        shape = tuple(getattr(v, "shape", ()) or ())
-        elems = int(np.prod([abs(s or 1) for s in shape])) if shape else 0
-        dtype = str(getattr(v, "dtype", "float32"))
-        path, reason, interp = _dispatch(op.type, elems, dtype)
-        if path == "pallas" and interp:
-            path = "pallas*"
-        lines.append(f"{i:>3} {op.type:<10}{pname[:21]:<22}{elems:>9} "
-                     f"{dtype:<9}{path:<8}{reason}")
-    lines.append("(pallas* = interpret-forced via "
-                 "PADDLE_FUSED_OPT_INTERPRET)")
-    if zero_stage:
-        from paddle_tpu.static import passes as passes_mod
-        from paddle_tpu.static.stepplan import zero_eligibility
-
-        comm = passes_mod.resolve_comm(strategy)
-        shard_cfg = passes_mod.resolve_sharding(strategy)
-        axis = passes_mod.comm_data_axis(shard_cfg)
-        comm_plan = None
-        if comm is not None and axis is not None:
-            cplan = passes_mod.comm_bucket_plan(blk, comm, axis[1])
-            if cplan:
-                comm_plan = (axis[0], axis[1], cplan)
-        _, plan = zero_eligibility(
-            optimized, blk, zero_stage, comm, comm_plan, shard_cfg,
-            passes_mod.resolve_gradient_merge(strategy),
-            passes_mod.resolve_pipeline(strategy), (),
-            bump=lambda *a, **k: None)
-        if plan is None:
-            lines.append("zero refused: per-bucket table unavailable "
-                         "(see --zero output)")
-        else:
-            lines.append(f"zero buckets (g={plan['group']}): the fused "
-                         "kernel runs on the PER-DEVICE chunk")
-            lines.append(f"{'bucket':>6}  {'opt':<10}{'chunk':>9} "
-                         f"{'path':<8}reason")
-            for j, b in enumerate(plan["buckets"]):
-                path, reason, interp = _dispatch(
-                    b["op_type"], int(b["chunk"]), "float32")
-                if path == "pallas" and interp:
-                    path = "pallas*"
-                lines.append(f"{j:>6}  {b['op_type']:<10}"
-                             f"{int(b['chunk']):>9} {path:<8}{reason}")
-    return "\n".join(lines)
-
-
 def main():
     ap = argparse.ArgumentParser(
         description="print per-pass op-count/timing table for a program")
@@ -470,10 +404,6 @@ def main():
                          "default 4; demo swaps in an MoE program) and "
                          "print the per-expert capacity/route table + "
                          "the explicit all_to_all wire bytes")
-    ap.add_argument("--fused-opt", action="store_true",
-                    help="print the per-update-op (and, with --zero, "
-                         "per-bucket) fused-kernel-vs-xla dispatch "
-                         "table with refusal reasons")
     ap.add_argument("--dot", default=None,
                     help="write the optimized block as graphviz dot")
     args = ap.parse_args()
@@ -570,9 +500,6 @@ def main():
     if args.moe:
         print()
         print(_moe_table(optimized, args.moe))
-    if args.fused_opt:
-        print()
-        print(_fused_opt_table(optimized, strategy, args.zero))
     if args.dot:
         static.save_dot(optimized, args.dot)
         print(f"optimized block dot -> {args.dot}")

@@ -180,10 +180,6 @@ def render_report(steps: List[dict], costs: List[dict],
             f"moe_a2a_bytes {_fmt_count(moe_b)} "
             f"({100.0 * moe_b / comm_b:.1f}% of comm_bytes) — the "
             f"explicit expert-parallel dispatch/combine exchange")
-        lines.append(
-            "fused optimizer: dispatch counters ride /metrics "
-            "(fused_opt.pallas / fused_opt.xla) and "
-            "`tools/dump_passes.py --fused-opt`")
     for field, title in (("top_flops", "top ops by model flops"),
                          ("top_bytes", "top ops by hbm bytes")):
         rows = cost.get(field) or []
