@@ -636,6 +636,55 @@ def _kernel_checks():
     xent(8192, 2304, 20480, f32, "causal-LM head, hidden 2304, top rung",
          unlabelled=lambda i: i == 8191)
 
+    def xent_cells_call(fails, n=32768, hd=768, v=30592, every=512, held=80):
+        """The BERT cells' own call, which runs at the step's precision:
+        float32 h and table at the DEFAULT matmul precision, where the
+        kernels round both to bfloat16 once before the call as the MXU
+        would on every grid step, through the ladder to the 8,192-row
+        rung. The logits path sees the operands as the product does and
+        only the labelled rows: the others add nothing to the loss or to
+        any gradient, and the logits of all 32,768 would be 4 GB."""
+        name = f"fused xent n={n} hd={hd} v={v}"
+        h, w = rnd(1, (n, hd)), rnd(2, (v, hd), scale=0.02)
+        b = rnd(3, (v,), scale=0.01)
+        lab = jax.random.randint(jax.random.key(4), (n,), 0, v)
+        labelled = np.arange(n) % every < held
+        lab = jnp.where(labelled, lab, -100)
+        rows = np.flatnonzero(labelled)
+
+        def rounded(x):
+            return x.astype(bf16).astype(f32)
+
+        def ref(hl, w, b):
+            logits = rounded(hl) @ rounded(w).T + b
+            lse = jax.scipy.special.logsumexp(logits, axis=-1)
+            ll = jnp.take_along_axis(logits, lab[rows][:, None], 1)[:, 0]
+            return jnp.mean(lse - ll), lse
+
+        (lr, lse_r), (dhl, dw_r, db_r) = jax.jit(jax.value_and_grad(
+            ref, argnums=(0, 1, 2), has_aux=True))(h[rows], w, b)
+        dh_r = jnp.zeros_like(h).at[rows].set(dhl)
+        with jax.default_matmul_precision("default"):
+            ladder = fx._ladder(n, fx._blocks(h, w)[0])
+            if min(k for k in ladder if k >= rows.size) != 8192:
+                fails.append(f"{name}: {rows.size} labelled rows do not "
+                             f"land on the 8,192-row rung of {ladder}")
+            lg, gg = jax.jit(jax.value_and_grad(
+                lambda h, w, b: fx._fused_xent_core(h, w, b, lab, -100),
+                argnums=(0, 1, 2)))(h, w, b)
+            lse, _ = jax.jit(lambda hl, w, b: fx._fwd_call(
+                hl, w, b, lab[rows], *fx._blocks(hl, w)))(h[rows], w, b)
+        _close(f"{name} loss", lg, lr, tol_of(f32), fails)
+        # the probabilities are rounded to bfloat16 before dh's and dW's
+        # products; db sums them in float32
+        for g, r, nm, dt in zip(gg, (dh_r, dw_r, db_r), "hWb",
+                                (bf16, bf16, f32)):
+            _close(f"{name} d{nm}", g, r, tol_of(dt), fails)
+        # summation order only: on a v5e 7e-7 of the largest lse (PR 30)
+        _close(f"{name} lse", lse, lse_r, 4e-6, fails)
+    checks.append(("fused xent, the BERT cells' call: float32 at the default "
+                   "precision, 80 of 512 labelled", xent_cells_call))
+
     # -- KDA chunk kernels against the same chunk formulas under XLA ---------
     def kda_chunks(fails, shape=(1, 8192, 32, 128)):
         from paddle_tpu.ops.pallas import kda

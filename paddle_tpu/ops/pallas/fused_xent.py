@@ -14,6 +14,32 @@ forward and recomputing the logit blocks in the backward for dh and
 dW/db (the flash trick: p = exp(s - lse) needs only the saved lse).
 Logits never land in HBM in either direction.
 
+A grid step costs what its (block_n, block_v) tile costs: the logits
+product, element-wise float32 work on the tile, the second product in
+the backward, and nothing that crosses lanes or turns a row into a
+column (a step of the first forward spent 5.5 of its 6.5 us on three
+cross-lane reductions and their relayouts, PERF.md section 6, PR 30).
+What is where:
+
+* resident blocks: the row block of h and the vocabulary block of W,
+  both as the MXU takes them (``_mxu_dtype``: bfloat16 at the default
+  matmul precision, rounded once in XLA before the call), the bias and
+  the per-row vectors as lane-major (1, block) rows, and the float32
+  accumulator the inner axis revisits (dh, or dW);
+* VMEM scratch, filled at the first inner step of an outer block: the
+  forward's running maximum, sum of exponentials and label logit, each
+  (block_n, 128) — one value per row AND lane column, updated
+  element-wise from the tile's 128-lane column groups and reduced across
+  the 128 lanes once, at the last vocabulary step; and every per-row
+  vector a kernel needs along sublanes, as a lane-replicated
+  (block_n, 128) column (labels in all of forward and dh; lse and the
+  row cotangent in dh);
+* dW/db run on the TRANSPOSED tile, W · h^T: there the per-row vectors
+  are lane-major rows as they arrive (the row block changes every step,
+  so a column could not be kept), the bias is the column in scratch, the
+  probabilities meet h without a transpose, and db sums lane-wise into a
+  (block_v, 128) scratch that is reduced at the last row step.
+
 Rows whose label is ignored add nothing to the loss or to any gradient,
 so the single-device path leaves them out: it counts the labelled rows
 on the device, orders the rows labelled-first and runs the kernels on
@@ -37,7 +63,7 @@ import jax.numpy as jnp
 
 from ...framework.flags import define_flag
 from .counters import kernel_call, nbytes
-from .flash_attention import _dot, _sds
+from .flash_attention import _sds
 
 define_flag("fused_vocab_xent", True,
             "Route large-vocab linear+cross-entropy heads (BERT MLM) "
@@ -46,17 +72,18 @@ define_flag("fused_vocab_xent", True,
 
 _F32 = jnp.float32
 _NEG = -1e30
-
-
+#: lanes of a vreg: the width of the lane-wise statistics and of a
+#: per-row vector replicated across lanes
+_LANES = 128
 
 _BN_CANDIDATES = (1024, 512, 256)
 _BV_CANDIDATES = (512, 384, 256, 128)
 #: pad modulus = the smallest row block we can always fall back to
 _BN_MIN = _BN_CANDIDATES[-1]
-#: per-kernel budget (bytes) for the block-resident f32 tensors as
-#: _fits counts them
+#: per-kernel budget (bytes) for what a grid step keeps in VMEM as _fits
+#: counts it: one copy of each block, the scratch, two float32 tiles
 _VMEM_BUDGET = 10 * 1024 * 1024
-#: scoped-VMEM limit handed to Mosaic. _fits counts one f32 copy of each
+#: scoped-VMEM limit handed to Mosaic. _fits counts one copy of each
 #: block; Mosaic also double-buffers every block and keeps more
 #: temporaries live, and against the 16 MiB default the two largest
 #: admitted working sets did not compile (measured on a v5e, PR 21: dW at
@@ -72,83 +99,153 @@ def _compiler_params():
     return pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
 
 
-def _fits(bn, bv, hd):
-    """Both backward kernels' block-resident f32 footprints must fit:
-    dh holds h + f32 dh accumulator + w tile + s/p pair; dW holds
-    h + w + f32 dW accumulator + s/p pair. Overflow fails Mosaic at
-    COMPILE time, so no over-budget pair may ever be picked."""
-    dh_kernel = 4 * (2 * bn * hd + bv * hd + 2 * bn * bv)
-    dw_kernel = 4 * (bn * hd + 2 * bv * hd + 2 * bn * bv)
+def _mxu_dtype(dtype):
+    """The type the kernels hand the MXU an operand of ``dtype`` in. At
+    the default matmul precision a Mosaic product of float32 operands is
+    ONE bfloat16 pass with float32 accumulation: bit for bit the product
+    of the operands cast to bfloat16 first, and no slower a grid step
+    (measured on a v5e, PR 30: PERF.md section 6). So the cast is made
+    once, in XLA, before the call, for what it halves: the kernels'
+    streams from HBM (every row block re-reads the whole table; every
+    vocabulary block of dW re-reads the rung of h, which bound that
+    kernel). Under any higher precision the operands stay as they are
+    and the product splits them as before."""
+    one_pass = jax.config.jax_default_matmul_precision in (
+        None, "default", "bfloat16")
+    return jnp.dtype(jnp.bfloat16 if one_pass and dtype == _F32 else dtype)
+
+
+def _fits(bn, bv, hd, itemsize):
+    """What one grid step of the widest kernel keeps in VMEM, one copy of
+    each block: the h and W blocks at the operands' ``itemsize``, the
+    float32 accumulator (dh: a row block; dW: a vocabulary block), the
+    lane-replicated (·, 128) float32 scratch (dh: labels, lse and the
+    cotangent as columns; dW: the bias column and db's lane-wise sums;
+    the forward's four are under dh's accumulator wherever hd >= 128),
+    and the float32 logits tile with its probabilities. Overflow fails
+    Mosaic at COMPILE time, so no over-budget pair may ever be picked."""
+    blocks = (bn + bv) * hd * itemsize + 2 * 4 * bn * bv
+    dh_kernel = blocks + 4 * bn * hd + 3 * 4 * bn * _LANES
+    dw_kernel = blocks + 4 * bv * hd + 2 * 4 * bv * _LANES
     return max(dh_kernel, dw_kernel) <= _VMEM_BUDGET
 
 
-def _pick_blocks(n, hd, v):
+def _pick_blocks(n, hd, v, itemsize=4):
     """Joint (block_n, block_v) choice, LARGEST bn first: every grid
-    row-block streams the ENTIRE weight table once (47 MB for BERT),
-    so bn — not bv — sets the dominant HBM traffic; at the benchmark's
-    rung (n=8192, hd=768) 1024-row blocks read W 8x (~0.38 GB) vs 32x
-    (~1.5 GB) at 256. A greedy-large bv that forced a smaller bn under
-    the VMEM cap would double exactly that traffic, so bv concedes
-    first. Returns None when nothing divides + fits (dispatch falls
-    back to XLA via _eligible). Vocab lane modulus 128: BERT's 30592
-    = 128 * 239 only admits 128-wide vocab blocks anyway."""
+    row-block streams the ENTIRE weight table once (47 MB of bfloat16
+    for BERT), so bn — not bv — sets the dominant HBM traffic; at the
+    benchmark's rung (n=8192, hd=768) 1024-row blocks read W 8x vs 32x
+    at 256. A greedy-large bv that forced a smaller bn under the VMEM
+    cap would double exactly that traffic, so bv concedes first.
+    ``itemsize`` is the MXU operands' (_mxu_dtype); the default, 4, is
+    the widest and what eligibility is decided on. Returns None when
+    nothing divides + fits (dispatch falls back to XLA via _eligible).
+    Vocab lane modulus 128: BERT's 30592 = 128 * 239 only admits
+    128-wide vocab blocks anyway."""
     for bn in _BN_CANDIDATES:
         if n % bn != 0:
             continue
         for bv in _BV_CANDIDATES:
-            if v % bv == 0 and _fits(bn, bv, hd):
+            if v % bv == 0 and _fits(bn, bv, hd, itemsize):
                 return bn, bv
     return None
 
 
 # ---------------------------------------------------------------------------
-# forward: grid (rows/bn, vocab/bv); m/l/ll accumulators live in output
-# refs indexed by the row block only (inner vocab steps revisit them)
+# what a grid step is made of: one product and element-wise work on its
+# (block_n, block_v) float32 tile. Nothing in a step crosses lanes or
+# turns a row into a column.
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(h_ref, w_ref, b_ref, lab_ref, lse_ref, ll_ref, m_ref,
-                l_ref, *, num_v, block_v):
+def _dot(a, b, ca, cb):
+    """a · b over a's dimension ``ca`` and b's ``cb``, accumulated in
+    float32 (fused_xent's own: flash_attention._dot is the flash
+    kernels'). A product of bfloat16 operands is exact in one pass, and
+    Mosaic refuses to be asked for more of one; float32 operands take
+    the ambient precision."""
+    one_pass = a.dtype == jnp.bfloat16
+    return jax.lax.dot_general(
+        a, b, (((ca,), (cb,)), ((), ())), preferred_element_type=_F32,
+        precision=jax.lax.Precision.DEFAULT if one_pass else None)
+
+
+def _lane_groups(x):
+    """The static, lane-aligned 128-wide column groups of a tile."""
+    return [x[:, c:c + _LANES] for c in range(0, x.shape[1], _LANES)]
+
+
+def _wide(x, width):
+    """A lane-replicated (rows, 128) vector across ``width`` lanes."""
+    return jnp.concatenate([x] * (width // _LANES), axis=1)
+
+
+def _column(row_ref):
+    """A (1, rows) lane-major block as a lane-replicated (rows, 128)
+    column: the one relayout of a per-row vector, made when its block
+    comes in and kept in scratch for the steps that revisit it."""
+    return jnp.broadcast_to(row_ref[0, :][:, None],
+                            (row_ref.shape[1], _LANES))
+
+
+# ---------------------------------------------------------------------------
+# forward: grid (rows/bn, vocab/bv). The running maximum, sum of
+# exponentials and label logit are kept per row AND lane column in VMEM
+# scratch; the 128 lanes of a row are reduced once, at the row block's
+# last vocabulary step
+# ---------------------------------------------------------------------------
+
+
+def _fwd_kernel(h_ref, w_ref, b_ref, lab_ref, lse_ref, ll_ref, m_scr, l_scr,
+                ll_scr, lab_scr, *, num_v, block_v):
     from jax.experimental import pallas as pl
 
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        ll_ref[...] = jnp.zeros_like(ll_ref)
+        m_scr[...] = jnp.full_like(m_scr, _NEG)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        ll_scr[...] = jnp.zeros_like(ll_scr)
+        lab_scr[...] = _column(lab_ref)
 
-    h = h_ref[...].astype(_F32)                    # (bn, H)
-    labels = lab_ref[0, :]                         # (bn,)
-    bn = h.shape[0]
-    s = _dot(h, w_ref[...].astype(_F32), trans_b=True)   # (bn, bv)
-    s = s + b_ref[0, :][None, :]
-    m = m_ref[0, :]
-    l = l_ref[0, :]
-    m_new = jnp.maximum(m, jnp.max(s, axis=1))
-    l_new = l * jnp.exp(m - m_new) + jnp.sum(
-        jnp.exp(s - m_new[:, None]), axis=1)
-    col = j * block_v + jax.lax.broadcasted_iota(jnp.int32, (bn, block_v),
-                                                 1)
-    hit = col == labels[:, None]
-    ll_ref[...] = ll_ref[...] + jnp.sum(
-        jnp.where(hit, s, 0.0), axis=1)[None, :]
-    m_ref[...] = m_new[None, :]
-    l_ref[...] = l_new[None, :]
+    s = _dot(h_ref[...], w_ref[...], 1, 1) + b_ref[...]       # (bn, bv)
+    groups = _lane_groups(s)
+    m_old = m_scr[...]
+    m_new = functools.reduce(jnp.maximum, groups, m_old)
+    l = l_scr[...] * jnp.exp(m_old - m_new)
+    ll = ll_scr[...]
+    # the label's column, counted from this tile's first
+    at = lab_scr[...] - j * block_v
+    lane = jax.lax.broadcasted_iota(jnp.int32, m_old.shape, 1)
+    for c, sc in enumerate(groups):
+        l = l + jnp.exp(sc - m_new)
+        ll = ll + jnp.where(at == lane + c * _LANES, sc, 0.0)
+    m_scr[...] = m_new
+    l_scr[...] = l
+    ll_scr[...] = ll
 
     @pl.when(j == num_v - 1)
     def _finalize():
-        lse_ref[...] = m_ref[...] + jnp.log(jnp.maximum(l_ref[...], 1e-30))
+        # every lane column has seen V/128 logits, so its l is at least 1
+        # and the row's sum at least the maximal column's: no log of 0. A
+        # column far under the row's maximum underflows to exactly 0
+        m_row = jnp.max(m_new, axis=1)
+        l_row = jnp.sum(l * jnp.exp(m_new - m_row[:, None]), axis=1)
+        lse_ref[...] = (m_row + jnp.log(l_row))[None, :]
+        ll_ref[...] = jnp.sum(ll, axis=1)[None, :]
 
 
 # ---------------------------------------------------------------------------
-# backward: dh over (rows, vocab) grid; dW/db over (vocab, rows) grid
+# backward: dh over a (rows, vocab) grid, its per-row vectors columns in
+# scratch; dW/db over a (vocab, rows) grid on the TRANSPOSED tile, where
+# a per-row vector is a lane-major row as it arrives, the bias is the
+# column, and the probabilities meet h without a transpose
 # ---------------------------------------------------------------------------
 
 
-def _bwd_dh_kernel(h_ref, w_ref, b_ref, lab_ref, lse_ref, g_ref, dh_ref, *,
-                   block_v):
+def _bwd_dh_kernel(h_ref, w_ref, b_ref, lab_ref, lse_ref, g_ref, dh_ref,
+                   lab_scr, lse_scr, g_scr, *, block_v):
     from jax.experimental import pallas as pl
 
     j = pl.program_id(1)
@@ -156,25 +253,24 @@ def _bwd_dh_kernel(h_ref, w_ref, b_ref, lab_ref, lse_ref, g_ref, dh_ref, *,
     @pl.when(j == 0)
     def _init():
         dh_ref[...] = jnp.zeros_like(dh_ref)
+        lab_scr[...] = _column(lab_ref)
+        lse_scr[...] = _column(lse_ref)
+        g_scr[...] = _column(g_ref)
 
-    h = h_ref[...].astype(_F32)
-    w = w_ref[...].astype(_F32)
-    labels = lab_ref[0, :]
-    lse = lse_ref[0, :]
-    g = g_ref[0, :]
-    bn = h.shape[0]
-    s = _dot(h, w, trans_b=True) + b_ref[0, :][None, :]
-    p = jnp.exp(s - lse[:, None])
-    col = j * block_v + jax.lax.broadcasted_iota(jnp.int32, (bn, block_v),
-                                                 1)
-    p = p - (col == labels[:, None]).astype(_F32)
+    w = w_ref[...]
+    s = _dot(h_ref[...], w, 1, 1) + b_ref[...]                # (bn, bv)
+    p = jnp.exp(s - _wide(lse_scr[...], block_v))
+    col = j * block_v + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    p = jnp.where(col == _wide(lab_scr[...], block_v), p - 1.0, p)
+    p = p * _wide(g_scr[...], block_v)
     # dh_ref is f32 regardless of input dtype: accumulating across the
     # vocab grid steps in bf16 would compound rounding per step
-    dh_ref[...] = dh_ref[...] + _dot(p * g[:, None], w)
+    dh_ref[...] = dh_ref[...] + _dot(p.astype(w.dtype), w, 1, 0)
 
 
 def _bwd_dw_kernel(h_ref, w_ref, b_ref, lab_ref, lse_ref, g_ref,
-                   dw_ref, db_ref, *, block_n, block_v):
+                   dw_ref, db_ref, b_scr, db_scr, *, num_n, block_n,
+                   block_v):
     from jax.experimental import pallas as pl
 
     vj = pl.program_id(0)
@@ -183,22 +279,21 @@ def _bwd_dw_kernel(h_ref, w_ref, b_ref, lab_ref, lse_ref, g_ref,
     @pl.when(i == 0)
     def _init():
         dw_ref[...] = jnp.zeros_like(dw_ref)
-        db_ref[...] = jnp.zeros_like(db_ref)
+        db_scr[...] = jnp.zeros_like(db_scr)
+        b_scr[...] = _column(b_ref)
 
-    w = w_ref[...].astype(_F32)                     # (bv, H)
-    bv = w.shape[0]
-    h = h_ref[...].astype(_F32)                     # (bn, H)
-    labels = lab_ref[0, :]
-    lse = lse_ref[0, :]
-    g = g_ref[0, :]
-    s = _dot(h, w, trans_b=True) + b_ref[0, :][None, :]
-    p = jnp.exp(s - lse[:, None])
-    col = vj * block_v + jax.lax.broadcasted_iota(
-        jnp.int32, (block_n, bv), 1)
-    p = (p - (col == labels[:, None]).astype(_F32)) * g[:, None]
+    h = h_ref[...]                                            # (bn, H)
+    st = _dot(w_ref[...], h, 1, 1) + _wide(b_scr[...], block_n)  # (bv, bn)
+    p = jnp.exp(st - lse_ref[...])
+    row = vj * block_v + jax.lax.broadcasted_iota(jnp.int32, st.shape, 0)
+    p = jnp.where(row == lab_ref[...], p - 1.0, p) * g_ref[...]
     # f32 accumulator refs (cast to the param dtype happens outside)
-    dw_ref[...] = dw_ref[...] + _dot(p.T, h)
-    db_ref[...] = db_ref[...] + jnp.sum(p, axis=0)[None, :]
+    dw_ref[...] = dw_ref[...] + _dot(p.astype(h.dtype), h, 1, 0)
+    db_scr[...] = functools.reduce(jnp.add, _lane_groups(p), db_scr[...])
+
+    @pl.when(i == num_n - 1)
+    def _finalize():
+        db_ref[...] = jnp.sum(db_scr[...], axis=1)[None, :]
 
 
 # ---------------------------------------------------------------------------
@@ -206,13 +301,25 @@ def _bwd_dw_kernel(h_ref, w_ref, b_ref, lab_ref, lse_ref, g_ref,
 # ---------------------------------------------------------------------------
 
 
+def _as_mxu(*operands):
+    return [x.astype(_mxu_dtype(x.dtype)) for x in operands]
+
+
+def _lane_scratch(rows, dtype=_F32):
+    """VMEM scratch for one value a row and lane column."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.VMEM((rows, _LANES), dtype)
+
+
 def _fwd_call(h, w, bias, labels, block_n, block_v, tag=""):
     from jax.experimental import pallas as pl
 
+    h, w = _as_mxu(h, w)
     n, hd = h.shape
     v = w.shape[0]
     num_v = v // block_v
-    lse, ll, _m, _l = kernel_call(
+    lse, ll = kernel_call(
         f"fused_xent_{tag}fwd",
         functools.partial(_fwd_kernel, num_v=num_v, block_v=block_v),
         grid=(n // block_n, num_v),
@@ -225,15 +332,14 @@ def _fwd_call(h, w, bias, labels, block_n, block_v, tag=""):
         out_specs=[
             pl.BlockSpec((1, block_n), lambda i, j: (0, i)),
             pl.BlockSpec((1, block_n), lambda i, j: (0, i)),
-            pl.BlockSpec((1, block_n), lambda i, j: (0, i)),
-            pl.BlockSpec((1, block_n), lambda i, j: (0, i)),
         ],
         out_shape=[
             _sds((1, n), _F32, h),     # lse
             _sds((1, n), _F32, h),     # label logit
-            _sds((1, n), _F32, h),     # running max (scratch-as-output)
-            _sds((1, n), _F32, h),     # running sumexp
         ],
+        # running max, running sumexp, label logit; the labels as a column
+        scratch_shapes=[_lane_scratch(block_n)] * 3
+        + [_lane_scratch(block_n, jnp.int32)],
         compiler_params=_compiler_params(),
     )(h, w, bias[None, :], labels[None, :])
     return lse[0], ll[0]
@@ -242,8 +348,12 @@ def _fwd_call(h, w, bias, labels, block_n, block_v, tag=""):
 def _bwd_call(h, w, bias, labels, lse, g, block_n, block_v, tag=""):
     from jax.experimental import pallas as pl
 
+    dtypes = h.dtype, w.dtype
+    h, w = _as_mxu(h, w)
     n, hd = h.shape
     v = w.shape[0]
+    operands = (h, w, bias[None, :], labels[None, :], lse[None, :],
+                g[None, :])
     # both backward kernels under the one role: a trace sums them
     dh = kernel_call(
         f"fused_xent_{tag}bwd",
@@ -259,12 +369,15 @@ def _bwd_call(h, w, bias, labels, lse, g, block_n, block_v, tag=""):
         ],
         out_specs=pl.BlockSpec((block_n, hd), lambda i, j: (i, 0)),
         out_shape=_sds((n, hd), _F32, h),
+        # labels, lse and the row cotangent as columns
+        scratch_shapes=[_lane_scratch(block_n, jnp.int32),
+                        _lane_scratch(block_n), _lane_scratch(block_n)],
         compiler_params=_compiler_params(),
-    )(h, w, bias[None, :], labels[None, :], lse[None, :], g[None, :])
+    )(*operands)
     dw, db = kernel_call(
         f"fused_xent_{tag}bwd",
-        functools.partial(_bwd_dw_kernel, block_n=block_n,
-                          block_v=block_v),
+        functools.partial(_bwd_dw_kernel, num_n=n // block_n,
+                          block_n=block_n, block_v=block_v),
         grid=(v // block_v, n // block_n),
         in_specs=[
             pl.BlockSpec((block_n, hd), lambda vj, i: (i, 0)),
@@ -282,9 +395,11 @@ def _bwd_call(h, w, bias, labels, lse, g, block_n, block_v, tag=""):
             _sds((v, hd), _F32, h),
             _sds((1, v), _F32, h),
         ],
+        # the bias as a column; db's sums, lane-wise
+        scratch_shapes=[_lane_scratch(block_v)] * 2,
         compiler_params=_compiler_params(),
-    )(h, w, bias[None, :], labels[None, :], lse[None, :], g[None, :])
-    return dh.astype(h.dtype), dw.astype(w.dtype), db[0]
+    )(*operands)
+    return dh.astype(dtypes[0]), dw.astype(dtypes[1]), db[0]
 
 
 def _ladder(n, block_n):
@@ -307,7 +422,8 @@ def _tag(k, n):
 
 
 def _blocks(h, w):
-    blocks = _pick_blocks(h.shape[0], h.shape[1], w.shape[0])
+    blocks = _pick_blocks(h.shape[0], h.shape[1], w.shape[0],
+                          _mxu_dtype(h.dtype).itemsize)
     if blocks is None:
         raise ValueError(
             f"fused_xent: no (block_n, block_v) divides+fits h "
@@ -457,8 +573,9 @@ def _eligible(n, hd, v):
     if not pallas_enabled():
         return False
     # the hidden width's ceiling is whatever _pick_blocks can hold in
-    # VMEM at its smallest blocks (about 4,600 float32 columns), not a
-    # constant of its own: 2304 runs at (256, 256)
+    # VMEM at its smallest blocks on float32 operands (3,840 columns), not
+    # a constant of its own: 2304 runs at (256, 256) there and at
+    # (512, 256) on bfloat16 operands
     return _pick_blocks(n, hd, v) is not None and hd % 128 == 0
 
 

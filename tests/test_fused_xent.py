@@ -27,7 +27,12 @@ def interp(monkeypatch):
                         functools.partial(pl.pallas_call, interpret=True))
     monkeypatch.setattr(bringup, "pallas_enabled", lambda: True)
     counters.reset()
-    yield
+    # float32 operands stay float32 into the kernels' products only above
+    # the default matmul precision (fused_xent._mxu_dtype): the tests
+    # that hold the kernels to a float32 reference ask for it, as
+    # chip_smoke.py does; test_tile_body_* below covers the default
+    with jax.default_matmul_precision("highest"):
+        yield
     counters.reset()
 
 
@@ -138,6 +143,86 @@ def test_bf16_grads_accumulate_in_f32(interp):
     np.testing.assert_allclose(np.asarray(gw, jnp.float32),
                                np.asarray(gr[1], jnp.float32),
                                rtol=2e-2, atol=2e-3)
+
+
+# -- the grid step's body: lane-wise statistics folded over a tile's lane
+# groups, per-row vectors as columns from scratch, dW on the transposed
+# tile. Two row blocks of 256 over 8 (block_v 128) or 2 (512) vocabulary
+# steps, the three arithmetics a caller can reach -------------------------
+TN, TBN = 512, 256
+
+
+def _bf16_round(x):
+    return x.astype(jnp.bfloat16).astype(jnp.float32)
+
+
+def _tile_case(case):
+    """(h, w, b, labels) for one corner of the tile body; labels are the
+    kernels' own (-1 where a row rides along unlabelled)."""
+    h, w, b, lab = _data(n=TN, seed=11, ignore_frac=0.3)
+    lab = np.array(jnp.where(lab < 0, -1, lab))
+    if case == "max_in_last_lane_of_last_block":
+        b = b.at[V - 1].set(25.0)
+    elif case == "row_block_all_ignored":
+        lab[:TBN] = -1
+    elif case == "labels_in_first_and_last_column":
+        lab[0::2], lab[1::2] = 0, V - 1
+    elif case.startswith("lane_column_far_below"):
+        # one lane column (every 128th logit) towers over the others by
+        # 60 (a small weight in the finalize) or 120 (exp underflows to 0)
+        b = b.at[3::128].add(float(case.rsplit("_", 1)[1]))
+    return h, w, b, jnp.asarray(lab, jnp.int32)
+
+
+@pytest.mark.parametrize("case", [
+    "random", "max_in_last_lane_of_last_block", "row_block_all_ignored",
+    "labels_in_first_and_last_column", "lane_column_far_below_60",
+    "lane_column_far_below_120"])
+@pytest.mark.parametrize("block_v", [128, 512])
+@pytest.mark.parametrize("arith", ["float32", "float32_one_pass",
+                                   "bfloat16"])
+def test_tile_body_matches_the_logits_path(interp, case, block_v, arith):
+    """loss, lse, the label logit, dh, dW and db of the three kernels
+    against materialised logits. ``float32``: float32 operands under
+    `highest`; ``float32_one_pass``: float32 inputs at the default
+    precision, which the kernels round to bfloat16 once before the call
+    as the MXU would on every step; ``bfloat16``: bfloat16 inputs. The
+    reference sees the operands as the product does."""
+    h, w, b, lab = _tile_case(case)
+    narrow = arith != "float32"
+    if arith == "bfloat16":
+        h, w = h.astype(jnp.bfloat16), w.astype(jnp.bfloat16)
+    g = jnp.where(lab >= 0, 0.37, 0.0)
+    with jax.default_matmul_precision("highest" if arith == "float32"
+                                      else "default"):
+        lse, ll = fx._fwd_call(h, w, b, lab, TBN, block_v)
+        dh, dw, db = fx._bwd_call(h, w, b, lab, lse, g, TBN, block_v)
+    assert dh.dtype == h.dtype and dw.dtype == w.dtype
+
+    hr, wr = (_bf16_round(x) if narrow else x for x in (h, w))
+    with jax.default_matmul_precision("highest"):
+        logits = hr @ wr.T + b
+        hit = jnp.arange(V)[None, :] == lab[:, None]
+        p = (jnp.exp(logits - lse[:, None]) - hit) * g[:, None]
+        want = (jax.scipy.special.logsumexp(logits, axis=-1),
+                jnp.sum(jnp.where(hit, logits, 0.0), axis=1),
+                p @ wr, p.T @ hr, jnp.sum(p, axis=0))
+    got = (lse, ll, dh, dw, db)
+    assert all(bool(jnp.all(jnp.isfinite(x.astype(jnp.float32))))
+               for x in got)
+    # the statistics are float32 whatever the operands; a gradient's
+    # probabilities are rounded to the operands' type before its product
+    tols = (2e-6, 2e-6) + ((1e-2,) * 2 + (2e-6,) if narrow else (2e-6,) * 3)
+    for name, a, r, tol in zip(("lse", "ll", "dh", "dw", "db"), got, want,
+                               tols):
+        a, r = np.asarray(a, np.float32), np.asarray(r, np.float32)
+        scale = max(float(np.max(np.abs(r))), 1e-30)
+        assert float(np.max(np.abs(a - r))) <= tol * scale, (name, case)
+    valid = lab >= 0
+    loss = jnp.sum(jnp.where(valid, lse - ll, 0.0))
+    np.testing.assert_allclose(
+        float(loss), float(jnp.sum(jnp.where(valid, want[0] - want[1], 0))),
+        rtol=2e-6)
 
 
 # -- the row-capacity ladder: 2048 rows in blocks of 256 may run at 256,

@@ -69,21 +69,36 @@ def test_stream_flash_compiles_at_mlas_widths_and_the_sequence_ceiling(
     assert "flash_attention_stream_bwd" in text
 
 
-def test_fused_xent_compiles_at_hidden_2304_on_the_top_rung(one_chip):
+@pytest.mark.parametrize("shape, precision, blocks", [
+    # the Kimi cell's top rung: bfloat16 operands at the default precision
+    ((8192, 2304, 20480), "default", (512, 256)),
+    # the same under `highest` (chip_smoke.py): float32 operands
+    ((8192, 2304, 20480), "highest", (256, 256)),
+    # the BERT cells' 8,192-row rung
+    ((8192, 768, 30592), "default", (1024, 128)),
+    ((8192, 768, 30592), "highest", (1024, 128)),
+])
+def test_fused_xent_compiles_at_the_cells_shapes(one_chip, shape, precision,
+                                                 blocks):
+    """Forward, dh and dW/db with their VMEM scratch, from float32 h and
+    table as the cells hand them over; the block pair follows from the
+    shapes and the operands' width (fused_xent._mxu_dtype)."""
     from paddle_tpu.ops.pallas import fused_xent as fx
 
-    n, hd, v = 8192, 2304, 20480
-    bn, bv = fx._pick_blocks(n, hd, v)
+    n, hd, v = shape
 
     def loss(h, w, b, lab):
         s, c = fx._fused_xent_sums(h, w, b, lab, -100, (n,))
         return s / c
 
-    out = _compile(jax.grad(loss, argnums=(0, 1)), one_chip,
-                   ((n, hd), F32), ((v, hd), F32), ((v,), F32),
-                   ((n,), jnp.int32))
-    assert (bn, bv) == (256, 256)
-    assert "fused_xent_bwd" in out.as_text()
+    with jax.default_matmul_precision(precision):
+        out = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+                       ((n, hd), F32), ((v, hd), F32), ((v,), F32),
+                       ((n,), jnp.int32))
+        assert fx._pick_blocks(
+            n, hd, v, fx._mxu_dtype(jnp.dtype(F32)).itemsize) == blocks
+    text = out.as_text()
+    assert "fused_xent_fwd" in text and "fused_xent_bwd" in text
 
 
 @pytest.mark.parametrize("precision", [None, "highest"])

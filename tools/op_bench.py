@@ -233,14 +233,50 @@ def bench_transpose(smoke):
             "gbps": x.nbytes * 2 / (ms / 1e3) / 1e9}
 
 
+def _xent_kernel_rows(n, hd, v):
+    """The three fused-xent kernels apart, at one call's shapes from
+    float32 h and table: ms a call and us a grid step beside one logits
+    product's us at the bf16 peak (the forward makes one a step, dh and
+    dW two each). dh and dW share ``_bwd_call``; fetching one's outputs
+    lets XLA drop the other's call."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas import fused_xent as fx
+
+    h = jax.random.normal(jax.random.key(0), (n, hd), jnp.float32)
+    w = jax.random.normal(jax.random.key(1), (v, hd), jnp.float32) * 0.02
+    b = jnp.zeros((v,), jnp.float32)
+    lab = jax.random.randint(jax.random.key(2), (n,), 0, v, jnp.int32)
+    g = jnp.full((n,), 1.0 / n, jnp.float32)
+    bn, bv = fx._blocks(h, w)
+    fwd = jax.jit(lambda h, w: fx._fwd_call(h, w, b, lab, bn, bv))
+    lse, _ = fwd(h, w)
+
+    def bwd(h, w):
+        return fx._bwd_call(h, w, b, lab, lse, g, bn, bv)
+
+    calls = {"fwd": fwd, "dh": jax.jit(lambda h, w: bwd(h, w)[0]),
+             "dw": jax.jit(lambda h, w: bwd(h, w)[1:])}
+    steps = (n // bn) * (v // bv)
+    ms = {k: _timeit(f, h, w) for k, f in calls.items()}
+    return {"shape": f"{n}x{hd}x{v}", "blocks": [bn, bv], "grid_steps": steps,
+            "ms": {k: round(t, 4) for k, t in ms.items()},
+            "us_per_step": {k: round(t * 1e3 / steps, 3)
+                            for k, t in ms.items()},
+            "product_us_at_peak": round(2e6 * bn * bv * hd / 197e12, 3)}
+
+
 def bench_fused_xent(smoke):
     """MLM-head A/B (VERDICT r4 #2): fused streamed linear+xent kernel
-    vs the materialised-logits XLA path, fwd+bwd at BERT shapes."""
+    vs the materialised-logits XLA path, fwd+bwd at BERT shapes; and
+    under ``kernels`` the forward, dh and dW kernels apart at the two
+    training cells' calls (BERT's 8,192-row rung, the causal LM's top
+    rung at hidden 2,304), so whoever reads ``xent_roofline_pct.train``
+    can see which kernel holds it down without a trace."""
     import jax.numpy as jnp
 
     from paddle_tpu.framework.bringup import TPU_PLATFORMS
-    from paddle_tpu.ops.pallas.fused_xent import (
-        _fused_xent_core, fused_linear_cross_entropy)
+    from paddle_tpu.ops.pallas.fused_xent import _fused_xent_core
 
     if jax.default_backend() not in TPU_PLATFORMS:
         return {"op": "fused_xent_vs_xla", "skipped": "tpu-only"}
@@ -264,9 +300,12 @@ def bench_fused_xent(smoke):
     xla = jax.jit(jax.grad(xla_loss, argnums=(0, 1)))
     ms_fused = _timeit(fused, h, w)
     ms_xla = _timeit(xla, h, w)
+    cells = ([(512, 128, 1024)] if smoke
+             else [(8192, 768, 30592), (8192, 2304, 20480)])
     return {"op": "fused_xent_vs_xla", "shape": f"{n}x{hd}x{v}",
             "ms": ms_fused, "ms_xla": round(ms_xla, 4),
-            "speedup": round(ms_xla / ms_fused, 3)}
+            "speedup": round(ms_xla / ms_fused, 3),
+            "kernels": [_xent_kernel_rows(*c) for c in cells]}
 
 
 BENCHES = {
