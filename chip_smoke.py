@@ -462,32 +462,53 @@ def _kernel_checks():
           xla(), (32, 512, 12, 64), bf16,
           lambda q, k: fa._pallas_ok(q, k, False))
 
-    def flash_mla(fails, shape=(1, 8192, 4), qk=192, vd=128):
-        """MLA's widths at the streaming kernels' sequence ceiling: keys
-        and queries 192 wide, values 128, causal, bfloat16."""
-        q, k = (rnd(s, shape + (qk,), bf16) for s in (1, 2))
-        v = rnd(3, shape + (vd,), bf16)
-        w = rnd(4, shape + (vd,), f32)
-        if not fa._pallas_ok(q, k, True, v=v):
-            fails.append(f"flash stream MLA widths: {shape} is outside "
-                         "its gate")
-            return
+    def flash_causal(label, q_shape, k_shape, v_shape, window=None):
+        """The streaming kernels, causal, bfloat16, at the sequence
+        ceiling, on operands whose shapes differ: output and the three
+        gradients against the XLA path."""
+        def check(fails):
+            q, k, v = (rnd(s, shape, bf16) for s, shape in
+                       ((1, q_shape), (2, k_shape), (3, v_shape)))
+            w = rnd(4, q_shape[:-1] + v_shape[-1:], f32)
+            if not fa._pallas_ok(q, k, True, v=v):
+                fails.append(f"flash stream {label}: {q_shape} is outside "
+                             "its gate")
+                return
 
-        def run(f):
-            return jax.jit(jax.value_and_grad(
-                lambda q, k, v: jnp.sum(f(q, k, v).astype(f32) * w),
-                argnums=(0, 1, 2)))(q, k, v)
+            def run(f):
+                return jax.jit(jax.value_and_grad(
+                    lambda q, k, v: jnp.sum(f(q, k, v).astype(f32) * w),
+                    argnums=(0, 1, 2)))(q, k, v)
 
-        kernel = lambda q, k, v: fa._flash_attention_pallas(  # noqa: E731
-            q, k, v, causal=True)
-        (_, got), (_, want) = run(kernel), run(xla(causal=True))
-        _close("flash stream MLA out", jax.jit(kernel)(q, k, v),
-               jax.jit(xla(causal=True))(q, k, v), tol_of(bf16), fails)
-        for g, r, nm in zip(got, want, "qkv"):
-            _close(f"flash stream MLA d{nm}", g, r, tol_of(bf16), fails)
-    # four heads: the XLA reference keeps (heads, 8192, 8192) scores
+            def kernel(q, k, v):
+                return fa._flash_attention_pallas(q, k, v, causal=True,
+                                                  window=window)
+
+            def ref(q, k, v):
+                return fa._xla_attention(q, k, v, None, 0.0, True, None,
+                                         window=window)
+
+            (_, got), (_, want) = run(kernel), run(ref)
+            _close(f"flash stream {label} out", jax.jit(kernel)(q, k, v),
+                   jax.jit(ref)(q, k, v), tol_of(bf16), fails)
+            for g, r, nm in zip(got, want, "qkv"):
+                _close(f"flash stream {label} d{nm}", g, r, tol_of(bf16),
+                       fails)
+        return check
+    # MLA's widths: keys and queries 192 wide, values 128. Four heads: the
+    # XLA reference keeps (heads, 8192, 8192) scores
     checks.append(("flash stream causal, MLA widths (1, 8192, 4, 192 / "
-                   "128)", flash_mla))
+                   "128)", flash_causal("MLA", (1, 8192, 4, 192),
+                                        (1, 8192, 4, 192),
+                                        (1, 8192, 4, 128))))
+    # eight query heads on two key heads of 128, with and without a
+    # sliding window: K and V through the block index maps, dK and dV
+    # summed over the group in the kernel, the band's blocks alone visited
+    for window in (None, 1024):
+        checks.append((
+            f"flash stream grouped 8q/2kv (1, 8192, ., 128) window={window}",
+            flash_causal(f"grouped window={window}", (1, 8192, 8, 128),
+                         (1, 8192, 2, 128), (1, 8192, 2, 128), window)))
 
     def flash_masked(fails, shape=(8, 512, 12, 64)):
         b, l = shape[:2]    # ragged key-padding: row i keeps l - 37 i keys
@@ -720,8 +741,9 @@ def _kernel_checks():
         its rungs. ``rungs`` maps a number of held experts that the
         correction bias makes every token's picks to the rows that must
         then run: the count of pairs decides the rung, ``ragged_dot`` on
-        a lower one, on the top one where more experts are held than a
-        token picks, every token through every expert where not."""
+        a lower one, on the top one where more than twice as many experts
+        are held as a token picks, every token through every expert
+        (t x held rows) where not."""
         from paddle_tpu.nn.moe import sparse_moe
 
         name = f"sparse experts {held} of {num_experts} held, rungs " \
@@ -776,10 +798,13 @@ def _kernel_checks():
                            fails)
         checks.append((name, check))
 
-    # the cell's share in the step's type, and a share that holds more
-    # experts than a token picks, exactly
+    # the Kimi cell's share in the step's type; a share that holds twice
+    # the experts a token picks (the Mellum cell's ratio), whose top rung
+    # is still every token through every expert; and one that holds
+    # three times as many, whose top rung is ragged_dot on every pair
     experts(8, bf16, {0: 16384, 8: 65536})
-    experts(16, f32, {0: 32768, 8: 65536})
+    experts(16, f32, {0: 32768, 8: 131072})
+    experts(24, f32, {0: 49152, 8: 65536})
 
     # -- fused embedding bag --------------------------------------------------
     def bag(vocab, d, b, s, dtype, combiner):

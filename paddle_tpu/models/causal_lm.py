@@ -8,13 +8,23 @@ block gets is decided per layer from the config, through two small
 tables below: a new architecture is a config file plus, at most, a new
 layer kind registered there.
 
-    mixer   ``linear_attn_config.kda_layers`` (1-based) -> ``kda``
+    mixer   with ``layer_types``: ``sliding_attention`` / ``full_attention``
+            -> ``gqa`` (``nn.GroupedQueryAttention``:
+            ``num_key_value_heads`` key heads of ``head_dim``, the
+            layer type's entry of ``rope_parameters``, ``sliding_window``
+            on the sliding layers, ``qk_norm``)
+            else ``linear_attn_config.kda_layers`` (1-based) -> ``kda``
             (``nn.KimiDeltaAttention``); every other layer -> ``mla``
             (``nn.MLAttention``, NoPE)
-    ffn     the first ``first_k_dense_replace`` layers -> ``dense``
+    ffn     with ``mlp_layer_types``: ``sparse`` -> ``moe``, ``dense`` ->
+            ``dense``
+            else the first ``first_k_dense_replace`` layers -> ``dense``
             (``nn.GatedFFN`` of ``intermediate_size``); the others ->
-            ``moe`` (``nn.SparseMoELayer``: ``num_experts_per_token`` of
-            the router's experts, ``num_shared_experts`` shared)
+            ``moe`` (``nn.SparseMoELayer``: ``num_experts_per_token`` or
+            ``num_experts_per_tok`` of the router's experts,
+            ``num_shared_experts`` shared; scores by
+            ``moe_router_activation_func``, or softmax for a config that
+            says ``norm_topk_prob``, which is then the renormalisation)
 
 A chip's share of an expert-parallel deployment is said with
 ``experts_held`` / ``expert_offset`` (the router keeps all its outputs).
@@ -29,9 +39,29 @@ import jax.numpy as jnp
 from .. import nn
 from ..framework.tensor import Tensor
 from ..nn import functional as F
+from ..nn.moe import SCORE_FUNCS
 
 
-def _mixer_kda(cfg):
+#: ``layer_types`` entries that the ``gqa`` mixer builds
+_GQA_LAYER_TYPES = ("sliding_attention", "full_attention")
+
+
+def _mixer_gqa(cfg, layer):
+    kind = cfg["layer_types"][layer - 1]
+    rope = cfg.get("rope_parameters")
+    if rope is not None and "rope_theta" not in rope:
+        rope = rope[kind]                   # one entry a layer type
+    heads = cfg["num_attention_heads"]
+    return nn.GroupedQueryAttention(
+        cfg["hidden_size"], heads, cfg.get("num_key_value_heads", heads),
+        cfg.get("head_dim", cfg["hidden_size"] // heads),
+        window=cfg["sliding_window"] if kind == "sliding_attention"
+        else None,
+        rope=rope, qk_norm=cfg.get("qk_norm", True),
+        epsilon=cfg["rms_norm_eps"])
+
+
+def _mixer_kda(cfg, layer):
     lin = cfg["linear_attn_config"]
     return nn.KimiDeltaAttention(
         cfg["hidden_size"], lin["num_heads"], lin["head_dim"],
@@ -39,7 +69,7 @@ def _mixer_kda(cfg):
         epsilon=cfg["rms_norm_eps"])
 
 
-def _mixer_mla(cfg):
+def _mixer_mla(cfg, layer):
     if cfg.get("q_lora_rank") is not None:
         raise NotImplementedError("MLA with a low-rank query projection")
     return nn.MLAttention(
@@ -55,32 +85,49 @@ def _ffn_dense(cfg):
 
 
 def _ffn_moe(cfg):
-    if cfg.get("moe_router_activation_func", "sigmoid") != "sigmoid":
-        raise NotImplementedError("only sigmoid router scores are built")
+    # a config that says norm_topk_prob follows the Qwen-MoE convention:
+    # softmax over all experts, then the top k, renormalised if it says so
+    score = cfg.get("moe_router_activation_func",
+                    "softmax" if "norm_topk_prob" in cfg else "sigmoid")
+    if score not in SCORE_FUNCS:
+        raise NotImplementedError(
+            f"router scores {score!r}: {sorted(SCORE_FUNCS)} are built "
+            "(nn.moe.SCORE_FUNCS)")
     if cfg.get("num_expert_group", 1) != 1 or cfg.get("topk_group", 1) != 1:
         raise NotImplementedError("group-limited routing")
     held = cfg.get("experts_held", cfg["num_experts"])
     shared = cfg.get("num_shared_experts", 0) * cfg["moe_intermediate_size"]
     return nn.SparseMoELayer(
         cfg["hidden_size"], cfg["moe_intermediate_size"],
-        cfg["num_experts"], cfg["num_experts_per_token"],
+        cfg["num_experts"],
+        cfg.get("num_experts_per_token", cfg.get("num_experts_per_tok")),
         experts_held=held, expert_offset=cfg.get("expert_offset", 0),
         scaling=cfg.get("routed_scaling_factor", 1.0),
-        renormalize=cfg.get("moe_renormalize", True),
-        shared_width=shared or None)
+        renormalize=cfg.get("moe_renormalize",
+                            cfg.get("norm_topk_prob", True)),
+        shared_width=shared or None, score_func=score)
 
 
-MIXERS = {"kda": _mixer_kda, "mla": _mixer_mla}
+MIXERS = {"gqa": _mixer_gqa, "kda": _mixer_kda, "mla": _mixer_mla}
 FFNS = {"dense": _ffn_dense, "moe": _ffn_moe}
 
 
 def mixer_kind(cfg, layer: int) -> str:
     """``layer`` counts from 1, as the config's layer lists do."""
+    if "layer_types" in cfg:
+        kind = cfg["layer_types"][layer - 1]
+        if kind not in _GQA_LAYER_TYPES:
+            raise NotImplementedError(
+                f"layer_types entry {kind!r}: {_GQA_LAYER_TYPES} are built")
+        return "gqa"
     lin = cfg.get("linear_attn_config") or {}
     return "kda" if layer in lin.get("kda_layers", ()) else "mla"
 
 
 def ffn_kind(cfg, layer: int) -> str:
+    if "mlp_layer_types" in cfg:
+        return {"sparse": "moe", "dense": "dense"}[
+            cfg["mlp_layer_types"][layer - 1]]
     dense = layer <= cfg.get("first_k_dense_replace", 0) \
         or "num_experts" not in cfg \
         or (layer - 1) % cfg.get("moe_layer_freq", 1) != 0
@@ -94,7 +141,7 @@ class DecoderBlock(nn.Layer):
         self.mixer_kind, self.ffn_kind = (mixer_kind(cfg, layer),
                                           ffn_kind(cfg, layer))
         self.input_norm = nn.RMSNorm(cfg["hidden_size"], epsilon=eps)
-        self.mixer = MIXERS[self.mixer_kind](cfg)
+        self.mixer = MIXERS[self.mixer_kind](cfg, layer)
         self.post_norm = nn.RMSNorm(cfg["hidden_size"], epsilon=eps)
         self.ffn = FFNS[self.ffn_kind](cfg)
 

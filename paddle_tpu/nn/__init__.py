@@ -49,6 +49,7 @@ from .moe import (  # noqa: F401
     MoELayer, SparseMoELayer, moe_apply_ep, MOE_EP_RULES,
 )
 from .linear_attention import KimiDeltaAttention  # noqa: F401
+from .grouped_query_attention import GroupedQueryAttention  # noqa: F401
 from .latent_attention import MLAttention  # noqa: F401
 from .crf import LinearChainCRF, crf_decoding, linear_chain_crf  # noqa: F401,E402
 

@@ -1005,21 +1005,89 @@ def ctc_loss(log_probs, labels, input_lengths, label_lengths, blank=0,
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False,
-                                 training=True, name=None):
-    """query/key/value: (B, L, H, D) paddle layout."""
+                                 training=True, name=None, window=None):
+    """query: (B, L, H, D) paddle layout; key/value: (B, L, Hkv, D) with
+    H a multiple of Hkv (grouped-query attention: query head h reads key
+    head h // (H / Hkv); Hkv == H is multi-head attention). ``window``,
+    with ``is_causal``, keeps the keys ``0 <= i - j < window`` of query
+    i: a sliding window that holds the query's own position."""
     use_dropout = dropout_p > 0.0 and training
     return _sdpa(query, key, value, attn_mask,
                  dropout_p=dropout_p if use_dropout else 0.0,
                  is_causal=is_causal,
-                 key_rng=next_rng_key() if use_dropout else None)
+                 key_rng=next_rng_key() if use_dropout else None,
+                 window=window)
 
 
 @primitive("sdpa")
-def _sdpa(q, k, v, mask, dropout_p, is_causal, key_rng):
+def _sdpa(q, k, v, mask, dropout_p, is_causal, key_rng, window=None):
     from ..ops.pallas.flash_attention import flash_attention_or_fallback
 
     return flash_attention_or_fallback(q, k, v, mask, dropout_p, is_causal,
-                                       key_rng)
+                                       key_rng, window=window)
+
+
+# ---------------------------------------------------------------------------
+# rotary position embedding (the rotate_half convention of the Hugging
+# Face decoders: channel i pairs with channel i + D/2)
+# ---------------------------------------------------------------------------
+
+
+def rope_inv_freq(head_dim, theta=10000.0):
+    """Plain rotary inverse frequencies ``theta ** (-2i / D)``, i = 0 ..
+    D/2 - 1, float64 numpy: a constant of the layer that holds it."""
+    return 1.0 / float(theta) ** (np.arange(0, head_dim, 2,
+                                            dtype=np.float64) / head_dim)
+
+
+def yarn_inv_freq(head_dim, theta, factor, original_max_position,
+                  beta_fast=32.0, beta_slow=1.0):
+    """YaRN's inverse frequencies (Hugging Face
+    ``_compute_yarn_parameters``): frequency i is interpolated (divided
+    by ``factor``) where it turns fewer than ``beta_slow`` times over
+    ``original_max_position`` positions, kept where it turns more than
+    ``beta_fast`` times, and blended linearly between: with ``c(r) = D
+    ln(P / (2 pi r)) / (2 ln theta)``, ``low = floor(c(beta_fast))`` and
+    ``high = ceil(c(beta_slow))`` clamped to [0, D - 1], ``ramp_i =
+    clip((i - low) / (high - low), 0, 1)`` and ``inv_freq = interp * ramp
+    + extrap * (1 - ramp)``. Returns ``(inv_freq float64 (D/2,), low,
+    high)``; the scale YaRN puts on cos and sin (``attention_factor``,
+    0.1 ln(factor) + 1 by default) is :func:`rotary_embedding`'s
+    ``scale``."""
+    def turns_to_dim(turns):
+        return head_dim * math.log(original_max_position
+                                   / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(turns_to_dim(beta_fast)), 0)
+    high = min(math.ceil(turns_to_dim(beta_slow)), head_dim - 1)
+    extrap = rope_inv_freq(head_dim, theta)
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float64) - low)
+                   / ((high - low) or 0.001), 0.0, 1.0)
+    return extrap / factor * ramp + extrap * (1.0 - ramp), low, high
+
+
+def _rotate_half(x):
+    half = x.shape[-1] // 2
+    return jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
+
+
+@primitive("rotary_embedding")
+def rotary_embedding(x, inv_freq, scale=1.0, positions=None, name=None):
+    """Rotate x (B, L, H, D) by its positions: ``x cos + rotate_half(x)
+    sin`` with ``cos, sin`` of ``positions[:, None] * inv_freq`` repeated
+    over both halves of D and multiplied by ``scale``. The tables are
+    float32, cast to x's type for the product. ``positions`` (L,)
+    defaults to 0 .. L - 1."""
+    with jax.named_scope("rotary_embedding"):
+        length = x.shape[1]
+        pos = jnp.arange(length, dtype=jnp.float32) if positions is None \
+            else jnp.asarray(positions, jnp.float32)
+        angles = pos[:, None] * jnp.asarray(inv_freq, jnp.float32)[None, :]
+        angles = jnp.concatenate([angles, angles], axis=-1)   # (L, D)
+        cos = (jnp.cos(angles) * scale).astype(x.dtype)[None, :, None, :]
+        sin = (jnp.sin(angles) * scale).astype(x.dtype)[None, :, None, :]
+        return x * cos + _rotate_half(x) * sin
 
 
 # ---------------------------------------------------------------------------

@@ -10,7 +10,7 @@
 Keys and values are low-rank in ``c``; nothing is absorbed into the
 projections here (that is a decode-time rewrite). ``rotary`` is refused:
 only the NoPE variant, whose ``d_pe`` part is carried unrotated, is
-built. The value width may differ from the key width; the attention
+built (``nn.functional.rotary_embedding`` exists; it is not wired here). The value width may differ from the key width; the attention
 entry (``ops/pallas/flash_attention.py``) takes that as it is.
 """
 from __future__ import annotations
@@ -31,7 +31,10 @@ class MLAttention(Layer):
         if rotary:
             raise NotImplementedError(
                 "MLAttention builds the NoPE variant only: its "
-                "qk_rope_head_dim part is not rotated")
+                "qk_rope_head_dim part is carried unrotated. The rotary op "
+                "is nn.functional.rotary_embedding (used by "
+                "nn.GroupedQueryAttention); rotating k_pe and q's pe part "
+                "with it is not wired here")
         self.num_heads = num_heads
         self.nope, self.pe, self.v_dim = (qk_nope_head_dim,
                                           qk_rope_head_dim, v_head_dim)

@@ -1,8 +1,8 @@
 """Mixture-of-Experts layers. Two of them, for two routing contracts:
 
-- :class:`SparseMoELayer` is the DROPLESS layer: sigmoid scores, top-k
-  of any k, renormalised and scaled weights, an optional shared expert,
-  gated (SwiGLU) experts. It is told which experts it holds
+- :class:`SparseMoELayer` is the DROPLESS layer: sigmoid or softmax
+  scores over all experts, top-k of any k, renormalised and scaled
+  weights, an optional shared expert, gated (SwiGLU) experts. It is told which experts it holds
   (``experts_held`` from ``expert_offset``), routes over all of them and
   computes its own experts' part; no token is ever dropped and there is
   no capacity factor. Sorted (token, expert) pairs go through a grouped
@@ -441,16 +441,24 @@ def _grouped_ffn(x, tokens, weights, sizes, w_gate, w_up, w_down, n_tokens):
     return jnp.zeros((n_tokens, x.shape[-1]), jnp.float32).at[tokens].add(out)
 
 
+#: the router's score of each expert from its logits, over ALL experts
+SCORE_FUNCS = {"sigmoid": jax.nn.sigmoid,
+               "softmax": lambda logits: jax.nn.softmax(logits, axis=-1)}
+
+
 @primitive("sparse_moe")
 def sparse_moe(x, router_w, router_bias, w_gate, w_up, w_down, top_k,
-               expert_offset=0, scaling=1.0, renormalize=True):
+               expert_offset=0, scaling=1.0, renormalize=True,
+               score_func="sigmoid"):
     """The routed part of the dropless layer on tokens ``x`` (T, D).
 
     ``router_w`` (D, E) scores ALL E experts; ``w_gate`` / ``w_up``
     (H, D, F) and ``w_down`` (H, F, D) are the H experts held here,
     experts ``expert_offset`` .. ``expert_offset + H - 1``. Returns the
     sum over the picked AND held experts of weight * expert(x), float32,
-    and ``[pairs on held experts, rows of the rung that ran]``. The
+    and ``[pairs on held experts, rows of the rung that ran]``. Scores are
+    ``score_func`` (:data:`SCORE_FUNCS`) of the logits: ``sigmoid`` scores
+    each expert alone, ``softmax`` over all E. The
     renormalisation is over all ``top_k`` picks, held or not; the pick
     itself passes no gradient. Routing is float32 at full precision
     whatever the autocast level (a rounded score flips picks); the
@@ -459,7 +467,7 @@ def sparse_moe(x, router_w, router_bias, w_gate, w_up, w_down, top_k,
 
     t = x.shape[0]
     num_experts, held = router_w.shape[1], w_gate.shape[0]
-    scores = jax.nn.sigmoid(jnp.matmul(
+    scores = SCORE_FUNCS[score_func](jnp.matmul(
         x.astype(jnp.float32), router_w.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
     _, picked = jax.lax.top_k(scores + router_bias, top_k)       # (T, k)
@@ -497,13 +505,24 @@ def sparse_moe(x, router_w, router_bias, w_gate, w_up, w_down, top_k,
             token, slot].add(weight)
         return _every_pair_ffn(x, by_expert[:, :held], w_gate, w_up, w_down)
 
-    # with no more experts here than a token picks, every pair is every
-    # token through every expert: no sort, no gathered copy of the rows
-    top = every_pair if held <= top_k else at(rungs[-1])
+    # The top rung. With no more experts here than a token picks, every
+    # pair is every token through every expert: no sort, no gathered copy
+    # of the rows. Up to twice as many experts as picks it still is the
+    # top rung, on T x H rows where the sorted pairs would be T x k: the
+    # TPU's ragged_dot takes as long as its LIVE rows, so a step's time on
+    # the sorted rung follows its routing (19,243-21,373 tokens/s over
+    # twelve seeds of the Mellum cell, 16 held of top 8, PERF.md section
+    # 6, PR 31), and the sort, the gather and the scatter of T x k rows
+    # cost more than the products they feed.
+    dense_top = held <= 2 * top_k
+    top = every_pair if dense_top else at(rungs[-1])
     rung = jnp.sum(count > jnp.asarray(rungs[:-1], jnp.int32))
     out = jax.lax.switch(rung, [at(r) for r in rungs[:-1]] + [top],
                          x, weight, w_gate, w_up, w_down)
-    ran = jnp.asarray(rungs, jnp.float32)[rung]
+    # the dense top rung's rows, in the ladder's whole 256-row blocks
+    rows = rungs[:-1] + ((-(-t * held // 256) * 256,) if dense_top
+                         else rungs[-1:])
+    ran = jnp.asarray(rows, jnp.float32)[rung]
     return out, jnp.stack([count.astype(jnp.float32), ran])
 
 
@@ -519,7 +538,7 @@ class SparseMoELayer(Layer):
 
     def __init__(self, d_model, d_expert, num_experts, top_k,
                  experts_held=None, expert_offset=0, scaling=1.0,
-                 renormalize=True, shared_width=None):
+                 renormalize=True, shared_width=None, score_func="sigmoid"):
         super().__init__()
         from .common import GatedFFN, Linear
 
@@ -528,6 +547,10 @@ class SparseMoELayer(Layer):
             raise ValueError(
                 f"experts {expert_offset}..{expert_offset + held - 1} are "
                 f"not among {num_experts}")
+        if score_func not in SCORE_FUNCS:
+            raise ValueError(f"router scores {score_func!r}: "
+                             f"{sorted(SCORE_FUNCS)} are built")
+        self.score_func = score_func
         self.top_k, self.expert_offset = int(top_k), int(expert_offset)
         self.scaling, self.renormalize = float(scaling), bool(renormalize)
         self.router = Linear(d_model, num_experts, bias_attr=False)
@@ -551,6 +574,6 @@ class SparseMoELayer(Layer):
             self.router_bias, self.experts_gate, self.experts_up,
             self.experts_down, top_k=self.top_k,
             expert_offset=self.expert_offset, scaling=self.scaling,
-            renormalize=self.renormalize)
+            renormalize=self.renormalize, score_func=self.score_func)
         out = ops.reshape(routed, list(shape))
         return out if self.shared is None else out + self.shared(x)

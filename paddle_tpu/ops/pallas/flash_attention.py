@@ -27,19 +27,32 @@ _NEG_INF = -1e30
 _F32 = jnp.float32
 
 
-def _xla_attention(q, k, v, mask, dropout_p, is_causal, key_rng):
-    """Reference XLA path: fused well enough for short sequences."""
+def _xla_attention(q, k, v, mask, dropout_p, is_causal, key_rng,
+                   window=None):
+    """Reference XLA path: fused well enough for short sequences. With
+    fewer key/value heads than query heads, query head h reads key head
+    h // (H / Hkv) through the einsum's own batching (no copy of K or V);
+    ``window`` keeps, under the causal mask, the keys with
+    ``0 <= i - j < window``."""
     # (B, L, H, D) -> (B, H, L, D)
     qh = jnp.swapaxes(q, 1, 2)
     kh = jnp.swapaxes(k, 1, 2)
     vh = jnp.swapaxes(v, 1, 2)
-    d = q.shape[-1]
-    scores = jnp.einsum("bhqd,bhkd->bhqk", qh, kh,
-                        preferred_element_type=jnp.float32)
+    b, h, ql, d = qh.shape
+    hkv, kl = kh.shape[1], kh.shape[2]
+    if hkv == h:
+        scores = jnp.einsum("bhqd,bhkd->bhqk", qh, kh,
+                            preferred_element_type=jnp.float32)
+    else:
+        scores = jnp.einsum(
+            "bngqd,bnkd->bngqk", qh.reshape(b, hkv, h // hkv, ql, d), kh,
+            preferred_element_type=jnp.float32).reshape(b, h, ql, kl)
     scores = scores / math.sqrt(d)
     if is_causal:
-        ql, kl = scores.shape[-2], scores.shape[-1]
         causal = jnp.tril(jnp.ones((ql, kl), bool), k=kl - ql)
+        if window is not None:
+            causal &= ~jnp.tril(jnp.ones((ql, kl), bool),
+                                k=kl - ql - window)
         scores = jnp.where(causal, scores, _NEG_INF)
     if mask is not None:
         if mask.dtype == jnp.bool_:
@@ -50,7 +63,12 @@ def _xla_attention(q, k, v, mask, dropout_p, is_causal, key_rng):
     if dropout_p > 0.0 and key_rng is not None:
         keep = jax.random.bernoulli(key_rng, 1.0 - dropout_p, probs.shape)
         probs = jnp.where(keep, probs / (1.0 - dropout_p), 0.0)
-    out = jnp.einsum("bhqk,bhkd->bhqd", probs, vh)
+    if hkv == h:
+        out = jnp.einsum("bhqk,bhkd->bhqd", probs, vh)
+    else:
+        out = jnp.einsum(
+            "bngqk,bnkd->bngqd", probs.reshape(b, hkv, h // hkv, ql, kl),
+            vh).reshape(b, h, ql, vh.shape[-1])
     return jnp.swapaxes(out, 1, 2)
 
 
@@ -92,9 +110,28 @@ def _keep_mask(seed, row, qi, j, shape, dropout_p):
     return bits >= threshold
 
 
+def _band(s, q_pos, k_pos, window):
+    """Scores outside the causal mask, and with a ``window`` outside
+    ``0 <= q_pos - k_pos < window``, set to -inf. A row whose keys in a
+    block are all masked adds ``exp(0)`` terms to its carry; the next
+    block that holds one of its keys rescales them by ``exp(-1e30 - m)``
+    = 0, and every row's own position is such a key."""
+    keep = q_pos >= k_pos
+    if window is not None:
+        keep &= q_pos - k_pos < window
+    return jnp.where(keep, s, _NEG_INF)
+
+
+def _first_kv_block(qi, q_block, block_kv, window):
+    """The first kv block a q block's band meets (0 with no window)."""
+    if window is None:
+        return 0
+    return jnp.maximum(qi * q_block - (window - 1), 0) // block_kv
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, kv_len,
                       block_kv, sm_scale, causal, q_block, masked=False,
-                      dropout_p=0.0):
+                      dropout_p=0.0, window=None):
     from jax.experimental import pallas as pl
 
     rest = list(rest)
@@ -120,7 +157,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, kv_len,
                 jnp.int32, (bq, block_kv), 0)
             k_pos = j * block_kv + jax.lax.broadcasted_iota(
                 jnp.int32, (bq, block_kv), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+            s = _band(s, q_pos, k_pos, window)
         m_new = jnp.maximum(m, jnp.max(s, axis=1))
         p = jnp.exp(s - m_new[:, None])
         alpha = jnp.exp(m - m_new)
@@ -142,7 +179,9 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, kv_len,
     m0 = jnp.full((bq,), _NEG_INF, _F32)
     l0 = jnp.zeros((bq,), _F32)
     acc0 = jnp.zeros((bq, v_ref.shape[-1]), _F32)
-    m, l, acc = jax.lax.fori_loop(0, last, body, (m0, l0, acc0))
+    m, l, acc = jax.lax.fori_loop(
+        _first_kv_block(qi, q_block, block_kv, window), last, body,
+        (m0, l0, acc0))
     o_ref[...] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
     lse_ref[...] = (m + jnp.log(jnp.maximum(l, 1e-30)))[None, :]
 
@@ -155,7 +194,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, kv_len,
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          *rest, kv_len, block_kv, sm_scale, causal,
-                         q_block, masked=False, dropout_p=0.0):
+                         q_block, masked=False, dropout_p=0.0, window=None):
     from jax.experimental import pallas as pl
 
     rest = list(rest)
@@ -183,7 +222,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 jnp.int32, (bq, block_kv), 0)
             k_pos = j * block_kv + jax.lax.broadcasted_iota(
                 jnp.int32, (bq, block_kv), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+            s = _band(s, q_pos, k_pos, window)
         p = jnp.exp(s - lse[:, None])            # (bq, bkv)
         dp = _dot(do, v, trans_b=True)           # (bq, bkv)
         if dropout_p > 0.0:
@@ -199,24 +238,30 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         last = jnp.minimum(((qi + 1) * q_block - 1) // block_kv + 1, num_kv)
     else:
         last = num_kv
-    dq = jax.lax.fori_loop(0, last, body, jnp.zeros_like(q))
+    dq = jax.lax.fori_loop(_first_kv_block(qi, q_block, block_kv, window),
+                           last, body, jnp.zeros_like(q))
     dq_ref[...] = (dq * sm_scale).astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           *rest, q_len, block_q, sm_scale,
-                          causal, kv_block, masked=False, dropout_p=0.0):
+                          causal, kv_block, masked=False, dropout_p=0.0,
+                          window=None, group=1):
+    """One query head's part of dK, dV of one kv block. With ``group``
+    query heads to a key head the grid is (key heads, group, kv blocks):
+    the parts are summed in float32 scratch that holds the key head's
+    whole dK, dV, and the last head of the group writes each block out."""
     from jax.experimental import pallas as pl
 
     rest = list(rest)
     mask_ref = rest.pop(0) if masked else None
     seed_ref = rest.pop(0) if dropout_p > 0.0 else None
-    dk_ref, dv_ref = rest
+    dk_ref, dv_ref = rest[:2]
     k = k_ref[...].astype(_F32)                  # (bkv, d)
     v = v_ref[...].astype(_F32)
     bkv = k.shape[0]
     row = pl.program_id(0)
-    kj = pl.program_id(1)
+    kj = pl.program_id(1 if group == 1 else 2)
     num_q = q_len // block_q
 
     def body(i, carry):
@@ -234,7 +279,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 jnp.int32, (block_q, bkv), 0)
             k_pos = kj * kv_block + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, bkv), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+            s = _band(s, q_pos, k_pos, window)
         p = jnp.exp(s - lse[:, None])
         dp = _dot(do, v, trans_b=True)
         if dropout_p > 0.0:
@@ -255,11 +300,37 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         first = (kj * kv_block) // block_q
     else:
         first = 0
+    if window is None:
+        last = num_q
+    else:
+        # the last q block whose band still holds a key of this block
+        last = jnp.minimum(
+            ((kj + 1) * kv_block + window - 2) // block_q + 1, num_q)
     dk0 = jnp.zeros_like(k)
     dv0 = jnp.zeros_like(v)
-    dk, dv = jax.lax.fori_loop(first, num_q, body, (dk0, dv0))
-    dk_ref[...] = dk.astype(dk_ref.dtype)
-    dv_ref[...] = dv.astype(dv_ref.dtype)
+    dk, dv = jax.lax.fori_loop(first, last, body, (dk0, dv0))
+    if group == 1:
+        dk_ref[...] = dk.astype(dk_ref.dtype)
+        dv_ref[...] = dv.astype(dv_ref.dtype)
+        return
+    dk_acc, dv_acc = rest[2:]
+    g = pl.program_id(1)
+    rows = pl.dslice(kj * kv_block, kv_block)
+
+    @pl.when(g == 0)
+    def _():
+        dk_acc[rows, :] = dk
+        dv_acc[rows, :] = dv
+
+    @pl.when(g > 0)
+    def _():
+        dk_acc[rows, :] += dk
+        dv_acc[rows, :] += dv
+
+    @pl.when(g == group - 1)
+    def _():
+        dk_ref[rows, :] = dk_acc[rows, :].astype(dk_ref.dtype)
+        dv_ref[rows, :] = dv_acc[rows, :].astype(dv_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -267,7 +338,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 # ---------------------------------------------------------------------------
 
 
-def _stream_params(rows, d, dv, itemsize):
+def _stream_params(rows, d, dv, itemsize, extra=0):
     """Scoped-VMEM limit of the streaming kernels: each keeps one head's
     whole K and V (the dk/dv kernel: Q and dO) resident, double-buffered,
     beside its blocks and float32 temporaries. Mosaic's 16 MiB default
@@ -276,7 +347,7 @@ def _stream_params(rows, d, dv, itemsize):
     MiB physical."""
     from jax.experimental.pallas import tpu as pltpu
 
-    resident = 2 * rows * (d + dv) * itemsize
+    resident = 2 * rows * (d + dv) * itemsize + extra
     return pltpu.CompilerParams(
         vmem_limit_bytes=max(16 << 20, min(resident + (12 << 20), 96 << 20)))
 
@@ -291,18 +362,43 @@ def _splitheads(x, b, h):
     return jnp.swapaxes(x.reshape(b, h, l, d), 1, 2)
 
 
+def _roles(window, group):
+    """(forward, backward) role names of the streaming launches. One key
+    head a query head and no window: ``flash_attention_stream_fwd`` /
+    ``_bwd``, as ever. A group's launches (``flash_attention_grouped``)
+    and a window's (``flash_attention_window``) are rows of their own in
+    a trace and in the work ledger, ONE name for the forward and the
+    backward's two launches: a trace reduction that keeps ten rows then
+    holds a layer kind's attention as one row (the Mellum cell's full
+    layer's forward and backward, apart, both fell under its tenth row;
+    PERF.md section 6, PR 31), and a profile tells them apart by scope."""
+    if window is None and group == 1:
+        return "flash_attention_stream_fwd", "flash_attention_stream_bwd"
+    name = "flash_attention_" + ("grouped" if window is None else "window")
+    return name, name
+
+
+def _kv_row(i, group):
+    """The key/value row (batch x key heads) that query row ``i`` (batch
+    x query heads) reads: consecutive query rows of a group map to one
+    block index, so the resident K and V are fetched once a key head."""
+    return i if group == 1 else i // group
+
+
 def _fwd_call(qm, km, vm, causal, block_q, block_kv, sm_scale,
-              mask_bias=None, heads=1, dropout_p=0.0, seed=None):
+              mask_bias=None, heads=1, dropout_p=0.0, seed=None,
+              window=None):
     from jax.experimental import pallas as pl
 
     bh, ql, d = qm.shape
     kl, dv = km.shape[1], vm.shape[2]      # values may be narrower (MLA)
+    group = bh // km.shape[0]              # query heads to a key head
     grid = (bh, ql // block_q)
     masked = mask_bias is not None
     in_specs = [
         pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
-        pl.BlockSpec((None, kl, d), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((None, kl, dv), lambda i, j: (i, 0, 0)),
+        pl.BlockSpec((None, kl, d), lambda i, j: (_kv_row(i, group), 0, 0)),
+        pl.BlockSpec((None, kl, dv), lambda i, j: (_kv_row(i, group), 0, 0)),
     ]
     operands = [qm, km, vm]
     if masked:
@@ -315,10 +411,10 @@ def _fwd_call(qm, km, vm, causal, block_q, block_kv, sm_scale,
         in_specs.append(pl.BlockSpec((1, 1), lambda i, j: (0, 0)))
         operands.append(seed)
     out, lse = kernel_call(
-        "flash_attention_stream_fwd",
+        _roles(window, group)[0],
         functools.partial(_flash_fwd_kernel, kv_len=kl, block_kv=block_kv,
                           sm_scale=sm_scale, causal=causal, q_block=block_q,
-                          masked=masked, dropout_p=dropout_p),
+                          masked=masked, dropout_p=dropout_p, window=window),
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -334,32 +430,43 @@ def _fwd_call(qm, km, vm, causal, block_q, block_kv, sm_scale,
     return out, lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash_attention_core(q, k, v, causal, block_q, block_kv):
-    out, _ = _flash_attention_core_fwd(q, k, v, causal, block_q, block_kv)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_attention_core(q, k, v, causal, block_q, block_kv, window=None):
+    """q (B, L, H, D); k, v (B, L, Hkv, D) with H a multiple of Hkv:
+    query head h reads key head h // (H / Hkv) through the kernels' block
+    index maps, and dK, dV come out Hkv heads wide. ``window`` (with
+    ``causal``) keeps the keys ``0 <= i - j < window`` and visits only
+    the blocks that meet that band."""
+    out, _ = _flash_attention_core_fwd(q, k, v, causal, block_q, block_kv,
+                                       window)
     return out
 
 
-def _flash_attention_core_fwd(q, k, v, causal, block_q, block_kv):
+def _flash_attention_core_fwd(q, k, v, causal, block_q, block_kv,
+                              window=None):
     b, ql, h, d = q.shape
     sm_scale = 1.0 / math.sqrt(d)
     qm, km, vm = _mergeheads(q), _mergeheads(k), _mergeheads(v)
-    out_m, lse = _fwd_call(qm, km, vm, causal, block_q, block_kv, sm_scale)
+    out_m, lse = _fwd_call(qm, km, vm, causal, block_q, block_kv, sm_scale,
+                           window=window)
     return _splitheads(out_m, b, h), (qm, km, vm, out_m, lse, b, h)
 
 
 def _bwd_call(qm, km, vm, dom, lse, delta, causal, block_q, block_kv,
-              sm_scale, mask_bias=None, heads=1, dropout_p=0.0, seed=None):
+              sm_scale, mask_bias=None, heads=1, dropout_p=0.0, seed=None,
+              window=None):
     from jax.experimental import pallas as pl
 
     bh, ql, d = qm.shape
     kl, dv = km.shape[1], vm.shape[2]
+    group = bh // km.shape[0]
     masked = mask_bias is not None
+    role = _roles(window, group)[1]
 
     dq_specs = [
         pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
-        pl.BlockSpec((None, kl, d), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((None, kl, dv), lambda i, j: (i, 0, 0)),
+        pl.BlockSpec((None, kl, d), lambda i, j: (_kv_row(i, group), 0, 0)),
+        pl.BlockSpec((None, kl, dv), lambda i, j: (_kv_row(i, group), 0, 0)),
         pl.BlockSpec((None, block_q, dv), lambda i, j: (i, j, 0)),
         pl.BlockSpec((None, 1, block_q), lambda i, j: (i, 0, j)),
         pl.BlockSpec((None, 1, block_q), lambda i, j: (i, 0, j)),
@@ -374,11 +481,11 @@ def _bwd_call(qm, km, vm, dom, lse, delta, causal, block_q, block_kv,
         dq_ops.append(seed)
     # dq and dk/dv under the one role: a trace sums them
     dq = kernel_call(
-        "flash_attention_stream_bwd",
+        role,
         functools.partial(_flash_bwd_dq_kernel, kv_len=kl,
                           block_kv=block_kv, sm_scale=sm_scale,
                           causal=causal, q_block=block_q, masked=masked,
-                          dropout_p=dropout_p),
+                          dropout_p=dropout_p, window=window),
         grid=(bh, ql // block_q),
         in_specs=dq_specs,
         out_specs=pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
@@ -395,6 +502,10 @@ def _bwd_call(qm, km, vm, dom, lse, delta, causal, block_q, block_kv,
         pl.BlockSpec((None, 1, ql), lambda i, j: (i, 0, 0)),
     ]
     dkv_ops = [qm, km, vm, dom, lse, delta]
+    if group > 1:
+        return dq, *_grouped_dkv_call(
+            dkv_ops, group, role, causal, block_q, block_kv, sm_scale,
+            window)
     if masked:
         dkv_specs.append(
             pl.BlockSpec((None, 1, block_kv),
@@ -404,11 +515,11 @@ def _bwd_call(qm, km, vm, dom, lse, delta, causal, block_q, block_kv,
         dkv_specs.append(pl.BlockSpec((1, 1), lambda i, j: (0, 0)))
         dkv_ops.append(seed)
     dk, dv = kernel_call(
-        "flash_attention_stream_bwd",
+        role,
         functools.partial(_flash_bwd_dkv_kernel, q_len=ql, block_q=block_q,
                           sm_scale=sm_scale, causal=causal,
                           kv_block=block_kv, masked=masked,
-                          dropout_p=dropout_p),
+                          dropout_p=dropout_p, window=window),
         grid=(bh, kl // block_kv),
         in_specs=dkv_specs,
         out_specs=[
@@ -424,7 +535,62 @@ def _bwd_call(qm, km, vm, dom, lse, delta, causal, block_q, block_kv,
     return dq, dk, dv
 
 
-def _flash_attention_core_bwd(causal, block_q, block_kv, res, dout):
+def _grouped_dkv_call(ops, group, role, causal, block_q, block_kv,
+                      sm_scale, window):
+    """dK, dV (batch x key heads, kl, .) of ``group`` query heads to a
+    key head: grid (key heads, group, kv blocks), a query head's Q, dO
+    and statistics resident over its kv blocks, the key head's whole dK,
+    dV resident as the output block and as float32 scratch over the
+    group (see :func:`_flash_bwd_dkv_kernel`)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    qm, km, vm = ops[:3]
+    ql, d = qm.shape[1:]
+    rows, kl, dv = km.shape[0], km.shape[1], vm.shape[2]
+
+    def head(i, g, j):
+        return (i * group + g, 0, 0)
+
+    def block(i, g, j):
+        return (i, j, 0)
+
+    def whole(i, g, j):
+        return (i, 0, 0)
+
+    size = qm.dtype.itemsize
+    return kernel_call(
+        role,
+        functools.partial(_flash_bwd_dkv_kernel, q_len=ql, block_q=block_q,
+                          sm_scale=sm_scale, causal=causal,
+                          kv_block=block_kv, window=window, group=group),
+        grid=(rows, group, kl // block_kv),
+        in_specs=[
+            pl.BlockSpec((None, ql, d), head),
+            pl.BlockSpec((None, block_kv, d), block),
+            pl.BlockSpec((None, block_kv, dv), block),
+            pl.BlockSpec((None, ql, dv), head),
+            pl.BlockSpec((None, 1, ql), head),
+            pl.BlockSpec((None, 1, ql), head),
+        ],
+        out_specs=[
+            pl.BlockSpec((None, kl, d), whole),
+            pl.BlockSpec((None, kl, dv), whole),
+        ],
+        out_shape=[
+            _sds((rows, kl, d), km.dtype, qm),
+            _sds((rows, kl, dv), vm.dtype, qm),
+        ],
+        scratch_shapes=[pltpu.VMEM((kl, d), _F32),
+                        pltpu.VMEM((kl, dv), _F32)],
+        # beside the resident Q and dO: the output blocks, double
+        # buffered, and the float32 scratch
+        compiler_params=_stream_params(
+            ql, d, dv, size, extra=kl * (d + dv) * (2 * size + 4)),
+    )(*ops)
+
+
+def _flash_attention_core_bwd(causal, block_q, block_kv, window, res, dout):
     qm, km, vm, out_m, lse, b, h = res
     d = qm.shape[-1]
     sm_scale = 1.0 / math.sqrt(d)
@@ -432,9 +598,10 @@ def _flash_attention_core_bwd(causal, block_q, block_kv, res, dout):
     delta = jnp.sum(dom.astype(_F32) * out_m.astype(_F32),
                     axis=-1)[:, None, :]                     # (bh, 1, ql)
     dq, dk, dv = _bwd_call(qm, km, vm, dom, lse, delta, causal, block_q,
-                           block_kv, sm_scale)
-    return (_splitheads(dq, b, h), _splitheads(dk, b, h),
-            _splitheads(dv, b, h))
+                           block_kv, sm_scale, window=window)
+    hkv = km.shape[0] // b
+    return (_splitheads(dq, b, h), _splitheads(dk, b, hkv),
+            _splitheads(dv, b, hkv))
 
 
 _flash_attention_core.defvjp(_flash_attention_core_fwd,
@@ -802,11 +969,15 @@ def _pick_blocks(ql, kl, block_q, block_kv):
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q",
-                                             "block_kv"))
+                                             "block_kv", "window"))
 def _flash_attention_pallas(q, k, v, causal=False, block_q=512,
-                            block_kv=512):
+                            block_kv=512, window=None):
+    # a windowed launch keeps the 512-wide blocks: at 2 x 8,192 x 32/4 x
+    # 128 with a 1,024 window, forward + backward took 15.3 ms at 512,
+    # 20.0 at 256 and 41.6 at 128 (a v5e, PR 31): a grid step costs about
+    # 2.5 us beside its blocks, more than the narrower band saves
     bq, bkv = _pick_blocks(q.shape[1], k.shape[1], block_q, block_kv)
-    return _flash_attention_core(q, k, v, causal, bq, bkv)
+    return _flash_attention_core(q, k, v, causal, bq, bkv, window)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q",
@@ -860,7 +1031,7 @@ def _pallas_ok(q, k, causal, seq_floor=256, v=None):
     return (ql >= seq_floor and kl >= seq_floor and
             ql % 128 == 0 and kl % 128 == 0 and d % 64 == 0 and
             d <= 256 and kl <= 8192 and ql <= 8192 and
-            (not causal or ql == kl))
+            (not causal or ql == kl) and h % k.shape[2] == 0)
 
 
 def _auto_note():
@@ -894,25 +1065,36 @@ def _rng_seed_arr(key_rng):
     return jax.lax.bitcast_convert_type(bits, jnp.int32)
 
 
-def _work(kind, q, k, v, causal):
+def band_pairs(length, window):
+    """(query, key) pairs with ``0 <= i - j < window`` among ``length``
+    positions: the first W rows' triangle, then W a row."""
+    w = min(window, length)
+    return w * (w + 1) // 2 + (length - w) * w
+
+
+def _work(kind, q, k, v, causal, window=None):
     """``work=`` / ``grad_work=`` of one call of the ``kind`` ("short" or
-    "stream") kernels, for the ledger in ``counters``. Forward: Q K^T
-    and P V, 4 B A Sq Sk D; backward: dV, dP, dQ, dK, 8 B A Sq Sk D (the
-    recomputed scores not counted); half of each under a causal mask.
-    Bytes: q, k, v read, the output and the f32 logsumexp written;
-    backward reads those with the output's cotangent and writes dq, dk,
-    dv."""
+    "stream") kernels, for the ledger in ``counters``, under the roles
+    the launches carry (:func:`_roles` for the streaming ones: a group's
+    or a window's forward and backward add up under one role).
+    Forward: Q K^T and P V, 4 B A Sq Sk D; backward: dV, dP, dQ, dK,
+    8 B A Sq Sk D (the recomputed scores not counted); half of each
+    under a causal mask, and with a window the band's own pairs
+    (:func:`band_pairs`). Bytes: q, k, v read, the output and the f32
+    logsumexp written; backward reads those with the output's cotangent
+    and writes dq, dk, dv. K and V count as they are: once a key head,
+    however many query heads read them."""
     b, ql, a, d = q.shape
+    pairs = band_pairs(ql, window) if window is not None \
+        else (0.5 if causal else 1.0) * ql * k.shape[1]
     # Q K^T is d wide and P V is v's width (the same, but for MLA)
-    mm = (0.5 if causal else 1.0) * b * a * ql * k.shape[1] \
-        * (d + v.shape[-1]) / 2
+    mm = b * a * pairs * (d + v.shape[-1]) / 2
     out = b * ql * a * v.shape[-1] * q.dtype.itemsize
     qkv, lse = nbytes(q, k, v), 4 * b * a * ql
-    return {
-        "work": {f"flash_attention_{kind}_fwd":
-                 (4.0 * mm, qkv + out + lse)},
-        "grad_work": {f"flash_attention_{kind}_bwd":
-                      (8.0 * mm, 2 * qkv + 2 * out + lse)}}
+    fwd, bwd = ("flash_attention_short_fwd", "flash_attention_short_bwd") \
+        if kind == "short" else _roles(window, a // k.shape[2])
+    return {"work": {fwd: (4.0 * mm, qkv + out + lse)},
+            "grad_work": {bwd: (8.0 * mm, 2 * qkv + 2 * out + lse)}}
 
 
 def _bump_short(q, k, v, causal):
@@ -956,6 +1138,65 @@ def _local_attention(q, k, v, is_causal):
          f"dispatch ineligible (q {tuple(q.shape)}, causal="
          f"{is_causal}; floor/modulus in _pallas_ok{_auto_note()})")
     return _xla_attention(q, k, v, None, 0.0, is_causal, None)
+
+
+def _grouped_attention(q, k, v, mask, dropout_p, is_causal, key_rng,
+                       window):
+    """Attention with fewer key/value heads than query heads, a sliding
+    window, or both. The streaming kernels take them as they are; what
+    they do not take goes to XLA, which batches over the key heads, or
+    (a mask or dropout without a window) through the one-head-a-query
+    paths on K and V repeated to the query heads. Each fallback is
+    counted with its reason."""
+    from ...parallel.ring import active_sequence_parallel
+    from .counters import bump
+
+    group = q.shape[2] // k.shape[2]
+    if q.shape[2] != group * k.shape[2]:
+        raise ValueError(f"{q.shape[2]} query heads are no multiple of "
+                         f"{k.shape[2]} key/value heads")
+    if window is not None and not (is_causal and window >= 1):
+        raise ValueError("a sliding window is the band 0 <= i - j < window "
+                         "of the causal mask: pass is_causal=True and "
+                         "window >= 1")
+    if window is not None and window >= k.shape[1]:
+        window = None                       # the band is the whole triangle
+    if window is None and group == 1:
+        return flash_attention_or_fallback(q, k, v, mask, dropout_p,
+                                           is_causal, key_rng)
+    plain = mask is None and dropout_p == 0.0
+    if active_sequence_parallel() is not None:
+        if window is not None:
+            raise NotImplementedError(
+                "a sliding window under sequence_parallel()")
+        plain = False
+    if plain and _pallas_ok(q, k, is_causal, v=v):
+        out = _flash_attention_pallas(q, k, v, causal=is_causal,
+                                      window=window)
+        bump("flash_attention", "pallas",
+             **_work("stream", q, k, v, is_causal, window))
+        if group > 1:
+            bump("flash_attention", "grouped")
+        if window is not None:
+            bump("flash_attention", "windowed")
+        return out
+    if window is None and not plain:
+        # the masked, dropout and ring paths read one key head a query head
+        bump("flash_attention", "grouped_replicated_kv",
+             f"mask={None if mask is None else tuple(mask.shape)}, "
+             f"dropout_p={dropout_p}, sequence_parallel="
+             f"{active_sequence_parallel() is not None}: K and V repeated "
+             f"{group}x to the query heads")
+        k, v = (jnp.repeat(a, group, axis=2) for a in (k, v))
+        return flash_attention_or_fallback(q, k, v, mask, dropout_p,
+                                           is_causal, key_rng)
+    bump("flash_attention", "xla",
+         f"grouped/windowed dispatch ineligible (q {tuple(q.shape)}, kv "
+         f"heads {k.shape[2]}, window={window}, mask="
+         f"{None if mask is None else tuple(mask.shape)}, dropout_p="
+         f"{dropout_p}; floor/modulus in _pallas_ok{_auto_note()})")
+    return _xla_attention(q, k, v, mask, dropout_p, is_causal, key_rng,
+                          window=window)
 
 
 def _as_kv_padding_mask(mask, b, lk):
@@ -1023,7 +1264,10 @@ def _decompose_concrete_mask(mask, b, lq, lk):
 
 
 def flash_attention_or_fallback(q, k, v, mask=None, dropout_p=0.0,
-                                is_causal=False, key_rng=None):
+                                is_causal=False, key_rng=None, window=None):
+    if window is not None or k.shape[2] != q.shape[2]:
+        return _grouped_attention(q, k, v, mask, dropout_p, is_causal,
+                                  key_rng, window)
     if dropout_p == 0.0:
         # context parallelism: shard the sequence axis over the mesh
         # (ring / Ulysses attention) when a sequence_parallel() scope is

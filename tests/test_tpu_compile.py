@@ -69,6 +69,32 @@ def test_stream_flash_compiles_at_mlas_widths_and_the_sequence_ceiling(
     assert "flash_attention_stream_bwd" in text
 
 
+@pytest.mark.parametrize("precision", [None, "highest"])
+@pytest.mark.parametrize("window", [None, 1024])
+def test_grouped_stream_flash_compiles_at_the_mellum_cells_shapes(
+        one_chip, window, precision):
+    """2 x 8,192 tokens, 32 query heads on 4 key/value heads of 128: the
+    grouped dK/dV launch keeps a key head's whole dK, dV in float32
+    scratch beside a query head's resident Q and dO; under `highest`
+    (chip_smoke.py) the products' operands are float32."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    def loss(q, k, v):
+        return jnp.sum(fa._flash_attention_pallas(
+            q, k, v, causal=True, window=window).astype(F32))
+
+    grads = jax.grad(loss, argnums=(0, 1, 2))
+    with jax.default_matmul_precision(precision or "default"):
+        out = _compile(grads, one_chip, ((2, 8192, 32, 128), BF16),
+                       ((2, 8192, 4, 128), BF16), ((2, 8192, 4, 128), BF16))
+    text = out.as_text()
+    assert "flash_attention_" + ("grouped" if window is None
+                                 else "window") in text
+    # no array of K or V 32 heads wide: (2 * 32, 8192, 128) is Q's alone
+    # (q, dq, out, dout), K and V stay (2 * 4, 8192, 128)
+    assert "bf16[8,8192,128]" in text
+
+
 @pytest.mark.parametrize("shape, precision, blocks", [
     # the Kimi cell's top rung: bfloat16 operands at the default precision
     ((8192, 2304, 20480), "default", (512, 256)),
