@@ -736,26 +736,29 @@ def _kernel_checks():
 
     # -- the dropless expert layer, each rung against a dense loop -----------
     def experts(held, dtype, rungs, t=8192, d=2304, f=1024,
-                num_experts=256, top_k=8, scaling=2.446):
-        """The new cell's layer shapes, one compiled layer through each of
-        its rungs. ``rungs`` maps a number of held experts that the
+                num_experts=256, top_k=8, scaling=2.446,
+                score_func="sigmoid"):
+        """A cell's layer shapes (by default the Kimi cell's), one compiled
+        layer through each of its rungs. ``rungs`` maps a number of held experts that the
         correction bias makes every token's picks to the rows that must
         then run: the count of pairs decides the rung, ``ragged_dot`` on
         a lower one, on the top one where more than twice as many experts
         are held as a token picks, every token through every expert
         (t x held rows) where not."""
-        from paddle_tpu.nn.moe import sparse_moe
+        from paddle_tpu.nn.moe import SCORE_FUNCS, sparse_moe
 
-        name = f"sparse experts {held} of {num_experts} held, rungs " \
+        name = f"sparse experts {held} of {num_experts} held, " \
+               f"{score_func}, {t} x {d} x {f}, rungs " \
                f"{sorted(rungs.values())}, {jnp.dtype(dtype).name}"
 
         def layer(x, router, wg, wu, wd, bias, w):
             out, routing = sparse_moe.raw_fn(
-                x, router, bias, wg, wu, wd, top_k=top_k, scaling=scaling)
+                x, router, bias, wg, wu, wd, top_k=top_k, scaling=scaling,
+                score_func=score_func)
             return jnp.sum(out * w), (out, routing)
 
         def loop(x, router, wg, wu, wd, bias, w):
-            scores = jax.nn.sigmoid(jnp.matmul(
+            scores = SCORE_FUNCS[score_func](jnp.matmul(
                 x.astype(f32), router, precision=jax.lax.Precision.HIGHEST))
             _, picked = jax.lax.top_k(scores + bias, top_k)
             weight = jnp.take_along_axis(scores, picked, axis=1)
@@ -805,6 +808,10 @@ def _kernel_checks():
     experts(8, bf16, {0: 16384, 8: 65536})
     experts(16, f32, {0: 32768, 8: 131072})
     experts(24, f32, {0: 49152, 8: 65536})
+    # the Mellum cell's share at its own shapes: one rung, the dense one
+    # (one gated FFN of width 16 x 896), whatever the routing
+    experts(16, bf16, {0: 262144}, t=16384, f=896, num_experts=64,
+            scaling=1.0, score_func="softmax")
 
     # -- fused embedding bag --------------------------------------------------
     def bag(vocab, d, b, s, dtype, combiner):

@@ -6,7 +6,13 @@
   (``experts_held`` from ``expert_offset``), routes over all of them and
   computes its own experts' part; no token is ever dropped and there is
   no capacity factor. Sorted (token, expert) pairs go through a grouped
-  matrix product (``jax.lax.ragged_dot``).
+  matrix product (``jax.lax.ragged_dot``) on a rung of a ladder of row
+  capacities that the count of pairs picks. The top rung of a share that
+  holds at most twice the experts a token picks is every token through
+  every held expert, as ONE gated FFN of width held x F with the routing
+  weights on its hidden activations (``_every_pair_ffn``): three large
+  products, no loop over experts, a step's time independent of its
+  routing.
 - :class:`MoELayer` is the CAPACITY layer: GShard top-2 softmax gating
   into a static ``(tokens, experts, capacity)`` grid that drops what
   overflows, with the explicit expert-parallel exchange below.
@@ -57,6 +63,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import Mesh, PartitionSpec
 
 from ..framework.op import primitive
@@ -403,22 +410,40 @@ def _row_ladder(pairs: int, experts_held: int, num_experts: int) -> tuple:
 
 
 def _every_pair_ffn(x, weight_by_expert, w_gate, w_up, w_down):
-    """The top rung: every token through every expert held, one expert
-    at a time, weighted by ``weight_by_expert`` (T, H) — zero where the
-    token did not pick the expert. The same rows as sorting all T x H
-    pairs would give the grouped product, without their T x H x D
-    gathered copy."""
-    rows = x.astype(w_gate.dtype)
+    """The top rung: every token through every expert held, as ONE gated
+    FFN of width H x F. ``weight_by_expert`` (T, H) is zero where the
+    token did not pick the expert; it scales the hidden activations (in
+    float32, before they are rounded to the products' type), so the down
+    product contracts expert and width together and the sum over experts
+    happens in its float32 accumulator. The same rows as sorting all
+    T x H pairs would give the grouped product, without their T x H x D
+    gathered copy; no loop over experts, so nothing is stacked for the
+    backward and a recomputed forward stops at the hidden activations."""
+    # pinned for their cotangents' sake: XLA forms each weight gradient
+    # as (H, F, D) and, left free, runs AdamW in that layout on transposed
+    # copies of the weight and both its moments, in and out
+    as_stored = Layout(major_to_minor=(0, 1, 2))
+    w_gate, w_up = (with_layout_constraint(w, as_stored)
+                    for w in (w_gate, w_up))
+    # the rows in the products' type once, and not once for each tile of
+    # both products inside their fusions (6.9 against 5.6 ms a product at
+    # 16,384 x 2304 x 14,336 on a v5e, PERF.md section 6, PR 32)
+    rows = jax.lax.optimization_barrier(x.astype(w_gate.dtype))
 
-    def one(acc, expert):
-        gate, up, down, w = expert
-        y = jnp.matmul(jax.nn.silu(jnp.matmul(rows, gate))
-                       * jnp.matmul(rows, up), down)
-        return acc + y.astype(jnp.float32) * w[:, None], None
+    # recomputed in the backward from the two products' own results: as
+    # a branch of the ladder's switch the rung would else hand its
+    # float32 intermediates over the branch's boundary as residuals
+    @jax.checkpoint
+    def weighted_hidden(gate, up, weight):
+        hidden = jax.nn.silu(gate.astype(jnp.float32)) \
+            * up.astype(jnp.float32) * weight[:, :, None]
+        return hidden.astype(w_down.dtype)
 
-    out, _ = jax.lax.scan(one, jnp.zeros(x.shape, jnp.float32),
-                          (w_gate, w_up, w_down, weight_by_expert.T))
-    return out
+    hidden = weighted_hidden(jnp.einsum("td,hdf->thf", rows, w_gate),
+                             jnp.einsum("td,hdf->thf", rows, w_up),
+                             weight_by_expert)
+    return jnp.einsum("thf,hfd->td", hidden, w_down,
+                      preferred_element_type=jnp.float32)
 
 
 def _grouped_ffn(x, tokens, weights, sizes, w_gate, w_up, w_down, n_tokens):
@@ -464,6 +489,7 @@ def sparse_moe(x, router_w, router_bias, w_gate, w_up, w_down, top_k,
     whatever the autocast level (a rounded score flips picks); the
     experts' products run in the autocast type."""
     from ..amp import amp_dtype, amp_enabled
+    from ..ops.pallas.counters import bump
 
     t = x.shape[0]
     num_experts, held = router_w.shape[1], w_gate.shape[0]
@@ -501,21 +527,27 @@ def sparse_moe(x, router_w, router_bias, w_gate, w_up, w_down, top_k,
         return run
 
     def every_pair(x, weight, w_gate, w_up, w_down):
-        by_expert = jnp.zeros((t, held + 1), jnp.float32).at[
-            token, slot].add(weight)
-        return _every_pair_ffn(x, by_expert[:, :held], w_gate, w_up, w_down)
+        # each token's weight on each held expert: its picks compared
+        # with the experts and summed (no scatter, see ``sizes``); a pick
+        # that is elsewhere (slot ``held``) matches none
+        by_expert = jnp.sum(jnp.where(
+            slot.reshape(t, top_k, 1) == jnp.arange(held),
+            weight.reshape(t, top_k, 1), 0.0), axis=1)
+        return _every_pair_ffn(x, by_expert, w_gate, w_up, w_down)
 
     # The top rung. With no more experts here than a token picks, every
     # pair is every token through every expert: no sort, no gathered copy
-    # of the rows. Up to twice as many experts as picks it still is the
-    # top rung, on T x H rows where the sorted pairs would be T x k: the
-    # TPU's ragged_dot takes as long as its LIVE rows, so a step's time on
-    # the sorted rung follows its routing (19,243-21,373 tokens/s over
-    # twelve seeds of the Mellum cell, 16 held of top 8, PERF.md section
-    # 6, PR 31), and the sort, the gather and the scatter of T x k rows
-    # cost more than the products they feed.
+    # of the rows, one gated FFN of width H x F. Up to twice as many
+    # experts as picks it still is the top rung, on T x H rows where the
+    # sorted pairs would be T x k: the TPU's ragged_dot takes as long as
+    # its LIVE rows, so a step's time on the sorted rung follows its
+    # routing (19,243-21,373 tokens/s over twelve seeds of the Mellum
+    # cell, 16 held of top 8, PERF.md section 6, PR 31), and the sort, the
+    # gather and the scatter of T x k rows cost more than the products
+    # they feed.
     dense_top = held <= 2 * top_k
     top = every_pair if dense_top else at(rungs[-1])
+    bump("sparse_moe", "every_pair" if dense_top else "sorted")
     rung = jnp.sum(count > jnp.asarray(rungs[:-1], jnp.int32))
     out = jax.lax.switch(rung, [at(r) for r in rungs[:-1]] + [top],
                          x, weight, w_gate, w_up, w_down)

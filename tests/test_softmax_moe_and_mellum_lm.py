@@ -7,6 +7,7 @@ kinds, the Kimi file builds what it built before."""
 import hashlib
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -257,3 +258,127 @@ def test_the_top_rung_is_dense_up_to_twice_as_many_experts_as_picks():
             x, gate[e], up[e], down[e], ref._dense) for e in range(top_k))
         np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                    rtol=1e-4, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# the dense top rung: one gated FFN of width held x F
+# ---------------------------------------------------------------------------
+#: held experts [offset, offset + held) of ``experts``, ``tokens`` rows;
+#: ``bias`` is the router's correction on one expert (steers the picks)
+WIDE_RUNG = {
+    "sigmoid, 8 held of top 8": dict(
+        score_func="sigmoid", experts=32, held=8, offset=8, tokens=256),
+    "softmax, 16 held of top 8": dict(
+        score_func="softmax", experts=64, held=16, offset=0, tokens=256),
+    "a token none of whose picks is held": dict(
+        score_func="softmax", experts=64, held=8, offset=24, tokens=256),
+    "an expert no token picked": dict(
+        score_func="softmax", experts=64, held=16, offset=16, tokens=256,
+        bias=(19, -50.0)),
+    "T not a multiple of 256": dict(
+        score_func="sigmoid", experts=32, held=8, offset=0, tokens=200),
+}
+WIDE_TOP_K, WIDE_D, WIDE_F = 8, 32, 16
+
+
+def _wide_case(case):
+    c = WIDE_RUNG[case]
+    rng = np.random.RandomState(11)
+    x = jnp.asarray(rng.randn(c["tokens"], WIDE_D), jnp.float32)
+    router = jnp.asarray(rng.randn(WIDE_D, c["experts"]), jnp.float32)
+    stacks = _experts(rng, c["held"], WIDE_D, WIDE_F)
+    cot = jnp.asarray(rng.randn(c["tokens"], WIDE_D), jnp.float32)
+    bias = jnp.zeros((c["experts"],), jnp.float32)
+    if "bias" in c:
+        bias = bias.at[c["bias"][0]].set(c["bias"][1])
+    return c, (x, router) + stacks, bias, cot
+
+
+def _expert_loop(x, router, gate, up, down, bias, c, dtype):
+    """The layer as a Python loop over its held experts, one plain gated
+    FFN each in ``dtype``, weighted after its down product."""
+    scores = SCORE_FUNCS[c["score_func"]](jnp.matmul(
+        x, router, precision=jax.lax.Precision.HIGHEST))
+    _, picked = jax.lax.top_k(scores + bias, WIDE_TOP_K)
+    weight = jnp.take_along_axis(scores, picked, axis=1)
+    weight = weight / jnp.sum(weight, axis=1, keepdims=True)
+    rows, out = x.astype(dtype), jnp.zeros(x.shape, jnp.float32)
+    for e in range(c["held"]):
+        y = jnp.matmul(jax.nn.silu(jnp.matmul(rows, gate[e].astype(dtype)))
+                       * jnp.matmul(rows, up[e].astype(dtype)),
+                       down[e].astype(dtype))
+        mine = jnp.sum(jnp.where(picked == e + c["offset"], weight, 0.0), 1)
+        out = out + y.astype(jnp.float32) * mine[:, None]
+    return out, picked
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(WIDE_RUNG))
+def test_the_wide_top_rung_matches_a_loop_over_its_experts(case, dtype):
+    """Values and gradients (tokens, router, the three expert stacks) of
+    the top rung against a per-expert loop: float32 to 1e-5, under
+    bfloat16 autocast to ``chip_smoke.py``'s 3e-2 of the largest value
+    (the weight meets the hidden activation, not the expert's output:
+    another place for the one rounding)."""
+    from paddle_tpu import amp
+
+    c, args, bias, cot = _wide_case(case)
+    low = dtype == "bfloat16"
+
+    def wide(*args):
+        with amp.auto_cast(enable=low, level="O1", dtype="bfloat16"):
+            out, routing = sparse_moe.raw_fn(
+                args[0], args[1], bias, *args[2:], top_k=WIDE_TOP_K,
+                expert_offset=c["offset"], score_func=c["score_func"])
+        return jnp.sum(out * cot), (out, routing)
+
+    def loop(*args):
+        out, picked = _expert_loop(*args, bias, c, jnp.dtype(dtype))
+        return jnp.sum(out * cot), (out, picked)
+
+    with jax.default_matmul_precision("highest"):
+        (_, (got, routing)), dgot = jax.value_and_grad(
+            wide, argnums=(0, 1, 2, 3, 4), has_aux=True)(*args)
+        (_, (want, picked)), dwant = jax.value_and_grad(
+            loop, argnums=(0, 1, 2, 3, 4), has_aux=True)(*args)
+    # the dense rung ran: every token through every held expert
+    local = np.asarray(picked) - c["offset"]
+    held_picks = ((local >= 0) & (local < c["held"]))
+    rows = -(-c["tokens"] * c["held"] // 256) * 256
+    assert [int(v) for v in routing] == [held_picks.sum(), rows]
+    if case == "a token none of whose picks is held":
+        none = ~held_picks.any(axis=1)
+        assert 0 < none.sum() < c["tokens"]
+        assert not np.asarray(got)[none].any()
+    if case == "an expert no token picked":
+        idle = c["bias"][0] - c["offset"]
+        assert not (local == idle).any()
+        for g in dgot[2:]:
+            assert not np.asarray(g[idle]).any()
+    tol = 3e-2 if low else 1e-5
+    for name, a, b in zip(("out", "dx", "drouter", "dgate", "dup", "ddown"),
+                          (got,) + dgot, (want,) + dwant):
+        err = float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+        assert err < tol, (name, err)
+
+
+def test_the_differentiated_top_rung_stacks_nothing():
+    """Under the block's recomputation the rung's backward needs the gate
+    and up products again and nothing carried from a loop: its HLO holds
+    no ``while`` and no ``dynamic-update-slice`` (a scan over the experts
+    has both: it stacks each iteration's residuals)."""
+    c, args, bias, cot = _wide_case("softmax, 16 held of top 8")
+
+    def layer(*args):
+        return sparse_moe.raw_fn(
+            args[0], args[1], bias, *args[2:], top_k=WIDE_TOP_K,
+            score_func=c["score_func"])[0]
+
+    lowered = jax.jit(jax.grad(
+        lambda *a: jnp.sum(jax.checkpoint(layer)(*a) * cot),
+        argnums=(0, 1, 2, 3, 4))).lower(*args)
+    unoptimized, compiled = (lowered.as_text(dialect="hlo"),
+                             lowered.compile().as_text())
+    assert re.search(r"\bdot\(", unoptimized)       # instructions read so
+    for text in (unoptimized, compiled):
+        assert not re.findall(r"\b(while|dynamic-update-slice)\(", text)

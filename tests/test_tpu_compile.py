@@ -9,6 +9,7 @@ The topology is described inside a fixture, never at import: only one
 process may load the TPU's library, and every xdist worker imports every
 test file. All such tests live in this one file."""
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -170,3 +171,37 @@ def test_short_flash_compiles_in_the_projections_layout(
              if (" copy(" in line or " transpose(" in line)
              and "s32[1,1]" not in line]         # the seed, to scalar memory
     assert bool(moved) == (fa._short_block_width(h, d) is None), moved
+
+
+def test_the_expert_layers_wide_rung_hands_its_gradients_over_as_stored(
+        one_chip):
+    """The Mellum cell's expert layer (16,384 tokens, 16 of 64 experts of
+    2304 x 896 held, softmax top 8, bfloat16 autocast), differentiated
+    under the block's recomputation: the dense top rung is plain large
+    products (no loop, nothing stacked), and the two (16, 2304, 896)
+    weight gradients leave their products in the weights' own layout.
+    Left free, XLA forms them as (16, 896, 2304) and transposes the
+    weight and both its Adam moments to match, in and out of the update
+    (48 copies of 132 MB a step of that cell)."""
+    from paddle_tpu import amp
+    from paddle_tpu.nn.moe import sparse_moe
+
+    t, d, f, held, experts = 16384, 2304, 896, 16, 64
+
+    def layer(x, router, gate, up, down):
+        with amp.auto_cast(level="O1", dtype="bfloat16"):
+            return sparse_moe.raw_fn(
+                x, router, jnp.zeros((experts,), F32), gate, up, down,
+                top_k=8, score_func="softmax")[0]
+
+    out = _compile(
+        jax.grad(lambda *a: jnp.sum(jax.checkpoint(layer)(*a)),
+                 argnums=(0, 1, 2, 3, 4)), one_chip,
+        ((t, d), BF16), ((d, experts), F32), ((held, d, f), F32),
+        ((held, d, f), F32), ((held, f, d), F32))
+    text = out.as_text()
+    # three products forward, two recomputed, six backward (and the router's)
+    assert len(re.findall(r" convolution\(", text)) >= 11
+    assert not re.findall(r"\b(while|dynamic-update-slice)\(", text)
+    assert not re.findall(
+        rf"\[{held},({d},{f}|{f},{d})\]\S* (copy|transpose)\(", text)
