@@ -33,6 +33,7 @@ from __future__ import annotations
 import faulthandler
 import gc
 import json
+import math
 import os
 import sys
 import time
@@ -734,27 +735,70 @@ def _kernel_checks():
             _close(f"kda chunk d{nm}", a, r, tol_of(f32), fails)
     checks.append(("kda chunk fwd + bwd (1, 8192, 32, 128)", kda_chunks))
 
+    # -- the state-space scan's kernels against the same chunk formula under
+    # XLA, which jax differentiates: the hand-derived backward's oracle -------
+    def ssd_chunks(dtype, b=2, t=8192, heads=64, p=64, groups=8, n=128):
+        name = f"ssd chunk fwd + bwd ({b}, {t}, {heads} x {p}; {groups} " \
+               f"x {n}) {jnp.dtype(dtype).name}"
+
+        def check(fails):
+            from paddle_tpu.ops.pallas import ssd
+
+            u = rnd(1, (b, t, heads * p), dtype)
+            bm = rnd(2, (b, t, groups * n), dtype, n ** -0.5)
+            cm = rnd(3, (b, t, groups * n), dtype)
+            w = rnd(4, (b, t, heads * p), f32)
+            # the family's start: steps log-uniform in [1e-3, 1e-1] around
+            # their bias, rates A in [1, 16]
+            delta = jnp.exp(jax.random.uniform(
+                jax.random.key(5), (b, t, heads), f32, math.log(1e-3),
+                math.log(1e-1)))
+            a = -jax.random.uniform(jax.random.key(6), (heads,), f32, 1.0,
+                                    16.0)
+            if ssd._ineligible(heads * p, groups * n, groups, ssd.CHUNK):
+                fails.append(f"{name}: outside its gate")
+                return
+
+            def run(form):
+                def loss(u, delta, a, bm, cm):
+                    rows = ssd._rows(delta, a, groups, ssd.CHUNK)
+                    return jnp.sum(form(u, bm, cm, rows, ssd.CHUNK)
+                                   .astype(f32) * w)
+                return jax.jit(jax.value_and_grad(
+                    loss, argnums=(0, 1, 2, 3, 4)))(u, delta, a, bm, cm)
+
+            (lk, got), (lx, want) = (run(ssd._pallas_scan),
+                                     run(ssd._xla_scan))
+            _close(f"{name} sum(y w)", lk, lx, tol_of(dtype), fails)
+            for g, r, nm in zip(got, want, ("u", "delta", "A", "B", "C")):
+                _close(f"{name} d{nm}", g, r, tol_of(dtype), fails)
+        checks.append((name, check))
+
+    ssd_chunks(bf16)
+    ssd_chunks(f32, b=1, t=2048)
+
     # -- the dropless expert layer, each rung against a dense loop -----------
     def experts(held, dtype, rungs, t=8192, d=2304, f=1024,
                 num_experts=256, top_k=8, scaling=2.446,
-                score_func="sigmoid"):
+                score_func="sigmoid", plain=False):
         """A cell's layer shapes (by default the Kimi cell's), one compiled
         layer through each of its rungs. ``rungs`` maps a number of held experts that the
         correction bias makes every token's picks to the rows that must
         then run: the count of pairs decides the rung, ``ragged_dot`` on
         a lower one, on the top one where more than twice as many experts
         are held as a token picks, every token through every expert
-        (t x held rows) where not."""
+        (t x held rows) where not. ``plain`` experts are ``relu(x U)^2
+        D`` and get no gate matrix."""
         from paddle_tpu.nn.moe import SCORE_FUNCS, sparse_moe
 
         name = f"sparse experts {held} of {num_experts} held, " \
-               f"{score_func}, {t} x {d} x {f}, rungs " \
-               f"{sorted(rungs.values())}, {jnp.dtype(dtype).name}"
+               f"{score_func}, {'plain' if plain else 'gated'}, {t} x {d} x {f}, " \
+               f"rungs {sorted(rungs.values())}, {jnp.dtype(dtype).name}"
 
         def layer(x, router, wg, wu, wd, bias, w):
             out, routing = sparse_moe.raw_fn(
-                x, router, bias, wg, wu, wd, top_k=top_k, scaling=scaling,
-                score_func=score_func)
+                x, router, bias, None if plain else wg, wu, wd,
+                top_k=top_k, scaling=scaling, score_func=score_func)
             return jnp.sum(out * w), (out, routing)
 
         def loop(x, router, wg, wu, wd, bias, w):
@@ -766,8 +810,10 @@ def _kernel_checks():
                                                 keepdims=True)
             out = jnp.zeros((t, d), f32)
             for e in range(held):
-                y = jnp.matmul(jax.nn.silu(jnp.matmul(x, wg[e]))
-                               * jnp.matmul(x, wu[e]), wd[e])
+                up = jnp.matmul(x, wu[e])
+                y = jnp.matmul(
+                    jnp.square(jax.nn.relu(up)) if plain else
+                    jax.nn.silu(jnp.matmul(x, wg[e])) * up, wd[e])
                 mine = jnp.sum(jnp.where(picked == e, weight, 0.0), 1)
                 out = out + y.astype(f32) * mine[:, None]
             return jnp.sum(out * w), (out, None)
@@ -797,6 +843,8 @@ def _kernel_checks():
                 _close(f"{name} {rows} out", got, want, tol_of(dtype), fails)
                 for a, r, nm in zip(dgot, dwant,
                                     ("x", "router", "gate", "up", "down")):
+                    if nm == "gate" and plain:
+                        continue        # plain experts have none
                     _close(f"{name} {rows} d{nm}", a, r, tol_of(dtype),
                            fails)
         checks.append((name, check))
@@ -812,6 +860,14 @@ def _kernel_checks():
     # (one gated FFN of width 16 x 896), whatever the routing
     experts(16, bf16, {0: 262144}, t=16384, f=896, num_experts=64,
             scaling=1.0, score_func="softmax")
+    # the Nemotron cell's share at its own shapes: plain relu^2 experts;
+    # one rung, the dense one (a sorted rung of 49,152 rows is over a
+    # third of its 131,072), whatever the routing; and the sorted rungs
+    # of plain experts at a router twice as wide
+    experts(8, bf16, {0: 131072}, t=16384, d=2688, f=1856,
+            num_experts=128, top_k=6, scaling=2.5, plain=True)
+    experts(8, bf16, {0: 24576, 6: 131072}, t=16384, d=2688, f=1856,
+            num_experts=256, top_k=6, scaling=2.5, plain=True)
 
     # -- fused embedding bag --------------------------------------------------
     def bag(vocab, d, b, s, dtype, combiner):

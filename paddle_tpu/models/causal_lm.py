@@ -2,30 +2,51 @@
 
 ``CausalLM.from_config(cfg)`` reads a Hugging Face style ``config.json``
 dict and builds: token embedding, ``num_hidden_layers`` pre-norm residual
-blocks (``x += Mix(RMSNorm(x))``, ``x += FFN(RMSNorm(x))``), a final
-RMSNorm and an untied head. Which token mixer and which feed-forward a
-block gets is decided per layer from the config, through two small
-tables below: a new architecture is a config file plus, at most, a new
-layer kind registered there.
+blocks, a final RMSNorm and an untied head. A block is a token mixer AND
+a feed-forward (``x += Mix(RMSNorm(x))``, ``x += FFN(RMSNorm(x))``) or,
+for a config that lays its layers out by ``hybrid_override_pattern``, ONE
+sublayer behind one norm (``x += Sub(RMSNorm(x))``). Which kinds a block
+gets is decided per layer from the config, through the small tables
+below: a new architecture is a config file plus, at most, a new layer
+kind registered there.
 
-    mixer   with ``layer_types``: ``sliding_attention`` / ``full_attention``
+    layout  ``hybrid_override_pattern`` (one character a layer): ``M`` ->
+            mixer ``mamba2``, ``*`` -> mixer ``gqa``, ``E`` -> ffn
+            ``moe``, ``-`` -> ffn ``dense``; that layer's other sublayer
+            is absent. Every other config: a mixer and an ffn a layer
+    mixer   ``mamba2`` -> ``nn.Mamba2Mixer`` (``mamba_num_heads`` heads
+            of ``mamba_head_dim``, ``ssm_state_size``, ``n_groups``,
+            ``conv_kernel``, ``time_step_*``)
+            with ``layer_types``: ``sliding_attention`` / ``full_attention``
             -> ``gqa`` (``nn.GroupedQueryAttention``:
             ``num_key_value_heads`` key heads of ``head_dim``, the
             layer type's entry of ``rope_parameters``, ``sliding_window``
-            on the sliding layers, ``qk_norm``)
+            on the sliding layers, ``qk_norm``); a pattern's ``*`` is a
+            full layer with no per-head norms and no positions unless
+            the file gives a ``rope`` entry
             else ``linear_attn_config.kda_layers`` (1-based) -> ``kda``
             (``nn.KimiDeltaAttention``); every other layer -> ``mla``
             (``nn.MLAttention``, NoPE)
     ffn     with ``mlp_layer_types``: ``sparse`` -> ``moe``, ``dense`` ->
             ``dense``
-            else the first ``first_k_dense_replace`` layers -> ``dense``
-            (``nn.GatedFFN`` of ``intermediate_size``); the others ->
-            ``moe`` (``nn.SparseMoELayer``: ``num_experts_per_token`` or
-            ``num_experts_per_tok`` of the router's experts,
-            ``num_shared_experts`` shared; scores by
+            else the first ``first_k_dense_replace`` layers -> ``dense``;
+            the others -> ``moe``
+            ``dense``: ``nn.GatedFFN`` of ``intermediate_size``
+            (``hidden_act``), or for a config that says
+            ``mlp_hidden_act`` (``nemotron_h``) ``nn.PlainFFN``
+            ``moe``: ``nn.SparseMoELayer``: ``num_experts_per_token`` or
+            ``num_experts_per_tok`` of the router's ``num_experts`` or
+            ``n_routed_experts``; ``num_shared_experts`` or
+            ``n_shared_experts`` shared (of
+            ``moe_shared_expert_intermediate_size`` where the file says
+            it); experts gated, or plain relu^2 ones for a config that
+            says ``mlp_hidden_act``; scores by
             ``moe_router_activation_func``, or softmax for a config that
-            says ``norm_topk_prob``, which is then the renormalisation)
+            lacks it and says ``norm_topk_prob``, which is then the
+            renormalisation (``nemotron_h`` says ``norm_topk_prob`` and
+            scores by sigmoid: its file has to say so)
 
+The norms' epsilon is ``rms_norm_eps`` or ``layer_norm_epsilon``.
 A chip's share of an expert-parallel deployment is said with
 ``experts_held`` / ``expert_offset`` (the router keeps all its outputs).
 ``loss`` goes through the fused vocabulary cross-entropy, so the
@@ -42,23 +63,72 @@ from ..nn import functional as F
 from ..nn.moe import SCORE_FUNCS
 
 
+def _first(cfg, *names, default=None):
+    """The value of the first of ``names`` the config has."""
+    for name in names:
+        if name in cfg:
+            return cfg[name]
+    return default
+
+
+def _eps(cfg):
+    return _first(cfg, "rms_norm_eps", "layer_norm_epsilon")
+
+
+#: a ``hybrid_override_pattern`` character -> (mixer kind, ffn kind)
+_PATTERN = {"M": ("mamba2", None), "*": ("gqa", None),
+            "E": (None, "moe"), "-": (None, "dense")}
+
+
+def _pattern_kinds(cfg, layer):
+    char = cfg["hybrid_override_pattern"][layer - 1]
+    if char not in _PATTERN:
+        raise NotImplementedError(
+            f"hybrid_override_pattern character {char!r} at layer {layer}: "
+            f"{sorted(_PATTERN)} are built")
+    return _PATTERN[char]
+
+
 #: ``layer_types`` entries that the ``gqa`` mixer builds
 _GQA_LAYER_TYPES = ("sliding_attention", "full_attention")
 
 
 def _mixer_gqa(cfg, layer):
-    kind = cfg["layer_types"][layer - 1]
-    rope = cfg.get("rope_parameters")
-    if rope is not None and "rope_theta" not in rope:
-        rope = rope[kind]                   # one entry a layer type
+    if "hybrid_override_pattern" in cfg:
+        # nemotron_h's attention: full, no per-head norms, and no
+        # positions (the state-space layers carry them) unless the file
+        # gives a ``rope`` entry
+        kind, rope, qk_norm = ("full_attention", cfg.get("rope"),
+                               cfg.get("qk_norm", False))
+    else:
+        kind = cfg["layer_types"][layer - 1]
+        rope, qk_norm = cfg.get("rope_parameters"), cfg.get("qk_norm", True)
+        if rope is not None and "rope_theta" not in rope:
+            rope = rope[kind]               # one entry a layer type
     heads = cfg["num_attention_heads"]
     return nn.GroupedQueryAttention(
         cfg["hidden_size"], heads, cfg.get("num_key_value_heads", heads),
         cfg.get("head_dim", cfg["hidden_size"] // heads),
         window=cfg["sliding_window"] if kind == "sliding_attention"
         else None,
-        rope=rope, qk_norm=cfg.get("qk_norm", True),
-        epsilon=cfg["rms_norm_eps"])
+        rope=rope, qk_norm=qk_norm, epsilon=_eps(cfg))
+
+
+def _mixer_mamba2(cfg, layer):
+    if not cfg.get("use_conv_bias", True) or cfg.get("mamba_proj_bias") \
+            or cfg.get("mamba_hidden_act", "silu") != "silu":
+        raise NotImplementedError(
+            "nn.Mamba2Mixer has a convolution bias, no projection bias "
+            "and SiLU")
+    # the inner width is heads x head width (the family's modelling
+    # code), not ``expand`` x hidden; ``chunk_size`` is the kernel's
+    return nn.Mamba2Mixer(
+        cfg["hidden_size"], cfg["mamba_num_heads"], cfg["mamba_head_dim"],
+        cfg["ssm_state_size"], groups=cfg["n_groups"],
+        conv_size=cfg["conv_kernel"], epsilon=_eps(cfg),
+        time_step=(cfg.get("time_step_min", 1e-3),
+                   cfg.get("time_step_max", 1e-1)),
+        time_step_floor=cfg.get("time_step_floor", 1e-4))
 
 
 def _mixer_kda(cfg, layer):
@@ -80,40 +150,57 @@ def _mixer_mla(cfg, layer):
 
 
 def _ffn_dense(cfg):
+    if "mlp_hidden_act" in cfg:
+        return nn.PlainFFN(cfg["hidden_size"], cfg["intermediate_size"],
+                           activation=cfg["mlp_hidden_act"])
     return nn.GatedFFN(cfg["hidden_size"], cfg["intermediate_size"],
                        activation=cfg["hidden_act"])
 
 
 def _ffn_moe(cfg):
-    # a config that says norm_topk_prob follows the Qwen-MoE convention:
-    # softmax over all experts, then the top k, renormalised if it says so
+    # a file that does not say how its router scores and says
+    # norm_topk_prob follows the Qwen-MoE convention: softmax over all
+    # experts, then the top k, renormalised if it says so
     score = cfg.get("moe_router_activation_func",
                     "softmax" if "norm_topk_prob" in cfg else "sigmoid")
     if score not in SCORE_FUNCS:
         raise NotImplementedError(
             f"router scores {score!r}: {sorted(SCORE_FUNCS)} are built "
             "(nn.moe.SCORE_FUNCS)")
-    if cfg.get("num_expert_group", 1) != 1 or cfg.get("topk_group", 1) != 1:
+    if _first(cfg, "num_expert_group", "n_group", default=1) != 1 \
+            or cfg.get("topk_group", 1) != 1:
         raise NotImplementedError("group-limited routing")
-    held = cfg.get("experts_held", cfg["num_experts"])
-    shared = cfg.get("num_shared_experts", 0) * cfg["moe_intermediate_size"]
+    experts = _first(cfg, "num_experts", "n_routed_experts")
+    width = cfg["moe_intermediate_size"]
+    shared = _first(cfg, "num_shared_experts", "n_shared_experts",
+                    default=0) * cfg.get(
+        "moe_shared_expert_intermediate_size", width)
+    # a file with ``mlp_hidden_act`` has plain experts, relu(x U)^2 D
+    plain = "mlp_hidden_act" in cfg
+    if plain and cfg["mlp_hidden_act"] != "relu2":
+        raise NotImplementedError(
+            f"plain experts of {cfg['mlp_hidden_act']!r}: relu2 is built")
     return nn.SparseMoELayer(
-        cfg["hidden_size"], cfg["moe_intermediate_size"],
-        cfg["num_experts"],
-        cfg.get("num_experts_per_token", cfg.get("num_experts_per_tok")),
-        experts_held=held, expert_offset=cfg.get("expert_offset", 0),
+        cfg["hidden_size"], width, experts,
+        _first(cfg, "num_experts_per_token", "num_experts_per_tok"),
+        experts_held=cfg.get("experts_held", experts),
+        expert_offset=cfg.get("expert_offset", 0),
         scaling=cfg.get("routed_scaling_factor", 1.0),
         renormalize=cfg.get("moe_renormalize",
                             cfg.get("norm_topk_prob", True)),
-        shared_width=shared or None, score_func=score)
+        shared_width=shared or None, score_func=score, gated=not plain)
 
 
-MIXERS = {"gqa": _mixer_gqa, "kda": _mixer_kda, "mla": _mixer_mla}
+MIXERS = {"gqa": _mixer_gqa, "kda": _mixer_kda, "mamba2": _mixer_mamba2,
+          "mla": _mixer_mla}
 FFNS = {"dense": _ffn_dense, "moe": _ffn_moe}
 
 
-def mixer_kind(cfg, layer: int) -> str:
-    """``layer`` counts from 1, as the config's layer lists do."""
+def mixer_kind(cfg, layer: int):
+    """``layer`` counts from 1, as the config's layer lists do. None for
+    a pattern's layer that is a feed-forward alone."""
+    if "hybrid_override_pattern" in cfg:
+        return _pattern_kinds(cfg, layer)[0]
     if "layer_types" in cfg:
         kind = cfg["layer_types"][layer - 1]
         if kind not in _GQA_LAYER_TYPES:
@@ -124,7 +211,10 @@ def mixer_kind(cfg, layer: int) -> str:
     return "kda" if layer in lin.get("kda_layers", ()) else "mla"
 
 
-def ffn_kind(cfg, layer: int) -> str:
+def ffn_kind(cfg, layer: int):
+    """None for a pattern's layer that is a token mixer alone."""
+    if "hybrid_override_pattern" in cfg:
+        return _pattern_kinds(cfg, layer)[1]
     if "mlp_layer_types" in cfg:
         return {"sparse": "moe", "dense": "dense"}[
             cfg["mlp_layer_types"][layer - 1]]
@@ -135,23 +225,38 @@ def ffn_kind(cfg, layer: int) -> str:
 
 
 class DecoderBlock(nn.Layer):
+    """``input_norm, mixer, post_norm, ffn``; or, where the layout gives
+    the layer ONE sublayer, ``norm`` and ``mixer`` or ``ffn``."""
+
     def __init__(self, cfg, layer: int):
         super().__init__()
-        eps = cfg["rms_norm_eps"]
+        eps, hidden = _eps(cfg), cfg["hidden_size"]
         self.mixer_kind, self.ffn_kind = (mixer_kind(cfg, layer),
                                           ffn_kind(cfg, layer))
-        self.input_norm = nn.RMSNorm(cfg["hidden_size"], epsilon=eps)
-        self.mixer = MIXERS[self.mixer_kind](cfg, layer)
-        self.post_norm = nn.RMSNorm(cfg["hidden_size"], epsilon=eps)
-        self.ffn = FFNS[self.ffn_kind](cfg)
+        if self.mixer_kind and self.ffn_kind:
+            self.input_norm = nn.RMSNorm(hidden, epsilon=eps)
+            self.mixer = MIXERS[self.mixer_kind](cfg, layer)
+            self.post_norm = nn.RMSNorm(hidden, epsilon=eps)
+            self.ffn = FFNS[self.ffn_kind](cfg)
+        else:
+            self.norm = nn.RMSNorm(hidden, epsilon=eps)
+            if self.mixer_kind:
+                self.mixer = MIXERS[self.mixer_kind](cfg, layer)
+            else:
+                self.ffn = FFNS[self.ffn_kind](cfg)
 
     def forward(self, x):
         """(x, routing): ``routing`` is the expert layer's [pairs on held
-        experts, rows of the rung that ran], zeros for a dense block."""
+        experts, rows of the rung that ran], zeros for a block without
+        one."""
         from .. import ops
 
-        x = x + self.mixer(self.input_norm(x))
-        x = x + self.ffn(self.post_norm(x))
+        if self.mixer_kind and self.ffn_kind:
+            x = x + self.mixer(self.input_norm(x))
+            x = x + self.ffn(self.post_norm(x))
+        else:
+            sub = self.mixer if self.mixer_kind else self.ffn
+            x = x + sub(self.norm(x))
         routing = self.ffn.last_routing if self.ffn_kind == "moe" \
             else ops.zeros([2], "float32")
         return x, routing
@@ -175,8 +280,7 @@ class CausalLM(nn.Layer):
         self.layers = nn.LayerList([
             DecoderBlock(cfg, n + 1)
             for n in range(cfg["num_hidden_layers"])])
-        self.final_norm = nn.RMSNorm(cfg["hidden_size"],
-                                     epsilon=cfg["rms_norm_eps"])
+        self.final_norm = nn.RMSNorm(cfg["hidden_size"], epsilon=_eps(cfg))
         # (vocabulary, hidden): the layout the fused cross-entropy streams
         self.head = self.create_parameter(
             [cfg["vocab_size"], cfg["hidden_size"]], attr=init)

@@ -4,7 +4,7 @@ from . import initializer  # noqa: F401
 from .layer import Layer, Parameter, ParamAttr  # noqa: F401
 from .container import Sequential, LayerList, LayerDict, ParameterList  # noqa: F401
 from .common import (  # noqa: F401
-    Identity, Linear, GatedFFN, Embedding, Dropout, Dropout2D, Dropout3D, AlphaDropout,
+    Identity, Linear, GatedFFN, PlainFFN, Embedding, Dropout, Dropout2D, Dropout3D, AlphaDropout,
     Flatten, Upsample, UpsamplingBilinear2D, UpsamplingNearest2D,
     PixelShuffle, Pad1D, Pad2D, Pad3D, CosineSimilarity, Bilinear,
     ReLU, ReLU6, LeakyReLU, ELU, CELU, SELU, GELU, Silu, Swish, Mish,
@@ -50,6 +50,7 @@ from .moe import (  # noqa: F401
 )
 from .linear_attention import KimiDeltaAttention  # noqa: F401
 from .grouped_query_attention import GroupedQueryAttention  # noqa: F401
+from .state_space import Mamba2Mixer  # noqa: F401
 from .latent_attention import MLAttention  # noqa: F401
 from .crf import LinearChainCRF, crf_decoding, linear_chain_crf  # noqa: F401,E402
 

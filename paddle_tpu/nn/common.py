@@ -36,7 +36,8 @@ class Linear(Layer):
 
 class GatedFFN(Layer):
     """(act(x W_gate) * x W_up) W_down, no biases: the feed-forward of
-    the gated decoder blocks (dense layers, shared and routed experts)."""
+    the gated decoder blocks (dense layers, and the shared expert of a
+    mixture whose routed experts are gated)."""
 
     def __init__(self, d_model, d_hidden, activation="silu"):
         super().__init__()
@@ -47,6 +48,20 @@ class GatedFFN(Layer):
 
     def forward(self, x):
         return self.down_proj(self._act(self.gate_proj(x)) * self.up_proj(x))
+
+
+class PlainFFN(Layer):
+    """act(x W_up) W_down, no biases and no gate: ``activation`` is a
+    name of ``nn.functional`` (``relu2`` among them: relu, squared)."""
+
+    def __init__(self, d_model, d_hidden, activation="relu2"):
+        super().__init__()
+        self.up_proj = Linear(d_model, d_hidden, bias_attr=False)
+        self.down_proj = Linear(d_hidden, d_model, bias_attr=False)
+        self._act = getattr(F, activation)
+
+    def forward(self, x):
+        return self.down_proj(self._act(self.up_proj(x)))
 
 
 class Embedding(Layer):

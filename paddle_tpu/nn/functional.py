@@ -30,6 +30,12 @@ def relu(x, name=None):
     return jax.nn.relu(x)
 
 
+@primitive("relu2")
+def relu2(x, name=None):
+    """relu(x) squared."""
+    return jnp.square(jax.nn.relu(x))
+
+
 @primitive("relu6")
 def relu6(x, name=None):
     return jnp.clip(x, 0.0, 6.0)
@@ -339,6 +345,18 @@ def _conv(x, weight, bias, stride, padding, dilation, groups, n, channel_last):
         bshape[-1 if channel_last else 1] = bias.shape[0]
         out = out + bias.reshape(bshape)
     return out
+
+
+def short_conv(x, taps, bias=None):
+    """Causal depthwise convolution along the sequence, on jax arrays
+    (the token mixers call it inside their primitives): x (B, T, C), taps
+    (W, C), bias (C,) or None; the last tap multiplies the current
+    token."""
+    width = taps.shape[0]
+    t = x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
+    out = sum(padded[:, j:j + t] * taps[j] for j in range(width))
+    return out if bias is None else out + bias
 
 
 @primitive("conv1d")

@@ -24,6 +24,7 @@ import numpy as np
 
 from ..framework.op import primitive
 from .common import Linear
+from .functional import short_conv
 from .layer import Layer
 
 __all__ = ["KimiDeltaAttention", "kda_mix"]
@@ -31,15 +32,6 @@ __all__ = ["KimiDeltaAttention", "kda_mix"]
 _F32 = jnp.float32
 #: added to the squared norm under the L2 normalisation of q and k
 L2_EPS = 1e-6
-
-
-def _short_conv(x, taps):
-    """Causal depthwise convolution: x (B, T, C), taps (W, C); the last
-    tap multiplies the current token."""
-    width = taps.shape[0]
-    t = x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
-    return sum(padded[:, j:j + t] * taps[j] for j in range(width))
 
 
 def _l2norm(x):
@@ -62,8 +54,8 @@ def kda_mix(q, k, v, q_taps, k_taps, v_taps, decay, a_log, dt_bias,
         return x.reshape(b, t, num_heads, d)
 
     def mixed(x, taps):
-        return heads(jax.nn.silu(_short_conv(x.astype(_F32),
-                                             taps.astype(_F32))))
+        return heads(jax.nn.silu(short_conv(x.astype(_F32),
+                                            taps.astype(_F32))))
 
     # the float32 element-wise chains on either side of the recurrence
     # are recomputed in the backward from their (autocast-typed) inputs:

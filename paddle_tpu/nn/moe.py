@@ -2,17 +2,21 @@
 
 - :class:`SparseMoELayer` is the DROPLESS layer: sigmoid or softmax
   scores over all experts, top-k of any k, renormalised and scaled
-  weights, an optional shared expert, gated (SwiGLU) experts. It is told which experts it holds
+  weights, an optional shared expert; experts gated, ``(silu(x G) * x U)
+  D``, or with ``w_gate`` absent plain, ``relu(x U)^2 D``. It is told
+  which experts it holds
   (``experts_held`` from ``expert_offset``), routes over all of them and
   computes its own experts' part; no token is ever dropped and there is
   no capacity factor. Sorted (token, expert) pairs go through a grouped
   matrix product (``jax.lax.ragged_dot``) on a rung of a ladder of row
   capacities that the count of pairs picks. The top rung of a share that
   holds at most twice the experts a token picks is every token through
-  every held expert, as ONE gated FFN of width held x F with the routing
+  every held expert, as ONE FFN of width held x F with the routing
   weights on its hidden activations (``_every_pair_ffn``): three large
-  products, no loop over experts, a step's time independent of its
-  routing.
+  products for gated experts, two for plain ones, no loop over experts,
+  a step's time independent of its routing. Under it a sorted rung
+  stays only at a third of its rows or fewer (``_row_ladder``): a share
+  whose lowest sorted rung would be wider runs the dense rung alone.
 - :class:`MoELayer` is the CAPACITY layer: GShard top-2 softmax gating
   into a static ``(tokens, experts, capacity)`` grid that drops what
   overflows, with the explicit expert-parallel exchange below.
@@ -391,7 +395,8 @@ class MoELayer(Layer):
 # ---------------------------------------------------------------------------
 # the dropless layer
 # ---------------------------------------------------------------------------
-def _row_ladder(pairs: int, experts_held: int, num_experts: int) -> tuple:
+def _row_ladder(pairs: int, experts_held: int, num_experts: int,
+                dense_rows: int = 0) -> tuple:
     """The static row capacities the grouped product may run at,
     ascending, in whole 256-row blocks. The top rung holds every (token,
     expert) pair there can be, so nothing is ever dropped; the lowest is
@@ -399,19 +404,48 @@ def _row_ladder(pairs: int, experts_held: int, num_experts: int) -> tuple:
     each rung is four times the one below: uneven routing (an expert's
     load follows its tokens' frequencies) then moves the rung seldom,
     and a step's time hardly depends on its data, at the price of rows
-    that hold no pair. All of it follows from the shapes."""
+    that hold no pair. Where the top rung is the dense one, every token
+    through every held expert on ``dense_rows`` rows, a sorted rung
+    stays only at a third of those rows or fewer: a sorted row costs
+    what 2.5 to 3.3 dense rows cost (its gather, its selects and its
+    float32 scatter-add, PERF.md section 7.10) and its product takes as
+    long as its live rows, so above a third it is no cheaper than the
+    dense rung and makes a step's time follow its routing. All of it
+    follows from the shapes."""
     top = -(-pairs // 256) * 256
     rung = -(-8 * pairs * experts_held // (num_experts * 256)) * 256
     rungs = []
-    while rung < top:
+    while rung < top and (not dense_rows or 3 * rung <= dense_rows):
         rungs.append(rung)
         rung *= 4
     return tuple(rungs) + (top,)
 
 
-def _every_pair_ffn(x, weight_by_expert, w_gate, w_up, w_down):
-    """The top rung: every token through every expert held, as ONE gated
-    FFN of width H x F. ``weight_by_expert`` (T, H) is zero where the
+def _hidden(gate, up, of):
+    """An expert's hidden activations: gated, ``silu(of(gate)) *
+    of(up)``, or with no gate plain, ``relu(of(up))^2``. ``of`` makes the
+    operand a rung computes on out of what it holds."""
+    if gate is None:
+        return jnp.square(jax.nn.relu(of(up)))
+    return jax.nn.silu(of(gate)) * of(up)
+
+
+def _stored_layout(w, device=None):
+    """The order of dimensions in which the device keeps an array of
+    ``w``'s shape and type, as a :class:`Layout`: not always the array's
+    own (a v5e keeps a float32 (8, 2688, 1856) stack with the 2688 minor,
+    1856 not being whole lanes of 128, and a (16, 2304, 896) one as it
+    is)."""
+    device = jax.devices()[0] if device is None else device
+    return Layout(major_to_minor=Layout.from_pjrt_layout(
+        device.client.get_default_layout(w.dtype, w.shape, device)
+    ).major_to_minor)
+
+
+def _every_pair_ffn(x, weight_by_expert, w_gate, w_up, w_down, stored):
+    """The top rung: every token through every expert held, as ONE FFN
+    of width H x F, gated or (``w_gate`` None) plain. ``weight_by_expert``
+    (T, H) is zero where the
     token did not pick the expert; it scales the hidden activations (in
     float32, before they are rounded to the products' type), so the down
     product contracts expert and width together and the sum over experts
@@ -419,35 +453,36 @@ def _every_pair_ffn(x, weight_by_expert, w_gate, w_up, w_down):
     T x H pairs would give the grouped product, without their T x H x D
     gathered copy; no loop over experts, so nothing is stacked for the
     backward and a recomputed forward stops at the hidden activations."""
-    # pinned for their cotangents' sake: XLA forms each weight gradient
-    # as (H, F, D) and, left free, runs AdamW in that layout on transposed
-    # copies of the weight and both its moments, in and out
-    as_stored = Layout(major_to_minor=(0, 1, 2))
-    w_gate, w_up = (with_layout_constraint(w, as_stored)
+    # pinned to ``stored``, the layout the device keeps them in, for
+    # their cotangents' sake: XLA forms each weight gradient as (H, F, D)
+    # and, left free where that is not how the weight is kept, runs AdamW
+    # in that layout on transposed copies of the weight and both its
+    # moments, in and out
+    w_gate, w_up = (w if w is None else with_layout_constraint(w, stored)
                     for w in (w_gate, w_up))
     # the rows in the products' type once, and not once for each tile of
     # both products inside their fusions (6.9 against 5.6 ms a product at
     # 16,384 x 2304 x 14,336 on a v5e, PERF.md section 6, PR 32)
-    rows = jax.lax.optimization_barrier(x.astype(w_gate.dtype))
+    rows = jax.lax.optimization_barrier(x.astype(w_up.dtype))
 
-    # recomputed in the backward from the two products' own results: as
+    # recomputed in the backward from the products' own results: as
     # a branch of the ladder's switch the rung would else hand its
     # float32 intermediates over the branch's boundary as residuals
     @jax.checkpoint
     def weighted_hidden(gate, up, weight):
-        hidden = jax.nn.silu(gate.astype(jnp.float32)) \
-            * up.astype(jnp.float32) * weight[:, :, None]
-        return hidden.astype(w_down.dtype)
+        hidden = _hidden(gate, up, lambda a: a.astype(jnp.float32))
+        return (hidden * weight[:, :, None]).astype(w_down.dtype)
 
-    hidden = weighted_hidden(jnp.einsum("td,hdf->thf", rows, w_gate),
-                             jnp.einsum("td,hdf->thf", rows, w_up),
-                             weight_by_expert)
+    hidden = weighted_hidden(
+        None if w_gate is None else jnp.einsum("td,hdf->thf", rows, w_gate),
+        jnp.einsum("td,hdf->thf", rows, w_up), weight_by_expert)
     return jnp.einsum("thf,hfd->td", hidden, w_down,
                       preferred_element_type=jnp.float32)
 
 
 def _grouped_ffn(x, tokens, weights, sizes, w_gate, w_up, w_down, n_tokens):
-    """Gated FFN of each expert on its own run of the sorted rows, then
+    """FFN of each expert, gated or (``w_gate`` None) plain, on its own
+    run of the sorted rows, then
     the weighted scatter-add back to the tokens. What ``ragged_dot``
     leaves in the rows past the last run is not specified (zeros on the
     CPU, not on the TPU), forward or transposed: those rows are selected
@@ -458,9 +493,9 @@ def _grouped_ffn(x, tokens, weights, sizes, w_gate, w_up, w_down, n_tokens):
     def runs(a):
         return jnp.where(live, a, jnp.zeros((), a.dtype))
 
-    rows = runs(x[tokens].astype(w_gate.dtype))
-    hidden = runs(jax.nn.silu(jax.lax.ragged_dot(rows, w_gate, sizes))
-                  * jax.lax.ragged_dot(rows, w_up, sizes))
+    rows = runs(x[tokens].astype(w_up.dtype))
+    hidden = runs(_hidden(w_gate, w_up,
+                          lambda w: jax.lax.ragged_dot(rows, w, sizes)))
     out = runs(jax.lax.ragged_dot(hidden, w_down, sizes))
     out = out.astype(jnp.float32) * weights[:, None]
     return jnp.zeros((n_tokens, x.shape[-1]), jnp.float32).at[tokens].add(out)
@@ -479,7 +514,9 @@ def sparse_moe(x, router_w, router_bias, w_gate, w_up, w_down, top_k,
 
     ``router_w`` (D, E) scores ALL E experts; ``w_gate`` / ``w_up``
     (H, D, F) and ``w_down`` (H, F, D) are the H experts held here,
-    experts ``expert_offset`` .. ``expert_offset + H - 1``. Returns the
+    experts ``expert_offset`` .. ``expert_offset + H - 1``: gated,
+    ``(silu(x G) * x U) D``, or with ``w_gate`` None plain,
+    ``relu(x U)^2 D``. Returns the
     sum over the picked AND held experts of weight * expert(x), float32,
     and ``[pairs on held experts, rows of the rung that ran]``. Scores are
     ``score_func`` (:data:`SCORE_FUNCS`) of the logits: ``sigmoid`` scores
@@ -492,7 +529,7 @@ def sparse_moe(x, router_w, router_bias, w_gate, w_up, w_down, top_k,
     from ..ops.pallas.counters import bump
 
     t = x.shape[0]
-    num_experts, held = router_w.shape[1], w_gate.shape[0]
+    num_experts, held = router_w.shape[1], w_up.shape[0]
     scores = SCORE_FUNCS[score_func](jnp.matmul(
         x.astype(jnp.float32), router_w.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
@@ -513,9 +550,12 @@ def sparse_moe(x, router_w, router_bias, w_gate, w_up, w_down, top_k,
     sizes = jnp.sum(jax.nn.one_hot(slot, held + 1, dtype=jnp.int32),
                     axis=0)[:held]
     count = jnp.sum(sizes)
-    rungs = _row_ladder(t * min(top_k, held), held, num_experts)
-    if amp_enabled():
-        w_gate, w_up, w_down = (w.astype(amp_dtype())
+    dense_top = held <= 2 * top_k
+    rungs = _row_ladder(t * min(top_k, held), held, num_experts,
+                        t * held if dense_top else 0)
+    stored = _stored_layout(w_up)       # of the stack as it is kept
+    if amp_enabled():       # (plain experts' absent gate stays None)
+        w_gate, w_up, w_down = (w if w is None else w.astype(amp_dtype())
                                 for w in (w_gate, w_up, w_down))
 
     def at(rows):
@@ -533,21 +573,24 @@ def sparse_moe(x, router_w, router_bias, w_gate, w_up, w_down, top_k,
         by_expert = jnp.sum(jnp.where(
             slot.reshape(t, top_k, 1) == jnp.arange(held),
             weight.reshape(t, top_k, 1), 0.0), axis=1)
-        return _every_pair_ffn(x, by_expert, w_gate, w_up, w_down)
+        return _every_pair_ffn(x, by_expert, w_gate, w_up, w_down, stored)
 
     # The top rung. With no more experts here than a token picks, every
     # pair is every token through every expert: no sort, no gathered copy
-    # of the rows, one gated FFN of width H x F. Up to twice as many
+    # of the rows, one FFN of width H x F. Up to twice as many
     # experts as picks it still is the top rung, on T x H rows where the
     # sorted pairs would be T x k: the TPU's ragged_dot takes as long as
     # its LIVE rows, so a step's time on the sorted rung follows its
     # routing (19,243-21,373 tokens/s over twelve seeds of the Mellum
     # cell, 16 held of top 8, PERF.md section 6, PR 31), and the sort, the
     # gather and the scatter of T x k rows cost more than the products
-    # they feed.
-    dense_top = held <= 2 * top_k
+    # they feed. For the same two reasons ``_row_ladder`` keeps a sorted
+    # rung under the dense one only at a third of its rows or fewer
+    # (19,385-20,097 tokens/s over twelve seeds of the Nemotron cell on
+    # a sorted rung of 0.375 of them, section 6, PR 33).
     top = every_pair if dense_top else at(rungs[-1])
     bump("sparse_moe", "every_pair" if dense_top else "sorted")
+    bump("sparse_moe", "plain" if w_gate is None else "gated")
     rung = jnp.sum(count > jnp.asarray(rungs[:-1], jnp.int32))
     out = jax.lax.switch(rung, [at(r) for r in rungs[:-1]] + [top],
                          x, weight, w_gate, w_up, w_down)
@@ -559,20 +602,25 @@ def sparse_moe(x, router_w, router_bias, w_gate, w_up, w_down, top_k,
 
 
 class SparseMoELayer(Layer):
-    """Dropless top-k mixture of gated experts (see the module docstring).
+    """Dropless top-k mixture of experts (see the module docstring).
 
     ``experts_held`` of the ``num_experts`` experts live here, from
     ``expert_offset``; with all of them held this is the whole layer. A
     share's output leaves out what the absent experts would have added;
-    ``shared_width`` adds one always-on expert that every share computes
-    alike. ``forward`` keeps ``[pairs on held experts, rows of the rung
-    that ran]`` of its last call in ``last_routing``."""
+    ``shared_width`` adds one always-on expert, of that width and the
+    routed experts' form, that every share computes alike. ``gated``
+    experts are ``(silu(x G) * x U) D`` (``nn.GatedFFN``), the others
+    plain, ``relu(x U)^2 D`` (``nn.PlainFFN``) with no ``experts_gate``.
+    ``forward`` keeps ``[pairs on
+    held experts, rows of the rung that ran]`` of its last call in
+    ``last_routing``."""
 
     def __init__(self, d_model, d_expert, num_experts, top_k,
                  experts_held=None, expert_offset=0, scaling=1.0,
-                 renormalize=True, shared_width=None, score_func="sigmoid"):
+                 renormalize=True, shared_width=None, score_func="sigmoid",
+                 gated=True):
         super().__init__()
-        from .common import GatedFFN, Linear
+        from .common import GatedFFN, Linear, PlainFFN
 
         held = num_experts if experts_held is None else int(experts_held)
         if not 0 <= expert_offset <= num_experts - held:
@@ -590,11 +638,12 @@ class SparseMoELayer(Layer):
         # rule outside the loss and not by its gradient
         self.register_buffer("router_bias",
                              jnp.zeros((num_experts,), jnp.float32))
-        self.experts_gate = self.create_parameter([held, d_model, d_expert])
+        self.experts_gate = self.create_parameter(
+            [held, d_model, d_expert]) if gated else None
         self.experts_up = self.create_parameter([held, d_model, d_expert])
         self.experts_down = self.create_parameter([held, d_expert, d_model])
-        self.shared = GatedFFN(d_model, shared_width) if shared_width \
-            else None
+        self.shared = (GatedFFN if gated else PlainFFN)(
+            d_model, shared_width) if shared_width else None
         self.last_routing = None
 
     def forward(self, x):

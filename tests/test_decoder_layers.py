@@ -149,23 +149,31 @@ def test_every_rung_of_the_row_ladder_gives_the_same_layer():
     assert _row_ladder(8192 * 8, 8, 256) == (16384, 65536)
     assert _row_ladder(64 * 4, 16, 16) == (256,)       # all held: one rung
     assert _row_ladder(600, 1, 16) == (512, 768)
-    layer, p, cfg = _moe(1, 5)                         # expert 5 alone
-    x = jax.random.normal(jax.random.key(9), (600, 64))
-    args = (p["f.router.weight"], jnp.zeros((16,)), p["f.experts_gate"],
+    # under a dense top rung a sorted rung stays at a third of the dense
+    # rows or fewer: a quarter does (the Kimi cell's), a half does not
+    assert _row_ladder(8192 * 8, 8, 256, 8192 * 8) == (16384, 65536)
+    assert _row_ladder(600, 1, 16, 600) == (768,)
+    assert _row_ladder(800, 1, 32, 800) == (256, 1024)
+    layer, p, cfg = _moe(1, 5)      # expert 5 alone, of a router of 32
+    router = jnp.concatenate([p["f.router.weight"], 0.2 * jax.random.normal(
+        jax.random.key(8), (64, 16))], axis=1)
+    x = jax.random.normal(jax.random.key(9), (800, 64))
+    args = (router, jnp.zeros((32,)), p["f.experts_gate"],
             p["f.experts_up"], p["f.experts_down"])
     routed = {k: v for k, v in p.items() if "shared" not in k}
+    routed["f.router.weight"] = router
     cfg = dict(cfg, num_shared_experts=0)
     out, (pairs, rows) = sparse_moe.raw_fn(x, *args, top_k=4,
                                            expert_offset=5, scaling=2.446)
-    assert rows == 512 and 0 < pairs <= rows           # the sorted rows
+    assert rows == 256 and 0 < pairs <= rows           # the sorted rows
     assert _rel(out, ref.moe(routed, "f.", x, cfg, ref._dense)) < 1e-5
     # a correction bias that makes the held expert every token's pick:
     # the count passes the lower rung, the layer runs every pair
-    bias = jnp.zeros((16,)).at[5].set(10.0)
+    bias = jnp.zeros((32,)).at[5].set(10.0)
     out, (pairs, rows) = sparse_moe.raw_fn(
         x, args[0], bias, *args[2:], top_k=4, expert_offset=5,
         scaling=2.446)
-    assert rows == 768 and pairs == 600
+    assert rows == 1024 and pairs == 800
     want = ref.moe(routed, "f.", x, cfg, ref._dense, router_bias=bias)
     assert _rel(out, want) < 1e-5
 

@@ -173,35 +173,91 @@ def test_short_flash_compiles_in_the_projections_layout(
     assert bool(moved) == (fa._short_block_width(h, d) is None), moved
 
 
+@pytest.mark.parametrize("d, f, held, experts, top_k, score, gated", [
+    (2304, 896, 16, 64, 8, "softmax", True),        # the Mellum cell's
+    (2688, 1856, 8, 128, 6, "sigmoid", False),      # the Nemotron cell's
+], ids=["mellum", "nemotron"])
 def test_the_expert_layers_wide_rung_hands_its_gradients_over_as_stored(
-        one_chip):
+        one_chip, monkeypatch, d, f, held, experts, top_k, score, gated):
     """The Mellum cell's expert layer (16,384 tokens, 16 of 64 experts of
-    2304 x 896 held, softmax top 8, bfloat16 autocast), differentiated
-    under the block's recomputation: the dense top rung is plain large
-    products (no loop, nothing stacked), and the two (16, 2304, 896)
-    weight gradients leave their products in the weights' own layout.
-    Left free, XLA forms them as (16, 896, 2304) and transposes the
-    weight and both its Adam moments to match, in and out of the update
-    (48 copies of 132 MB a step of that cell)."""
+    2304 x 896 held, softmax top 8, bfloat16 autocast) and the Nemotron
+    cell's (8 of 128 plain experts of 2688 x 1856, sigmoid top 6),
+    differentiated under the block's recomputation: the dense top rung is
+    the ladder's one rung (the Nemotron share's sorted rung would be
+    0.375 of the dense rows, over a third), plain large products (no
+    loop, nothing stacked, no grouped product, no branch), and the
+    (held, D, F) weight gradients leave their products in the layout the
+    chip keeps the weights in: the array's own for the Mellum stack, the
+    2688 minor for the Nemotron one (1856 is not whole lanes). Left free,
+    XLA forms them as (held, F, D) and transposes the Mellum weight and
+    both its Adam moments to match, in and out of the update (48 copies
+    of 132 MB a step of that cell); pinned to the array's own order, the
+    Nemotron stack gets the same copies the other way round."""
+    import functools
+
     from paddle_tpu import amp
+    from paddle_tpu.nn import moe
     from paddle_tpu.nn.moe import sparse_moe
 
-    t, d, f, held, experts = 16384, 2304, 896, 16, 64
+    t = 16384
+    chip, = one_chip.device_set         # the layer asks the first device
+    monkeypatch.setattr(moe, "_stored_layout", functools.partial(
+        moe._stored_layout, device=chip))
+    assert moe._stored_layout(
+        jax.ShapeDtypeStruct((held, d, f), F32)).major_to_minor == \
+        ((0, 1, 2) if gated else (0, 2, 1))
 
-    def layer(x, router, gate, up, down):
+    def layer(x, router, up, down, gate=None):
         with amp.auto_cast(level="O1", dtype="bfloat16"):
             return sparse_moe.raw_fn(
                 x, router, jnp.zeros((experts,), F32), gate, up, down,
-                top_k=8, score_func="softmax")[0]
+                top_k=top_k, score_func=score)[0]
 
+    shapes = (((t, d), BF16), ((d, experts), F32), ((held, d, f), F32),
+              ((held, f, d), F32)) + ((((held, d, f), F32),) if gated else ())
     out = _compile(
         jax.grad(lambda *a: jnp.sum(jax.checkpoint(layer)(*a)),
-                 argnums=(0, 1, 2, 3, 4)), one_chip,
-        ((t, d), BF16), ((d, experts), F32), ((held, d, f), F32),
-        ((held, d, f), F32), ((held, f, d), F32))
+                 argnums=tuple(range(len(shapes)))), one_chip, *shapes)
     text = out.as_text()
-    # three products forward, two recomputed, six backward (and the router's)
-    assert len(re.findall(r" convolution\(", text)) >= 11
-    assert not re.findall(r"\b(while|dynamic-update-slice)\(", text)
+    # gated: three products forward, two recomputed, six backward (and the
+    # router's); plain: two, one and four
+    assert len(re.findall(r" convolution\(", text)) >= (11 if gated else 7)
+    assert not re.findall(
+        r"\b(while|dynamic-update-slice|conditional|ragged-dot)\(", text)
     assert not re.findall(
         rf"\[{held},({d},{f}|{f},{d})\]\S* (copy|transpose)\(", text)
+
+
+@pytest.mark.parametrize("dtype,precision", [(BF16, None), (F32, "highest")],
+                         ids=["bfloat16", "float32_highest"])
+def test_ssd_chunk_kernels_compile_at_the_nemotron_cells_shapes(
+        one_chip, monkeypatch, dtype, precision):
+    """2 x 8,192 tokens, 64 heads of 64 in 8 groups, a state 128 wide:
+    the scan's forward (saving the chunk states) and its hand-derived
+    backward, one group's eight heads a grid step, on the projection's
+    (B, T, H * P) layout. Under `highest` (chip_smoke.py) the products'
+    operands are float32."""
+    import paddle_tpu.framework.bringup as bringup
+    from paddle_tpu.ops.pallas import ssd
+
+    monkeypatch.setattr(bringup, "pallas_enabled", lambda: True)
+    b, t, heads, p, groups, n = 2, 8192, 64, 64, 8, 128
+
+    def loss(u, delta, a, bm, cm):
+        return jnp.sum(ssd.ssd_chunk_scan(u, delta, a, bm, cm, groups)
+                       .astype(F32))
+
+    with jax.default_matmul_precision(precision or "default"):
+        out = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)), one_chip,
+                       ((b, t, heads * p), dtype), ((b, t, heads), F32),
+                       ((heads,), F32), ((b, t, groups * n), dtype),
+                       ((b, t, groups * n), dtype))
+    text = out.as_text()
+    # two launches under the one role, and nothing of u's size is
+    # transposed or copied on the way in or out
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                          text)) == 2
+    assert text.count("ssd_chunk") >= 2
+    assert not re.findall(
+        rf"\[{b},{t},{heads * p}\]\S* (copy|transpose)\(", text)
+    assert not re.findall(rf"\[{b},{heads},{t},{p}\]", text)
