@@ -777,6 +777,68 @@ def _kernel_checks():
     ssd_chunks(bf16)
     ssd_chunks(f32, b=1, t=2048)
 
+    # -- the Mamba-2 mixer's fused element-wise stages against the float32
+    # formulas they replace, at the Nemotron cell's shapes -------------------
+    def mamba2_stage(stage, b=2, t=8192, heads=64, p=64, groups=8, n=128,
+                     dtype=bf16):
+        inner, gn = heads * p, groups * n
+        total = 2 * inner + 2 * gn + heads
+        name = f"mamba2 {stage} stage fwd + bwd ({b}, {t}, {total}) " \
+               f"{jnp.dtype(dtype).name}"
+
+        def check(fails):
+            from paddle_tpu.ops.pallas import counters
+            from paddle_tpu.ops.pallas import mamba2_stages as stages
+
+            proj = rnd(1, (b, t, total), dtype)
+            if stage == "conv":
+                widths = (inner, gn, gn)
+                args = (proj, rnd(2, (4, sum(widths)), f32, 0.5),
+                        rnd(3, (sum(widths),), f32, 0.3))
+                ws = [rnd(4 + i, (b, t, w), f32)
+                      for i, w in enumerate(widths)]
+                names = ("xBC", "taps", "bias")
+
+                def run(form):
+                    def loss(*a):
+                        outs = form(*a, inner, widths)
+                        return sum(jnp.sum(o.astype(f32) * w)
+                                   for o, w in zip(outs, ws)), outs
+                    return loss
+                forms = (stages.conv_silu, stages.conv_silu_xla)
+            else:
+                args = (rnd(2, (b, t, inner), dtype),
+                        rnd(3, (b, t, inner), dtype), proj,
+                        1.0 + rnd(4, (heads,), f32, 0.5),
+                        1.0 + rnd(5, (inner,), f32, 0.2))
+                w = rnd(6, (b, t, inner), f32)
+                names = ("y", "u", "z", "D", "weight")
+
+                def run(form):
+                    def loss(*a):
+                        out = form(*a, groups, 1e-5)
+                        return jnp.sum(out.astype(f32) * w), (out,)
+                    return loss
+                forms = (stages.gate_norm, stages.gate_norm_xla)
+            before = counters.snapshot()
+            (_, outs), got = jax.jit(jax.value_and_grad(
+                run(forms[0]), argnums=tuple(range(len(args))),
+                has_aux=True))(*args)
+            if counters.delta(before) != {"mamba2_stage.fused": 1}:
+                fails.append(f"{name}: outside its gate")
+                return
+            (_, refs), want = jax.jit(jax.value_and_grad(
+                run(forms[1]), argnums=tuple(range(len(args))),
+                has_aux=True))(*args)
+            for i, (o, r) in enumerate(zip(outs, refs)):
+                _close(f"{name} out {i}", o, r, tol_of(dtype), fails)
+            for g, r, nm in zip(got, want, names):
+                _close(f"{name} d{nm}", g, r, tol_of(dtype), fails)
+        checks.append((name, check))
+
+    mamba2_stage("conv")
+    mamba2_stage("gate_norm")
+
     # -- the dropless expert layer, each rung against a dense loop -----------
     def experts(held, dtype, rungs, t=8192, d=2304, f=1024,
                 num_experts=256, top_k=8, scaling=2.446,

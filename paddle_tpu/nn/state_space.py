@@ -11,27 +11,25 @@ scalar decay (SSD), with B and C shared by a group of heads.
     out = y W_out
 
 The recurrence runs chunk by chunk in ``ops/pallas/ssd.py``, on ``u``,
-``B`` and ``C`` as the convolution leaves them, heads side by side;
-``delta``, the decay, the skip and the gated norm are float32 whatever
-the autocast level, the scan's products take the projection's (autocast)
-type.
+``B`` and ``C`` as the convolution leaves them, heads side by side. The
+two element-wise stages on either side of it (convolution + SiLU; skip +
+gate + grouped norm) are ``ops/pallas/mamba2_stages.py``'s: each reads
+and writes the projection's (autocast) type once a direction and is
+float32 inside the pass; ``delta`` and the decay are float32 whatever
+the autocast level, the scan's products take the projection's type.
 """
 from __future__ import annotations
 
 import math
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from ..framework.op import primitive
 from .common import Linear
-from .functional import short_conv
 from .layer import Layer
 
 __all__ = ["Mamba2Mixer", "mamba2_mix"]
-
-_F32 = jnp.float32
 
 
 @primitive("mamba2_mix")
@@ -40,41 +38,23 @@ def mamba2_mix(proj, conv_taps, conv_bias, a_log, dt_bias, d_skip,
                epsilon=1e-5):
     """Everything of the mixer between its input projection and its
     output projection. proj: (B, T, 2 inner + 2 G N + H), the input
-    projection's ``[z | xBC | dt]``. Returns (B, T, inner), float32."""
+    projection's ``[z | xBC | dt]``. Returns (B, T, inner): in ``proj``'s
+    type from the fused stages (the rounding the output projection
+    applies under autocast, at the same point), float32 from their XLA
+    formulas."""
+    from ..ops.pallas import mamba2_stages as stages
     from ..ops.pallas import ssd
 
-    b, t, _ = proj.shape
     inner, gn = num_heads * head_dim, groups * state_size
-    z, dt = proj[..., :inner], proj[..., 2 * inner + 2 * gn:]
-
-    # each part of xBC through its own channels of the convolution: three
-    # arrays as the scan reads them, and no slice of a (B, T, inner + 2GN)
-    # one; recomputed in the backward from the projection
-    @jax.checkpoint
-    def mixed(proj, taps, bias):
-        def part(lo, hi):
-            x = short_conv(
-                proj[..., inner + lo:inner + hi].astype(_F32),
-                taps[:, lo:hi].astype(_F32), bias[lo:hi].astype(_F32))
-            return jax.nn.silu(x).astype(proj.dtype)
-
-        with jax.named_scope("short_conv"):
-            return (part(0, inner), part(inner, inner + gn),
-                    part(inner + gn, inner + 2 * gn))
-
-    u, bm, cm = mixed(proj, conv_taps, conv_bias)
+    with jax.named_scope("short_conv"):
+        u, bm, cm = stages.conv_silu(proj, conv_taps, conv_bias, inner,
+                                     (inner, gn, gn))
     with jax.named_scope("ssd_scan"):
-        y = ssd.ssd_scan(u, dt, a_log, bm, cm, d_skip, dt_bias, groups)
-
-    @jax.checkpoint
-    def gated_norm(y, z, weight):
-        with jax.named_scope("gated_norm"):
-            y = (y * jax.nn.silu(z.astype(_F32))).reshape(b, t, groups, -1)
-            y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
-                                  + epsilon)
-            return y.reshape(b, t, inner) * weight.astype(_F32)
-
-    return gated_norm(y, z, norm_weight)
+        y = ssd.ssd_scan(u, proj[..., 2 * inner + 2 * gn:], a_log, bm, cm,
+                         dt_bias, groups)
+    with jax.named_scope("gated_norm"):
+        return stages.gate_norm(y, u, proj, d_skip, norm_weight, groups,
+                                epsilon)
 
 
 class Mamba2Mixer(Layer):

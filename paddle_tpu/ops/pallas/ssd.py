@@ -431,16 +431,13 @@ def ssd_chunk_scan(u, delta, a, bm, cm, groups, chunk=CHUNK):
     return y[:, :t] if pad else y
 
 
-def ssd_scan(u, dt, a_log, bm, cm, d_skip, dt_bias, groups, chunk=CHUNK):
+def ssd_scan(u, dt, a_log, bm, cm, dt_bias, groups, chunk=CHUNK):
     """Mamba-2's selective scan from its parameters: ``delta =
-    softplus(dt + dt_bias)``, ``A = -exp(A_log)``, the recurrence of the
-    module docstring, and the skip ``y += D_h u``. u: (B, T, H * P); dt:
-    (B, T, H); a_log, d_skip, dt_bias: (H,); bm, cm: (B, T, G * N).
-    Returns (B, T, H * P) float32."""
-    b, t, hp = u.shape
-    heads = dt.shape[-1]
+    softplus(dt + dt_bias)``, ``A = -exp(A_log)`` and the recurrence of
+    the module docstring; the skip ``D_h u`` is the caller's (the mixer
+    adds it where it gates and norms). u: (B, T, H * P); dt: (B, T, H);
+    a_log, dt_bias: (H,); bm, cm: (B, T, G * N). Returns (B, T, H * P) in
+    ``u``'s type."""
     delta = jax.nn.softplus(dt.astype(_F32) + dt_bias.astype(_F32))
-    y = ssd_chunk_scan(u, delta, -jnp.exp(a_log.astype(_F32)), bm, cm,
-                       groups, chunk)
-    skip = jnp.repeat(d_skip.astype(_F32), hp // heads)
-    return y.astype(_F32) + skip * u.astype(_F32)
+    return ssd_chunk_scan(u, delta, -jnp.exp(a_log.astype(_F32)), bm, cm,
+                          groups, chunk)

@@ -168,3 +168,42 @@ ENTRY %main.9 (a: f32[8]) -> f32[8] {
     assert dict(out["by_scope"]) == {
         "loss/bert/pooler": 2.0, "loss/fused_xent_fwd": 1.0,
         mod.NO_SCOPE: 1.0}
+
+
+def test_profile_step_lists_what_a_scope_is_made_of():
+    """``--within``: the operations under a named scope by family and
+    result shape, from the compiled text's shapes (a tuple's first)."""
+    mod = _profile_step()
+    hlo = """ENTRY %main.9 (a: f32[8]) -> f32[8] {
+  %fusion.12 = f32[2,8192,4096]{2,1,0:T(8,128)} fusion(%a), kind=kLoop, calls=%c.1
+  %fusion.13 = f32[2,8192,4096]{2,1,0:T(8,128)} fusion(%a), kind=kLoop, calls=%c.2
+  %mamba2_conv.3 = (bf16[2,8192,1024]{2,1,0:T(8,128)(2,1)}, f32[2,5,8,1024]{3,2,1,0}) custom-call(%a), custom_call_target="tpu_custom_call"
+  ROOT %pad.7 = bf16[2,8192,10304]{2,1,0} pad(%a, %a), padding=0_0
+}
+"""
+    shapes = mod.shapes_from_text(hlo)
+    assert shapes == {"fusion.12": "f32[2,8192,4096]",
+                      "fusion.13": "f32[2,8192,4096]",
+                      "mamba2_conv.3": "bf16[2,8192,1024]",
+                      "pad.7": "bf16[2,8192,10304]"}
+    mixer = _STEP + "transpose(jvp(loss))/layer/mixer/"
+    events = [("fusion.12", mixer + "checkpoint/short_conv/mul", 2.0),
+              ("fusion.13", mixer + "checkpoint/short_conv/add", 1.0),
+              ("pad.7", mixer + "checkpoint/short_conv/pad", 0.5),
+              ("kernel:mamba2_conv.3",
+               _STEP + "jvp(loss)/layer/mixer/short_conv/pallas/"
+               "mamba2_conv/pallas_call", 0.25),
+              ("fusion.99", mixer + "gated_norm/mul", 4.0),
+              ("copy.1", None, 8.0)]
+    got = mod.within(events, ("short_conv", "ssd_scan"), shapes)
+    assert got["short_conv"] == {
+        "total_s": 3.75,
+        "by_phase": [("backward", 3.5), ("forward", 0.25)],
+        "by_op": [("fusion f32[2,8192,4096]", 3.0),
+                  ("pad bf16[2,8192,10304]", 0.5),
+                  ("kernel:mamba2_conv bf16[2,8192,1024]", 0.25)]}
+    assert got["ssd_scan"] == {"total_s": 0.0, "by_phase": [], "by_op": []}
+    text = mod.render_within(got)
+    assert "within short_conv: 3.7500 s (backward 3.5000, forward 0.2500)" \
+        in text
+    assert "| fusion f32[2,8192,4096] | 3.0000 |" in text

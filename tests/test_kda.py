@@ -134,3 +134,38 @@ def test_unit_lower_inverse():
     inv = kda._unit_lower_inverse(jnp.asarray(n * 0.3))
     np.testing.assert_allclose(np.asarray(inv) @ (np.eye(64) + n * 0.3),
                                np.eye(64), atol=2e-4)
+
+
+#: sha256 of ``kda_mix``'s lowered text (below) on commit 4e54371, the
+#: parent of PR 36
+KDA_MIX_TEXT = {
+    "bfloat16":
+        "c5e4df10cb542d6e6fb898d83bd826e8b3d81ad0b6cf43ca2d0e256ca699c035",
+    "float32":
+        "533aaa3b7c18f053a85af416b27109aa4a2e02f19c54d0cf47d316dc4f25aa1a"}
+
+
+@pytest.mark.parametrize("dtype", sorted(KDA_MIX_TEXT))
+def test_kda_mix_lowers_to_the_text_it_lowered_to_before_pr_36(dtype):
+    """PR 36 gave the Mamba-2 mixer fused stages of its own and left
+    ``F.short_conv`` and ``kda_mix`` as they were: the KDA layer,
+    differentiated under ``jax.checkpoint`` as a block of the Kimi cell
+    is, lowers to the parent's StableHLO byte for byte (the projections
+    in the step's autocast type and in float32)."""
+    import hashlib
+
+    from paddle_tpu.nn.linear_attention import kda_mix
+
+    b, t, heads, d = 2, 64, 2, 16
+    w, act, f32 = heads * d, jnp.dtype(dtype), jnp.float32
+    shapes = ([((b, t, w), act)] * 3 + [((4, w), f32)] * 3
+              + [((b, t, w), act), ((heads,), f32), ((w,), f32),
+                 ((b, t, heads), act), ((b, t, w), act), ((d,), f32)])
+
+    @jax.checkpoint
+    def layer(*a):
+        return jnp.sum(kda_mix.raw_fn(*a, num_heads=heads))
+
+    text = jax.jit(jax.grad(layer, argnums=tuple(range(len(shapes))))).lower(
+        *(jax.ShapeDtypeStruct(s, dt) for s, dt in shapes)).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == KDA_MIX_TEXT[dtype]

@@ -76,17 +76,23 @@ def _rel(a, b):
     return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
 
 
+def scan(u, dt, a_log, bm, cm, d_skip, dt_bias, groups, chunk):
+    """``ssd.ssd_scan`` and the skip ``D_h u``, which is its caller's."""
+    y = ssd.ssd_scan(u, dt, a_log, bm, cm, dt_bias, groups, chunk=chunk)
+    return y + jnp.repeat(d_skip, u.shape[-1] // d_skip.shape[0]) * u
+
+
 def _agree(args, w, groups, chunk, tol=2e-4):
     with jax.default_matmul_precision("highest"):
         y_ref = recurrence(*args, groups)
-        y = ssd.ssd_scan(*args, groups, chunk=chunk)
+        y = scan(*args, groups, chunk)
         assert y.shape == y_ref.shape
         assert _rel(y, y_ref) < tol
         every = tuple(range(len(args)))
         want = jax.grad(lambda *a: jnp.sum(recurrence(*a, groups) * w),
                         argnums=every)(*args)
         got = jax.grad(lambda *a: jnp.sum(
-            ssd.ssd_scan(*a, groups, chunk=chunk) * w), argnums=every)(*args)
+            scan(*a, groups, chunk) * w), argnums=every)(*args)
     for name, a, b in zip(NAMES, got, want):
         assert _rel(a, b) < tol, name
 
@@ -145,7 +151,7 @@ def test_an_ineligible_shape_is_counted_with_its_reason(interp, capsys):
     args, _ = inputs(4, 1, 128, 2, 16, 1, 128, DECAYS["mixed"])
     set_flags({"log_pallas_fallback": True})
     try:
-        ssd.ssd_scan(*args, 1, chunk=128)
+        scan(*args, 1, 128)
     finally:
         set_flags({"log_pallas_fallback": False})
     assert counters.snapshot() == {"ssd.xla": 1}

@@ -261,3 +261,47 @@ def test_ssd_chunk_kernels_compile_at_the_nemotron_cells_shapes(
     assert not re.findall(
         rf"\[{b},{t},{heads * p}\]\S* (copy|transpose)\(", text)
     assert not re.findall(rf"\[{b},{heads},{t},{p}\]", text)
+
+
+@pytest.mark.parametrize("t,dtype", [(8192, BF16), (8192, F32), (8240, BF16)],
+                         ids=["bfloat16", "float32", "ragged_bfloat16"])
+@pytest.mark.parametrize("stage", ["conv", "gate_norm"])
+def test_mamba2_stage_kernels_compile_at_the_nemotron_cells_shapes(
+        one_chip, monkeypatch, stage, t, dtype):
+    """The mixer's fused element-wise stages on the projection ``[z | xBC
+    | dt]`` of 2 x 8,192 tokens, 10,304 wide: forward and the one-pass
+    backward, each part of xBC and each norm group found by block index
+    (no sliced copy of the projection goes into a kernel), also at a
+    length that is no whole number of blocks."""
+    import paddle_tpu.framework.bringup as bringup
+    from paddle_tpu.ops.pallas import mamba2_stages as stages
+
+    monkeypatch.setattr(bringup, "pallas_enabled", lambda: True)
+    b, inner, gn, heads, groups = 2, 4096, 1024, 64, 8
+    total = 2 * inner + 2 * gn + heads
+    if stage == "conv":
+        def loss(proj, taps, bias):
+            return sum(jnp.sum(o.astype(F32)) for o in stages.conv_silu(
+                proj, taps, bias, inner, (inner, gn, gn)))
+
+        shapes = [((b, t, total), dtype), ((4, inner + 2 * gn), F32),
+                  ((inner + 2 * gn,), F32)]
+        launches, role = 6, stages.ROLE_CONV
+    else:
+        def loss(y, u, proj, d_skip, weight):
+            return jnp.sum(stages.gate_norm(y, u, proj, d_skip, weight,
+                                            groups, 1e-5).astype(F32))
+
+        shapes = [((b, t, inner), dtype), ((b, t, inner), dtype),
+                  ((b, t, total), dtype), ((heads,), F32), ((inner,), F32)]
+        launches, role = 2, stages.ROLE_NORM
+    # (the value keeps the forward launches: the backward needs none of
+    # their results, its residuals are the stage's inputs)
+    out = _compile(jax.value_and_grad(
+        loss, argnums=tuple(range(len(shapes)))), one_chip, *shapes)
+    text = out.as_text()
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                          text)) == launches
+    assert text.count(role) >= launches
+    # the projection goes into the kernels as it is: no slice of it
+    assert not re.findall(rf"\[{b},{t},(4096|6144)\]\S* slice\(", text)

@@ -5,7 +5,7 @@ role (``fused_xent_fwd``). The one operator's reader of the scopes that
 ``nn.Layer.__call__`` and ``jit.TrainStep`` put on the compiled step.
 
     python tools/profile_step.py --workload <cell> --out DIR
-        [--seed N] [--steps 10] [--depth 5]
+        [--seed N] [--steps 10] [--depth 5] [--within SCOPE[,SCOPE...]]
 
 Builds the cell's ``Loop`` through ``benchmarks.harness.context`` and the
 cell's driver, warms it, traces ``--steps`` steps on the chip and keeps
@@ -38,6 +38,7 @@ _TRANSFORM = re.compile(r"^(jvp|transpose|vmap|remat|checkpoint|"
 _JIT = re.compile(r"^p?jit\(.*\)$")
 _INSTRUCTION = re.compile(
     r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*?op_name=\"([^\"]+)\"")
+_RESULT = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = (\(?[a-z]\w*\[[\d,]*\])")
 _NUM_SUFFIX = re.compile(r"(\.\d+)+$")
 
 
@@ -49,6 +50,50 @@ def scopes_from_text(hlo_text: str) -> Dict[str, str]:
         if m:
             out[m.group(1)] = m.group(2)
     return out
+
+
+def shapes_from_text(hlo_text: str) -> Dict[str, str]:
+    """instruction name -> its result's type and shape (a tuple's
+    first), from a compiled module's text."""
+    out = {}
+    for line in hlo_text.splitlines():
+        m = _RESULT.match(line)
+        if m:
+            out[m.group(1)] = m.group(2).lstrip("(")
+    return out
+
+
+def within(events: Iterable[Tuple[str, Optional[str], float]],
+           names: Iterable[str], shapes: Optional[Dict[str, str]] = None
+           ) -> Dict[str, dict]:
+    """For each scope name: the seconds of the operations whose scope
+    path holds it, by phase and by (operation family, result shape) —
+    which instructions a layer's part is made of."""
+    shapes = shapes or {}
+    out = {n: {"total_s": 0.0, "by_phase": {}, "by_op": {}} for n in names}
+    for name, op_name, seconds in events:
+        phase, path = split_op_name(op_name)
+        for n, row in out.items():
+            if n not in path:
+                continue
+            key = (f"{_NUM_SUFFIX.sub('', name)} "
+                   f"{shapes.get(name.removeprefix('kernel:'), '')}").strip()
+            row["total_s"] += seconds
+            row["by_phase"][phase] = row["by_phase"].get(phase, 0.0) + seconds
+            row["by_op"][key] = row["by_op"].get(key, 0.0) + seconds
+    for row in out.values():
+        for k in ("by_phase", "by_op"):
+            row[k] = sorted(row[k].items(), key=lambda kv: -kv[1])
+    return out
+
+
+def render_within(table: Dict[str, dict], top: int = 12) -> str:
+    lines = []
+    for name, row in table.items():
+        phases = ", ".join(f"{p} {s:.4f}" for p, s in row["by_phase"])
+        lines.append(f"\nwithin {name}: {row['total_s']:.4f} s ({phases})")
+        lines += [f"| {op} | {s:.4f} |" for op, s in row["by_op"][:top]]
+    return "\n".join(lines)
 
 
 def split_op_name(op_name: Optional[str]) -> Tuple[str, List[str]]:
@@ -184,7 +229,8 @@ def render(summary: dict, steps: int) -> str:
 
 def profile(workload: str, out: str, seed: int = 1, steps: int = 10,
             depth: int = 5, warm: int = 3, root: Optional[str] = None,
-            check_device: bool = True) -> dict:
+            check_device: bool = True, scopes_within: Tuple[str, ...] = ()
+            ) -> dict:
     """Trace ``steps`` steps of the cell's loop and reduce the trace."""
     import jax
 
@@ -221,7 +267,8 @@ def profile(workload: str, out: str, seed: int = 1, steps: int = 10,
     import paddle_tpu as paddle
 
     batch = [paddle.to_tensor(a) for a in batches[0]]
-    scopes = scopes_from_text(loop.step.lower(*batch).compile().as_text())
+    text = loop.step.lower(*batch).compile().as_text()
+    scopes = scopes_from_text(text)
     events = [(n, scopes.get(n.removeprefix("kernel:")), s)
               for n, s in events]
     summary = summarize(events, depth)
@@ -229,6 +276,10 @@ def profile(workload: str, out: str, seed: int = 1, steps: int = 10,
     print(f"{workload} on {info}: trace kept at {path} "
           f"({os.path.getsize(path)} bytes)")
     print(render(summary, steps))
+    if scopes_within:
+        summary["within"] = within(events, scopes_within,
+                                   shapes_from_text(text))
+        print(render_within(summary["within"]))
     return summary
 
 
@@ -243,12 +294,16 @@ def main(argv=None) -> int:
     ap.add_argument("--steps", type=int, default=10)
     ap.add_argument("--depth", type=int, default=5,
                     help="module scopes are cut at this depth")
+    ap.add_argument("--within", default="",
+                    help="scope names, comma-separated: for each, the "
+                         "operations under it by family and result shape")
     args = ap.parse_args(argv)
     from benchmarks import harness
 
     try:
-        summary = profile(args.workload, args.out, args.seed, args.steps,
-                          args.depth)
+        summary = profile(
+            args.workload, args.out, args.seed, args.steps, args.depth,
+            scopes_within=tuple(n for n in args.within.split(",") if n))
     except harness.Refused as e:
         print(f"REFUSED: {e}", file=sys.stderr)
         return 2
