@@ -59,7 +59,14 @@ def all_flags():
 
 
 # Core flags (subset of the reference's platform/flags.cc that is meaningful on TPU).
-define_flag("check_nan_inf", False, "Scan op outputs for NaN/Inf (reference flags.cc:44)")
+define_flag("check_nan_inf", False,
+            "Scan op outputs for NaN/Inf (reference flags.cc:44): eager "
+            "ops raise FloatingPointError naming the op; a jit.TrainStep "
+            "BUILT while it is set computes a finiteness and magnitude "
+            "record by layer, gradient leaf and named probe beside its "
+            "loss (TrainStep.numerics(), framework/nan_inf.py). Not "
+            "carried through lax.scan bodies, shard_map or the static "
+            "executor")
 define_flag("prng_impl", "auto",
             "PRNG key impl: auto|rbg|threefry2x32. auto = rbg on TPU "
             "(hardware RngBitGenerator; measured +27% BERT train step vs "

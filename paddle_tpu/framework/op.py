@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from . import dtype as dtype_mod
 from . import flags
+from . import nan_inf
 from . import tape as tape_mod
 from .tensor import Tensor
 
@@ -102,7 +103,7 @@ def primitive(name=None, nondiff=()):
                 a, kw = jax.tree_util.tree_unflatten(treedef, arrays)
                 out = fn(*a, **kw)
                 if flags.get_flag("check_nan_inf"):
-                    _check_nan_inf(op_name, out)
+                    nan_inf.check_outputs(op_name, out)
                 return _wrap_outputs(out, stop_gradient=True)
 
             def pure(*diff_arrays):
@@ -118,7 +119,7 @@ def primitive(name=None, nondiff=()):
                                      op_name, pure_fn=pure, primals=primals)
             result = _wrap_outputs(out, stop_gradient=False, node=node)
             if flags.get_flag("check_nan_inf"):
-                _check_nan_inf(op_name, out)
+                nan_inf.check_outputs(op_name, out)
             return result
 
         wrapper.op_name = op_name
@@ -139,16 +140,6 @@ def _wrap_outputs(out, stop_gradient, node=None):
             node.add_output(t)
         wrapped.append(t)
     return jax.tree_util.tree_unflatten(treedef, wrapped)
-
-
-def _check_nan_inf(op_name, out):
-    """FLAGS_check_nan_inf parity (reference details/nan_inf_utils_detail.cc)."""
-    for leaf in jax.tree_util.tree_leaves(out):
-        if dtype_mod.is_inexact(leaf.dtype):
-            if bool(jnp.any(~jnp.isfinite(leaf))):
-                raise FloatingPointError(
-                    f"Operator {op_name} output contains NaN/Inf"
-                )
 
 
 def unwrap_args(*xs):

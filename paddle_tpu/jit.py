@@ -16,6 +16,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from .framework import nan_inf
 from .framework import random as random_mod
 from .framework import tape as tape_mod
 from .framework.random import rng_scope
@@ -217,6 +218,10 @@ class TrainStep:
         self._zero_axis = zero_axis
         self._placed = False
         self._pinned = None  # (param, slot) shardings of the placement
+        # FLAGS_check_nan_inf, as it stood when the step was built: the
+        # record's static keys and the last step's table, on the device
+        self._numerics_keys = None
+        self._numerics = None
         # route this step's XLA compiles through the disk-persistent cache
         from .static.compile_cache import ensure_enabled
         ensure_enabled()
@@ -301,21 +306,27 @@ class TrainStep:
         loss_fn = self.loss_fn
         optimizer = self.optimizer
         model = self.model
+        from contextlib import nullcontext
+
         from .ops.pallas import counters
+
+        # read here, as the amp level is: a step is built with the record
+        # or without it, and the flag's later value does not retrace it
+        check_nan_inf = nan_inf.enabled()
 
         # the name is the XLA module's (``jit_train_step``), the key of
         # compile_cache.seconds_by_function() and of counters.step_work()
         def train_step(params, buffers, opt_state, lr, batch):
             step_idx = opt_state["step"]
 
-            def loss_of(params):
+            def loss_of(params, sink=None):
+                if sink is not None:    # the gradient probes' way out
+                    nan_inf.record.sink = sink
                 key = jax.random.fold_in(random_mod.make_key(self._seed), step_idx)
                 saved_p ={n: p._value for n, p in model.named_parameters()}
                 saved_b = {n: b._value for n, b in model.named_buffers()}
                 model.load_param_pytree(params)
                 model.load_buffer_pytree(buffers)
-                from contextlib import nullcontext
-
                 from .parallel.ring import sequence_parallel as _sp_scope
 
                 sp_ctx = (_sp_scope(*self._sequence_parallel,
@@ -337,6 +348,9 @@ class TrainStep:
                         p._value = saved_p[n]
                     for n, b in model.named_buffers():
                         b._value = saved_b[n]
+                if sink is not None:    # the forward rows, as an output
+                    return loss_arr, (new_buffers, aux_arr,
+                                      nan_inf.record.frames[0].stacked())
                 return loss_arr, (new_buffers, aux_arr)
 
             from .parallel.mesh import trace_mesh
@@ -350,9 +364,20 @@ class TrainStep:
             # work of one execution (counters.step_work("train_step"))
             with trace_mesh(self._mesh, self._batch_row_axes()), \
                     counters.capture("train_step"):
-                with counters.differentiated():
-                    (loss, (new_buffers, aux)), grads = jax.value_and_grad(
-                        loss_of, has_aux=True)(params)
+                with counters.differentiated(), \
+                        (nan_inf.recording(model) if check_nan_inf
+                         else nullcontext()) as record:
+                    if record is None:
+                        (loss, (new_buffers, aux)), grads = \
+                            jax.value_and_grad(loss_of, has_aux=True)(params)
+                    else:
+                        (loss, (new_buffers, aux, rows)), (grads, slots) = \
+                            jax.value_and_grad(
+                                loss_of, argnums=(0, 1), has_aux=True)(
+                                    params, jnp.zeros((nan_inf.GRAD_SLOTS, 3),
+                                                      jnp.float32))
+                        self._numerics_keys, numerics = record.finish(
+                            loss, rows, slots, grads)
                 with jax.named_scope("optimizer"):
                     new_params, new_opt_state = \
                         optimizer.apply_gradients_fn(
@@ -369,6 +394,9 @@ class TrainStep:
                 new_opt_state = dict(new_opt_state, slots=_tree.tree_map(
                     jax.lax.with_sharding_constraint,
                     new_opt_state["slots"], s_sh))
+            if check_nan_inf:
+                return (loss, aux, new_params, new_buffers, new_opt_state,
+                        numerics)
             return loss, aux, new_params, new_buffers, new_opt_state
 
         # params + optimizer state are donated: XLA updates the (large)
@@ -444,8 +472,10 @@ class TrainStep:
                     for tree in (params, self._opt_state)
                     for a in _tree.tree_leaves(tree))
             profiler.bump_counter("donated_bytes", self._donated_nbytes)
-        loss, aux, new_params, new_buffers, new_opt_state = self._compiled(
-            params, buffers, self._opt_state, lr, batch_arrays)
+        loss, aux, new_params, new_buffers, new_opt_state, *numerics = \
+            self._compiled(params, buffers, self._opt_state, lr, batch_arrays)
+        if numerics:
+            self._numerics, = numerics
         for n, p in model.named_parameters():
             if n in new_params:
                 p._value = new_params[n]
@@ -461,6 +491,18 @@ class TrainStep:
         if aux:
             return (Tensor(loss),) + tuple(_tree.tree_map(_wrap_in, a) for a in aux)
         return Tensor(loss)
+
+    def numerics(self, check=False):
+        """The last step's ``FLAGS_check_nan_inf`` record, fetched: a
+        ``framework.nan_inf.Numerics``, ``{key: {"pass", "nonfinite",
+        "absmax"}}`` in execution order with ``first_nonfinite`` and
+        ``first_pass``; None for a step built with the flag off or not
+        run yet. With ``check``, raise ``FloatingPointError`` naming the
+        first non-finite key and its pass. The fetch is the caller's:
+        the step itself adds none."""
+        if self._numerics is None:
+            return None
+        return nan_inf.report(self._numerics_keys, self._numerics, check)
 
     @property
     def opt_state(self):

@@ -16,6 +16,7 @@ import jax
 import numpy as np
 
 from ..framework import dtype as dtype_mod
+from ..framework import nan_inf
 from ..framework.tensor import Tensor
 from . import initializer as I
 
@@ -172,11 +173,17 @@ class Layer:
         # traced inside (``bert/encoder/layer/self_attn``), nothing at
         # step time; a root layer has none
         scope = self.__dict__.get("_scope")
+        # the step's FLAGS_check_nan_inf record, in a step built with the
+        # flag set: a row for each output (framework/nan_inf.py)
+        record = nan_inf.record
         if scope is None:
             out = self.forward(*inputs, **kwargs)
-        else:
+        elif record is None:
             with jax.named_scope(scope):
                 out = self.forward(*inputs, **kwargs)
+        else:
+            with jax.named_scope(scope):
+                out = record.layer_call(self, inputs, kwargs)
         for hook in self._forward_post_hooks.values():
             result = hook(self, inputs, out)
             if result is not None:

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..framework.nan_inf import checkpoint, probe
 from ..framework.op import primitive
 from .common import Linear
 from .functional import short_conv
@@ -60,7 +61,9 @@ def kda_mix(q, k, v, q_taps, k_taps, v_taps, decay, a_log, dt_bias,
     # the float32 element-wise chains on either side of the recurrence
     # are recomputed in the backward from their (autocast-typed) inputs:
     # kept, they are a dozen (B, T, H * D) float32 arrays a layer
-    @jax.checkpoint
+    # (``checkpoint`` is jax.checkpoint, and ``probe`` the identity,
+    # except in a step built under FLAGS_check_nan_inf)
+    @checkpoint
     def before(q, k, v, decay, beta_logits, q_taps, k_taps, v_taps, a_log,
                dt_bias):
         g = -jnp.exp(a_log.astype(_F32))[:, None] * heads(
@@ -69,16 +72,21 @@ def kda_mix(q, k, v, q_taps, k_taps, v_taps, decay, a_log, dt_bias,
                 _l2norm(mixed(k, k_taps)), mixed(v, v_taps), g,
                 jax.nn.sigmoid(beta_logits.astype(_F32)))
 
-    @jax.checkpoint
+    @checkpoint
     def after(o, gate, norm_weight):
         o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
                               + epsilon)
         return o * norm_weight.astype(_F32) * heads(
             jax.nn.sigmoid(gate.astype(_F32)))
 
-    o = chunk_kda(*before(q, k, v, decay, beta_logits, q_taps, k_taps,
-                          v_taps, a_log, dt_bias))
-    return after(o, gate, norm_weight).reshape(b, t, width)
+    # the recurrence's operands and result, and as the cotangents of
+    # these the five gradients that leave its hand-written backward
+    operands = before(q, k, v, decay, beta_logits, q_taps, k_taps, v_taps,
+                      a_log, dt_bias)
+    o = chunk_kda(*(probe(name, a, grad=True) for name, a in zip(
+        ("kda_q", "kda_k", "kda_v", "kda_g", "kda_beta"), operands)))
+    return after(probe("kda_o", o, grad=True), gate,
+                 norm_weight).reshape(b, t, width)
 
 
 class KimiDeltaAttention(Layer):

@@ -70,6 +70,7 @@ import jax.numpy as jnp
 from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import Mesh, PartitionSpec
 
+from ..framework import nan_inf
 from ..framework.op import primitive
 from .layer import Layer
 
@@ -468,10 +469,11 @@ def _every_pair_ffn(x, weight_by_expert, w_gate, w_up, w_down, stored):
     # recomputed in the backward from the products' own results: as
     # a branch of the ladder's switch the rung would else hand its
     # float32 intermediates over the branch's boundary as residuals
-    @jax.checkpoint
+    @nan_inf.checkpoint
     def weighted_hidden(gate, up, weight):
         hidden = _hidden(gate, up, lambda a: a.astype(jnp.float32))
-        return (hidden * weight[:, :, None]).astype(w_down.dtype)
+        return nan_inf.probe(
+            "hidden", (hidden * weight[:, :, None]).astype(w_down.dtype))
 
     hidden = weighted_hidden(
         None if w_gate is None else jnp.einsum("td,hdf->thf", rows, w_gate),
@@ -494,8 +496,8 @@ def _grouped_ffn(x, tokens, weights, sizes, w_gate, w_up, w_down, n_tokens):
         return jnp.where(live, a, jnp.zeros((), a.dtype))
 
     rows = runs(x[tokens].astype(w_up.dtype))
-    hidden = runs(_hidden(w_gate, w_up,
-                          lambda w: jax.lax.ragged_dot(rows, w, sizes)))
+    hidden = nan_inf.probe("hidden", runs(_hidden(
+        w_gate, w_up, lambda w: jax.lax.ragged_dot(rows, w, sizes))))
     out = runs(jax.lax.ragged_dot(hidden, w_down, sizes))
     out = out.astype(jnp.float32) * weights[:, None]
     return jnp.zeros((n_tokens, x.shape[-1]), jnp.float32).at[tokens].add(out)
@@ -536,7 +538,9 @@ def sparse_moe(x, router_w, router_bias, w_gate, w_up, w_down, top_k,
     _, picked = jax.lax.top_k(scores + router_bias, top_k)       # (T, k)
     weight = jnp.take_along_axis(scores, picked, axis=1)
     if renormalize:
-        weight = weight / jnp.sum(weight, axis=1, keepdims=True)
+        weight = weight / nan_inf.probe(
+            "renorm_denominator", jnp.sum(weight, axis=1, keepdims=True),
+            smallest=True)
     weight = (scaling * weight).reshape(-1)
     local = picked.reshape(-1) - expert_offset
     mine = (local >= 0) & (local < held)
@@ -592,8 +596,11 @@ def sparse_moe(x, router_w, router_bias, w_gate, w_up, w_down, top_k,
     bump("sparse_moe", "every_pair" if dense_top else "sorted")
     bump("sparse_moe", "plain" if w_gate is None else "gated")
     rung = jnp.sum(count > jnp.asarray(rungs[:-1], jnp.int32))
-    out = jax.lax.switch(rung, [at(r) for r in rungs[:-1]] + [top],
-                         x, weight, w_gate, w_up, w_down)
+    # (lax.switch; in a step built under FLAGS_check_nan_inf it also hands
+    # out the ``hidden`` row of the rung that ran)
+    out = nan_inf.probe("routed", nan_inf.switch(
+        rung, [at(r) for r in rungs[:-1]] + [top],
+        x, weight, w_gate, w_up, w_down))
     # the dense top rung's rows, in the ladder's whole 256-row blocks
     rows = rungs[:-1] + ((-(-t * held // 256) * 256,) if dense_top
                          else rungs[-1:])

@@ -47,6 +47,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ...framework import nan_inf
 from .counters import bump, kernel_call
 from .flash_attention import _sds
 
@@ -386,19 +387,25 @@ def _cumulate(g, chunk):
                       axis=2).reshape(g.shape)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _chunk_kda(q, k, v, g, beta, chunk, kernel):
-    return _chunk_kda_fwd(q, k, v, g, beta, chunk, kernel)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _chunk_kda(q, k, v, g, beta, chunk, kernel, record=False):
+    return _chunk_kda_fwd(q, k, v, g, beta, chunk, kernel, record)[0]
 
 
-def _chunk_kda_fwd(q, k, v, g, beta, chunk, kernel):
+def _chunk_kda_fwd(q, k, v, g, beta, chunk, kernel, record):
+    """``record`` (a step built under FLAGS_check_nan_inf): the result
+    is (o, the ``nan_inf.row`` of the states the chunks started from),
+    which no probe outside this rule can reach."""
     gc = _cumulate(g, chunk)
     o, states = (_pallas_fwd if kernel else _xla_fwd)(q, k, v, gc, beta,
                                                       chunk)
-    return o, (q, k, v, gc, beta, states)
+    return ((o, nan_inf.row(states)) if record else o,
+            (q, k, v, gc, beta, states))
 
 
-def _chunk_kda_bwd(chunk, kernel, res, do):
+def _chunk_kda_bwd(chunk, kernel, record, res, do):
+    if record:
+        do, _ = do
     q, k, v, gc, beta, states = res
     dq, dk, dv, dgc, dbeta = (_pallas_bwd if kernel else _xla_bwd)(
         q, k, v, gc, beta, states, do, chunk)
@@ -449,5 +456,9 @@ def chunk_kda(q, k, v, g, beta, chunk=CHUNK):
         bump("kda_chunk", "xla",
              f"dispatch ineligible (q {tuple(q.shape)}, v {tuple(v.shape)}"
              f", chunk {chunk}; backend or 128-lane heads)")
-    o = _chunk_kda(q, k, v, g, beta, chunk, kernel)
+    o = _chunk_kda(q, k, v, g, beta, chunk, kernel,
+                   nan_inf.record is not None)
+    if nan_inf.record is not None:
+        o, states_row = o
+        nan_inf.probe_row("kda_states", states_row)
     return o[:, :t] if pad else o

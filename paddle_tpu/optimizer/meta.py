@@ -102,6 +102,7 @@ def recompute(function, *args, **kwargs):
 
     Inside a jit trace (TrainStep) the same call lowers to
     ``jax.checkpoint`` — XLA remat, same semantics, compiled."""
+    from ..framework import nan_inf
     from ..framework import random as random_mod
     from ..framework import tape as tape_mod
     from ..framework.tensor import Tensor
@@ -143,7 +144,9 @@ def recompute(function, *args, **kwargs):
     if traced:
         # jit path: values are tracers, the tape is off — lower straight
         # to jax.checkpoint over a pure function of (args, params)
-        vals = jax.checkpoint(
+        # (handing out the FLAGS_check_nan_inf rows made inside, in a
+        # step built with the flag set; jax.checkpoint itself else)
+        vals = nan_inf.checkpoint(
             lambda av, pv: _call_with(av, pv, meta))(
                 [t.value for t in arg_ts], [p.value for p in params])
         outs = [Tensor(v, stop_gradient=False) for v in vals]

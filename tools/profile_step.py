@@ -17,7 +17,9 @@ the ``metadata={op_name=...}`` of the compiled step's text
 of one of the operations fused into it: a weight's AdamW update that XLA
 fused into the matmul of its gradient counts under that layer's backward.
 Chip only, like the benchmark; :func:`summarize` is plain arithmetic and
-is tested on a small recorded event list.
+is tested on a small recorded event list. This tool reads times; for
+VALUES (which layer, in which pass, first makes a non-finite value, and
+each layer's magnitudes step by step) see ``tools/find_nonfinite.py``.
 """
 from __future__ import annotations
 
@@ -227,6 +229,23 @@ def render(summary: dict, steps: int) -> str:
     return "\n".join(lines)
 
 
+def loop_and_batches(ctx, driver) -> tuple:
+    """(the cell's ``Loop`` from ``ctx.seed``, its host batches): what
+    this tool and ``tools/find_nonfinite.py`` drive."""
+    from benchmarks import harness, traffic
+
+    if not hasattr(driver, "Loop"):
+        raise harness.Refused(f"the driver of {ctx.name} has no Loop: only "
+                              "training cells have a step to drive")
+    if hasattr(driver, "loop_and_batches"):     # a driver with a feed of
+        return driver.loop_and_batches(ctx)                 # its own
+    cfg, cell = ctx.config, ctx.cell
+    batches = traffic.train_batches(cell["traffic"], cfg["vocab_size"],
+                                    ctx.seed)
+    return driver.Loop(cfg, cell, driver.make_params(cfg, ctx.seed),
+                       ctx.seed), batches
+
+
 def profile(workload: str, out: str, seed: int = 1, steps: int = 10,
             depth: int = 5, warm: int = 3, root: Optional[str] = None,
             check_device: bool = True, scopes_within: Tuple[str, ...] = ()
@@ -234,20 +253,11 @@ def profile(workload: str, out: str, seed: int = 1, steps: int = 10,
     """Trace ``steps`` steps of the cell's loop and reduce the trace."""
     import jax
 
-    from benchmarks import harness, trace_reduce, traffic
+    from benchmarks import harness, trace_reduce
 
     ctx, driver, info = harness.context(
         workload, seed, 0.0, root or harness.ROOT, check_device)
-    if not hasattr(driver, "Loop"):
-        raise harness.Refused(f"the driver of {workload} has no Loop: "
-                              "only training cells have a step to profile")
-    cfg, cell = ctx.config, ctx.cell
-    if hasattr(driver, "loop_and_batches"):     # a driver with a feed of
-        loop, batches = driver.loop_and_batches(ctx)        # its own
-    else:
-        batches = traffic.train_batches(cell["traffic"], cfg["vocab_size"],
-                                        seed)
-        loop = driver.Loop(cfg, cell, driver.make_params(cfg, seed), seed)
+    loop, batches = loop_and_batches(ctx, driver)
     loss = None
     for _ in range(warm):
         loss = loop.feed_and_step(batches[loop.steps % len(batches)])
