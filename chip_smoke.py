@@ -707,33 +707,52 @@ def _kernel_checks():
     checks.append(("fused xent, the BERT cells' call: float32 at the default "
                    "precision, 80 of 512 labelled", xent_cells_call))
 
-    # -- KDA chunk kernels against the same chunk formulas under XLA ---------
-    def kda_chunks(fails, shape=(1, 8192, 32, 128)):
-        from paddle_tpu.ops.pallas import kda
+    # -- KDA chunk kernels against the same chunk formulas under XLA: on
+    # random keys at the family's starting decays, and on keys at a mean
+    # cosine of 0.8 with beta 0.99 and weak decay, where an inverse formed
+    # from powers of the whole chunk's matrix is lost to rounding (PR 38) ----
+    def kda_chunks(cosine, shape=(1, 8192, 32, 128)):
+        name = f"kda chunk, key cosine {cosine}"
 
-        def unit(x):
-            return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+        def check(fails):
+            from paddle_tpu.ops.pallas import kda
 
-        q, k = unit(rnd(1, shape)) * shape[-1] ** -0.5, unit(rnd(2, shape))
-        v, w = rnd(3, shape), rnd(4, shape)
-        # log decays of the family's start: -A softplus(.), A in [1, 16]
-        g = -jax.random.uniform(jax.random.key(5), shape, f32, 1e-3, 1.6)
-        beta = jax.nn.sigmoid(rnd(6, shape[:3]))
-        if not kda._kernel_takes(q, v, kda.CHUNK):
-            fails.append(f"kda chunk: {shape} is outside its gate")
-            return
+            def unit(x):
+                return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
 
-        def run(kernel):
-            return jax.jit(jax.value_and_grad(
-                lambda *a: jnp.sum(kda._chunk_kda(*a, kda.CHUNK, kernel)
-                                   * w), argnums=(0, 1, 2, 3, 4)))(
-                q, k, v, g, beta)
+            q, k = unit(rnd(1, shape)) * shape[-1] ** -0.5, unit(rnd(2, shape))
+            v, w = rnd(3, shape), rnd(4, shape)
+            if cosine:
+                common = unit(rnd(7, (1, 1) + shape[2:]))
+                k = unit(cosine ** 0.5 * common + (1 - cosine) ** 0.5 * k)
+                g = -jax.random.uniform(jax.random.key(5), shape, f32,
+                                        1e-3, 0.05)
+                beta = jnp.full(shape[:3], 0.99, f32)
+            else:
+                # log decays of the family's start: -A softplus(.), A in
+                # [1, 16]
+                g = -jax.random.uniform(jax.random.key(5), shape, f32,
+                                        1e-3, 1.6)
+                beta = jax.nn.sigmoid(rnd(6, shape[:3]))
+            if not kda._kernel_takes(q, v, kda.CHUNK):
+                fails.append(f"{name}: {shape} is outside its gate")
+                return
 
-        (lk, got), (lx, want) = run(True), run(False)
-        _close("kda chunk sum(o w)", lk, lx, tol_of(f32), fails)
-        for a, r, nm in zip(got, want, ("q", "k", "v", "g", "beta")):
-            _close(f"kda chunk d{nm}", a, r, tol_of(f32), fails)
-    checks.append(("kda chunk fwd + bwd (1, 8192, 32, 128)", kda_chunks))
+            def run(kernel):
+                return jax.jit(jax.value_and_grad(
+                    lambda *a: jnp.sum(kda._chunk_kda(*a, kda.CHUNK, kernel)
+                                       * w), argnums=(0, 1, 2, 3, 4)))(
+                    q, k, v, g, beta)
+
+            (lk, got), (lx, want) = run(True), run(False)
+            _close(f"{name} sum(o w)", lk, lx, tol_of(f32), fails)
+            for a, r, nm in zip(got, want, ("q", "k", "v", "g", "beta")):
+                _close(f"{name} d{nm}", a, r, tol_of(f32), fails)
+        checks.append((f"kda chunk fwd + bwd {shape}, key cosine {cosine}",
+                       check))
+
+    kda_chunks(0)
+    kda_chunks(0.8)
 
     # -- the state-space scan's kernels against the same chunk formula under
     # XLA, which jax differentiates: the hand-derived backward's oracle -------

@@ -32,13 +32,24 @@ its carry; it reads the state each chunk started from (saved by the
 forward, ``C`` times smaller than per-token states) and recomputes the
 chunk's triangular system.
 
+The triangular system is solved by 16 x 16 blocks
+(:func:`_unit_lower_inverse`): a float32 inverse formed from powers of
+the whole 64 x 64 matrix is lost to their rounding once a chunk's keys
+are correlated, and the wrong ``U`` then grows the state a factor a
+chunk until it overflows (PR 37 found it, PR 38 repaired it).
+
 One set of chunk formulas (:func:`_chunk_fwd`, :func:`_chunk_bwd`, plain
 ``jax.numpy`` on 2-D blocks) serves both paths: under ``lax.scan`` they
 are the XLA form — the CPU path and the kernels' parity oracle — and as
 the bodies of the two Pallas kernels, launched under the roles
-``kda_chunk_fwd`` and ``kda_chunk_bwd``, they are the TPU path. The
-token-by-token recurrence lives only in the tests and in the
-benchmark's reference.
+``kda_chunk_fwd`` and ``kda_chunk_bwd``, they are the TPU path. A grid
+step of a kernel is one chunk of ``G`` heads (:func:`_heads_a_step`):
+lane-dense ``(C, G * 128)`` blocks of the projections' own ``(B, T,
+H * D)`` arrays, and inside the step the chunk formulas on a leading
+axis of ``G`` heads (``jax.vmap``, as the XLA form runs them), so that
+every link of the dependent chain is ``G`` independent products the
+MXUs take back to back. The token-by-token recurrence lives only in
+the tests and in the benchmark's reference.
 """
 from __future__ import annotations
 
@@ -73,15 +84,36 @@ def _iota(shape, axis):
 
 
 def _unit_lower_inverse(n):
-    """(I + N)^-1 for a strictly lower triangular ``n`` (nilpotent):
-    (I - N)(I + N^2)(I + N^4)... — log2(C) squarings, all on the MXU."""
+    """(I + N)^-1 for a strictly lower triangular ``n``, by ``_SUB`` x
+    ``_SUB`` blocks, all on the MXU. The diagonal blocks, all at once,
+    by the product form (I - N)(I + N^2)(I + N^4)(I + N^8) of ``n``
+    masked to them: exact for a nilpotent block, and its powers stay
+    under C(14, 7). Then blocks are merged two and two, D <- D - D L D
+    with L the part of ``n`` under the diagonal blocks inside each
+    merged one. (The product form on the whole chunk sums powers that
+    reach C(62, 31) and cancel to entries under 1: with correlated keys
+    their float32 rounding is larger than the inverse.)"""
     c = n.shape[0]
-    eye = (_iota((c, c), 0) == _iota((c, c), 1)).astype(_F32)
-    inv, power, span = eye - n, n, 1
-    while 2 * span < c:
+    row, col = _iota((c, c), 0), _iota((c, c), 1)
+
+    def same_block(width):
+        return jax.lax.div(row, jnp.int32(width)) \
+            == jax.lax.div(col, jnp.int32(width))
+
+    merged = same_block(_SUB)
+    inside = jnp.where(merged, n, 0.0)
+    inv, power, span = (row == col).astype(_F32) - inside, inside, 1
+    while 2 * span < _SUB:
         power = _dot(power, power)
         inv = inv + _dot(inv, power)
         span *= 2
+    width = _SUB
+    while width < c:
+        width *= 2
+        wider = same_block(width)
+        below = jnp.where(wider & ~merged, n, 0.0)
+        inv = inv - _dot(_dot(inv, below), inv)
+        merged = wider
     return inv
 
 
@@ -255,9 +287,45 @@ def _xla_bwd(q, k, v, gc, beta, states, do, chunk):
 
 
 # ---------------------------------------------------------------------------
-# the Pallas kernels: grid (batch, head, chunk), chunks in order, the
-# state (its cotangent, backward) in a VMEM scratch across them
+# the Pallas kernels: grid (batch, heads / G, chunk), chunks in order, the
+# G states (their cotangents, backward) in a VMEM scratch across them
 # ---------------------------------------------------------------------------
+#: scoped VMEM a launch may use: Mosaic's default on a v5e, which the
+#: launches leave unsaid (a limit spelled out is written by XLA under
+#: every instruction of the module, ``scoped_memory_configs``)
+_VMEM_LIMIT = 16 << 20
+#: (C, 128) float32 arrays a head's backward chain keeps alive beside its
+#: blocks (Mosaic counted 19.4 MB for eight heads at C = 64, PR 38)
+_LIVE = 50
+
+
+def _heads_a_step(h, kd, vd, chunk):
+    """G, the heads one grid step takes: the largest of 8, 4, 2, 1 that
+    divides ``h`` and fits the VMEM limit with its blocks double-buffered
+    and its temporaries. Counted on the backward, the larger launch (q,
+    k, v, gc, do and a state in, five cotangents out, beta's rows padded
+    to a tile), so that both launches of a call take the same G."""
+    tokens = 4 * chunk * (6 * kd + 3 * vd) + 2 * 4 * 8 * max(chunk, 128)
+    blocks = 2 * (tokens + 4 * vd * kd) + 4 * vd * kd
+    a_head = blocks + _LIVE * 4 * chunk * max(kd, vd)
+    return next((g for g in (8, 4, 2)
+                 if h % g == 0 and g * a_head <= _VMEM_LIMIT), 1)
+
+
+def _heads_of(ref, heads):
+    """A (C, G * D) token block as (G, C, D): each head's 128-lane
+    slice, stacked on a leading axis (whole tiles: nothing moves)."""
+    d = ref.shape[-1] // heads
+    return jnp.stack([ref[:, n * d:(n + 1) * d] for n in range(heads)])
+
+
+def _to_heads(ref, x):
+    """(G, C, D) back into a (C, G * D) token block."""
+    d = x.shape[-1]
+    for n in range(x.shape[0]):
+        ref[:, n * d:(n + 1) * d] = x[n]
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, st_ref, carry):
     from jax.experimental import pallas as pl
 
@@ -265,11 +333,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, st_ref, carry):
     def _start():
         carry[...] = jnp.zeros_like(carry)
 
+    heads = carry.shape[0]
     st = carry[...]
     st_ref[...] = st
-    o, st_next = _chunk_fwd(q_ref[...], k_ref[...], v_ref[...], g_ref[...],
-                            b_ref[...], st)
-    o_ref[...] = o
+    # the chunk formulas on a leading axis of G heads, as the XLA form
+    # runs them: every product is G independent ones, back to back
+    o, st_next = _heads(_chunk_fwd)(
+        _heads_of(q_ref, heads), _heads_of(k_ref, heads),
+        _heads_of(v_ref, heads), _heads_of(g_ref, heads), b_ref[...], st)
+    _to_heads(o_ref, o)
     carry[...] = st_next
 
 
@@ -281,13 +353,13 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, st_ref, do_ref,
     def _start():
         carry[...] = jnp.zeros_like(carry)
 
-    dq, dk, dv, dgc, dbeta, dst = _chunk_bwd(
-        q_ref[...], k_ref[...], v_ref[...], g_ref[...], b_ref[...],
-        st_ref[...], do_ref[...], carry[...])
-    dq_ref[...] = dq
-    dk_ref[...] = dk
-    dv_ref[...] = dv
-    dg_ref[...] = dgc
+    heads = carry.shape[0]
+    dq, dk, dv, dgc, dbeta, dst = _heads(_chunk_bwd)(
+        _heads_of(q_ref, heads), _heads_of(k_ref, heads),
+        _heads_of(v_ref, heads), _heads_of(g_ref, heads), b_ref[...],
+        st_ref[...], _heads_of(do_ref, heads), carry[...])
+    for ref, x in ((dq_ref, dq), (dk_ref, dk), (dv_ref, dv), (dg_ref, dgc)):
+        _to_heads(ref, x)
     db_ref[...] = dbeta
     carry[...] = dst
 
@@ -297,22 +369,23 @@ def _flat(x):
     return x.reshape(b, t, h * d)
 
 
-def _specs(h, kd, vd, chunk, nc, reverse):
-    """Block specs by kind: a (C, D) block of the (B, T, H*D) arrays at
-    head h (no transposed copy of q, k, v in HBM), beta's (1, C) row and
-    a chunk's (V, K) state."""
+def _specs(heads, kd, vd, chunk, nc, reverse):
+    """Block specs by kind: a (C, G * D) block of the (B, T, H * D)
+    arrays at head group hg (no transposed copy of q, k, v in HBM), G
+    (1, C) rows of beta and a chunk's G (V, K) states."""
     from jax.experimental import pallas as pl
 
     def at(c):
         return nc - 1 - c if reverse else c
 
     def tokens(d):
-        return pl.BlockSpec((None, chunk, d), lambda b, h, c: (b, at(c), h))
+        return pl.BlockSpec((None, chunk, heads * d),
+                            lambda b, hg, c: (b, at(c), hg))
 
-    row = pl.BlockSpec((None, None, None, 1, chunk),
-                       lambda b, h, c: (b, h, at(c), 0, 0))
-    state = pl.BlockSpec((None, None, None, vd, kd),
-                         lambda b, h, c: (b, h, at(c), 0, 0))
+    row = pl.BlockSpec((None, heads, None, 1, chunk),
+                       lambda b, hg, c: (b, hg, at(c), 0, 0))
+    state = pl.BlockSpec((None, heads, None, vd, kd),
+                         lambda b, hg, c: (b, hg, at(c), 0, 0))
     return tokens(kd), tokens(vd), row, state
 
 
@@ -323,34 +396,43 @@ def _compiler_params():
         dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
-def _pallas_fwd(q, k, v, gc, beta, chunk):
+# each launch in a jit of its own: a step calls them twelve times (four
+# layers; forward, recomputed, backward) and jax traces and lowers a
+# kernel once a shape, not once a call. The jit holds the launch ALONE,
+# on the arrays as the kernel takes them: with the reshapes around it
+# inside too, XLA formed other fusions round the kernels in a recomputed
+# block and the Kimi step's other operations cost 10 ms more (PR 38)
+@jax.jit
+def _launch_fwd(q, k, v, gc, rows):
     from jax.experimental.pallas import tpu as pltpu
 
-    b, t, h, kd = q.shape
-    vd = v.shape[-1]
-    nc = t // chunk
-    keys, vals, row, state = _specs(h, kd, vd, chunk, nc, reverse=False)
-    o, states = kernel_call(
-        "kda_chunk_fwd", _fwd_kernel, grid=(b, h, nc),
+    b, t, _ = q.shape
+    _, h, nc, _, chunk = rows.shape
+    kd, vd = q.shape[-1] // h, v.shape[-1] // h
+    heads = _heads_a_step(h, kd, vd, chunk)
+    keys, vals, row, state = _specs(heads, kd, vd, chunk, nc, reverse=False)
+    return kernel_call(
+        "kda_chunk_fwd", _fwd_kernel, grid=(b, h // heads, nc),
         in_specs=[keys, keys, vals, keys, row],
         out_specs=[vals, state],
         out_shape=[_sds((b, t, h * vd), _F32, q),
                    _sds((b, h, nc, vd, kd), _F32, q)],
-        scratch_shapes=[pltpu.VMEM((vd, kd), _F32)],
+        scratch_shapes=[pltpu.VMEM((heads, vd, kd), _F32)],
         compiler_params=_compiler_params(),
-    )(_flat(q), _flat(k), _flat(v), _flat(gc), _beta_rows(beta, chunk))
-    return o.reshape(b, t, h, vd), states
+    )(q, k, v, gc, rows)
 
 
-def _pallas_bwd(q, k, v, gc, beta, states, do, chunk):
+@jax.jit
+def _launch_bwd(q, k, v, gc, rows, states, do):
     from jax.experimental.pallas import tpu as pltpu
 
-    b, t, h, kd = q.shape
-    vd = v.shape[-1]
-    nc = t // chunk
-    keys, vals, row, state = _specs(h, kd, vd, chunk, nc, reverse=True)
-    dq, dk, dv, dgc, dbeta = kernel_call(
-        "kda_chunk_bwd", _bwd_kernel, grid=(b, h, nc),
+    b, t, _ = q.shape
+    _, h, nc, vd, kd = states.shape
+    chunk = rows.shape[-1]
+    heads = _heads_a_step(h, kd, vd, chunk)
+    keys, vals, row, state = _specs(heads, kd, vd, chunk, nc, reverse=True)
+    return kernel_call(
+        "kda_chunk_bwd", _bwd_kernel, grid=(b, h // heads, nc),
         in_specs=[keys, keys, vals, keys, row, state, vals],
         out_specs=[keys, keys, vals, keys, row],
         out_shape=[_sds((b, t, h * kd), _F32, q),
@@ -358,10 +440,21 @@ def _pallas_bwd(q, k, v, gc, beta, states, do, chunk):
                    _sds((b, t, h * vd), _F32, q),
                    _sds((b, t, h * kd), _F32, q),
                    _sds((b, h, nc, 1, chunk), _F32, q)],
-        scratch_shapes=[pltpu.VMEM((vd, kd), _F32)],
+        scratch_shapes=[pltpu.VMEM((heads, vd, kd), _F32)],
         compiler_params=_compiler_params(),
-    )(_flat(q), _flat(k), _flat(v), _flat(gc), _beta_rows(beta, chunk),
-      states, _flat(do))
+    )(q, k, v, gc, rows, states, do)
+
+
+def _pallas_fwd(q, k, v, gc, beta, chunk):
+    o, states = _launch_fwd(_flat(q), _flat(k), _flat(v), _flat(gc),
+                            _beta_rows(beta, chunk))
+    return o.reshape(v.shape), states
+
+
+def _pallas_bwd(q, k, v, gc, beta, states, do, chunk):
+    dq, dk, dv, dgc, dbeta = _launch_bwd(
+        _flat(q), _flat(k), _flat(v), _flat(gc), _beta_rows(beta, chunk),
+        states, _flat(do))
     return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
             dgc.reshape(q.shape), _beta_from_rows(dbeta))
 
@@ -452,6 +545,7 @@ def chunk_kda(q, k, v, g, beta, chunk=CHUNK):
     kernel = _kernel_takes(q, v, chunk)
     if kernel:
         bump("kda_chunk", "pallas", **kda_work(b, t + pad, h, kd, vd))
+        bump("kda_chunk", f"heads{_heads_a_step(h, kd, vd, chunk)}")
     else:
         bump("kda_chunk", "xla",
              f"dispatch ineligible (q {tuple(q.shape)}, v {tuple(v.shape)}"

@@ -1,8 +1,11 @@
 """KDA (ops/pallas/kda.py): the chunked gated delta rule — its XLA form
 and its Pallas kernels in interpret mode — against the token-by-token
 recurrence it replaces, forward and all five gradients, at two chunk
-sizes, a sequence that is no multiple of the chunk, and decays strong
-enough to overflow a naive e^G / e^-G split. Real Mosaic lowering is
+sizes, a sequence that is no multiple of the chunk, decays strong
+enough to overflow a naive e^G / e^-G split, and keys correlated enough
+to lose a triangular inverse formed from powers of the whole chunk's
+matrix (PR 38); the kernels at every number of heads a grid step. Real
+Mosaic lowering is
 ``chip_smoke.py kernels``' (and the v5e compile in the benchmark's
 tests)."""
 import functools
@@ -60,6 +63,19 @@ def inputs(seed, b, t, h, kd, vd, decay):
     return (q, k, v, g, beta), jax.random.normal(ks[5], (b, t, h, vd))
 
 
+def correlated_inputs(seed, b, t, h, kd, vd, cosine=0.8, beta=0.99,
+                      decay=0.05):
+    """Unit keys that share a component a head (mean cosine ``cosine``
+    between any two), a write strength near 1 and a weak decay: what the
+    Kimi cell's last KDA layer reads after some fifty steps (PR 37)."""
+    (q, k, v, g, _), w = inputs(seed, b, t, h, kd, vd, decay)
+    common = jax.random.normal(jax.random.key(seed + 100), (b, 1, h, kd))
+    common = common / jnp.linalg.norm(common, axis=-1, keepdims=True)
+    k = cosine ** 0.5 * common + (1 - cosine) ** 0.5 * k
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    return (q, k, v, g, jnp.full((b, t, h), beta)), w
+
+
 def _rel(a, b):
     return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
 
@@ -95,18 +111,70 @@ def test_kernels_match_the_recurrence(interp, t, chunk):
     assert counters.snapshot()["kda_chunk.pallas"] >= 1
 
 
-def test_kernels_match_the_xla_form_on_the_same_chunks(interp):
-    args, w = inputs(2, 2, 128, 2, 128, 128, 1.0)
+def test_correlated_keys_match_the_recurrence():
+    """Keys at a mean cosine of 0.8, beta 0.99, decay <= 0.05, four
+    chunks of 64: the recurrence's state stays under 10 and the chunked
+    form must follow it. FAILS on the parent's ``_unit_lower_inverse``
+    (the product form on the whole 64 x 64 matrix: its powers reach
+    C(62, 31) 0.79^31 and their float32 rounding is larger than the
+    inverse): there the output and all five gradients come out
+    non-finite where the change reads 1.3e-5 to 1.8e-5; this is the
+    fault that took the Kimi cell's state to 1e24 and NaN."""
+    args, w = correlated_inputs(5, 1, 256, 2, 16, 24)
+    _agree(args, w, 64, 2e-4)
+    assert counters.snapshot().get("kda_chunk.xla", 0) >= 1
 
-    def run(kernel):
+
+def test_kernels_match_the_recurrence_on_correlated_keys(interp):
+    """The same case through the kernels (128-wide heads), which share
+    the inverse with the XLA form; the parent's kernels fail it too."""
+    args, w = correlated_inputs(6, 1, 256, 2, 128, 128)
+    _agree(args, w, 64, 2e-4)
+    assert counters.snapshot()["kda_chunk.pallas"] >= 1
+
+
+@pytest.mark.parametrize("heads,taken", [(2, 2), (8, 4), (4, 4), (3, 1)])
+def test_kernels_match_the_xla_form_on_the_same_chunks(interp, heads, taken):
+    """G heads a grid step, G the largest of 8, 4, 2, 1 that divides the
+    head count and fits the launch's VMEM (four 128-wide heads do, eight
+    do not): every G runs the same chunks as the XLA form, and the
+    dispatch says which it took."""
+    assert kda._heads_a_step(heads, 128, 128, 64) == taken
+    args, w = inputs(2, 2 if heads == 2 else 1, 128, heads, 128, 128, 1.0)
+
+    def run(fn):
         return jax.value_and_grad(
-            lambda *a: jnp.sum(kda._chunk_kda(*a, 64, kernel) * w),
-            argnums=(0, 1, 2, 3, 4))(*args)
+            lambda *a: jnp.sum(fn(*a) * w), argnums=(0, 1, 2, 3, 4))(*args)
 
-    (lk, gk), (lx, gx) = run(True), run(False)
+    (lk, gk) = run(lambda *a: kda.chunk_kda(*a, chunk=64))
+    (lx, gx) = run(lambda *a: kda._chunk_kda(*a, 64, False))
     assert float(lk) == pytest.approx(float(lx), rel=1e-5)
     for a, b in zip(gk, gx):
         assert _rel(a, b) < 1e-5
+    snap = counters.snapshot()
+    assert snap["kda_chunk.pallas"] == snap[f"kda_chunk.heads{taken}"] == 1
+    assert [k for k in snap if k.startswith("kda_chunk.heads")] \
+        == [f"kda_chunk.heads{taken}"] and "kda_chunk.xla" not in snap
+
+
+def test_a_launch_is_traced_once_a_shape(interp, monkeypatch):
+    """Each launch sits in a ``jax.jit`` of its own: three layers' calls
+    at one shape trace the kernel's body once (a Kimi step has twelve
+    launches; jax would trace and lower each on every process start)."""
+    traced = []
+    fwd = kda._chunk_fwd
+    monkeypatch.setattr(kda, "_chunk_fwd",
+                        lambda *a: traced.append(1) or fwd(*a))
+    kda._launch_fwd.clear_cache()
+    args, _ = inputs(7, 1, 128, 2, 128, 128, 0.2)
+
+    @jax.jit
+    def three_layers(*a):
+        return sum(kda.chunk_kda(*a) for _ in range(3))
+
+    three_layers(*args)
+    assert len(traced) == 1
+    kda._launch_fwd.clear_cache()
 
 
 def test_narrow_heads_take_the_xla_form(interp):
@@ -129,29 +197,55 @@ def test_declared_work_is_the_recurrences(interp):
     assert work["kda_chunk_bwd"]["bytes"] == 2 * work["kda_chunk_fwd"]["bytes"]
 
 
-def test_unit_lower_inverse():
-    n = np.tril(np.random.RandomState(0).randn(64, 64), -1).astype("float32")
-    inv = kda._unit_lower_inverse(jnp.asarray(n * 0.3))
-    np.testing.assert_allclose(np.asarray(inv) @ (np.eye(64) + n * 0.3),
-                               np.eye(64), atol=2e-4)
+def _random_lower(c):
+    n = np.tril(np.random.RandomState(0).randn(c, c), -1)
+    return (n * 0.3).astype("float32")
 
 
-#: sha256 of ``kda_mix``'s lowered text (below) on commit 4e54371, the
-#: parent of PR 36
+def _gram_lower(c):
+    """beta M of ``c`` unit keys at a mean cosine of 0.8, beta 0.99."""
+    rng = np.random.RandomState(1)
+    k = rng.randn(c, 128)
+    k /= np.linalg.norm(k, axis=1, keepdims=True)
+    common = rng.randn(128)
+    k = 0.8 ** 0.5 * common / np.linalg.norm(common) + 0.2 ** 0.5 * k
+    k /= np.linalg.norm(k, axis=1, keepdims=True)
+    return (np.tril(k @ k.T, -1) * 0.99).astype("float32")
+
+
+@pytest.mark.parametrize("c", [64, 32, 48])
+@pytest.mark.parametrize("lower", [_random_lower, _gram_lower])
+def test_unit_lower_inverse(lower, c):
+    """Against float64, entry by entry; the Gram case is what a chunk of
+    correlated keys hands it (the parent's product form errs by 1e8
+    there at 64 rows; the true inverse's entries are under 1)."""
+    n = lower(c)
+    inv = kda._unit_lower_inverse(jnp.asarray(n))
+    want = np.linalg.inv(np.eye(c) + n.astype("float64"))
+    np.testing.assert_allclose(np.asarray(inv), want, atol=2e-4)
+    np.testing.assert_allclose(np.asarray(inv) @ (np.eye(c) + n),
+                               np.eye(c), atol=2e-4)
+
+
+#: sha256 of ``kda_mix``'s lowered text (below) as PR 38 left it
 KDA_MIX_TEXT = {
     "bfloat16":
-        "c5e4df10cb542d6e6fb898d83bd826e8b3d81ad0b6cf43ca2d0e256ca699c035",
+        "1a12e487297b907f8871b94f124fc29673bbb76db85e2f4167ab5e03e03a6fa8",
     "float32":
-        "533aaa3b7c18f053a85af416b27109aa4a2e02f19c54d0cf47d316dc4f25aa1a"}
+        "b619b8b94cb46424f11bc07c83e90227705176a905d282e45a15f494bb1c5090"}
 
 
 @pytest.mark.parametrize("dtype", sorted(KDA_MIX_TEXT))
-def test_kda_mix_lowers_to_the_text_it_lowered_to_before_pr_36(dtype):
-    """PR 36 gave the Mamba-2 mixer fused stages of its own and left
-    ``F.short_conv`` and ``kda_mix`` as they were: the KDA layer,
-    differentiated under ``jax.checkpoint`` as a block of the Kimi cell
-    is, lowers to the parent's StableHLO byte for byte (the projections
-    in the step's autocast type and in float32)."""
+def test_kda_mix_lowers_to_its_pinned_text(dtype):
+    """The KDA layer, differentiated under ``jax.checkpoint`` as a block
+    of the Kimi cell is, lowers to one StableHLO text (the projections
+    in the step's autocast type and in float32): a PR that means to
+    leave ``F.short_conv``, ``kda_mix`` and the chunk formulas alone
+    keeps these digests (PR 36 did, byte for byte). Re-pinned ON PURPOSE
+    by PR 38, which changed the chunk formulas' arithmetic and nothing
+    else of the layer: ``_unit_lower_inverse`` solves by 16 x 16 blocks
+    (before: c5e4df10... / 533aaa3b..., from commit 4e54371). The next
+    PR that changes the layer (ROADMAP S15) re-pins them again."""
     import hashlib
 
     from paddle_tpu.nn.linear_attention import kda_mix

@@ -130,11 +130,16 @@ def lowered_digest(name):
     return hashlib.sha256(step.lower(*batch).as_text().encode()).hexdigest()
 
 
-#: sha256 of each builder's lowered step text on the parent commit 3f20322
-#: (``python tests/test_step_numerics.py`` on that tree prints them)
+#: sha256 of each builder's lowered step text on PR 37's parent commit
+#: 3f20322 (``PYTHONPATH=. python tests/test_step_numerics.py`` prints
+#: them). ``kimi`` was re-pinned ON PURPOSE by PR 38, which changed the
+#: KDA chunk formulas' arithmetic (``kda._unit_lower_inverse`` solves by
+#: 16 x 16 blocks; before: 19df3056...) and touched nothing the other
+#: three builders run: their digests are PR 37's, byte for byte, which is
+#: the proof that no other cell's program changed.
 PARENT_TEXT = {
     "bert": "cc586cdb4418fd36d06f83fe638f348b7835e82c2ae1a579a405c563e55611bf",
-    "kimi": "19df3056a2dcd7e80e638a44d4badd7f968f51c2490a9ad2c5735374cb36654b",
+    "kimi": "8600284c94389648ef1678bc431a870aeb6bfd9a093fbe8cb3fafdf9b5640dec",
     "mellum":
         "9b230a9ff398b840b30a848609d18c6a5b923306d689e07f1df8d1b46c780912",
     "nemotron":

@@ -39,18 +39,41 @@ F32, BF16 = jnp.float32, jnp.bfloat16
 KDA = (1, 8192, 32, 128)
 
 
+def _pallas_grids(fn, *shapes):
+    """The grid of every ``pallas_call`` ``fn`` traces, nested jits
+    included."""
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield tuple(eqn.params["grid_mapping"].grid)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from walk(sub)
+
+    args = [jax.ShapeDtypeStruct(s, d) for s, d in shapes]
+    return list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
 @pytest.mark.parametrize("which", ["fwd", "bwd"])
 def test_kda_chunk_kernels_compile_at_the_cells_shapes(one_chip, which):
+    """Both launches with the G heads a grid step the dispatch picks for
+    the cell's 32 heads of 128 x 128 (PR 38): the grid has H / G head
+    steps, and G chains' blocks and temporaries fit the scoped VMEM the
+    launch asks for."""
     from paddle_tpu.ops.pallas import kda
 
+    b, t, h, d = KDA
+    heads = kda._heads_a_step(h, d, d, kda.CHUNK)
+    assert heads in (4, 8)
+    launch = kda._pallas_fwd if which == "fwd" else kda._pallas_bwd
     seq = [(KDA, F32)] * 4 + [(KDA[:3], F32)]
-    if which == "fwd":
-        out = _compile(lambda *a: kda._pallas_fwd(*a, kda.CHUNK), one_chip,
-                       *seq)
-    else:
-        states = (1, 32, 8192 // kda.CHUNK, 128, 128)
-        out = _compile(lambda *a: kda._pallas_bwd(*a, kda.CHUNK), one_chip,
-                       *seq, (states, F32), (KDA, F32))
+    if which == "bwd":
+        seq += [((b, h, t // kda.CHUNK, d, d), F32), (KDA, F32)]
+
+    def fn(*a):
+        return launch(*a, kda.CHUNK)
+
+    assert _pallas_grids(fn, *seq) == [(b, h // heads, t // kda.CHUNK)]
+    out = _compile(fn, one_chip, *seq)
     assert f"kda_chunk_{which}" in out.as_text()
 
 
