@@ -26,11 +26,17 @@ kind registered there.
             the file gives a ``rope`` entry
             else ``linear_attn_config.kda_layers`` (1-based) -> ``kda``
             (``nn.KimiDeltaAttention``); every other layer -> ``mla``
-            (``nn.MLAttention``, NoPE)
+            (``nn.MLAttention``: NoPE where the file says
+            ``mla_use_nope``, else its ``qk_rope_head_dim`` part rotated
+            by ``rope_theta``, de-interleaved first where the file says
+            ``rope_interleave``; ``q_lora_rank`` and ``rope_scaling``
+            have to be null)
     ffn     with ``mlp_layer_types``: ``sparse`` -> ``moe``, ``dense`` ->
             ``dense``
             else the first ``first_k_dense_replace`` layers -> ``dense``;
-            the others -> ``moe``
+            the others -> ``moe`` where the file counts experts
+            (``num_experts`` or ``n_routed_experts``) and ``dense``
+            where it counts none
             ``dense``: ``nn.GatedFFN`` of ``intermediate_size``
             (``hidden_act``), or for a config that says
             ``mlp_hidden_act`` (``nemotron_h``) ``nn.PlainFFN``
@@ -41,10 +47,11 @@ kind registered there.
             ``moe_shared_expert_intermediate_size`` where the file says
             it); experts gated, or plain relu^2 ones for a config that
             says ``mlp_hidden_act``; scores by
-            ``moe_router_activation_func``, or softmax for a config that
-            lacks it and says ``norm_topk_prob``, which is then the
-            renormalisation (``nemotron_h`` says ``norm_topk_prob`` and
-            scores by sigmoid: its file has to say so)
+            ``moe_router_activation_func`` or ``scoring_func``, or
+            softmax for a config that has neither key and says
+            ``norm_topk_prob``, which is then the renormalisation (a
+            file that says ``norm_topk_prob``, scores by sigmoid and has
+            neither key has to gain one)
 
 The norms' epsilon is ``rms_norm_eps`` or ``layer_norm_epsilon``.
 A chip's share of an expert-parallel deployment is said with
@@ -142,11 +149,20 @@ def _mixer_kda(cfg, layer):
 def _mixer_mla(cfg, layer):
     if cfg.get("q_lora_rank") is not None:
         raise NotImplementedError("MLA with a low-rank query projection")
+    # a file that does not say ``mla_use_nope`` rotates its ``d_pe`` part
+    rope = None
+    if not cfg.get("mla_use_nope", False):
+        if cfg.get("rope_scaling") is not None:
+            raise NotImplementedError(
+                "MLA with rope_scaling (scaled frequencies and the "
+                "softmax's mscale)")
+        rope = {"rope_theta": cfg["rope_theta"],
+                "interleave": cfg.get("rope_interleave", False)}
     return nn.MLAttention(
         cfg["hidden_size"], cfg["num_attention_heads"],
         cfg["qk_nope_head_dim"], cfg["qk_rope_head_dim"],
         cfg["v_head_dim"], cfg["kv_lora_rank"],
-        epsilon=cfg["rms_norm_eps"], rotary=not cfg["mla_use_nope"])
+        epsilon=cfg["rms_norm_eps"], rope=rope)
 
 
 def _ffn_dense(cfg):
@@ -158,11 +174,12 @@ def _ffn_dense(cfg):
 
 
 def _ffn_moe(cfg):
-    # a file that does not say how its router scores and says
-    # norm_topk_prob follows the Qwen-MoE convention: softmax over all
-    # experts, then the top k, renormalised if it says so
-    score = cfg.get("moe_router_activation_func",
-                    "softmax" if "norm_topk_prob" in cfg else "sigmoid")
+    # a file that does not say how its router scores (under either key)
+    # and says norm_topk_prob follows the Qwen-MoE convention: softmax
+    # over all experts, then the top k, renormalised if it says so
+    score = _first(cfg, "moe_router_activation_func", "scoring_func",
+                   default="softmax" if "norm_topk_prob" in cfg
+                   else "sigmoid")
     if score not in SCORE_FUNCS:
         raise NotImplementedError(
             f"router scores {score!r}: {sorted(SCORE_FUNCS)} are built "
@@ -219,7 +236,7 @@ def ffn_kind(cfg, layer: int):
         return {"sparse": "moe", "dense": "dense"}[
             cfg["mlp_layer_types"][layer - 1]]
     dense = layer <= cfg.get("first_k_dense_replace", 0) \
-        or "num_experts" not in cfg \
+        or _first(cfg, "num_experts", "n_routed_experts") is None \
         or (layer - 1) % cfg.get("moe_layer_freq", 1) != 0
     return "dense" if dense else "moe"
 

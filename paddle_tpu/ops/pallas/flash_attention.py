@@ -1133,10 +1133,14 @@ def _local_attention(q, k, v, is_causal):
         out = _flash_attention_pallas(q, k, v, causal=is_causal)
         bump("flash_attention", "pallas",
              **_work("stream", q, k, v, is_causal))
+        if not _one_width(q, v):
+            # a value width of its own: latent attention's 192 / 128
+            bump("flash_attention", "latent")
         return out
     bump("flash_attention", "xla",
-         f"dispatch ineligible (q {tuple(q.shape)}, causal="
-         f"{is_causal}; floor/modulus in _pallas_ok{_auto_note()})")
+         f"dispatch ineligible (q {tuple(q.shape)}, values "
+         f"{v.shape[-1]} wide, causal={is_causal}; floor/modulus in "
+         f"_pallas_ok{_auto_note()})")
     return _xla_attention(q, k, v, None, 0.0, is_causal, None)
 
 

