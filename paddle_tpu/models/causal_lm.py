@@ -57,8 +57,14 @@ The norms' epsilon is ``rms_norm_eps`` or ``layer_norm_epsilon``.
 A chip's share of an expert-parallel deployment is said with
 ``experts_held`` / ``expert_offset`` (the router keeps all its outputs).
 ``loss`` goes through the fused vocabulary cross-entropy, so the
-``(tokens, vocabulary)`` logits never exist; ``recompute=True`` keeps
-only each block's input for the backward and runs the block again there.
+``(tokens, vocabulary)`` logits never exist. ``recompute=True`` runs each
+block again in the backward (``optimizer.meta.recompute``) and keeps of
+it, for that: its input, its parameters, and the outputs of the flash
+attention kernels inside it (the attention output and its logsumexp,
+H x (2 dv + 4) bytes a token). The second run brings q, k, v back from
+the projections; the attention kernel's forward launch, the one O(T^2)
+piece of a block, would only write those two arrays again, so it runs
+once a step and not twice.
 """
 from __future__ import annotations
 

@@ -52,6 +52,9 @@ _COUNTS: collections.Counter = collections.Counter()
 _capture: Optional[Dict[str, list]] = None
 #: whether the trace being captured is differentiated right now
 _differentiated = False
+#: whether the trace in progress is a segment that
+#: ``optimizer.meta.recompute`` runs again in the backward
+_recomputed = False
 #: step name -> the work of one execution, from its latest trace
 _STEP_WORK: Dict[str, Dict[str, Dict[str, float]]] = {}
 
@@ -107,6 +110,23 @@ def differentiated():
         yield
     finally:
         _differentiated = outer
+
+
+@contextlib.contextmanager
+def recomputed():
+    """Inside, ``optimizer.meta.recompute`` traces a segment on the jit
+    path: its checkpoint keeps what a kernel's forward rule names for it
+    (:func:`in_recomputed`), and the dispatch counts that."""
+    global _recomputed
+    outer, _recomputed = _recomputed, True
+    try:
+        yield
+    finally:
+        _recomputed = outer
+
+
+def in_recomputed() -> bool:
+    return _recomputed
 
 
 def step_work(step: str) -> Dict[str, Dict[str, float]]:

@@ -20,11 +20,20 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from .counters import kernel_call, nbytes
 
 _NEG_INF = -1e30
 _F32 = jnp.float32
+#: what every forward rule here calls its kernel's output and logsumexp:
+#: ``optimizer.meta.recompute`` keeps the values of this name across a
+#: recomputed segment, so the segment's second run launches no forward
+#: kernel (its q, k, v come back from the projections; the O(T^2) launch
+#: would only write again what the first had written). An identity
+#: outside a checkpoint with a policy: it lowers to nothing
+KEPT = "flash_attention_out_lse"
+_kept = functools.partial(checkpoint_name, name=KEPT)
 
 
 def _xla_attention(q, k, v, mask, dropout_p, is_causal, key_rng,
@@ -447,8 +456,8 @@ def _flash_attention_core_fwd(q, k, v, causal, block_q, block_kv,
     b, ql, h, d = q.shape
     sm_scale = 1.0 / math.sqrt(d)
     qm, km, vm = _mergeheads(q), _mergeheads(k), _mergeheads(v)
-    out_m, lse = _fwd_call(qm, km, vm, causal, block_q, block_kv, sm_scale,
-                           window=window)
+    out_m, lse = _kept(_fwd_call(qm, km, vm, causal, block_q, block_kv,
+                                 sm_scale, window=window))
     return _splitheads(out_m, b, h), (qm, km, vm, out_m, lse, b, h)
 
 
@@ -623,8 +632,8 @@ def _flash_attention_core_masked_fwd(q, k, v, mask_bias, causal, block_q,
     sm_scale = 1.0 / math.sqrt(d)
     qm, km, vm = _mergeheads(q), _mergeheads(k), _mergeheads(v)
     mm = mask_bias.astype(_F32)[:, None, :]      # (b, 1, kl), no h copy
-    out_m, lse = _fwd_call(qm, km, vm, causal, block_q, block_kv, sm_scale,
-                           mask_bias=mm, heads=h)
+    out_m, lse = _kept(_fwd_call(qm, km, vm, causal, block_q, block_kv,
+                                 sm_scale, mask_bias=mm, heads=h))
     return (_splitheads(out_m, b, h),
             (qm, km, vm, out_m, lse, mm, mask_bias, b, h))
 
@@ -664,8 +673,8 @@ def _flash_attention_core_dropout_fwd(q, k, v, seed, causal, block_q,
     b, ql, h, d = q.shape
     sm_scale = 1.0 / math.sqrt(d)
     qm, km, vm = _mergeheads(q), _mergeheads(k), _mergeheads(v)
-    out_m, lse = _fwd_call(qm, km, vm, causal, block_q, block_kv, sm_scale,
-                           dropout_p=dropout_p, seed=seed)
+    out_m, lse = _kept(_fwd_call(qm, km, vm, causal, block_q, block_kv,
+                                 sm_scale, dropout_p=dropout_p, seed=seed))
     return _splitheads(out_m, b, h), (qm, km, vm, out_m, lse, seed, b, h)
 
 
@@ -897,9 +906,9 @@ def _flash_attention_core_short(q, k, v, seed, causal, dropout_p):
 
 def _flash_attention_core_short_fwd(q, k, v, seed, causal, dropout_p):
     qp, kp, vp = _short_pack(q), _short_pack(k), _short_pack(v)
-    out_p, lse = _short_call(
+    out_p, lse = _kept(_short_call(
         "flash_attention_short_fwd", _short_fwd_kernel, (qp, kp, vp), None,
-        seed, q.shape[-1], causal, dropout_p)
+        seed, q.shape[-1], causal, dropout_p))
     return _short_unpack(out_p, q.shape), (qp, kp, vp, out_p, lse, seed)
 
 
@@ -1097,13 +1106,26 @@ def _work(kind, q, k, v, causal, window=None):
             "grad_work": {bwd: (8.0 * mm, 2 * qkv + 2 * out + lse)}}
 
 
+def _bump_pallas(kind, q, k, v, causal, window=None):
+    """Count one dispatch to the ``kind`` kernels with the work it
+    declares and, traced inside a segment that ``recompute`` runs again,
+    that the segment keeps this launch's output and logsumexp
+    (:data:`KEPT`)."""
+    from .counters import bump, in_recomputed
+
+    bump("flash_attention", "pallas",
+         **_work(kind, q, k, v, causal, window))
+    if in_recomputed():
+        bump("flash_attention", "kept_across_recompute")
+
+
 def _bump_short(q, k, v, causal):
     """Count one dispatch to the short kernels, and whether they took the
     projections' layout as it is (``short_packed``) or behind the
     transposing wrapper."""
     from .counters import bump
 
-    bump("flash_attention", "pallas", **_work("short", q, k, v, causal))
+    _bump_pallas("short", q, k, v, causal)
     if _short_block_width(q.shape[2], q.shape[3]) is not None:
         bump("flash_attention", "short_packed")
 
@@ -1131,8 +1153,7 @@ def _local_attention(q, k, v, is_causal):
     # choice == "stream" or no autotune verdict: static streaming path
     if _pallas_ok(q, k, is_causal, v=v):
         out = _flash_attention_pallas(q, k, v, causal=is_causal)
-        bump("flash_attention", "pallas",
-             **_work("stream", q, k, v, is_causal))
+        _bump_pallas("stream", q, k, v, is_causal)
         if not _one_width(q, v):
             # a value width of its own: latent attention's 192 / 128
             bump("flash_attention", "latent")
@@ -1177,8 +1198,7 @@ def _grouped_attention(q, k, v, mask, dropout_p, is_causal, key_rng,
     if plain and _pallas_ok(q, k, is_causal, v=v):
         out = _flash_attention_pallas(q, k, v, causal=is_causal,
                                       window=window)
-        bump("flash_attention", "pallas",
-             **_work("stream", q, k, v, is_causal, window))
+        _bump_pallas("stream", q, k, v, is_causal, window)
         if group > 1:
             bump("flash_attention", "grouped")
         if window is not None:
@@ -1346,8 +1366,7 @@ def flash_attention_or_fallback(q, k, v, mask=None, dropout_p=0.0,
         # b32/s512)
         out = _flash_attention_pallas_dropout(
             q, k, v, _rng_seed_arr(key_rng), dropout_p, causal=is_causal)
-        bump("flash_attention", "pallas",
-             **_work("stream", q, k, v, is_causal))
+        _bump_pallas("stream", q, k, v, is_causal)
         return out
     if mask is not None and dropout_p == 0.0 and _one_width(q, v) \
             and _pallas_ok(q, k, is_causal):
@@ -1357,8 +1376,7 @@ def flash_attention_or_fallback(q, k, v, mask=None, dropout_p=0.0,
         if bias is not None:
             out = _flash_attention_pallas_masked(q, k, v, bias,
                                                  causal=is_causal)
-            bump("flash_attention", "pallas",
-                 **_work("stream", q, k, v, is_causal))
+            _bump_pallas("stream", q, k, v, is_causal)
             return out
     bump("flash_attention", "xla",
          f"dropout/mask dispatch ineligible (q {tuple(q.shape)}, mask="

@@ -7,6 +7,8 @@ paddle_tpu.parallel.pipeline; recompute maps onto jax.checkpoint.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -88,6 +90,19 @@ def _segment_params(fn):
     return []
 
 
+@functools.cache
+def _kept_policy():
+    """What a recomputed segment keeps beside its inputs: the values
+    named ``flash_attention.KEPT``. ONE object for every segment: jax
+    keys its partial-evaluation caches on the policy, and a policy a
+    block would part the jitted helpers the blocks share (``tril``,
+    ``silu``, ...) once a block."""
+    from ..ops.pallas import flash_attention
+
+    return jax.checkpoint_policies.save_only_these_names(
+        flash_attention.KEPT)
+
+
 def recompute(function, *args, **kwargs):
     """Eager activation rematerialization (reference
     fleet.utils.recompute / RecomputeOptimizer checkpoints): run
@@ -101,7 +116,16 @@ def recompute(function, *args, **kwargs):
     inside the segment replays the bitwise-identical mask.
 
     Inside a jit trace (TrainStep) the same call lowers to
-    ``jax.checkpoint`` — XLA remat, same semantics, compiled."""
+    ``jax.checkpoint`` — XLA remat, same semantics, compiled. There a
+    segment keeps its inputs, its parameters AND what the flash
+    attention kernels inside it wrote, their output and logsumexp
+    (``flash_attention.KEPT``: H x (2 dv + 4) bytes a token beside the
+    segment's input): the backward's second run of the segment brings
+    q, k, v back from the projections, which is what recomputation is
+    for, and would launch the O(T^2) forward kernel only to write the
+    same two arrays again. There is no length the kernels accept at
+    which that launch is cheaper than the bytes, so it is no option.
+    The dispatch counts ``flash_attention.kept_across_recompute``."""
     from ..framework import nan_inf
     from ..framework import random as random_mod
     from ..framework import tape as tape_mod
@@ -146,9 +170,13 @@ def recompute(function, *args, **kwargs):
         # to jax.checkpoint over a pure function of (args, params)
         # (handing out the FLAGS_check_nan_inf rows made inside, in a
         # step built with the flag set; jax.checkpoint itself else)
-        vals = nan_inf.checkpoint(
-            lambda av, pv: _call_with(av, pv, meta))(
-                [t.value for t in arg_ts], [p.value for p in params])
+        from ..ops.pallas import counters
+
+        with counters.recomputed():
+            vals = nan_inf.checkpoint(
+                lambda av, pv: _call_with(av, pv, meta),
+                policy=_kept_policy())(
+                    [t.value for t in arg_ts], [p.value for p in params])
         outs = [Tensor(v, stop_gradient=False) for v in vals]
         return outs[0] if meta["single"] else tuple(outs)
 
