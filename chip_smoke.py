@@ -707,6 +707,62 @@ def _kernel_checks():
     checks.append(("fused xent, the BERT cells' call: float32 at the default "
                    "precision, 80 of 512 labelled", xent_cells_call))
 
+    def xent_rows_call(fails, n=32768, hd=2048, v=49152, block=2048):
+        """A looped model's ONE head call (``reduction="none"`` on its
+        four passes' rows stacked, every row but a pass's last labelled:
+        the top rung), as its step runs it: float32 rows and table at the
+        DEFAULT matmul precision. A loss a row out, a weight a row in as
+        the cotangent. The logits path sees the operands as the product
+        does, a block of rows at a time: all of them would be 6.4 GB."""
+        from paddle_tpu.ops.pallas import counters
+
+        name = f"fused xent per row n={n} hd={hd} v={v}"
+        h, w = rnd(1, (n, hd)), rnd(2, (v, hd), scale=0.02)
+        b = jnp.zeros((v,), f32)
+        lab = jax.random.randint(jax.random.key(4), (n,), 0, v)
+        lab = jnp.where(jnp.arange(n) % 8192 == 8191, -100, lab)
+        weight = jax.random.uniform(jax.random.key(5), (n,), f32, 0.1, 1.0)
+
+        def rounded(x):
+            return x.astype(bf16).astype(f32)
+
+        def ref(h, w, b):
+            @jax.checkpoint
+            def rows_of(args):
+                hb, lb = args
+                logits = rounded(hb) @ rounded(w).T + b
+                lse = jax.scipy.special.logsumexp(logits, axis=-1)
+                ll = jnp.take_along_axis(
+                    logits, jnp.maximum(lb, 0)[:, None], 1)[:, 0]
+                return jnp.where(lb != -100, lse - ll, 0.0)
+
+            rows = jax.lax.map(rows_of, (h.reshape(-1, block, hd),
+                                         lab.reshape(-1, block))).reshape(n)
+            return jnp.sum(rows * weight), rows
+
+        def fused(h, w, b):
+            rows = fx.fused_linear_cross_entropy(h, w, b, lab,
+                                                 reduction="none")
+            return jnp.sum(rows * weight), rows
+
+        def run(f):
+            return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2),
+                                              has_aux=True))(h, w, b)
+
+        before = counters.snapshot()
+        with jax.default_matmul_precision("default"):
+            (_, rows_g), gg = run(fused)
+        took = counters.delta(before)
+        if not (took.get("fused_xent.per_row") and
+                took.get("fused_xent.pallas")):
+            fails.append(f"{name}: not dispatched to the kernels ({took})")
+        (_, rows_r), gr = run(ref)
+        _close(f"{name} rows", rows_g, rows_r, tol_of(f32), fails)
+        for g, r, nm, dt in zip(gg, gr, "hWb", (bf16, bf16, f32)):
+            _close(f"{name} d{nm}", g, r, tol_of(dt), fails)
+    checks.append(("fused xent per row, a looped head's call: 4 x 8192 rows "
+                   "at hidden 2048 on 49152 columns", xent_rows_call))
+
     # -- KDA chunk kernels against the same chunk formulas under XLA: on
     # random keys at the family's starting decays, and on keys at a mean
     # cosine of 0.8 with beta 0.99 and weak decay, where an inverse formed

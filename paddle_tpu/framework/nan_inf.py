@@ -13,6 +13,9 @@ Two readers of one flag (reference ``details/nan_inf_utils_detail.cc``):
   - *forward*, each inexact output of every sublayer call, keyed by the
     layer's parameter-name path with its container index
     (``layers.3.mixer``; a second output ``layers.3:1``), and the loss;
+    a looped model (``models.causal_lm``, ``total_ut_steps`` > 1) calls
+    one layer once a pass, and the rows made in pass t are keyed
+    ``layers.3@ut<t>`` (``layers.3.mixer@ut2``, ``final_norm@ut4``);
   - *backward*, each parameter's gradient leaf as ``value_and_grad``
     returns it, keyed by the parameter's name, deepest layer first;
   - *named probes*, :func:`probe` ``(name, x)`` at a point of interest
@@ -37,6 +40,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -47,6 +51,8 @@ from . import flags
 from .tensor import Tensor
 
 _F32 = jnp.float32
+#: what :func:`ut_step` appends to a key
+_UT = re.compile(r"@ut\d+")
 #: the record of the step being traced; None outside a trace and in a step
 #: built with the flag off. ``Layer.__call__`` and :func:`probe` read this.
 record = None
@@ -105,6 +111,7 @@ class Record:
         self.names = {id(layer): name
                       for name, layer in model.named_sublayers()}
         self.path = []          # full names of the layer calls under way
+        self.suffix = ""        # "@ut<t>" inside a looped model's pass t
         self.frames = [_Frame()]
         self.sink = None        # (GRAD_SLOTS, 3) zeros, differentiated
         self.grad_slots = 0
@@ -127,12 +134,13 @@ class Record:
             self.path.pop()
         leaves = [_array(x) for x in jax.tree_util.tree_leaves(
             out, is_leaf=lambda x: isinstance(x, Tensor))]
+        key = name + self.suffix
         for i, x in enumerate(a for a in leaves if a is not None):
-            self.add_row(f"{name}:{i}" if i else name, row(x))
+            self.add_row(f"{key}:{i}" if i else key, row(x))
         return out
 
     def probe_key(self, name):
-        return "/".join(self.path[-1:] + [name])
+        return "/".join([p + self.suffix for p in self.path[-1:]] + [name])
 
     def probe(self, name, x, grad, smallest):
         key = self.probe_key(name)
@@ -174,6 +182,11 @@ class Record:
             entries.append((key if n == 1 else f"{key}#{n}", slot, smallest))
         forward = [e for e in entries if e[1] is None]
         at = {key: i for i, (key, _, _) in enumerate(entries)}
+        # a looped model's layer under its plain name too, where its
+        # FIRST pass returned: the backward reaches that use last, and
+        # only there is a parameter's gradient the sum over its uses
+        for i, (key, _, _) in enumerate(entries):
+            at.setdefault(_UT.sub("", key), i)
 
         def owner_at(param):
             # where the layer that holds it (or, for one that is handed
@@ -214,6 +227,22 @@ def _tap(x, slot):
 
 
 _tap.defvjp(lambda x, slot: (x, None), lambda _, ct: (ct, row(ct)))
+
+
+@contextlib.contextmanager
+def ut_step(t):
+    """Inside, the rows of the step being recorded are keyed
+    ``<key>@ut<t>``: pass ``t`` of a looped model, which calls each of
+    its layers once a pass. Nothing outside a step built with the flag
+    set."""
+    if record is None:
+        yield
+        return
+    saved, record.suffix = record.suffix, f"@ut{t}"
+    try:
+        yield
+    finally:
+        record.suffix = saved
 
 
 @contextlib.contextmanager

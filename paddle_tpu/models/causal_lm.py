@@ -1,9 +1,9 @@
 """A decoder-only causal LM assembled from a model's own config keys.
 
 ``CausalLM.from_config(cfg)`` reads a Hugging Face style ``config.json``
-dict and builds: token embedding, ``num_hidden_layers`` pre-norm residual
-blocks, a final RMSNorm and an untied head. A block is a token mixer AND
-a feed-forward (``x += Mix(RMSNorm(x))``, ``x += FFN(RMSNorm(x))``) or,
+dict and builds: token embedding, ``num_hidden_layers`` residual blocks,
+a final RMSNorm and an untied head. A block is a token mixer AND a
+feed-forward (``x += Mix(RMSNorm(x))``, ``x += FFN(RMSNorm(x))``) or,
 for a config that lays its layers out by ``hybrid_override_pattern``, ONE
 sublayer behind one norm (``x += Sub(RMSNorm(x))``). Which kinds a block
 gets is decided per layer from the config, through the small tables
@@ -14,16 +14,29 @@ kind registered there.
             mixer ``mamba2``, ``*`` -> mixer ``gqa``, ``E`` -> ffn
             ``moe``, ``-`` -> ffn ``dense``; that layer's other sublayer
             is absent. Every other config: a mixer and an ffn a layer
+    norms   two a block, before each sublayer; with ``sandwich_norm`` four:
+            the sublayer's OUTPUT is normed too before it is added
+            (``x += RMSNorm(Mix(RMSNorm(x)))``, the same round the ffn)
+    passes  ``total_ut_steps`` T (default 1): the SAME blocks are walked T
+            times a step, the final norm after each pass; its output is
+            what the head and the exit gate read and what the next pass
+            starts from (below)
     mixer   ``mamba2`` -> ``nn.Mamba2Mixer`` (``mamba_num_heads`` heads
             of ``mamba_head_dim``, ``ssm_state_size``, ``n_groups``,
             ``conv_kernel``, ``time_step_*``)
             with ``layer_types``: ``sliding_attention`` / ``full_attention``
             -> ``gqa`` (``nn.GroupedQueryAttention``:
-            ``num_key_value_heads`` key heads of ``head_dim``, the
-            layer type's entry of ``rope_parameters``, ``sliding_window``
-            on the sliding layers, ``qk_norm``); a pattern's ``*`` is a
-            full layer with no per-head norms and no positions unless
-            the file gives a ``rope`` entry
+            ``num_key_value_heads`` key heads of ``head_dim``,
+            ``sliding_window`` on the sliding layers, ``qk_norm``). Its
+            positions: the layer type's entry of ``rope_parameters``, or
+            for a file in the older spelling top-level ``rope_theta``
+            (``rope_scaling`` has to be null); a file with neither key
+            is refused, one that says ``rope_parameters: null`` has none.
+            A file that states a relative-position term (``d_rel``), a
+            short convolution (``use_sconv``) or a length-dependent
+            scale (``log_scaling_alpha``) is refused by that key.
+            A pattern's ``*`` is a full layer with no per-head norms and
+            no positions unless the file gives a ``rope`` entry
             else ``linear_attn_config.kda_layers`` (1-based) -> ``kda``
             (``nn.KimiDeltaAttention``); every other layer -> ``mla``
             (``nn.MLAttention``: NoPE where the file says
@@ -65,6 +78,26 @@ H x (2 dv + 4) bytes a token). The second run brings q, k, v back from
 the projections; the attention kernel's forward launch, the one O(T^2)
 piece of a block, would only write those two arrays again, so it runs
 once a step and not twice.
+
+**A looped model** (``total_ut_steps`` T > 1). ``h^0 = E[ids]``; pass t
+walks all the blocks from ``h^{t-1}`` and ends in the final norm,
+``h^t``. A block's parameters are used T times a step and their gradient
+is the sum over the uses (each use a ``recompute`` segment of its own
+with its own kept flash outputs). One untied head and one exit gate
+``Linear(hidden, 1)`` read every pass: ``l^t_i`` the cross-entropy of
+position i from ``h^t_i``, ``a^t_i`` the gate's logit, and the exit
+distribution ``p^t_i = sigmoid(a^t_i) prod_{j<t} (1 - sigmoid(a^j_i))``
+for t < T with the rest of the mass on the last pass. ``loss`` is the
+expected loss under it with an entropy term (``exit_entropy_beta``, a
+uniform prior over exits): ``mean_i [sum_t p^t_i l^t_i + beta sum_t
+p^t_i log p^t_i]``, the distribution in float32 from log-sigmoids
+(``F.expected_exit_loss``). The head runs ONCE, on the T passes' rows
+stacked, through ``F.fused_linear_cross_entropy(reduction="none")``.
+Training never exits early: every pass runs; ``forward`` gives the last
+pass's logits. In the HLO pass t is under the scope ``ut_step<t>`` and
+gate, head and loss under ``ut_exit_loss``; the counters
+``causal_lm.ut_steps`` (T a build) and ``causal_lm.block_applications``
+(T x L a traced step) say what ran.
 """
 from __future__ import annotations
 
@@ -106,6 +139,29 @@ def _pattern_kinds(cfg, layer):
 _GQA_LAYER_TYPES = ("sliding_attention", "full_attention")
 
 
+def _gqa_rope(cfg, kind):
+    """The positions of a ``layer_types`` file's attention layer of
+    ``kind``, as ``nn.GroupedQueryAttention`` takes them: the layer
+    type's entry of ``rope_parameters`` (or the one entry for all), else
+    the older spelling's top-level ``rope_theta``. None only where the
+    file says ``rope_parameters: null``."""
+    if "rope_parameters" in cfg:
+        rope = cfg["rope_parameters"]
+        if rope is not None and "rope_theta" not in rope:
+            rope = rope[kind]               # one entry a layer type
+        return rope
+    if "rope_theta" in cfg:
+        if cfg.get("rope_scaling") is not None:
+            raise NotImplementedError(
+                "rope_scaling beside a top-level rope_theta: say the scaled "
+                "positions as a rope_parameters entry (rope_type yarn)")
+        return {"rope_type": "default", "rope_theta": cfg["rope_theta"]}
+    raise ValueError(
+        "a layer_types config with neither rope_parameters nor rope_theta: "
+        "its attention would be built without positions (say "
+        "rope_parameters: null if it has none)")
+
+
 def _mixer_gqa(cfg, layer):
     if "hybrid_override_pattern" in cfg:
         # nemotron_h's attention: full, no per-head norms, and no
@@ -115,9 +171,7 @@ def _mixer_gqa(cfg, layer):
                                cfg.get("qk_norm", False))
     else:
         kind = cfg["layer_types"][layer - 1]
-        rope, qk_norm = cfg.get("rope_parameters"), cfg.get("qk_norm", True)
-        if rope is not None and "rope_theta" not in rope:
-            rope = rope[kind]               # one entry a layer type
+        rope, qk_norm = _gqa_rope(cfg, kind), cfg.get("qk_norm", True)
     heads = cfg["num_attention_heads"]
     return nn.GroupedQueryAttention(
         cfg["hidden_size"], heads, cfg.get("num_key_value_heads", heads),
@@ -219,9 +273,20 @@ MIXERS = {"gqa": _mixer_gqa, "kda": _mixer_kda, "mamba2": _mixer_mamba2,
 FFNS = {"dense": _ffn_dense, "moe": _ffn_moe}
 
 
+#: keys by which a config states an attention variant that no mixer
+#: here builds: refused by name, never read past
+_UNBUILT_ATTENTION = {
+    "d_rel": "a relative-position term on the scores",
+    "use_sconv": "a short convolution inside the attention layer",
+    "log_scaling_alpha": "a length-dependent scale on the scores"}
+
+
 def mixer_kind(cfg, layer: int):
     """``layer`` counts from 1, as the config's layer lists do. None for
     a pattern's layer that is a feed-forward alone."""
+    for key, what in _UNBUILT_ATTENTION.items():
+        if cfg.get(key):
+            raise NotImplementedError(f"{key}: {what} is not built")
     if "hybrid_override_pattern" in cfg:
         return _pattern_kinds(cfg, layer)[0]
     if "layer_types" in cfg:
@@ -248,20 +313,30 @@ def ffn_kind(cfg, layer: int):
 
 
 class DecoderBlock(nn.Layer):
-    """``input_norm, mixer, post_norm, ffn``; or, where the layout gives
-    the layer ONE sublayer, ``norm`` and ``mixer`` or ``ffn``."""
+    """``input_norm, mixer, post_norm, ffn``, with ``sandwich_norm`` also
+    ``mixer_out_norm`` and ``ffn_out_norm`` on the sublayers' outputs; or,
+    where the layout gives the layer ONE sublayer, ``norm`` and ``mixer``
+    or ``ffn``."""
 
     def __init__(self, cfg, layer: int):
         super().__init__()
         eps, hidden = _eps(cfg), cfg["hidden_size"]
         self.mixer_kind, self.ffn_kind = (mixer_kind(cfg, layer),
                                           ffn_kind(cfg, layer))
+        self.sandwich = bool(cfg.get("sandwich_norm", False))
         if self.mixer_kind and self.ffn_kind:
             self.input_norm = nn.RMSNorm(hidden, epsilon=eps)
             self.mixer = MIXERS[self.mixer_kind](cfg, layer)
+            if self.sandwich:
+                self.mixer_out_norm = nn.RMSNorm(hidden, epsilon=eps)
             self.post_norm = nn.RMSNorm(hidden, epsilon=eps)
             self.ffn = FFNS[self.ffn_kind](cfg)
+            if self.sandwich:
+                self.ffn_out_norm = nn.RMSNorm(hidden, epsilon=eps)
         else:
+            if self.sandwich:
+                raise NotImplementedError(
+                    "sandwich_norm in a block of one sublayer")
             self.norm = nn.RMSNorm(hidden, epsilon=eps)
             if self.mixer_kind:
                 self.mixer = MIXERS[self.mixer_kind](cfg, layer)
@@ -274,7 +349,10 @@ class DecoderBlock(nn.Layer):
         one."""
         from .. import ops
 
-        if self.mixer_kind and self.ffn_kind:
+        if self.sandwich:
+            x = x + self.mixer_out_norm(self.mixer(self.input_norm(x)))
+            x = x + self.ffn_out_norm(self.ffn(self.post_norm(x)))
+        elif self.mixer_kind and self.ffn_kind:
             x = x + self.mixer(self.input_norm(x))
             x = x + self.ffn(self.post_norm(x))
         else:
@@ -309,22 +387,60 @@ class CausalLM(nn.Layer):
             [cfg["vocab_size"], cfg["hidden_size"]], attr=init)
         # the fused cross-entropy takes a bias; this head has none
         self._no_bias = Tensor(jnp.zeros((cfg["vocab_size"],), jnp.float32))
+        self.ut_steps = int(cfg.get("total_ut_steps", 1))
+        if self.ut_steps < 1:
+            raise ValueError(f"total_ut_steps {self.ut_steps}")
+        if self.ut_steps > 1:
+            from ..ops.pallas.counters import bump
+
+            # one gate for all passes; its logit decides a token's exit
+            self.exit_gate = nn.Linear(cfg["hidden_size"], 1,
+                                       weight_attr=init)
+            self.exit_entropy_beta = float(cfg["exit_entropy_beta"])
+            bump("causal_lm", "ut_steps", times=self.ut_steps)
 
     @classmethod
     def from_config(cls, cfg: dict, recompute: bool = False) -> "CausalLM":
         return cls(cfg, recompute=recompute)
 
-    def hidden(self, input_ids):
-        """(final hidden states, per-layer routing (L, 2))."""
+    def _walk(self, x):
+        """One pass: every block once, then the final norm. (normed
+        states, per-layer routing (L, 2))."""
         from .. import ops
         from ..optimizer.meta import recompute
 
-        x = self.embed(input_ids)
         routing = []
         for block in self.layers:
             x, r = recompute(block, x) if self.recompute else block(x)
             routing.append(r)
         return self.final_norm(x), ops.stack(routing, axis=0)
+
+    def hidden_passes(self, input_ids):
+        """([h^1 .. h^T], per-layer routing (L, 2) of the last pass):
+        the normed output of each of the ``total_ut_steps`` passes over
+        the SAME blocks, each pass starting from the one before."""
+        import jax
+
+        from ..framework import nan_inf
+        from ..ops.pallas.counters import bump
+
+        x = self.embed(input_ids)
+        if self.ut_steps == 1:
+            x, routing = self._walk(x)
+            return [x], routing
+        passes = []
+        for t in range(1, self.ut_steps + 1):
+            with jax.named_scope(f"ut_step{t}"), nan_inf.ut_step(t):
+                x, routing = self._walk(x)
+            bump("causal_lm", "block_applications", times=len(self.layers))
+            passes.append(x)
+        return passes, routing
+
+    def hidden(self, input_ids):
+        """(final hidden states, per-layer routing (L, 2)): of a looped
+        model, the last pass's."""
+        passes, routing = self.hidden_passes(input_ids)
+        return passes[-1], routing
 
     def forward(self, input_ids):
         from .. import ops
@@ -336,9 +452,36 @@ class CausalLM(nn.Layer):
              return_routing=False):
         """Mean cross-entropy of each position's logits against
         ``labels`` (already the next token; ``ignore_index`` where there
-        is none). With ``return_routing``, also the (L, 2) routing
-        counters of this call."""
-        h, routing = self.hidden(input_ids)
-        loss = F.fused_linear_cross_entropy(
-            h, self.head, self._no_bias, labels, ignore_index=ignore_index)
+        is none); of a looped model the expected loss over its exits
+        (the module docstring). With ``return_routing``, also the (L, 2)
+        routing counters of this call."""
+        passes, routing = self.hidden_passes(input_ids)
+        if self.ut_steps == 1:
+            loss = F.fused_linear_cross_entropy(
+                passes[0], self.head, self._no_bias, labels,
+                ignore_index=ignore_index)
+        else:
+            loss = self._expected_exit_loss(passes, labels, ignore_index)
         return (loss, routing) if return_routing else loss
+
+    def _expected_exit_loss(self, passes, labels, ignore_index):
+        import jax
+
+        from .. import amp, ops
+
+        with jax.named_scope("ut_exit_loss"):
+            # ONE head call on the T passes' rows: (T, B, S, hidden)
+            # against the labels T times over, a loss a row back
+            stacked = ops.stack(passes, axis=0)
+            tiled = ops.stack([labels] * self.ut_steps, axis=0)
+            rows = F.fused_linear_cross_entropy(
+                stacked, self.head, self._no_bias, tiled,
+                ignore_index=ignore_index, reduction="none")
+            # the gate reads passes 1 .. T-1 (the last pass takes what is
+            # left), in float32 whatever the autocast
+            with amp.auto_cast(enable=False):
+                logits = ops.squeeze(self.exit_gate(ops.cast(
+                    stacked[:-1], "float32")), axis=-1)
+            return F.expected_exit_loss(
+                rows, logits, labels, beta=self.exit_entropy_beta,
+                ignore_index=ignore_index)

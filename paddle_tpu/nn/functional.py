@@ -756,16 +756,48 @@ def _reduce_loss(loss, reduction):
 
 @primitive("fused_linear_cross_entropy", nondiff=("label",))
 def fused_linear_cross_entropy(h, weight, bias, label, ignore_index=-100,
-                               name=None):
-    """mean softmax-xent of (h @ weight^T + bias) without materialising
-    the (rows, vocab) logits in HBM: the Pallas kernel streams vocab
-    tiles with an online logsumexp (ops/pallas/fused_xent.py — the MLM
-    head's ~1 GB logits round-trips were the top non-MXU cost at
-    bert512). weight: (V, H) (embedding layout, tied-decoder ready);
-    falls back to the equivalent XLA computation off-TPU."""
+                               reduction="mean", name=None):
+    """softmax-xent of (h @ weight^T + bias) without materialising the
+    (rows, vocab) logits in HBM: the Pallas kernel streams vocab tiles
+    with an online logsumexp (ops/pallas/fused_xent.py — the MLM head's
+    ~1 GB logits round-trips were the top non-MXU cost at bert512).
+    weight: (V, H) (embedding layout, tied-decoder ready); falls back to
+    the equivalent XLA computation off-TPU. ``reduction``: ``"mean"``
+    over the labelled rows, or ``"none"``: each row's loss in ``label``'s
+    shape, float32, zero where the label is ``ignore_index``."""
     from ..ops.pallas.fused_xent import fused_linear_cross_entropy as core
 
-    return core(h, weight, bias, label, ignore_index=ignore_index)
+    return core(h, weight, bias, label, ignore_index=ignore_index,
+                reduction=reduction)
+
+
+@primitive("expected_exit_loss", nondiff=("label",))
+def expected_exit_loss(pass_loss, gate_logit, label, beta=0.0,
+                       ignore_index=-100, name=None):
+    """A looped model's training loss: the expected loss under its exit
+    distribution, with an entropy term (a uniform prior over exits).
+
+    ``pass_loss`` (T, ...): each position's loss after pass 1 .. T;
+    ``gate_logit`` (T - 1, ...): the exit gate's logit ``a^t`` after
+    pass 1 .. T - 1; ``label`` (...): positions with ``ignore_index``
+    count for nothing. In float32, from log-sigmoids:
+
+        log p^t = log sigmoid(a^t) + sum_{j<t} log sigmoid(-a^j)   t < T
+        log p^T = sum_{j<T} log sigmoid(-a^j)         (the p sum to one)
+        loss = mean_i [sum_t p^t_i l^t_i + beta sum_t p^t_i log p^t_i]
+
+    The mean is over the labelled positions."""
+    loss = pass_loss.astype(jnp.float32)
+    a = gate_logit.astype(jnp.float32)
+    stay = jnp.cumsum(jax.nn.log_sigmoid(-a), axis=0)
+    before = jnp.concatenate([jnp.zeros_like(stay[:1]), stay], axis=0)
+    log_p = before + jnp.concatenate(
+        [jax.nn.log_sigmoid(a), jnp.zeros_like(stay[:1])], axis=0)
+    p = jnp.exp(log_p)
+    each = jnp.sum(p * (loss + beta * log_p), axis=0)
+    valid = label != ignore_index
+    count = jnp.maximum(jnp.sum(valid.astype(jnp.float32)), 1.0)
+    return jnp.sum(jnp.where(valid, each, 0.0)) / count
 
 
 @primitive("softmax_with_cross_entropy")

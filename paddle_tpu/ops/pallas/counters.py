@@ -61,12 +61,13 @@ _STEP_WORK: Dict[str, Dict[str, Dict[str, float]]] = {}
 
 def bump(kernel: str, path: str, reason: str = "",
          work: Optional[Work] = None,
-         grad_work: Optional[Work] = None) -> None:
-    """Count one dispatch decision. ``work`` is what the call requires,
-    ``grad_work`` what its backward requires on top when the enclosing
-    trace differentiates it (:func:`differentiated`); both only count
-    inside a :func:`capture`."""
-    _COUNTS[f"{kernel}.{path}"] += 1
+         grad_work: Optional[Work] = None, times: int = 1) -> None:
+    """Count one dispatch decision (or ``times`` of a thing that is
+    counted in bulk: a looped model's passes, its block applications).
+    ``work`` is what the call requires, ``grad_work`` what its backward
+    requires on top when the enclosing trace differentiates it
+    (:func:`differentiated`); both only count inside a :func:`capture`."""
+    _COUNTS[f"{kernel}.{path}"] += times
     if path == "xla" and get_flag("log_pallas_fallback"):
         msg = f"pallas-fallback: {kernel} -> {path}"
         if reason:

@@ -49,6 +49,17 @@ row capacities (``_ladder``) that holds the count, picked with
 (``_tag``), so a device trace shows which rung ran and the work
 ledger charges exactly that rung's rows.
 
+``reduction="none"`` hands back each row's own loss and takes a cotangent
+a row on the way back, on the same two launches: the kernels always
+computed ``lse - ll`` a row and always took a per-row ``g`` in the
+backward; the mean's wrapper sums before it returns and broadcasts one
+``ds``. A caller that weighs rows (a looped model's expected loss over
+its exits, ``models/causal_lm.py``) stacks them and calls ONCE. One
+table used by four passes' rows is then one use of the parameter: dW is
+accumulated over all the stacked rows inside the dW launch's row axis
+and written once, where four calls would each write a float32 dW the
+size of the table (403 MB at 49,152 x 2048) for autodiff to add up.
+
 Reference analog: softmax_with_cross_entropy_op.cu fuses softmax+xent
 (but not the matmul); the matmul fusion is the TPU-native extension
 the MFU push needs (VERDICT r4 #2). XLA fallback covers ineligible
@@ -433,7 +444,7 @@ def _blocks(h, w):
 
 
 def _fused_xent_core(h, w, bias, labels, ignore_index):
-    """mean loss = sum / clamp(count): derived from the ONE sum-form
+    """mean loss = sum / clamp(count): derived from the sum-form
     custom_vjp below (autodiff of the division supplies the 1/count
     the hand-written mean backward used to hard-code — r5 review
     dedup)."""
@@ -442,18 +453,18 @@ def _fused_xent_core(h, w, bias, labels, ignore_index):
     return s / jnp.maximum(c, 1.0)
 
 
-# -- the single custom_vjp: per-shard (loss_sum, valid_count), so the
-# shard_map'd multi-device path can psum BEFORE the mean. ``rungs`` are
-# the row capacities it may run at, the last one all of its rows --------
+# -- two custom_vjps on the same two launches (:func:`_forward`,
+# :func:`_backward`). ``_fused_xent_sums``: per-shard (loss_sum,
+# valid_count), so the shard_map'd multi-device path can psum BEFORE the
+# mean. ``_fused_xent_rows``: every row's own loss, zero where the label
+# is ignored, and a cotangent a row on the way back. ``rungs`` are the
+# row capacities either may run at, the last one all of its rows ---------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _fused_xent_sums(h, w, bias, labels, ignore_index, rungs):
-    (s, c), _ = _fused_xent_sums_fwd(h, w, bias, labels, ignore_index, rungs)
-    return s, c
-
-
-def _fused_xent_sums_fwd(h, w, bias, labels, ignore_index, rungs):
+def _forward(h, w, bias, labels, ignore_index, rungs, per_row):
+    """(the sum of the rows' losses, or with ``per_row`` the (n,) losses
+    in the caller's row order; the labelled rows' count; the residuals
+    :func:`_backward` takes)."""
     n = h.shape[0]
     valid = labels != ignore_index
     # an unlabelled row that rides along in a rung (or fills the top one)
@@ -469,27 +480,36 @@ def _fused_xent_sums_fwd(h, w, bias, labels, ignore_index, rungs):
 
     def at(k):
         def run(h, safe, valid):
+            rows = None
             if k == n:
                 lse, ll = _fwd_call(h, w, bias, safe, bn, bv)
             else:
                 rows = order[:k]
                 h, safe, valid = h[rows], safe[rows], valid[rows]
                 lse, ll = _fwd_call(h, w, bias, safe, bn, bv, _tag(k, n))
+            loss = jnp.where(valid, lse - ll, 0.0)
+            if not per_row:
+                loss = jnp.sum(loss)
+            elif rows is not None:
+                # the rows left out are unlabelled: their loss is zero
+                loss = jnp.zeros((n,), _F32).at[rows].set(
+                    loss, unique_indices=True)
             # lse stays in the rung's own row order, for its backward
-            return (jnp.sum(jnp.where(valid, lse - ll, 0.0)),
-                    jnp.pad(lse, (0, n - k)))
+            return loss, jnp.pad(lse, (0, n - k))
         return run
 
-    s, lse = jax.lax.switch(rung, [at(k) for k in rungs], h, safe, valid)
-    return (s, count.astype(_F32)), (h, w, bias, safe, valid, lse, order,
+    out, lse = jax.lax.switch(rung, [at(k) for k in rungs], h, safe, valid)
+    return out, count.astype(_F32), (h, w, bias, safe, valid, lse, order,
                                      rung)
 
 
-def _fused_xent_sums_bwd(ignore_index, rungs, res, ct):
-    ds, _dc = ct   # count is a step function of int labels: no grad path
+def _backward(rungs, res, ct):
+    """(dh, dw, db, None) for the cotangent ``ct`` of the rows' losses:
+    one scalar for their sum, or (n,), one a row. A row without a label
+    gets none."""
     h, w, bias, safe, valid, lse, order, rung = res
     n = h.shape[0]
-    g = jnp.where(valid, ds, 0.0).astype(_F32)
+    g = jnp.where(valid, ct, 0.0).astype(_F32)
     bn, bv = _blocks(h, w)
 
     def at(k):
@@ -509,16 +529,51 @@ def _fused_xent_sums_bwd(ignore_index, rungs, res, ct):
     return dh, dw, db.astype(bias.dtype), None
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _fused_xent_sums(h, w, bias, labels, ignore_index, rungs):
+    (s, c), _ = _fused_xent_sums_fwd(h, w, bias, labels, ignore_index, rungs)
+    return s, c
+
+
+def _fused_xent_sums_fwd(h, w, bias, labels, ignore_index, rungs):
+    s, count, res = _forward(h, w, bias, labels, ignore_index, rungs, False)
+    return (s, count), res
+
+
+def _fused_xent_sums_bwd(ignore_index, rungs, res, ct):
+    ds, _dc = ct   # count is a step function of int labels: no grad path
+    return _backward(rungs, res, ds)
+
+
 _fused_xent_sums.defvjp(_fused_xent_sums_fwd, _fused_xent_sums_bwd)
 
 
-def _sharded_fused(h2, w, bias, lab, mesh, row_axes, ignore_index):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _fused_xent_rows(h, w, bias, labels, ignore_index, rungs):
+    return _forward(h, w, bias, labels, ignore_index, rungs, True)[0]
+
+
+def _fused_xent_rows_fwd(h, w, bias, labels, ignore_index, rungs):
+    rows, _, res = _forward(h, w, bias, labels, ignore_index, rungs, True)
+    return rows, res
+
+
+def _fused_xent_rows_bwd(ignore_index, rungs, res, ct):
+    return _backward(rungs, res, ct)
+
+
+_fused_xent_rows.defvjp(_fused_xent_rows_fwd, _fused_xent_rows_bwd)
+
+
+def _sharded_fused(h2, w, bias, lab, mesh, row_axes, ignore_index,
+                   per_row=False):
     """Row-parallel fused xent under a multi-device TrainStep trace:
     shard_map over the batch-row axes (each shard streams the full W —
     replicated spec; pjit inserts the gather if TP shards it), psum the
-    per-shard sums, divide once. This is how the opaque pallas call
-    becomes SPMD-partitionable — the manual axes make the partitioning
-    explicit instead of asking XLA to infer it."""
+    per-shard sums, divide once; with ``per_row`` each shard hands back
+    its own rows' losses and nothing is summed. This is how the opaque
+    pallas call becomes SPMD-partitionable — the manual axes make the
+    partitioning explicit instead of asking XLA to infer it."""
     from jax.sharding import PartitionSpec as P
 
     from ...parallel.ring import _SHARD_MAP_CHECK_VMA, _shard_map
@@ -535,6 +590,9 @@ def _sharded_fused(h2, w, bias, lab, mesh, row_axes, ignore_index):
                       for a in (ws, bs))
         # every shard on all of its rows: the ladder is the single-device
         # path's (PERF.md §7)
+        if per_row:
+            return _fused_xent_rows(hs, ws, bs, ls, ignore_index,
+                                    (hs.shape[0],))
         s, c = _fused_xent_sums(hs, ws, bs, ls, ignore_index,
                                 (hs.shape[0],))
         s = jax.lax.psum(s, row_axes)
@@ -543,7 +601,8 @@ def _sharded_fused(h2, w, bias, lab, mesh, row_axes, ignore_index):
 
     return _shard_map(local, mesh,
                       (P(row_axes, None), P(None, None), P(None),
-                       P(row_axes)), P())(h2, w, bias, lab)
+                       P(row_axes)),
+                      P(row_axes) if per_row else P())(h2, w, bias, lab)
 
 
 def _trace_shard_plan(n, hd, v):
@@ -579,13 +638,15 @@ def _eligible(n, hd, v):
     return _pick_blocks(n, hd, v) is not None and hd % 128 == 0
 
 
-def _work(h2, w, bias, lab, rungs):
+def _work(h2, w, bias, lab, rungs, per_row=False):
     """``work=`` / ``grad_work=`` of one call that runs at one of the row
     capacities ``rungs``, for the ledger in ``counters``, each rung under
     its own roles: the logits matmul forward (2 K H V); dh and dW backward
     (4 K H V, the recomputed logits not counted). Bytes: K rows of h and
     labels, W and bias read, lse and the label logit written; backward
-    reads those with lse and the row cotangent and writes dh, dW, db."""
+    reads those with lse and the row cotangent and writes dh, dW, db.
+    ``per_row`` adds what leaves and enters a row at a time: K float32
+    losses out, K float32 cotangents in."""
     n, hd = h2.shape
     v = w.shape[0]
     work, grad_work = {}, {}
@@ -593,27 +654,42 @@ def _work(h2, w, bias, lab, rungs):
         rows = nbytes(h2, lab) * k // n
         read = rows + nbytes(w, bias)
         tag = _tag(k, n)
-        work[f"fused_xent_{tag}fwd"] = (2.0 * k * hd * v, read + 8 * k)
+        each = 4 * k if per_row else 0
+        work[f"fused_xent_{tag}fwd"] = (2.0 * k * hd * v,
+                                        read + 8 * k + each)
         grad_work[f"fused_xent_{tag}bwd"] = (
             4.0 * k * hd * v,
-            read + 8 * k + nbytes(h2) * k // n + nbytes(w, bias))
+            read + 8 * k + each + nbytes(h2) * k // n + nbytes(w, bias))
     return {"work": work, "grad_work": grad_work}
 
 
-def fused_linear_cross_entropy(h, w, bias, labels, ignore_index=-100):
-    """mean softmax-xent of (h @ w^T + bias) against labels, streaming
-    the vocab axis so the logits never land in HBM. h: (..., H); w:
-    (V, H); bias: (V,); labels: (...,) int. Falls back to the XLA
-    logits path off-TPU / for ineligible shapes (counters record
-    which)."""
+def fused_linear_cross_entropy(h, w, bias, labels, ignore_index=-100,
+                               reduction="mean"):
+    """softmax-xent of (h @ w^T + bias) against labels, streaming the
+    vocab axis so the logits never land in HBM. h: (..., H); w: (V, H);
+    bias: (V,); labels: (...,) int. ``reduction="mean"``: the mean over
+    the labelled rows, a scalar. ``reduction="none"``: every row's own
+    loss, float32 in the labels' shape, zero where the label is
+    ``ignore_index``; its cotangent comes back a row at a time, so a
+    caller may weigh the rows as it likes (a looped model's expected loss
+    over its exits: ``models/causal_lm.py`` stacks its passes' rows into
+    ONE such call, so that the float32 dW of the table is accumulated
+    inside one launch and not written once a pass and added up). Falls
+    back to the XLA logits path off-TPU / for ineligible shapes
+    (counters record which)."""
     from .counters import bump
 
+    if reduction not in ("mean", "none"):
+        raise ValueError(f"reduction {reduction!r}: 'mean' or 'none'")
+    per_row = reduction == "none"
     hd = h.shape[-1]
     h2 = h.reshape(-1, hd)
     lab = labels.reshape(-1)
     n = h2.shape[0]
     pad = (-n) % _BN_MIN
     plan = _trace_shard_plan(n, hd, w.shape[0])
+    if per_row:
+        bump("fused_xent", "per_row")
     if plan == "gate":
         bump("fused_xent", "xla",
              "multi-device trace without shard-divisible rows/row axes "
@@ -622,19 +698,24 @@ def fused_linear_cross_entropy(h, w, bias, labels, ignore_index=-100):
     elif plan is not None:
         mesh, row_axes = plan
         out = _sharded_fused(h2, w, bias, lab, mesh, row_axes,
-                             int(ignore_index))
+                             int(ignore_index), per_row)
         bump("fused_xent", "pallas_sharded",
-             **_work(h2, w, bias, lab, (n,)))
-        return out
+             **_work(h2, w, bias, lab, (n,), per_row))
+        return out.reshape(labels.shape) if per_row else out
     elif _eligible(n + pad, hd, w.shape[0]):
         if pad:
             h2 = jnp.concatenate(
                 [h2, jnp.zeros((pad, hd), h2.dtype)], 0)
             lab = jnp.concatenate(
                 [lab, jnp.full((pad,), ignore_index, lab.dtype)], 0)
-        out = _fused_xent_core(h2, w, bias, lab, int(ignore_index))
         rungs = _ladder(n + pad, _blocks(h2, w)[0])
-        bump("fused_xent", "pallas", **_work(h2, w, bias, lab, rungs))
+        if per_row:
+            out = _fused_xent_rows(h2, w, bias, lab, int(ignore_index),
+                                   rungs)[:n].reshape(labels.shape)
+        else:
+            out = _fused_xent_core(h2, w, bias, lab, int(ignore_index))
+        bump("fused_xent", "pallas",
+             **_work(h2, w, bias, lab, rungs, per_row))
         if len(rungs) > 1:
             bump("fused_xent", "ladder")
         return out
@@ -647,5 +728,7 @@ def fused_linear_cross_entropy(h, w, bias, labels, ignore_index=-100):
     lse = jax.scipy.special.logsumexp(logits, axis=-1)
     ll = jnp.take_along_axis(
         logits, safe[:, None].astype(jnp.int32), axis=1)[:, 0]
+    if per_row:
+        return jnp.where(valid, lse - ll, 0.0).reshape(labels.shape)
     count = jnp.maximum(jnp.sum(valid.astype(_F32)), 1.0)
     return jnp.sum(jnp.where(valid, lse - ll, 0.0)) / count

@@ -125,7 +125,16 @@ def recompute(function, *args, **kwargs):
     for, and would launch the O(T^2) forward kernel only to write the
     same two arrays again. There is no length the kernels accept at
     which that launch is cheaper than the bytes, so it is no option.
-    The dispatch counts ``flash_attention.kept_across_recompute``."""
+    The dispatch counts ``flash_attention.kept_across_recompute``.
+
+    A segment may be called several times in one step on the SAME
+    parameters (a looped model walks its blocks ``total_ut_steps``
+    times): every call is a segment of its own, with its own kept input
+    and its own kept flash outputs, and a parameter's gradient is the sum
+    over its uses. Under jit that is ``jax.checkpoint``'s own transpose;
+    on the eager tape each call is one node whose vjp hands the
+    parameter a cotangent, and the tape adds them into ``p.grad``
+    (``tests/test_looped_lm.py`` holds both to an untied model)."""
     from ..framework import nan_inf
     from ..framework import random as random_mod
     from ..framework import tape as tape_mod

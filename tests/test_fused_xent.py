@@ -519,3 +519,16 @@ def test_sharded_wrapper_under_check_vma(monkeypatch):
         np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
         for g, r in zip(got[1], want[1]):
             np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-6)
+        # a loss a row (PR 41): each shard hands back its own rows' and
+        # takes their cotangents; weighed by 1 / count it is the mean
+        rows, grads = jax.jit(lambda h, w, b: jax.value_and_grad(
+            lambda h, w, b: (lambda r: (jnp.sum(r) / jnp.sum(lab != -100),
+                                        r))(fx._sharded_fused(
+                                            h, w, b, lab, mesh, axes, -100,
+                                            per_row=True)),
+            argnums=(0, 1, 2), has_aux=True)(h, w, b))(h, w, b)
+        np.testing.assert_allclose(rows[0], want[0], rtol=1e-5)
+        assert rows[1].shape == (n,)
+        assert float(jnp.abs(rows[1][::5]).max()) == 0.0
+        for g, r in zip(grads, want[1]):
+            np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-6)

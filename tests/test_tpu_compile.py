@@ -151,6 +151,54 @@ def test_fused_xent_compiles_at_the_cells_shapes(one_chip, shape, precision,
     assert "fused_xent_fwd" in text and "fused_xent_bwd" in text
 
 
+def test_per_row_fused_xent_compiles_at_the_looped_cells_shapes(one_chip):
+    """A looped model's ONE head call: its four passes' 8,192 rows stacked
+    against the whole 49,152-row table at hidden 2048, a loss a row out
+    and a cotangent a row in, through the ladder as the step calls it: at
+    the default precision, bfloat16 operands at blocks (512, 384). Under
+    `highest` the picker takes (256, 384) for float32 operands, the first
+    shape whose vocabulary 384 divides at hidden 2048, and dh's launch
+    wants 30.97 MB of the 30.12 MB scoped limit (found here, PR 41;
+    PERF.md section 7): ``chip_smoke.py``'s check of this call runs it at
+    the default precision, as the cell does."""
+    from paddle_tpu.ops.pallas import fused_xent as fx
+
+    n, hd, v = 32768, 2048, 49152
+    precision = "default"
+    assert fx._pick_blocks(n, hd, v, 2) == (512, 384)
+    assert fx._pick_blocks(n, hd, v, 4) == (256, 384)
+
+    def loss(h, w, b, lab, weight):
+        rows = fx._fused_xent_rows(h, w, b, lab, -100,
+                                   fx._ladder(n, fx._blocks(h, w)[0]))
+        return jnp.sum(rows * weight)
+
+    with jax.default_matmul_precision(precision):
+        out = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+                       ((n, hd), F32), ((v, hd), F32), ((v,), F32),
+                       ((n,), jnp.int32), ((n,), F32))
+    text = out.as_text()
+    for role in ("fused_xent_fwd", "fused_xent_bwd",
+                 "fused_xent_rows4096_fwd", "fused_xent_rows16384_bwd"):
+        assert role in text
+
+
+def test_stream_flash_compiles_at_the_looped_cells_widths(one_chip):
+    """1 x 8,192 tokens, 16 heads on 16 key heads of 128 / 128: the plain
+    stream roles at a width no other cell runs them at."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    def loss(q, k, v):
+        return jnp.sum(fa._flash_attention_pallas(
+            q, k, v, causal=True).astype(F32))
+
+    shape = ((1, 8192, 16, 128), BF16)
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+                    shape, shape, shape).as_text()
+    assert "flash_attention_stream_fwd" in text
+    assert "flash_attention_stream_bwd" in text
+
+
 @pytest.mark.parametrize("precision", [None, "highest"])
 @pytest.mark.parametrize("dropout_p", [0.0, 0.1])
 @pytest.mark.parametrize("shape, dtype", [
