@@ -4,10 +4,11 @@ TPU-native replacement for the reference fused attention CUDA kernel
 (/root/reference/paddle/fluid/operators/fused/multihead_matmul_op.cu and
 math/bert_encoder_functor.cu): an online-softmax Pallas kernel tiled for
 the MXU (q blocks stream over kv blocks), a matching flash backward
-(dq and dk/dv kernels recomputing probabilities from the saved
-logsumexp), wired together with jax.custom_vjp so the kernel is used in
-training too. An XLA fallback covers shapes/backends the kernel does not
-(masks, dropout, unaligned lengths, CPU tests).
+(one kernel that recomputes the probabilities from the saved logsumexp
+once a tile pair and forms dq, dk and dv from them), wired together with
+jax.custom_vjp so the kernel is used in training too. An XLA fallback
+covers shapes/backends the kernel does not (masks, dropout, unaligned
+lengths, CPU tests).
 
 Layout convention is paddle's (batch, seq, heads, head_dim). Speed
 against the XLA path on the chip: not measured (no committed capture);
@@ -105,7 +106,7 @@ def _sds(shape, dtype, ref):
 def _keep_mask(seed, row, qi, j, shape, dropout_p):
     """Regenerable per-tile dropout keep-mask from the TPU hardware PRNG.
     Seeding with (seed, row, q_tile, kv_tile) makes the mask a pure
-    function of tile coordinates, so forward and both backward kernels
+    function of tile coordinates, so the forward and the backward kernel
     reproduce identical bits without any HBM mask tensor."""
     from jax.experimental.pallas import tpu as pltpu
 
@@ -196,89 +197,59 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, kv_len,
 
 
 # ---------------------------------------------------------------------------
-# backward kernels (standard flash bwd): probabilities recomputed from lse;
-# delta = rowsum(dout * out) precomputed outside
+# backward kernel (flash bwd, one launch): probabilities recomputed from
+# lse once a (q block, kv block) pair, and all three gradients formed from
+# them; delta = rowsum(dout * out) precomputed outside
 # ---------------------------------------------------------------------------
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         *rest, kv_len, block_kv, sm_scale, causal,
-                         q_block, masked=False, dropout_p=0.0, window=None):
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      *rest, q_len, block_q, sm_scale,
+                      causal, kv_block, masked=False, dropout_p=0.0,
+                      window=None, group=1):
+    """One query head's dQ and its part of dK, dV, one kv block a grid
+    step. The head's Q, dO and statistics stay resident over its kv
+    blocks, and so does its whole dQ: float32 scratch that every kv block
+    adds its q blocks' ``dS K`` to, in ascending order, and that the last
+    kv block scales and writes out. With ``group`` query heads to a key
+    head the grid is (key heads, group, kv blocks): dK, dV are summed in
+    float32 scratch that holds the key head's whole dK, dV, and the last
+    head of the group writes each block out."""
     from jax.experimental import pallas as pl
 
     rest = list(rest)
     mask_ref = rest.pop(0) if masked else None
     seed_ref = rest.pop(0) if dropout_p > 0.0 else None
-    (dq_ref,) = rest
-    q = q_ref[...].astype(_F32) * sm_scale       # (bq, d)
-    do = do_ref[...].astype(_F32)
-    lse = lse_ref[0, :]                          # (bq,)
-    delta = delta_ref[0, :]                      # (bq,)
-    bq = q.shape[0]
-    row = pl.program_id(0)
-    qi = pl.program_id(1)
-    num_kv = kv_len // block_kv
-
-    def body(j, dq):
-        k = k_ref[pl.dslice(j * block_kv, block_kv), :].astype(_F32)
-        v = v_ref[pl.dslice(j * block_kv, block_kv), :].astype(_F32)
-        s = _dot(q, k, trans_b=True)
-        if mask_ref is not None:
-            mb = mask_ref[0, pl.dslice(j * block_kv, block_kv)]
-            s = s + mb[None, :].astype(_F32)
-        if causal:
-            q_pos = qi * q_block + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_kv), 0)
-            k_pos = j * block_kv + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_kv), 1)
-            s = _band(s, q_pos, k_pos, window)
-        p = jnp.exp(s - lse[:, None])            # (bq, bkv)
-        dp = _dot(do, v, trans_b=True)           # (bq, bkv)
-        if dropout_p > 0.0:
-            # same tile coordinates as forward -> identical keep mask;
-            # delta = rowsum(do*out) already equals <dp_dropped, p>
-            keep = _keep_mask(seed_ref[0, 0], row, qi, j,
-                              (bq, block_kv), dropout_p)
-            dp = jnp.where(keep, dp / (1.0 - dropout_p), 0.0)
-        ds = p * (dp - delta[:, None])
-        return dq + _dot(ds, k)                  # grad wrt scaled q
-
-    if causal:
-        last = jnp.minimum(((qi + 1) * q_block - 1) // block_kv + 1, num_kv)
-    else:
-        last = num_kv
-    dq = jax.lax.fori_loop(_first_kv_block(qi, q_block, block_kv, window),
-                           last, body, jnp.zeros_like(q))
-    dq_ref[...] = (dq * sm_scale).astype(dq_ref.dtype)
-
-
-def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          *rest, q_len, block_q, sm_scale,
-                          causal, kv_block, masked=False, dropout_p=0.0,
-                          window=None, group=1):
-    """One query head's part of dK, dV of one kv block. With ``group``
-    query heads to a key head the grid is (key heads, group, kv blocks):
-    the parts are summed in float32 scratch that holds the key head's
-    whole dK, dV, and the last head of the group writes each block out."""
-    from jax.experimental import pallas as pl
-
-    rest = list(rest)
-    mask_ref = rest.pop(0) if masked else None
-    seed_ref = rest.pop(0) if dropout_p > 0.0 else None
-    dk_ref, dv_ref = rest[:2]
+    dq_ref, dk_ref, dv_ref, dq_acc = rest[:4]
     k = k_ref[...].astype(_F32)                  # (bkv, d)
     v = v_ref[...].astype(_F32)
     bkv = k.shape[0]
     row = pl.program_id(0)
-    kj = pl.program_id(1 if group == 1 else 2)
+    axis = 1 if group == 1 else 2                # the kv blocks' grid axis
+    kj = pl.program_id(axis)
     num_q = q_len // block_q
+
+    def q_rows(i):
+        return pl.dslice(i * block_q, block_q)
+
+    def each_q_block(fn):
+        jax.lax.fori_loop(0, num_q, lambda i, _: fn(i), None)
+
+    @pl.when(kj == 0)
+    def _():
+        zeros = jnp.zeros((block_q, dq_acc.shape[1]), _F32)
+
+        def clear(i):
+            dq_acc[q_rows(i), :] = zeros
+
+        each_q_block(clear)
 
     def body(i, carry):
         dk, dv = carry
-        q = q_ref[pl.dslice(i * block_q, block_q), :].astype(_F32) * sm_scale
-        do = do_ref[pl.dslice(i * block_q, block_q), :].astype(_F32)
-        lse = lse_ref[0, pl.dslice(i * block_q, block_q)]
-        delta = delta_ref[0, pl.dslice(i * block_q, block_q)]
+        q = q_ref[q_rows(i), :].astype(_F32) * sm_scale
+        do = do_ref[q_rows(i), :].astype(_F32)
+        lse = lse_ref[0, q_rows(i)]
+        delta = delta_ref[0, q_rows(i)]
         s = _dot(q, k, trans_b=True)             # (bq, bkv)
         if mask_ref is not None:
             mb = mask_ref[0, :]
@@ -292,7 +263,8 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         p = jnp.exp(s - lse[:, None])
         dp = _dot(do, v, trans_b=True)
         if dropout_p > 0.0:
-            # (row, q_tile=i, kv_tile=kj) matches the forward's seeding
+            # (row, q_tile=i, kv_tile=kj) matches the forward's seeding;
+            # delta = rowsum(do * out) already equals <dp_dropped, p>
             keep = _keep_mask(seed_ref[0, 0], row, i, kj,
                               (block_q, bkv), dropout_p)
             inv = 1.0 / (1.0 - dropout_p)
@@ -301,6 +273,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         else:
             dv = dv + _dot(p.T, do)
         ds = p * (dp - delta[:, None])
+        dq_acc[q_rows(i), :] += _dot(ds, k)      # grad wrt scaled q
         dk = dk + _dot(ds.T, q)                  # q already scaled
         return dk, dv
 
@@ -318,11 +291,20 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dk0 = jnp.zeros_like(k)
     dv0 = jnp.zeros_like(v)
     dk, dv = jax.lax.fori_loop(first, last, body, (dk0, dv0))
+
+    @pl.when(kj == pl.num_programs(axis) - 1)
+    def _():
+        def write(i):
+            dq_ref[q_rows(i), :] = (dq_acc[q_rows(i), :]
+                                    * sm_scale).astype(dq_ref.dtype)
+
+        each_q_block(write)
+
     if group == 1:
         dk_ref[...] = dk.astype(dk_ref.dtype)
         dv_ref[...] = dv.astype(dv_ref.dtype)
         return
-    dk_acc, dv_acc = rest[2:]
+    dk_acc, dv_acc = rest[4:]
     g = pl.program_id(1)
     rows = pl.dslice(kj * kv_block, kv_block)
 
@@ -347,16 +329,22 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 # ---------------------------------------------------------------------------
 
 
+def _lanes(width):
+    """Columns a row of ``width`` takes in VMEM: whole vregs of 128."""
+    return -(-width // 128) * 128
+
+
 def _stream_params(rows, d, dv, itemsize, extra=0):
     """Scoped-VMEM limit of the streaming kernels: each keeps one head's
-    whole K and V (the dk/dv kernel: Q and dO) resident, double-buffered,
-    beside its blocks and float32 temporaries. Mosaic's 16 MiB default
-    holds that up to about 8192 rows of 128 bfloat16 columns; 8192 x
-    (192 + 128) needed 17.7 MB (seen compiling for a v5e, PR 26). Of 128
-    MiB physical."""
+    whole K and V (the backward kernel: Q and dO) resident, double-buffered,
+    beside its blocks and float32 temporaries; ``extra`` is what else a
+    launch holds whole. Mosaic's 16 MiB default holds that up to about
+    8192 rows of 128 bfloat16 columns; 8192 x (192 + 128) needed 17.7 MB
+    (seen compiling for a v5e, PR 26), a row of 192 taking the 256 lanes
+    of two vregs. Of 128 MiB physical."""
     from jax.experimental.pallas import tpu as pltpu
 
-    resident = 2 * rows * (d + dv) * itemsize + extra
+    resident = 2 * rows * (_lanes(d) + _lanes(dv)) * itemsize + extra
     return pltpu.CompilerParams(
         vmem_limit_bytes=max(16 << 20, min(resident + (12 << 20), 96 << 20)))
 
@@ -377,7 +365,7 @@ def _roles(window, group):
     ``_bwd``, as ever. A group's launches (``flash_attention_grouped``)
     and a window's (``flash_attention_window``) are rows of their own in
     a trace and in the work ledger, ONE name for the forward and the
-    backward's two launches: a trace reduction that keeps ten rows then
+    backward launch: a trace reduction that keeps ten rows then
     holds a layer kind's attention as one row (the Mellum cell's full
     layer's forward and backward, apart, both fell under its tenth row;
     PERF.md section 6, PR 31), and a profile tells them apart by scope."""
@@ -464,139 +452,85 @@ def _flash_attention_core_fwd(q, k, v, causal, block_q, block_kv,
 def _bwd_call(qm, km, vm, dom, lse, delta, causal, block_q, block_kv,
               sm_scale, mask_bias=None, heads=1, dropout_p=0.0, seed=None,
               window=None):
-    from jax.experimental import pallas as pl
-
-    bh, ql, d = qm.shape
-    kl, dv = km.shape[1], vm.shape[2]
-    group = bh // km.shape[0]
-    masked = mask_bias is not None
-    role = _roles(window, group)[1]
-
-    dq_specs = [
-        pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
-        pl.BlockSpec((None, kl, d), lambda i, j: (_kv_row(i, group), 0, 0)),
-        pl.BlockSpec((None, kl, dv), lambda i, j: (_kv_row(i, group), 0, 0)),
-        pl.BlockSpec((None, block_q, dv), lambda i, j: (i, j, 0)),
-        pl.BlockSpec((None, 1, block_q), lambda i, j: (i, 0, j)),
-        pl.BlockSpec((None, 1, block_q), lambda i, j: (i, 0, j)),
-    ]
-    dq_ops = [qm, km, vm, dom, lse, delta]
-    if masked:
-        dq_specs.append(pl.BlockSpec((None, 1, kl),
-                                     lambda i, j: (i // heads, 0, 0)))
-        dq_ops.append(mask_bias)
-    if dropout_p > 0.0:
-        dq_specs.append(pl.BlockSpec((1, 1), lambda i, j: (0, 0)))
-        dq_ops.append(seed)
-    # dq and dk/dv under the one role: a trace sums them
-    dq = kernel_call(
-        role,
-        functools.partial(_flash_bwd_dq_kernel, kv_len=kl,
-                          block_kv=block_kv, sm_scale=sm_scale,
-                          causal=causal, q_block=block_q, masked=masked,
-                          dropout_p=dropout_p, window=window),
-        grid=(bh, ql // block_q),
-        in_specs=dq_specs,
-        out_specs=pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
-        out_shape=_sds((bh, ql, d), qm.dtype, qm),
-        compiler_params=_stream_params(kl, d, dv, km.dtype.itemsize),
-    )(*dq_ops)
-
-    dkv_specs = [
-        pl.BlockSpec((None, ql, d), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((None, block_kv, d), lambda i, j: (i, j, 0)),
-        pl.BlockSpec((None, block_kv, dv), lambda i, j: (i, j, 0)),
-        pl.BlockSpec((None, ql, dv), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((None, 1, ql), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((None, 1, ql), lambda i, j: (i, 0, 0)),
-    ]
-    dkv_ops = [qm, km, vm, dom, lse, delta]
-    if group > 1:
-        return dq, *_grouped_dkv_call(
-            dkv_ops, group, role, causal, block_q, block_kv, sm_scale,
-            window)
-    if masked:
-        dkv_specs.append(
-            pl.BlockSpec((None, 1, block_kv),
-                         lambda i, j: (i // heads, 0, j)))
-        dkv_ops.append(mask_bias)
-    if dropout_p > 0.0:
-        dkv_specs.append(pl.BlockSpec((1, 1), lambda i, j: (0, 0)))
-        dkv_ops.append(seed)
-    dk, dv = kernel_call(
-        role,
-        functools.partial(_flash_bwd_dkv_kernel, q_len=ql, block_q=block_q,
-                          sm_scale=sm_scale, causal=causal,
-                          kv_block=block_kv, masked=masked,
-                          dropout_p=dropout_p, window=window),
-        grid=(bh, kl // block_kv),
-        in_specs=dkv_specs,
-        out_specs=[
-            pl.BlockSpec((None, block_kv, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((None, block_kv, dv), lambda i, j: (i, j, 0)),
-        ],
-        out_shape=[
-            _sds((bh, kl, d), km.dtype, qm),
-            _sds((bh, kl, dv), vm.dtype, qm),
-        ],
-        compiler_params=_stream_params(ql, d, dv, qm.dtype.itemsize),
-    )(*dkv_ops)
-    return dq, dk, dv
-
-
-def _grouped_dkv_call(ops, group, role, causal, block_q, block_kv,
-                      sm_scale, window):
-    """dK, dV (batch x key heads, kl, .) of ``group`` query heads to a
-    key head: grid (key heads, group, kv blocks), a query head's Q, dO
-    and statistics resident over its kv blocks, the key head's whole dK,
-    dV resident as the output block and as float32 scratch over the
-    group (see :func:`_flash_bwd_dkv_kernel`)."""
+    """(dq, dk, dv) from ONE launch of :func:`_flash_bwd_kernel`: grid
+    (query heads, kv blocks), or with ``group`` query heads to a key head
+    (key heads, group, kv blocks). A query head's Q, dO and statistics
+    are resident over its kv blocks, beside its whole dQ as the output
+    block and as float32 scratch; a group's dK, dV (batch x key heads,
+    kl, .) are held the same way over the group."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    qm, km, vm = ops[:3]
-    ql, d = qm.shape[1:]
+    bh, ql, d = qm.shape
     rows, kl, dv = km.shape[0], km.shape[1], vm.shape[2]
-
-    def head(i, g, j):
-        return (i * group + g, 0, 0)
-
-    def block(i, g, j):
-        return (i, j, 0)
-
-    def whole(i, g, j):
-        return (i, 0, 0)
-
+    group = bh // rows
+    masked = mask_bias is not None
     size = qm.dtype.itemsize
+    scratch = [pltpu.VMEM((ql, d), _F32)]
+    # beside the resident Q and dO: the output blocks that stay, double
+    # buffered, and their float32 scratch
+    extra = ql * _lanes(d) * (2 * size + 4)
+    if group == 1:
+        grid = (bh, kl // block_kv)
+
+        def head(i, j):
+            return (i, 0, 0)
+
+        def block(i, j):
+            return (i, j, 0)
+
+        dk_spec = pl.BlockSpec((None, block_kv, d), block)
+        dv_spec = pl.BlockSpec((None, block_kv, dv), block)
+    else:
+        grid = (rows, group, kl // block_kv)
+
+        def head(i, g, j):
+            return (i * group + g, 0, 0)
+
+        def block(i, g, j):
+            return (i, j, 0)
+
+        def whole(i, g, j):
+            return (i, 0, 0)
+
+        dk_spec = pl.BlockSpec((None, kl, d), whole)
+        dv_spec = pl.BlockSpec((None, kl, dv), whole)
+        scratch += [pltpu.VMEM((kl, d), _F32), pltpu.VMEM((kl, dv), _F32)]
+        extra += kl * (_lanes(d) + _lanes(dv)) * (2 * size + 4)
+    in_specs = [
+        pl.BlockSpec((None, ql, d), head),
+        pl.BlockSpec((None, block_kv, d), block),
+        pl.BlockSpec((None, block_kv, dv), block),
+        pl.BlockSpec((None, ql, dv), head),
+        pl.BlockSpec((None, 1, ql), head),
+        pl.BlockSpec((None, 1, ql), head),
+    ]
+    operands = [qm, km, vm, dom, lse, delta]
+    # a mask or dropout comes with one key head a query head
+    if masked:
+        in_specs.append(pl.BlockSpec((None, 1, block_kv),
+                                     lambda i, j: (i // heads, 0, j)))
+        operands.append(mask_bias)
+    if dropout_p > 0.0:
+        in_specs.append(pl.BlockSpec((1, 1), lambda i, j: (0, 0)))
+        operands.append(seed)
     return kernel_call(
-        role,
-        functools.partial(_flash_bwd_dkv_kernel, q_len=ql, block_q=block_q,
+        _roles(window, group)[1],
+        functools.partial(_flash_bwd_kernel, q_len=ql, block_q=block_q,
                           sm_scale=sm_scale, causal=causal,
-                          kv_block=block_kv, window=window, group=group),
-        grid=(rows, group, kl // block_kv),
-        in_specs=[
-            pl.BlockSpec((None, ql, d), head),
-            pl.BlockSpec((None, block_kv, d), block),
-            pl.BlockSpec((None, block_kv, dv), block),
-            pl.BlockSpec((None, ql, dv), head),
-            pl.BlockSpec((None, 1, ql), head),
-            pl.BlockSpec((None, 1, ql), head),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, kl, d), whole),
-            pl.BlockSpec((None, kl, dv), whole),
-        ],
+                          kv_block=block_kv, masked=masked,
+                          dropout_p=dropout_p, window=window, group=group),
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=[pl.BlockSpec((None, ql, d), head), dk_spec, dv_spec],
         out_shape=[
+            _sds((bh, ql, d), qm.dtype, qm),
             _sds((rows, kl, d), km.dtype, qm),
             _sds((rows, kl, dv), vm.dtype, qm),
         ],
-        scratch_shapes=[pltpu.VMEM((kl, d), _F32),
-                        pltpu.VMEM((kl, dv), _F32)],
-        # beside the resident Q and dO: the output blocks, double
-        # buffered, and the float32 scratch
-        compiler_params=_stream_params(
-            ql, d, dv, size, extra=kl * (d + dv) * (2 * size + 4)),
-    )(*ops)
+        scratch_shapes=scratch,
+        compiler_params=_stream_params(ql, d, dv, size, extra=extra),
+    )(*operands)
 
 
 def _flash_attention_core_bwd(causal, block_q, block_kv, window, res, dout):
@@ -1108,13 +1042,16 @@ def _work(kind, q, k, v, causal, window=None):
 
 def _bump_pallas(kind, q, k, v, causal, window=None):
     """Count one dispatch to the ``kind`` kernels with the work it
-    declares and, traced inside a segment that ``recompute`` runs again,
-    that the segment keeps this launch's output and logsumexp
-    (:data:`KEPT`)."""
+    declares, that a streaming dispatch's backward is one launch
+    (:func:`_bwd_call`) and, traced inside a segment that ``recompute``
+    runs again, that the segment keeps this launch's output and
+    logsumexp (:data:`KEPT`)."""
     from .counters import bump, in_recomputed
 
     bump("flash_attention", "pallas",
          **_work(kind, q, k, v, causal, window))
+    if kind == "stream":
+        bump("flash_attention", "bwd_one_launch")
     if in_recomputed():
         bump("flash_attention", "kept_across_recompute")
 

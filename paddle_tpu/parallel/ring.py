@@ -69,7 +69,7 @@ def _shard_map(fn, mesh, in_specs, out_specs):
 # flash-attention kernel with ppermute KV rotation"). Forward runs the
 # streaming flash FORWARD kernel on each arriving KV block and merges the
 # normalized block outputs by their logsumexp; backward re-walks the ring
-# calling the flash dq/dkv kernels with the GLOBAL lse (the standard flash
+# calling the flash backward kernel with the GLOBAL lse (the standard flash
 # decomposition: p = exp(s - lse_global) is the true probability, so each
 # block's dq/dk/dv contribution is exact), rotating each block's dk/dv
 # accumulators around the ring WITH the block so they arrive home after a

@@ -156,6 +156,10 @@ CASES = [
     (8, 2, 300, 256, 128),
     (4, 2, 128, 128, 256),
     (4, 4, 1, 128, 128),            # every query reads itself alone
+    # the Mellum cell's group of 8: since PR 42 the launch that sums a
+    # key head's dK, dV over the group also holds each query head's dQ
+    (8, 1, None, 128, 128),
+    (8, 1, 300, 128, 128),          # a band over four kv blocks
 ]
 
 

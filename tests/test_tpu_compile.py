@@ -39,18 +39,22 @@ F32, BF16 = jnp.float32, jnp.bfloat16
 KDA = (1, 8192, 32, 128)
 
 
-def _pallas_grids(fn, *shapes):
-    """The grid of every ``pallas_call`` ``fn`` traces, nested jits
+def _pallas_calls(fn, *shapes):
+    """The parameters of every ``pallas_call`` ``fn`` traces, nested jits
     included."""
     def walk(jaxpr):
         for eqn in jaxpr.eqns:
             if eqn.primitive.name == "pallas_call":
-                yield tuple(eqn.params["grid_mapping"].grid)
+                yield eqn.params
             for sub in jax.core.jaxprs_in_params(eqn.params):
                 yield from walk(sub)
 
     args = [jax.ShapeDtypeStruct(s, d) for s, d in shapes]
     return list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+def _pallas_grids(fn, *shapes):
+    return [tuple(p["grid_mapping"].grid) for p in _pallas_calls(fn, *shapes)]
 
 
 @pytest.mark.parametrize("which", ["fwd", "bwd"])
@@ -98,8 +102,8 @@ def test_stream_flash_compiles_at_mlas_widths_and_the_sequence_ceiling(
 def test_grouped_stream_flash_compiles_at_the_mellum_cells_shapes(
         one_chip, window, precision):
     """2 x 8,192 tokens, 32 query heads on 4 key/value heads of 128: the
-    grouped dK/dV launch keeps a key head's whole dK, dV in float32
-    scratch beside a query head's resident Q and dO; under `highest`
+    grouped backward launch keeps a key head's whole dK, dV in float32
+    scratch beside a query head's resident Q, dO and dQ; under `highest`
     (chip_smoke.py) the products' operands are float32."""
     from paddle_tpu.ops.pallas import flash_attention as fa
 
@@ -181,6 +185,49 @@ def test_per_row_fused_xent_compiles_at_the_looped_cells_shapes(one_chip):
     for role in ("fused_xent_fwd", "fused_xent_bwd",
                  "fused_xent_rows4096_fwd", "fused_xent_rows16384_bwd"):
         assert role in text
+
+
+@pytest.mark.parametrize("heads, kv_heads, d, dv, window, role", [
+    # the Kanana cell's launch: 2 x 32 heads, keys 192, values 128
+    (32, 32, 192, 128, None, "flash_attention_stream_bwd"),
+    # the Mellum cell's: 2 x 4 key heads, 8 query heads to each
+    (32, 4, 128, 128, None, "flash_attention_grouped"),
+    (32, 4, 128, 128, 1024, "flash_attention_window"),
+], ids=["kanana", "mellum-grouped", "mellum-windowed"])
+def test_the_stream_backward_is_one_launch_under_the_vmem_cap(
+        one_chip, heads, kv_heads, d, dv, window, role):
+    """Since PR 42 a layer's streaming backward is ONE Mosaic custom call:
+    the kernel that sums dK, dV holds the query head's whole dQ, (8192, d)
+    float32 scratch and the double-buffered output block, beside the
+    resident Q and dO. It lowers for the v5e at the cells' launches and
+    asks for less scoped VMEM than ``_stream_params``' 96 MiB cap."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    def loss(q, k, v):
+        return jnp.sum(fa._flash_attention_pallas(
+            q, k, v, causal=True, window=window).astype(F32))
+
+    grads = jax.grad(loss, argnums=(0, 1, 2))
+    shapes = (((2, 8192, heads, d), BF16), ((2, 8192, kv_heads, d), BF16),
+              ((2, 8192, kv_heads, dv), BF16))
+    launched = _pallas_calls(grads, *shapes)
+    forward = "flash_attention_stream_fwd" if role.endswith("_bwd") else role
+    assert [p["name"] for p in launched] == [forward, role]
+    # held whole: Q, dO and dQ's block twice, dQ in float32; never the cap
+    rows = 8192 * fa._lanes(d)
+    asked = launched[1]["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+    assert 2 * 2 * 2 * rows + 4 * rows < asked < 96 << 20
+    text = _compile(grads, one_chip, *shapes).as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    backward = [line for line in calls
+                if f"transpose(jvp(jit(_flash_attention_pallas)))/pallas/"
+                   f"{role}/" in line]
+    assert len(calls) == 2 and len(backward) == 1
+    # the one call writes all three gradients: dQ query heads wide, dK
+    # and dV key heads wide
+    assert (f"(bf16[{2 * heads},8192,{d}]" in backward[0]
+            and f"bf16[{2 * kv_heads},8192,{dv}]" in backward[0])
 
 
 def test_stream_flash_compiles_at_the_looped_cells_widths(one_chip):
