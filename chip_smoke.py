@@ -790,15 +790,18 @@ def _kernel_checks():
                 g = -jax.random.uniform(jax.random.key(5), shape, f32,
                                         1e-3, 1.6)
                 beta = jax.nn.sigmoid(rnd(6, shape[:3]))
-            if not kda._kernel_takes(q, v, kda.CHUNK):
+            if not kda._kernel_takes(shape[3], shape[3], kda.CHUNK):
                 fails.append(f"{name}: {shape} is outside its gate")
                 return
+
+            def flat(x):
+                return x.reshape(*shape[:2], -1)
 
             def run(kernel):
                 return jax.jit(jax.value_and_grad(
                     lambda *a: jnp.sum(kda._chunk_kda(*a, kda.CHUNK, kernel)
-                                       * w), argnums=(0, 1, 2, 3, 4)))(
-                    q, k, v, g, beta)
+                                       * flat(w)), argnums=(0, 1, 2, 3, 4)))(
+                    flat(q), flat(k), flat(v), flat(g), beta)
 
             (lk, got), (lx, want) = run(True), run(False)
             _close(f"{name} sum(o w)", lk, lx, tol_of(f32), fails)
@@ -913,6 +916,58 @@ def _kernel_checks():
 
     mamba2_stage("conv")
     mamba2_stage("gate_norm")
+
+    # -- the KDA mixer's fused element-wise stages against the float32
+    # formulas they replace, at the Kimi cell's shapes -----------------------
+    def kda_stage(stage, b=1, t=8192, heads=32, d=128, dtype=bf16):
+        shape = (b, t, heads * d)
+        name = f"kda {stage} stage fwd + bwd {shape} " \
+               f"{jnp.dtype(dtype).name}"
+
+        def check(fails):
+            from paddle_tpu.ops.pallas import counters
+            from paddle_tpu.ops.pallas import kda_stages as stages
+
+            if stage == "conv":
+                args = tuple(rnd(i, shape, dtype) for i in (1, 2, 3)) \
+                    + tuple(rnd(i, (4, heads * d), f32, 0.5)
+                            for i in (4, 5, 6))
+                ws = [rnd(i, shape, f32) for i in (7, 8, 9)]
+                names = ("q", "k", "v", "q_taps", "k_taps", "v_taps")
+                forms = (lambda *a: stages.conv_norm(*a, d),
+                         lambda *a: stages.conv_norm_xla(*a, d))
+            else:
+                args = (rnd(1, shape, f32), rnd(2, shape, dtype),
+                        1.0 + rnd(3, (d,), f32, 0.2))
+                ws = [rnd(4, shape, f32)]
+                names = ("o", "gate", "weight")
+                forms = (lambda *a: (stages.norm_gate(*a, 1e-5),),
+                         lambda *a: (stages.norm_gate_xla(*a, 1e-5),))
+
+            def run(form):
+                def loss(*a):
+                    outs = form(*a)
+                    return sum(jnp.sum(o * w) for o, w in zip(outs, ws)), outs
+                return jax.jit(jax.value_and_grad(
+                    loss, argnums=tuple(range(len(args))),
+                    has_aux=True))(*args)
+
+            before = counters.snapshot()
+            (_, outs), got = run(forms[0])
+            if counters.delta(before) != {"kda_stage.fused": 1}:
+                fails.append(f"{name}: outside its gate")
+                return
+            (_, refs), want = run(forms[1])
+            for i, (o, r) in enumerate(zip(outs, refs)):
+                if o.dtype != f32:
+                    fails.append(f"{name} out {i}: {o.dtype}, not float32")
+                _close(f"{name} out {i}", o, r, tol_of(f32), fails)
+            for g, r, nm in zip(got, want, names):
+                _close(f"{name} d{nm}", g, r, tol_of(g.dtype), fails)
+        checks.append((name, check))
+
+    kda_stage("conv")
+    kda_stage("gate_norm")
 
     # -- the dropless expert layer, each rung against a dense loop -----------
     def experts(held, dtype, rungs, t=8192, d=2304, f=1024,

@@ -12,7 +12,13 @@ state follows the gated delta rule with a decay per key channel.
 ``ShortConv`` is a causal depthwise convolution along the sequence. The
 recurrence runs chunk by chunk in ``ops/pallas/kda.py``; everything
 between the projections and that call is float32 whatever the autocast
-level (the norms, the decay and the state are precision-sensitive).
+level (the norms, the decay and the state are precision-sensitive), and
+everything is laid out as the projections are, (B, T, H * D). On the TPU
+the element-wise work on either side of the recurrence is two fused
+stages (``ops/pallas/kda_stages.py``: convolution + SiLU + L2 norm
+before it, head norm x gate after it) that read the projections once
+and keep their float32 intermediates in VMEM; HBM holds the projections,
+the recurrence's float32 operands (q, k, v, g, beta) and its output.
 """
 from __future__ import annotations
 
@@ -22,21 +28,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..framework.nan_inf import checkpoint, probe
+from ..framework.nan_inf import probe
 from ..framework.op import primitive
 from .common import Linear
-from .functional import short_conv
 from .layer import Layer
 
 __all__ = ["KimiDeltaAttention", "kda_mix"]
 
 _F32 = jnp.float32
-#: added to the squared norm under the L2 normalisation of q and k
-L2_EPS = 1e-6
-
-
-def _l2norm(x):
-    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
 
 
 @primitive("kda_mix")
@@ -46,47 +45,33 @@ def kda_mix(q, k, v, q_taps, k_taps, v_taps, decay, a_log, dt_bias,
     output projection. q, k, v, decay, gate: (B, T, H * D) projections of
     the block's input; beta_logits: (B, T, H). Returns (B, T, H * D),
     float32."""
-    from ..ops.pallas.kda import chunk_kda
+    from ..ops.pallas import kda_stages as stages
+    from ..ops.pallas.kda import chunk_kda_flat
 
-    b, t, width = q.shape
-    d = width // num_heads
-
-    def heads(x):
-        return x.reshape(b, t, num_heads, d)
-
-    def mixed(x, taps):
-        return heads(jax.nn.silu(short_conv(x.astype(_F32),
-                                            taps.astype(_F32))))
-
-    # the float32 element-wise chains on either side of the recurrence
-    # are recomputed in the backward from their (autocast-typed) inputs:
-    # kept, they are a dozen (B, T, H * D) float32 arrays a layer
-    # (``checkpoint`` is jax.checkpoint, and ``probe`` the identity,
-    # except in a step built under FLAGS_check_nan_inf)
-    @checkpoint
-    def before(q, k, v, decay, beta_logits, q_taps, k_taps, v_taps, a_log,
-               dt_bias):
-        g = -jnp.exp(a_log.astype(_F32))[:, None] * heads(
-            jax.nn.softplus(decay.astype(_F32) + dt_bias.astype(_F32)))
-        return (_l2norm(mixed(q, q_taps)) * (d ** -0.5),
-                _l2norm(mixed(k, k_taps)), mixed(v, v_taps), g,
-                jax.nn.sigmoid(beta_logits.astype(_F32)))
-
-    @checkpoint
-    def after(o, gate, norm_weight):
-        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
-                              + epsilon)
-        return o * norm_weight.astype(_F32) * heads(
-            jax.nn.sigmoid(gate.astype(_F32)))
-
+    d = q.shape[-1] // num_heads
+    # every array between the projections and ``o_proj`` is (B, T, H * D),
+    # as the projections are and as the three launches take their blocks:
+    # none has the heads on an axis of its own (on the chip that is
+    # another tiling, and a relayout each way). What lives in HBM: the
+    # projections in their (autocast) type, which are also all that the
+    # two stages keep for their backward; the recurrence's float32
+    # operands q, k, v, g, beta and its output. The convolution's
+    # pre-activation, SiLU, the norms and the gate are float32 in VMEM
+    # only, forward and (recomputed) backward.
+    with jax.named_scope("kda_before"):
+        q, k, v = stages.conv_norm(q, k, v, q_taps, k_taps, v_taps, d)
+        g = -jnp.repeat(jnp.exp(a_log.astype(_F32)), d) * jax.nn.softplus(
+            decay.astype(_F32) + dt_bias.astype(_F32))
+        beta = jax.nn.sigmoid(beta_logits.astype(_F32))
     # the recurrence's operands and result, and as the cotangents of
     # these the five gradients that leave its hand-written backward
-    operands = before(q, k, v, decay, beta_logits, q_taps, k_taps, v_taps,
-                      a_log, dt_bias)
-    o = chunk_kda(*(probe(name, a, grad=True) for name, a in zip(
-        ("kda_q", "kda_k", "kda_v", "kda_g", "kda_beta"), operands)))
-    return after(probe("kda_o", o, grad=True), gate,
-                 norm_weight).reshape(b, t, width)
+    # (``probe`` is the identity except in a step built under
+    # FLAGS_check_nan_inf)
+    o = chunk_kda_flat(*(probe(name, a, grad=True) for name, a in zip(
+        ("kda_q", "kda_k", "kda_v", "kda_g", "kda_beta"), (q, k, v, g, beta))))
+    with jax.named_scope("kda_after"):
+        return stages.norm_gate(probe("kda_o", o, grad=True), gate,
+                                norm_weight, epsilon)
 
 
 class KimiDeltaAttention(Layer):

@@ -48,8 +48,12 @@ lane-dense ``(C, G * 128)`` blocks of the projections' own ``(B, T,
 H * D)`` arrays, and inside the step the chunk formulas on a leading
 axis of ``G`` heads (``jax.vmap``, as the XLA form runs them), so that
 every link of the dependent chain is ``G`` independent products the
-MXUs take back to back. The token-by-token recurrence lives only in
-the tests and in the benchmark's reference.
+MXUs take back to back. Everything outside the chunk formulas is on that
+flat layout too (:func:`chunk_kda_flat`: the cumulative decay, the
+residuals, the cotangents), so that no array with the heads on the
+sublanes is made round a launch; :func:`chunk_kda` is the same call on
+(B, T, H, D) arrays. The token-by-token recurrence lives only in the
+tests and in the benchmark's reference.
 """
 from __future__ import annotations
 
@@ -225,16 +229,17 @@ def _chunk_bwd(q, k, v, gc, beta, st, do, dst_next):
 # ---------------------------------------------------------------------------
 # the XLA form: the same chunk formulas under lax.scan, heads under vmap
 # ---------------------------------------------------------------------------
-def _by_chunk(x, chunk):
-    """(B, T, H, D) -> (NC, B, H, C, D)."""
-    b, t, h, d = x.shape
-    return x.reshape(b, t // chunk, chunk, h, d).transpose(1, 0, 3, 2, 4)
+def _by_chunk(x, heads, chunk):
+    """(B, T, H * D) -> (NC, B, H, C, D)."""
+    b, t, width = x.shape
+    return x.reshape(b, t // chunk, chunk, heads, width // heads).transpose(
+        1, 0, 3, 2, 4)
 
 
 def _from_chunks(x):
-    """(NC, B, H, C, D) -> (B, T, H, D)."""
+    """(NC, B, H, C, D) -> (B, T, H * D)."""
     nc, b, h, c, d = x.shape
-    return x.transpose(1, 0, 3, 2, 4).reshape(b, nc * c, h, d)
+    return x.transpose(1, 0, 3, 2, 4).reshape(b, nc * c, h * d)
 
 
 def _beta_rows(beta, chunk):
@@ -254,32 +259,32 @@ _heads = functools.partial(jax.vmap, in_axes=0)
 
 
 def _xla_fwd(q, k, v, gc, beta, chunk):
-    b, _, h, kd = q.shape
-    vd = v.shape[-1]
+    b, _, h = beta.shape
+    kd, vd = q.shape[-1] // h, v.shape[-1] // h
     step = _heads(_heads(_chunk_fwd))
 
     def body(st, xs):
         o, st_next = step(*xs, st)
         return st_next, (o, st)
 
-    xs = tuple(_by_chunk(a, chunk) for a in (q, k, v, gc)) \
+    xs = tuple(_by_chunk(a, h, chunk) for a in (q, k, v, gc)) \
         + (jnp.moveaxis(_beta_rows(beta, chunk), 2, 0),)
     _, (o, states) = jax.lax.scan(body, jnp.zeros((b, h, vd, kd), _F32), xs)
     return _from_chunks(o), states
 
 
 def _xla_bwd(q, k, v, gc, beta, states, do, chunk):
-    b, _, h, kd = q.shape
-    vd = v.shape[-1]
+    b, _, h = beta.shape
+    kd, vd = q.shape[-1] // h, v.shape[-1] // h
     step = _heads(_heads(_chunk_bwd))
 
     def body(dst, xs):
         dq, dk, dv, dgc, dbeta, dst = step(*xs, dst)
         return dst, (dq, dk, dv, dgc, dbeta)
 
-    xs = tuple(_by_chunk(a, chunk) for a in (q, k, v, gc)) \
+    xs = tuple(_by_chunk(a, h, chunk) for a in (q, k, v, gc)) \
         + (jnp.moveaxis(_beta_rows(beta, chunk), 2, 0), states,
-           _by_chunk(do, chunk))
+           _by_chunk(do, h, chunk))
     _, (dq, dk, dv, dgc, dbeta) = jax.lax.scan(
         body, jnp.zeros((b, h, vd, kd), _F32), xs, reverse=True)
     dbeta = _beta_from_rows(jnp.moveaxis(dbeta, 0, 2))
@@ -364,11 +369,6 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, st_ref, do_ref,
     carry[...] = dst
 
 
-def _flat(x):
-    b, t, h, d = x.shape
-    return x.reshape(b, t, h * d)
-
-
 def _specs(heads, kd, vd, chunk, nc, reverse):
     """Block specs by kind: a (C, G * D) block of the (B, T, H * D)
     arrays at head group hg (no transposed copy of q, k, v in HBM), G
@@ -446,38 +446,41 @@ def _launch_bwd(q, k, v, gc, rows, states, do):
 
 
 def _pallas_fwd(q, k, v, gc, beta, chunk):
-    o, states = _launch_fwd(_flat(q), _flat(k), _flat(v), _flat(gc),
-                            _beta_rows(beta, chunk))
-    return o.reshape(v.shape), states
+    return _launch_fwd(q, k, v, gc, _beta_rows(beta, chunk))
 
 
 def _pallas_bwd(q, k, v, gc, beta, states, do, chunk):
-    dq, dk, dv, dgc, dbeta = _launch_bwd(
-        _flat(q), _flat(k), _flat(v), _flat(gc), _beta_rows(beta, chunk),
-        states, _flat(do))
-    return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
-            dgc.reshape(q.shape), _beta_from_rows(dbeta))
+    *tokens, dbeta = _launch_bwd(q, k, v, gc, _beta_rows(beta, chunk),
+                                 states, do)
+    return (*tokens, _beta_from_rows(dbeta))
 
 
 # ---------------------------------------------------------------------------
 # dispatch + custom_vjp
 # ---------------------------------------------------------------------------
-def _kernel_takes(q, v, chunk):
+def _kernel_takes(kd, vd, chunk):
     """The kernels take lane-dense heads (128-wide keys and values) on a
     single-device TPU trace; everything else runs the XLA form."""
     from ...framework.bringup import pallas_enabled
     from ...parallel.mesh import auto_partitioned_trace
 
     return (pallas_enabled() and not auto_partitioned_trace()
-            and q.shape[-1] % 128 == 0 and v.shape[-1] % 128 == 0
+            and kd % 128 == 0 and vd % 128 == 0
             and chunk % _SUB == 0 and chunk % 8 == 0)
 
 
-def _cumulate(g, chunk):
-    """Inclusive cumulative log-decay inside each chunk."""
-    b, t, h, d = g.shape
-    return jnp.cumsum(g.reshape(b, t // chunk, chunk, h, d),
-                      axis=2).reshape(g.shape)
+def _cumulate(g, chunk, reverse=False):
+    """Inclusive cumulative sum inside each chunk of a (B, T, H * D)
+    array, the tokens on the sublanes; ``reverse``: from the chunk's end
+    (the transpose). As a product with a triangle of ones on the MXU
+    (exact in its bfloat16 passes: every term is the float32 sum's)."""
+    b, t, width = g.shape
+    row, col = _iota((chunk, chunk), 0), _iota((chunk, chunk), 1)
+    ones = (row <= col if reverse else row >= col).astype(_F32)
+    return jnp.einsum("ij,bnjw->bniw", ones,
+                      g.reshape(b, t // chunk, chunk, width),
+                      precision=_HIGHEST,
+                      preferred_element_type=_F32).reshape(g.shape)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
@@ -502,11 +505,7 @@ def _chunk_kda_bwd(chunk, kernel, record, res, do):
     q, k, v, gc, beta, states = res
     dq, dk, dv, dgc, dbeta = (_pallas_bwd if kernel else _xla_bwd)(
         q, k, v, gc, beta, states, do, chunk)
-    b, t, h, d = dgc.shape
-    # the cumulative sum's transpose: a reversed cumulative sum per chunk
-    dg = jnp.flip(jnp.cumsum(jnp.flip(
-        dgc.reshape(b, t // chunk, chunk, h, d), 2), axis=2), 2)
-    return dq, dk, dv, dg.reshape(dgc.shape), dbeta
+    return dq, dk, dv, _cumulate(dgc, chunk, reverse=True), dbeta
 
 
 _chunk_kda.defvjp(_chunk_kda_fwd, _chunk_kda_bwd)
@@ -527,32 +526,40 @@ def kda_work(b, t, h, kd, vd):
             2 * moved)}}
 
 
-def chunk_kda(q, k, v, g, beta, chunk=CHUNK):
-    """Gated delta rule over each row of a batch from a zero state.
-
-    q, k, g: (B, T, H, K); v: (B, T, H, V); beta: (B, T, H); ``g`` is the
-    per-token, per-channel LOG decay (<= 0). Returns o (B, T, H, V),
+def chunk_kda_flat(q, k, v, g, beta, chunk=CHUNK):
+    """Gated delta rule over each row of a batch from a zero state, on
+    the projections' own layout: q, k, g (B, T, H * K); v (B, T, H * V);
+    beta (B, T, H), which says how many heads the channels are; ``g`` is
+    the per-token, per-channel LOG decay (<= 0). Returns o (B, T, H * V),
     float32. A length that is no multiple of ``chunk`` is padded with
     tokens that neither write nor decay."""
     q, k, v, g, beta = (a.astype(_F32) for a in (q, k, v, g, beta))
-    b, t, h, kd = q.shape
-    vd = v.shape[-1]
+    b, t, h = beta.shape
+    kd, vd = q.shape[-1] // h, v.shape[-1] // h
     pad = (-t) % chunk
     if pad:
-        q, k, v, g = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
-                      for a in (q, k, v, g))
-        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
-    kernel = _kernel_takes(q, v, chunk)
+        q, k, v, g, beta = (jnp.pad(a, ((0, 0), (0, pad), (0, 0)))
+                            for a in (q, k, v, g, beta))
+    kernel = _kernel_takes(kd, vd, chunk)
     if kernel:
         bump("kda_chunk", "pallas", **kda_work(b, t + pad, h, kd, vd))
         bump("kda_chunk", f"heads{_heads_a_step(h, kd, vd, chunk)}")
     else:
         bump("kda_chunk", "xla",
-             f"dispatch ineligible (q {tuple(q.shape)}, v {tuple(v.shape)}"
-             f", chunk {chunk}; backend or 128-lane heads)")
+             f"dispatch ineligible ({h} heads of {kd} x {vd}, chunk {chunk}"
+             "; backend or 128-lane heads)")
     o = _chunk_kda(q, k, v, g, beta, chunk, kernel,
                    nan_inf.record is not None)
     if nan_inf.record is not None:
         o, states_row = o
         nan_inf.probe_row("kda_states", states_row)
     return o[:, :t] if pad else o
+
+
+def chunk_kda(q, k, v, g, beta, chunk=CHUNK):
+    """:func:`chunk_kda_flat` with the heads on an axis of their own: q,
+    k, g (B, T, H, K); v (B, T, H, V); returns o (B, T, H, V)."""
+    b, t, h, _ = q.shape
+    o = chunk_kda_flat(*(a.reshape(b, t, -1) for a in (q, k, v, g)), beta,
+                       chunk)
+    return o.reshape(b, t, h, -1)

@@ -40,6 +40,7 @@ as the path of the CPU and of any shape the stages do not take — counted
 from __future__ import annotations
 
 import functools
+from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -64,6 +65,36 @@ def _silu_grad(x):
     """(SiLU(x), dSiLU/dx), float32."""
     s = jax.nn.sigmoid(x)
     return x * s, s * (1.0 + x * (1.0 - s))
+
+
+class ConvForm(NamedTuple):
+    """What a caller of the convolution's launches fixes beside the
+    shapes: the kernels' role name, the type the forward writes (the
+    input's when None) and, behind SiLU, an L2 norm of each head —
+    ``norm = (lanes of a head, scale, epsilon)``: ``y * scale *
+    rsqrt(sum_head(y^2) + epsilon)`` — or None."""
+    role: str
+    out_dtype: Optional[Any] = None
+    norm: Optional[Tuple[int, float, float]] = None
+
+    @property
+    def head(self):
+        """Lanes a channel tile must hold whole."""
+        return self.norm[0] if self.norm else 128
+
+
+def per_head(fn, head, *xs):
+    """``fn`` on each head's ``head`` lanes of (n, C) arrays (whole lane
+    tiles: nothing moves); its result, or each of a tuple of results,
+    side by side again."""
+    outs = [fn(*(x[:, lo:lo + head] for x in xs))
+            for lo in range(0, xs[0].shape[1], head)]
+
+    def join(parts):
+        return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+
+    return tuple(map(join, zip(*outs))) if isinstance(outs[0], tuple) \
+        else join(outs)
 
 
 # ---------------------------------------------------------------------------
@@ -141,10 +172,11 @@ def _block_rows(t, lanes, direction):
     return max(SLAB, rows // SLAB * SLAB)
 
 
-def _lanes(*offsets):
-    """The widest channel tile that starts and ends on every offset."""
+def _lanes(*offsets, head=128):
+    """The widest channel tile that starts and ends on every offset and
+    holds whole heads."""
     return next(c for c in (512, 256, 128)
-                if all(o % c == 0 for o in offsets))
+                if c % head == 0 and all(o % c == 0 for o in offsets))
 
 
 def _compiler_params():
@@ -180,19 +212,45 @@ def _first_slab(prev_ref, x_ref, first):
 
 
 def _conv_pre(xs, taps, bias):
-    return bias + sum(taps[j:j + 1] * x for j, x in enumerate(xs))
+    pre = sum(taps[j:j + 1] * x for j, x in enumerate(xs))
+    return pre if bias is None else bias + pre
 
 
-def _conv_fwd_kernel(prev_ref, x_ref, taps_ref, bias_ref, out_ref):
+def _l2_norm(y, norm):
+    """Each head of SiLU's result over its own length."""
+    head, scale, epsilon = norm
+    return per_head(lambda h: h * (scale * jax.lax.rsqrt(
+        jnp.sum(h * h, axis=-1, keepdims=True) + epsilon)), head, y)
+
+
+def _l2_norm_bwd(y, dn, norm):
+    """With r = rsqrt(sum y^2 + eps), u = y r and n = scale u:
+    dy = scale r (dn - u sum(dn u)), a head at a time."""
+    head, scale, epsilon = norm
+
+    def one(y, dn):
+        r = jax.lax.rsqrt(jnp.sum(y * y, axis=-1, keepdims=True) + epsilon)
+        u = y * r
+        return (scale * r) * (dn - u * jnp.sum(dn * u, axis=-1,
+                                               keepdims=True))
+
+    return per_head(one, head, y, dn)
+
+
+def _conv_fwd_kernel(prev_ref, x_ref, taps_ref, *refs, norm=None):
+    """refs: the bias' (1, C) row where there is one, then the result."""
     from jax.experimental import pallas as pl
 
-    taps, bias = taps_ref[...], bias_ref[...]
+    *bias_ref, out_ref = refs
+    taps, bias = taps_ref[...], bias_ref[0][...] if bias_ref else None
     width = taps.shape[0]
 
     def emit(r0, xe):
         pre = _conv_pre(_shifted(xe.astype(_F32), width, HALO), taps, bias)
-        out_ref[pl.ds(r0, SLAB), :] = (
-            pre * jax.nn.sigmoid(pre)).astype(out_ref.dtype)
+        y = pre * jax.nn.sigmoid(pre)
+        if norm is not None:
+            y = _l2_norm(y, norm)
+        out_ref[pl.ds(r0, SLAB), :] = y.astype(out_ref.dtype)
 
     emit(0, _first_slab(prev_ref, x_ref, pl.program_id(2) == 0))
     _slabs(x_ref.shape[0], lambda r0: emit(
@@ -200,13 +258,15 @@ def _conv_fwd_kernel(prev_ref, x_ref, taps_ref, bias_ref, out_ref):
 
 
 def _conv_bwd_kernel(prev_ref, x_ref, next_ref, dy_ref, dynext_ref, taps_ref,
-                     bias_ref, dx_ref, dw_ref, dp_ref, *, length):
-    """dp_ref: ``d_pre`` of the block's rows and of the HALO after them,
-    float32 scratch."""
+                     *refs, length, norm=None):
+    """refs: the bias' row where there is one, then dx, the partial sums
+    (dtaps' rows; behind them dbias' with a bias) and dp_ref: ``d_pre``
+    of the block's rows and of the HALO after them, float32 scratch."""
     from jax.experimental import pallas as pl
 
+    *bias_ref, dx_ref, dw_ref, dp_ref = refs
     rows = x_ref.shape[0]
-    taps, bias = taps_ref[...], bias_ref[...]
+    taps, bias = taps_ref[...], bias_ref[0][...] if bias_ref else None
     width = taps.shape[0]
     i = pl.program_id(2)
     ragged = length % rows != 0
@@ -225,15 +285,19 @@ def _conv_bwd_kernel(prev_ref, x_ref, next_ref, dy_ref, dynext_ref, taps_ref,
             xe = jnp.where(_row_ids(i * rows + r0 - HALO, HALO + n) < length,
                            xe, 0.0)
         xs = _shifted(xe, width, HALO)
-        _, slope = _silu_grad(_conv_pre(xs, taps, bias))
-        dp = dy.astype(_F32) * slope
+        y, slope = _silu_grad(_conv_pre(xs, taps, bias))
+        dy = dy.astype(_F32)
+        if norm is not None:
+            dy = _l2_norm_bwd(y, dy, norm)
+        dp = dy * slope
         if ragged or not own:
             dp = jnp.where(_row_ids(i * rows + r0, n) < length, dp, 0.0)
         dp_ref[pl.ds(r0, n), :] = dp
         if own:
             for j, x in enumerate(xs):
                 dw_ref[j] += _fold(dp * x)
-            dw_ref[width] += _fold(dp)
+            if bias is not None:
+                dw_ref[width] += _fold(dp)
 
     d_pre(0, _first_slab(prev_ref, x_ref, i == 0), dy_ref[0:SLAB, :], True)
     _slabs(rows, lambda r0: d_pre(
@@ -280,48 +344,58 @@ def _partial_spec(k, lanes):
     return pl.BlockSpec((None, k, 8, lanes), lambda b, c, i: (b, 0, 0, c))
 
 
-@functools.partial(jax.jit, static_argnums=(3,))
-def _conv_part_fwd(proj, taps, bias, offset):
+def _conv_vectors(taps, bias, lanes):
+    """(operands, block specs) of the taps and, where there is one, the
+    bias' row."""
+    vectors = [taps] if bias is None else [taps, bias]
+    return vectors, [_vector_spec(v.shape[0], lanes) for v in vectors]
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _conv_part_fwd(proj, taps, bias, offset, form=ConvForm(ROLE_CONV)):
     """One part of xBC: channels [offset, offset + w) of the projection,
-    w = taps.shape[1]."""
+    w = taps.shape[1]; bias (1, w) or None."""
     b, t, _ = proj.shape
     width, w = taps.shape
-    lanes = _lanes(offset, w)
+    lanes = _lanes(offset, w, head=form.head)
     rows = _block_rows(t, lanes, "fwd")
     own, before, _ = _conv_specs(t, rows, lanes, offset // lanes)
     mine = _conv_specs(t, rows, lanes, 0)[0]
+    vectors, vector_specs = _conv_vectors(taps, bias, lanes)
     return kernel_call(
-        ROLE_CONV, _conv_fwd_kernel, grid=(b, w // lanes, -(-t // rows)),
-        in_specs=[before, own, _vector_spec(width, lanes),
-                  _vector_spec(1, lanes)],
-        out_specs=mine, out_shape=_sds((b, t, w), proj.dtype, proj),
+        form.role, functools.partial(_conv_fwd_kernel, norm=form.norm),
+        grid=(b, w // lanes, -(-t // rows)),
+        in_specs=[before, own, *vector_specs], out_specs=mine,
+        out_shape=_sds((b, t, w), form.out_dtype or proj.dtype, proj),
         compiler_params=_compiler_params(),
-    )(proj, proj, taps, bias)
+    )(proj, proj, *vectors)
 
 
-@functools.partial(jax.jit, static_argnums=(3,))
-def _conv_part_bwd(proj, taps, bias, offset, dy):
+@functools.partial(jax.jit, static_argnums=(3, 5))
+def _conv_part_bwd(proj, taps, bias, offset, dy, form=ConvForm(ROLE_CONV)):
     """(dx (B, T, w), partial sums (B, W + 1, 8, w) float32: dtaps' rows,
-    then dbias')."""
+    then dbias'; (B, W, 8, w) without a bias)."""
     from jax.experimental.pallas import tpu as pltpu
 
     b, t, _ = proj.shape
     width, w = taps.shape
-    lanes = _lanes(offset, w)
+    lanes = _lanes(offset, w, head=form.head)
     rows = _block_rows(t, lanes, "bwd")
     own, before, after = _conv_specs(t, rows, lanes, offset // lanes)
     mine, _, mine_after = _conv_specs(t, rows, lanes, 0)
+    vectors, vector_specs = _conv_vectors(taps, bias, lanes)
+    sums = width + len(vectors) - 1
     return kernel_call(
-        ROLE_CONV, functools.partial(_conv_bwd_kernel, length=t),
+        form.role, functools.partial(_conv_bwd_kernel, length=t,
+                                     norm=form.norm),
         grid=(b, w // lanes, -(-t // rows)),
-        in_specs=[before, own, after, mine, mine_after,
-                  _vector_spec(width, lanes), _vector_spec(1, lanes)],
-        out_specs=[mine, _partial_spec(width + 1, lanes)],
+        in_specs=[before, own, after, mine, mine_after, *vector_specs],
+        out_specs=[mine, _partial_spec(sums, lanes)],
         out_shape=[_sds((b, t, w), proj.dtype, proj),
-                   _sds((b, width + 1, 8, w), _F32, proj)],
+                   _sds((b, sums, 8, w), _F32, proj)],
         scratch_shapes=[pltpu.VMEM((rows + HALO, lanes), _F32)],
         compiler_params=_compiler_params(),
-    )(proj, proj, proj, dy, dy, taps, bias)
+    )(proj, proj, proj, dy, dy, *vectors)
 
 
 def _conv_operands(taps, bias, lo, hi):

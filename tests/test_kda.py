@@ -147,7 +147,9 @@ def test_kernels_match_the_xla_form_on_the_same_chunks(interp, heads, taken):
             lambda *a: jnp.sum(fn(*a) * w), argnums=(0, 1, 2, 3, 4))(*args)
 
     (lk, gk) = run(lambda *a: kda.chunk_kda(*a, chunk=64))
-    (lx, gx) = run(lambda *a: kda._chunk_kda(*a, 64, False))
+    (lx, gx) = run(lambda *a: kda._chunk_kda(
+        *(x.reshape(*x.shape[:2], -1) for x in a[:4]), a[4], 64,
+        False).reshape(w.shape))
     assert float(lk) == pytest.approx(float(lx), rel=1e-5)
     for a, b in zip(gk, gx):
         assert _rel(a, b) < 1e-5
@@ -227,12 +229,12 @@ def test_unit_lower_inverse(lower, c):
                                np.eye(c), atol=2e-4)
 
 
-#: sha256 of ``kda_mix``'s lowered text (below) as PR 38 left it
+#: sha256 of ``kda_mix``'s lowered text (below) as PR 43 left it
 KDA_MIX_TEXT = {
     "bfloat16":
-        "1a12e487297b907f8871b94f124fc29673bbb76db85e2f4167ab5e03e03a6fa8",
+        "51bc8271f0937ac105a03e215540bd6ed68614e5a9efb7e7437d00a02a1d0303",
     "float32":
-        "b619b8b94cb46424f11bc07c83e90227705176a905d282e45a15f494bb1c5090"}
+        "7a872269091a173780fd930230986fa9113508d597fb51f96136b49f4f58cae3"}
 
 
 @pytest.mark.parametrize("dtype", sorted(KDA_MIX_TEXT))
@@ -242,10 +244,20 @@ def test_kda_mix_lowers_to_its_pinned_text(dtype):
     in the step's autocast type and in float32): a PR that means to
     leave ``F.short_conv``, ``kda_mix`` and the chunk formulas alone
     keeps these digests (PR 36 did, byte for byte). Re-pinned ON PURPOSE
-    by PR 38, which changed the chunk formulas' arithmetic and nothing
-    else of the layer: ``_unit_lower_inverse`` solves by 16 x 16 blocks
-    (before: c5e4df10... / 533aaa3b..., from commit 4e54371). The next
-    PR that changes the layer (ROADMAP S15) re-pins them again."""
+    by PR 38 (the chunk formulas' ``_unit_lower_inverse`` solves by 16 x
+    16 blocks) and by PR 43 (ROADMAP S15; before: 1a12e487... /
+    b619b8b9..., from commit 784e939), which changed how the layer is
+    LAID OUT and nothing of its arithmetic but the order of two sums:
+    this is the CPU's path, the float32 formulas of
+    ``ops/pallas/kda_stages.py`` (``conv_norm_xla``, ``norm_gate_xla``:
+    the same convolution + SiLU + L2 norm and head norm x gate, each
+    under its own ``jax.checkpoint``, where ``kda_mix`` had two
+    checkpoints over all of one side), the decay and every array round
+    the recurrence on (B, T, H * D) with ``A_log`` repeated to the width
+    (``chunk_kda_flat``; no reshape to (B, T, H, D) left outside the XLA
+    form's scan), and the chunk's cumulative decay as a product with a
+    triangle of ones (its transpose the same with the triangle turned)
+    where ``jnp.cumsum`` and two flips were."""
     import hashlib
 
     from paddle_tpu.nn.linear_attention import kda_mix

@@ -232,3 +232,56 @@ def test_the_mixer_on_its_kernels_is_the_mixer_on_the_formulas(monkeypatch):
     assert sorted(got) == sorted(want)
     for name in want:
         assert _rel(got[name], want[name]) < 2e-4, name
+
+
+#: sha256 of the traced text (below) of the two stages at the Nemotron
+#: cell's shapes on commit 784e939, PR 43's parent
+NEMOTRON_STAGE_TEXT = {
+    ("conv", "bfloat16"):
+        "babff08f9f925adbd19b370316a3b6bf40d3ae62e3952f9583fc7f1513453eda",
+    ("conv", "float32"):
+        "3db52322047de330427aa23f0091927a883d08167e81eb90c475c0577fed5fb2",
+    ("norm", "bfloat16"):
+        "6df61399d5bf7ae51b253d4a8292884bf096a04a17edf281e6cc54935aed6050",
+    ("norm", "float32"):
+        "ac48aff18473cce2c2f9ee26048db25f0a85a5dba875f084c54844bb5de3ba31"}
+
+
+@pytest.mark.parametrize("stage,dtype", sorted(NEMOTRON_STAGE_TEXT))
+def test_the_nemotron_cells_stages_trace_to_their_pinned_text(
+        monkeypatch, stage, dtype):
+    """The mirror of ``test_kda.py``'s pin of ``kda_mix``: each stage,
+    value and gradient, on the Nemotron cell's projection (2 x 8,192
+    tokens, ``[z | xBC | dt]`` 10,304 wide, 64 heads of 64 in 8 groups)
+    traces to one jaxpr text — the kernels' bodies, their grids and
+    block maps, the launches' jits and the glue between them. PR 43 made
+    the convolution take a role, an output type, no bias and a norm
+    behind SiLU as static arguments for the KDA mixer; these digests are
+    its PARENT's, so the Nemotron cell's kernels are the ones it ran
+    before. (The lowered Mosaic text cannot be pinned: its bytecode
+    holds the checkout's path and every line number.)"""
+    import hashlib
+
+    monkeypatch.setattr(bringup, "pallas_enabled", lambda: True)
+    b, t, inner, gn, heads, groups = 2, 8192, 4096, 1024, 64, 8
+    total, act = 2 * inner + 2 * gn + heads, jnp.dtype(dtype)
+    if stage == "conv":
+        def loss(proj, taps, bias):
+            return sum(jnp.sum(o.astype(F32)) for o in stages.conv_silu(
+                proj, taps, bias, inner, (inner, gn, gn)))
+
+        shapes = [((b, t, total), act), ((4, inner + 2 * gn), F32),
+                  ((inner + 2 * gn,), F32)]
+    else:
+        def loss(y, u, proj, d_skip, weight):
+            return jnp.sum(stages.gate_norm(y, u, proj, d_skip, weight,
+                                            groups, 1e-5).astype(F32))
+
+        shapes = [((b, t, inner), act), ((b, t, inner), act),
+                  ((b, t, total), act), ((heads,), F32), ((inner,), F32)]
+    text = str(jax.make_jaxpr(jax.value_and_grad(
+        loss, argnums=tuple(range(len(shapes)))))(
+        *(jax.ShapeDtypeStruct(s, d) for s, d in shapes)))
+    assert "mamba2_conv" in text or "mamba2_gate_norm" in text
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == NEMOTRON_STAGE_TEXT[stage, dtype]

@@ -134,12 +134,15 @@ def lowered_digest(name):
 #: 3f20322 (``PYTHONPATH=. python tests/test_step_numerics.py`` prints
 #: them). ``kimi`` was re-pinned ON PURPOSE by PR 38, which changed the
 #: KDA chunk formulas' arithmetic (``kda._unit_lower_inverse`` solves by
-#: 16 x 16 blocks; before: 19df3056...) and touched nothing the other
-#: three builders run: their digests are PR 37's, byte for byte, which is
-#: the proof that no other cell's program changed.
+#: 16 x 16 blocks; before: 19df3056...) and by PR 43 (``kda_mix`` on the
+#: flat (B, T, H * D) layout through ``ops/pallas/kda_stages.py``'s
+#: formulas, see ``tests/test_kda.py``'s pin; before: 8600284c...); both
+#: touched nothing the other three builders run: their digests are PR
+#: 37's, byte for byte, which is the proof that no other cell's program
+#: changed.
 PARENT_TEXT = {
     "bert": "cc586cdb4418fd36d06f83fe638f348b7835e82c2ae1a579a405c563e55611bf",
-    "kimi": "8600284c94389648ef1678bc431a870aeb6bfd9a093fbe8cb3fafdf9b5640dec",
+    "kimi": "580eec3e589fcdf94d2891c7b497ac80f34b1d0e2672328b7feb1a42106a6345",
     "mellum":
         "9b230a9ff398b840b30a848609d18c6a5b923306d689e07f1df8d1b46c780912",
     "nemotron":

@@ -69,9 +69,9 @@ def test_kda_chunk_kernels_compile_at_the_cells_shapes(one_chip, which):
     heads = kda._heads_a_step(h, d, d, kda.CHUNK)
     assert heads in (4, 8)
     launch = kda._pallas_fwd if which == "fwd" else kda._pallas_bwd
-    seq = [(KDA, F32)] * 4 + [(KDA[:3], F32)]
+    seq = [((b, t, h * d), F32)] * 4 + [(KDA[:3], F32)]
     if which == "bwd":
-        seq += [((b, h, t // kda.CHUNK, d, d), F32), (KDA, F32)]
+        seq += [((b, h, t // kda.CHUNK, d, d), F32), ((b, t, h * d), F32)]
 
     def fn(*a):
         return launch(*a, kda.CHUNK)
@@ -423,3 +423,43 @@ def test_mamba2_stage_kernels_compile_at_the_nemotron_cells_shapes(
     assert text.count(role) >= launches
     # the projection goes into the kernels as it is: no slice of it
     assert not re.findall(rf"\[{b},{t},(4096|6144)\]\S* slice\(", text)
+
+
+@pytest.mark.parametrize("t,dtype", [(8192, BF16), (8192, F32), (8240, BF16)],
+                         ids=["bfloat16", "float32", "ragged_bfloat16"])
+@pytest.mark.parametrize("stage", ["conv", "gate_norm"])
+def test_kda_stage_kernels_compile_at_the_kimi_cells_shapes(
+        one_chip, monkeypatch, stage, t, dtype):
+    """The KDA mixer's fused element-wise stages on the projections of
+    1 x 8,192 tokens, 32 heads of 128 (PR 43): forward and the one-pass
+    backward, float32 results from projections of either type, also at a
+    length that is no whole number of blocks; and nothing beside the
+    launches has the heads on an axis of its own."""
+    import paddle_tpu.framework.bringup as bringup
+    from paddle_tpu.ops.pallas import kda_stages as stages
+
+    monkeypatch.setattr(bringup, "pallas_enabled", lambda: True)
+    b, h, d = 1, 32, 128
+    if stage == "conv":
+        def loss(*a):
+            outs = stages.conv_norm(*a, d)
+            assert all(o.dtype == F32 for o in outs)
+            return sum(jnp.sum(o) for o in outs)
+
+        shapes = [((b, t, h * d), dtype)] * 3 + [((4, h * d), F32)] * 3
+        launches, role = 6, stages.ROLE_CONV
+    else:
+        def loss(o, gate, weight):
+            out = stages.norm_gate(o, gate, weight, 1e-5)
+            assert out.dtype == F32
+            return jnp.sum(out)
+
+        shapes = [((b, t, h * d), F32), ((b, t, h * d), dtype), ((d,), F32)]
+        launches, role = 2, stages.ROLE_NORM
+    out = _compile(jax.value_and_grad(
+        loss, argnums=tuple(range(len(shapes)))), one_chip, *shapes)
+    text = out.as_text()
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                          text)) == launches
+    assert text.count(role) >= launches
+    assert f"[{b},{t},{h},{d}]" not in text
