@@ -1,5 +1,6 @@
-"""BERT-base pretraining via the public API (bench.py's config as a
-user-style script; set BERT_SMOKE=1 for a tiny CPU run)."""
+"""BERT-base pretraining via the public API (the model of
+benchmarks/configs/bert-base.json as a user-style script; set
+BERT_SMOKE=1 for a tiny CPU run)."""
 import os
 import time
 

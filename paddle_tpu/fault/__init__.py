@@ -12,7 +12,7 @@ Two building blocks the rest of the framework composes:
 
 All activity lands in process-global profiler counters
 (``retry_attempts``, ``retry_giveups``, ``faults_injected``, ...)
-surfaced through ``Executor.counters`` and bench rows.
+surfaced through ``Executor.counters``.
 """
 from . import injector  # noqa: F401
 from .injector import (  # noqa: F401

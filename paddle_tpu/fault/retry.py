@@ -13,8 +13,8 @@ code changes::
     PADDLE_RETRY_BASE_DELAY_S   first backoff delay (default 0.1)
     PADDLE_RETRY_MAX_DELAY_S    backoff cap (default 30.0)
 
-Counters (paddle_tpu.profiler, surfaced via ``exe.counters`` and bench
-rows): ``retry_attempts`` — re-attempts after a retryable failure;
+Counters (paddle_tpu.profiler, surfaced via ``exe.counters``):
+``retry_attempts`` — re-attempts after a retryable failure;
 ``retry_giveups`` — exhaustions (budget/deadline spent, last error
 re-raised).
 """

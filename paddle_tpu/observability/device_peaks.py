@@ -1,18 +1,17 @@
 """Device peak-performance registry: bf16 peak FLOP/s and HBM bandwidth
 per TPU generation — the ONE home of the numbers every utilization
-metric divides by (bench.attach_mfu, the executor's live ``mfu`` /
-``arith_intensity`` gauges, tools/perf_report.py's roofline buckets).
+metric divides by (the executor's live ``mfu`` / ``arith_intensity``
+gauges, tools/perf_report.py's roofline buckets).
 
-The table moved here from bench.py so the MFU formula keeps a single
-denominator source; bench imports it back. Bandwidth entries make the
-roofline position derivable: ``machine_balance`` (peak FLOP/s divided
-by HBM byte/s) is the arithmetic-intensity threshold separating
-bandwidth-bound from compute-bound ops.
+Bandwidth entries make the roofline position derivable:
+``machine_balance`` (peak FLOP/s divided by HBM byte/s) is the
+arithmetic-intensity threshold separating bandwidth-bound from
+compute-bound ops.
 
 Matching is by lowercased substring, first hit wins — "v5 lite" must
 stay ahead of the bare "v5" family entries. Unknown chips resolve to
-``None`` rather than a guess (bench then reports mfu=null), unless the
-operator pins peaks explicitly:
+``None`` rather than a guess (the ``mfu`` gauge is then left unset),
+unless the operator pins peaks explicitly:
 
 - ``PADDLE_PEAK_FLOPS``: peak FLOP/s override (any backend, including
   CPU runs — lets a dev box exercise the whole MFU plane)
@@ -25,7 +24,7 @@ from __future__ import annotations
 import os
 from typing import NamedTuple, Optional
 
-__all__ = ["DevicePeak", "PEAK_FLOPS", "DEVICE_PEAKS", "peaks_for",
+__all__ = ["DevicePeak", "DEVICE_PEAKS", "peaks_for",
            "peak_flops", "hbm_bandwidth", "machine_balance"]
 
 
@@ -39,8 +38,7 @@ class DevicePeak(NamedTuple):
 
 # (device_kind substring, bf16 peak FLOP/s, HBM GB/s) — lowercased
 # substring match, first hit wins ("v5 lite" before the bare "v5").
-# FLOP/s figures are the ones bench.py shipped with since round 2;
-# bandwidths are the published per-chip HBM numbers.
+# FLOP/s and bandwidths are the published per-chip numbers.
 DEVICE_PEAKS = (
     ("v5 lite", 197e12, 819.0),
     ("v5e", 197e12, 819.0),
@@ -51,9 +49,6 @@ DEVICE_PEAKS = (
     ("v3", 123e12, 900.0),
     ("v2", 45e12, 700.0),
 )
-
-# legacy bench.py surface: (substring, peak_flops) pairs
-PEAK_FLOPS = tuple((sub, fl) for sub, fl, _bw in DEVICE_PEAKS)
 
 
 def _env_float(name: str) -> Optional[float]:
@@ -89,7 +84,7 @@ def peaks_for(kind: str) -> Optional[DevicePeak]:
 
 def peak_flops(kind: str) -> Optional[float]:
     """Peak bf16 FLOP/s for ``kind``; None when unknown (never a
-    guess — bench reports mfu=null instead)."""
+    guess — the ``mfu`` gauge is left unset instead)."""
     p = peaks_for(kind)
     return p.flops if p is not None and p.flops > 0 else None
 

@@ -8,7 +8,7 @@ payload encoded (per-block scaled int8, or bf16) and every reduce step
 ACCUMULATING IN f32 (the PR 5 accumulator discipline, so the accuracy
 gates stay provable). int8 wire bytes are ~1/4 of f32 plus one f32
 scale per ``QUANT_BLOCK`` elements — ``encoded_nbytes`` is the closed
-form the cost model, the PS wire plane, and the bench probe all share.
+form the cost model, the PS wire plane, and the comm gauges all share.
 
 Determinism: encode is pure jnp arithmetic (round-half-to-even via
 ``jnp.rint``, max-abs block scales), decode is exact multiply — the

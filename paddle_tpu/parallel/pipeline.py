@@ -160,8 +160,8 @@ def gpipe_schedule(num_stages: int, num_microbatches: int):
 
 def gpipe_bubble_fraction(num_stages: int, num_microbatches: int) -> float:
     """Analytic GPipe bubble: the idle fraction (S-1)/(S+M-1) of the
-    fill-drain schedule — the quantity the MULTICHIP bench probe reports
-    as ``pp_bubble_frac`` and that growing M amortises."""
+    fill-drain schedule — the quantity the executor's ``pp_bubble_frac``
+    gauge reports and that growing M amortises."""
     s_count, m_count = int(num_stages), int(num_microbatches)
     return (s_count - 1) / max(s_count + m_count - 1, 1)
 
@@ -289,7 +289,7 @@ def schedule_bubble_fraction(schedule: str, num_stages: int,
                              num_microbatches: int,
                              interleave: int = 2) -> float:
     """Schedule-aware analytic bubble fraction, one convention across
-    the cost model, the gauges and the bench probes.
+    the cost model and the gauges.
 
     The per-microbatch work unit weighs backward at 2× forward
     (B = 2F, the standard roofline for matmul-dominated stages), so a

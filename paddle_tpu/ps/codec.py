@@ -5,8 +5,8 @@ stdlib + numpy ONLY — ps/ must stay importable without jax (the PR 9
 contract: fault/http_kv/ps serve on boxes that never load XLA). The
 jnp trace-time encoders in parallel/collectives.py implement the SAME
 layout; ``encoded_nbytes`` is the ONE closed form the cost model, the
-wire readers on both ends, and the bench probe's comm_bytes_saved_pct
-all share.
+wire readers on both ends, and the comm_bytes_saved_pct gauge all
+share.
 
 Layouts (all little-endian, deterministic):
   f32   raw float32 payload (codec id 0 — the pre-codec wire bytes)

@@ -10,8 +10,8 @@ PS v2 page codec buys: ``ps/codec.py`` int8 with ``block = H * D`` (one
 f32 scale per token row — the same layout the int8 KV pool stores), so
 a page travels at ~26% of its f32 bytes and, on serving-scale models,
 orders of magnitude under the prefill-recompute FLOP-equivalent
-(:func:`migration_cost` is the closed form both the chaos drill and the
-bench probe assert against).
+(:func:`migration_cost` is the closed form the chaos drill asserts
+against).
 
 The wire unit is a PAGE FRAME: a fixed header (magic, version, codec
 byte from ``CODEC_IDS``, pool geometry, token count), the covered

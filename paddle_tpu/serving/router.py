@@ -274,7 +274,7 @@ class DecodeEngineServer:
 # ---------------------------------------------------------------------------
 class LocalReplica:
     """An in-process ``DecodeEngine`` behind the replica interface —
-    what tests, the bench probe, and ``load_gen --fleet`` route to."""
+    what tests and ``load_gen --fleet`` route to."""
 
     def __init__(self, engine, name: Optional[str] = None):
         self.engine = engine

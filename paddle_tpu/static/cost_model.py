@@ -8,7 +8,7 @@ Accounting conventions (PaLM-style MFU numerator):
 - ``model_flops`` counts matmul-class ops only (matmul/mul/conv) at
   2 FLOPs per MAC; elementwise/reduction/normalization ops contribute
   HBM bytes, not FLOPs — they are bandwidth-bound and excluded from the
-  MFU numerator exactly like bench.py's closed forms exclude them.
+  MFU numerator exactly like benchmarks/flops.py's closed forms do.
 - ``hbm_bytes`` is the dtype-aware payload traffic of every op: input
   reads + output writes from VarDesc shapes and dtypes. The AMP pass
   stamps rewritten vars bf16/fp16, so mixed-precision bytes halve with
@@ -391,7 +391,7 @@ def program_cost(program, feed_shapes=None, batch_size=None, gm=None,
             # toward hbm_bytes — never the whole pool the KPages/VPages
             # operands declare. FLOPs are the two attention matmuls
             # (scores + values) over the table-bounded context, the
-            # same accounting the bench closed forms use.
+            # same accounting the closed forms use.
             q_name = (op.inputs.get("Q") or [None])[0]
             kp_name = (op.inputs.get("KPages") or [None])[0]
             pt_name = (op.inputs.get("PageTable") or [None])[0]

@@ -390,8 +390,8 @@ class Executor:
         # the xla_*_bytes gauges read its compiled.memory_analysis()
         self._last_entry: Optional[_ExecEntry] = None
         # per-executor view of the hot-path counters; the module-global
-        # aggregate lives in the profiler's metrics registry (bench and
-        # the /metrics endpoint read that one)
+        # aggregate lives in the profiler's metrics registry (the
+        # /metrics endpoint reads that one)
         import collections
         self._counters = collections.Counter()
         # trainer scrape surface: PADDLE_METRICS_PORT starts the
@@ -450,9 +450,9 @@ class Executor:
     def memory_stats(self) -> Dict[str, int]:
         """XLA memory analysis of the LAST executable this executor ran:
         peak_bytes / temp_bytes / argument_bytes / output_bytes /
-        generated_code_bytes / alias_bytes. The objective gate for the
-        recompute pass — bench's remat probe asserts temp/peak strictly
-        drop with BuildStrategy.recompute on. {} before the first run."""
+        generated_code_bytes / alias_bytes, as the compiler of the
+        backend it ran on counts them (so no figure of what a step
+        holds on another backend). {} before the first run."""
         return self._memory_analysis_dict(self._last_entry)
 
     def cost_stats(self, top: int = 10) -> Dict[str, Any]:

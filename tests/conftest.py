@@ -12,10 +12,6 @@ import re
 import sys
 
 os.environ.setdefault("JAX_ENABLE_X64", "0")
-# test-suite bench invocations must not pollute the committed capture
-# log (tests that exercise persistence override with BENCH_CAPTURES_PATH
-# and re-enable)
-os.environ.setdefault("BENCH_NO_PERSIST", "1")
 # the on-by-default disk compile cache would write every test's (and
 # every spawned worker's) executables under <checkout>/.jax_cache; tests
 # that exercise it re-enable it in a subprocess with their own directory

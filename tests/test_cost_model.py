@@ -15,8 +15,8 @@ Covers the PR-12 acceptance surface:
 - tools/metrics_watch.py bucket-derived p50/p99 deltas between polls
 - observability/device_peaks.py resolution (substring precedence, env
   pins, machine balance)
-- bench.py's ``ir_flops_per_step`` cross-check probes (bert + nmt
-  closed forms reproduced exactly by the IR walk)
+- the IR walk's train-step FLOPs against the bert and nmt closed forms
+  on a transformer-shaped program (within 2%)
 """
 import json
 import os
@@ -690,33 +690,75 @@ def test_device_peaks_env_pins(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# bench.py ir_flops cross-check probes
+# the IR walk against the closed forms of a transformer-shaped program
 # ---------------------------------------------------------------------------
-def test_bench_ir_flops_matches_bert_closed_form():
-    import bench
+def _transformer_ir_flops(layers, batch, seq, hidden, ffn, vocab,
+                          dec_layers=0, head_transform=True):
+    """IR-derived train-step model FLOPs of a transformer-shaped static
+    program: per encoder layer qkv+out projections, scores/values
+    matmuls and the ffn pair (+ a cross-attention block per decoder
+    layer), plus the vocab head, walked by static/cost_model.py (the
+    per-op rules behind the executor's live mfu gauge).
 
+    Graph construction only: no Scope, no execution, no device."""
+    H = hidden
+
+    def attention(h, kv):
+        # 3 H->H projections + out proj (the closed form's 8H^2/token),
+        # scores q@k^T and probs@v (its 4*S*H/token)
+        q = static.nn.fc(h, H, num_flatten_dims=2)
+        k = static.nn.fc(kv, H, num_flatten_dims=2)
+        v = static.nn.fc(kv, H, num_flatten_dims=2)
+        probs = static.softmax(static.matmul(q, k, transpose_y=True))
+        return static.nn.fc(static.matmul(probs, v), H,
+                            num_flatten_dims=2)
+
+    def ffn_block(h):
+        h = static.nn.fc(h, ffn, num_flatten_dims=2, act="relu")
+        return static.nn.fc(h, H, num_flatten_dims=2)
+
+    with unique_name.guard():
+        main, startup = static.Program(), static.Program()
+        with static.program_guard(main, startup):
+            x = static.data("x", [-1, seq, H])
+            h = x
+            for _ in range(layers):
+                h = ffn_block(attention(h, h))
+            if dec_layers:
+                y = static.data("y", [-1, seq, H])
+                enc = h
+                h = y
+                for _ in range(dec_layers):
+                    h = attention(h, h)          # decoder self-attention
+                    h = ffn_block(attention(h, enc))  # cross-attention
+            if head_transform:
+                h = static.nn.fc(h, H, num_flatten_dims=2)
+            logits = static.nn.fc(h, vocab, num_flatten_dims=2)
+            loss = static.mean(logits)
+            static.SGD(0.01).minimize(loss)
+        report = program_cost(
+            main, feed_shapes={"x": (batch, seq, H)})
+    return int(report.model_flops)
+
+
+def test_ir_flops_matches_bert_closed_form():
     h, i, v, layers, b, s = 128, 256, 1024, 2, 2, 16
     closed = 3 * (layers * (8 * h * h + 4 * h * i + 4 * s * h)
                   + 2 * h * h + 2 * h * v) * b * s
-    ir = bench._transformer_ir_flops(layers=layers, batch=b, seq=s,
-                                     hidden=h, ffn=i, vocab=v)
+    ir = _transformer_ir_flops(layers=layers, batch=b, seq=s,
+                               hidden=h, ffn=i, vocab=v)
     assert abs(ir - closed) / closed <= 0.02
-    fields = bench._ir_flops_fields(ir, closed)
-    assert fields["ir_flops_per_step"] == ir
-    assert fields["ir_flops_delta"] <= 0.02
 
 
-def test_bench_ir_flops_matches_nmt_closed_form():
-    import bench
-
+def test_ir_flops_matches_nmt_closed_form():
     v, h, i, le, b, s = 512, 64, 128, 2, 2, 16
     enc = le * (8 * h * h + 4 * h * i + 4 * s * h)
     dec = le * (16 * h * h + 4 * h * i + 8 * s * h) + 2 * h * v
     closed = 3 * (enc + dec) * b * s
-    ir = bench._transformer_ir_flops(layers=le, batch=b, seq=s,
-                                     hidden=h, ffn=i, vocab=v,
-                                     dec_layers=le,
-                                     head_transform=False)
+    ir = _transformer_ir_flops(layers=le, batch=b, seq=s,
+                               hidden=h, ffn=i, vocab=v,
+                               dec_layers=le,
+                               head_transform=False)
     assert abs(ir - closed) / closed <= 0.02
 
 

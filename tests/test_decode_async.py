@@ -210,6 +210,25 @@ def test_host_kv_pool_roundtrip_and_capacity():
     assert host.take_prefix(b"k1") is None
 
 
+def test_host_page_bytes_are_the_closed_form_and_under_half_of_f32():
+    """A page costs the host tier what the cost model's closed form
+    says (what ``kv_offload_bytes`` charges a spilled page), which is
+    the bytes of the int8 rows and f32 scales it holds and under half
+    of the f32 page the device pool holds."""
+    from paddle_tpu.static.cost_model import kv_offload_page_bytes
+
+    page = 4
+    host = HostKVPool(n_layers=CFG.n_layers, page_size=page,
+                      heads=CFG.n_heads, head_dim=CFG.head_dim,
+                      capacity_bytes=1 << 20)
+    assert host.page_nbytes == kv_offload_page_bytes(CFG, page)
+    rows = np.zeros((CFG.n_layers, page, CFG.n_heads, CFG.head_dim),
+                    np.int8)
+    scales = np.zeros((CFG.n_layers, page), np.float32)
+    assert host.page_nbytes == 2 * (rows.nbytes + scales.nbytes)
+    assert host.page_nbytes < 0.5 * 2 * rows.size * 4
+
+
 def _offload_workload():
     plens = (9, 11, 9, 11, 9, 11)
     prompts = []

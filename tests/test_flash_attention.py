@@ -1,6 +1,6 @@
 """Flash-attention Pallas kernels in interpret mode (CPU-hermetic): the
 forward/backward math must match the XLA reference. On-chip speed is
-covered by bench.py."""
+the BERT cells' (benchmarks/run.py)."""
 import functools
 
 import jax

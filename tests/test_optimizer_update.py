@@ -347,7 +347,7 @@ def test_the_executors_cache_keys_read_no_optimizer_escape(monkeypatch):
 # ---------------------------------------------------------------------------
 
 
-def _build_moe_program(static, seed=7):
+def _build_moe_program(static, seed=7, codec=None):
     main, startup = static.Program(), static.Program()
     main.random_seed = startup.random_seed = seed
     with static.program_guard(main, startup):
@@ -355,7 +355,7 @@ def _build_moe_program(static, seed=7):
         label = static.data("label", [32, 1], dtype="int64")
         h = static.nn.fc(x, 16, act="relu")
         m, aux = static.nn.moe(h, num_experts=4, d_hidden=32,
-                               capacity_factor=2.0)
+                               capacity_factor=2.0, dispatch_codec=codec)
         logits = static.nn.fc(m, 4)
         loss = static.mean(
             static.softmax_with_cross_entropy(logits, label)) \
@@ -364,7 +364,7 @@ def _build_moe_program(static, seed=7):
     return main, startup, loss
 
 
-def _run_moe(strategy=None, steps=2):
+def _run_moe(strategy=None, steps=2, codec=None):
     import paddle_tpu as paddle
     from paddle_tpu import static
     from paddle_tpu.utils import unique_name
@@ -376,7 +376,7 @@ def _run_moe(strategy=None, steps=2):
     with unique_name.guard():
         scope = static.Scope()
         with static.scope_guard(scope):
-            main, startup, loss = _build_moe_program(static)
+            main, startup, loss = _build_moe_program(static, codec=codec)
             exe = static.Executor()
             exe.run(startup)
             target = (static.CompiledProgram(main, build_strategy=strategy)
@@ -405,6 +405,24 @@ def test_static_moe_ep_stamp_parity_and_cost():
     np.testing.assert_allclose(ep, dense, rtol=1e-5, atol=1e-6)
     cs = exe.cost_stats()
     assert cs.get("moe_a2a_bytes", 0) > 0, cs
+
+
+def test_static_moe_int8_dispatch_tracks_dense_on_fewer_wire_bytes():
+    """``dispatch_codec="int8"``: the rows the experts exchange travel
+    encoded, the loss stays inside the quant gate of the dense path and
+    the cost model charges the all_to_all fewer bytes than at f32."""
+    from paddle_tpu import static
+
+    bs = static.BuildStrategy()
+    bs.mesh_shape = {"ep": 4, "dp": 2}
+    dense, _ = _run_moe()
+    _, exe_f32 = _run_moe(bs)
+    counters.reset()
+    quant, exe_q = _run_moe(bs, codec="int8")
+    assert counters.snapshot().get("moe_a2a.a2a", 0) >= 1
+    assert np.abs(quant - dense).max() <= 1e-2, (quant, dense)
+    wire_q = exe_q.cost_stats()["moe_a2a_bytes"]
+    assert 0 < wire_q < exe_f32.cost_stats()["moe_a2a_bytes"]
 
 
 def test_moe_ep_pass_stamps_exchange_plan():

@@ -1,60 +1,7 @@
-"""Unit tests for the durable bench-capture log (tools/_captures.py).
-
-VERDICT r3 weak #1: three rounds of live-TPU numbers evaporated because
-bench.py only printed to stdout. Every measured row now appends to a
-committed BENCH_CAPTURES.jsonl with timestamp + git sha so any number
-is traceable to the code that produced it (reference posture:
-operators/benchmark/op_tester.cc persists beside the harness).
-"""
-import json
+"""Unit tests for tools/profile_step.py's reduction of a device trace:
+op names split into phase and scope, seconds summed by phase, module
+scope and Pallas kernel role, and the join with the compiled text."""
 import os
-
-from tools._captures import captures_path, git_sha, persist_row
-
-
-def test_persist_row_appends_with_provenance(tmp_path, monkeypatch):
-    dest = tmp_path / "caps.jsonl"
-    monkeypatch.setenv("BENCH_CAPTURES_PATH", str(dest))
-    monkeypatch.setenv("BENCH_NO_PERSIST", "0")
-    assert persist_row({"metric": "m", "value": 1.5, "backend": "cpu"},
-                       kind="bench")
-    assert persist_row({"op": "matmul", "ms": 0.2}, kind="opbench")
-    recs = [json.loads(ln) for ln in dest.read_text().splitlines()]
-    assert len(recs) == 2
-    for rec in recs:
-        assert rec["ts"] and rec["git_sha"]
-    assert recs[0]["kind"] == "bench" and recs[0]["value"] == 1.5
-    assert recs[1]["kind"] == "opbench" and recs[1]["op"] == "matmul"
-
-
-def test_persist_row_disabled_by_flag(tmp_path, monkeypatch):
-    dest = tmp_path / "caps.jsonl"
-    monkeypatch.setenv("BENCH_CAPTURES_PATH", str(dest))
-    monkeypatch.setenv("BENCH_NO_PERSIST", "1")
-    assert not persist_row({"metric": "m"})
-    assert not dest.exists()
-
-
-def test_persist_row_never_raises_on_bad_path(monkeypatch):
-    monkeypatch.setenv("BENCH_CAPTURES_PATH", "/proc/definitely/not/here")
-    monkeypatch.setenv("BENCH_NO_PERSIST", "0")
-    assert not persist_row({"metric": "m"})
-
-
-def test_git_sha_resolves_in_checkout():
-    sha = git_sha()
-    assert sha and sha != "unknown"
-    assert all(c in "0123456789abcdef" for c in sha)
-
-
-def test_default_captures_path_is_repo_root():
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    old = os.environ.pop("BENCH_CAPTURES_PATH", None)
-    try:
-        assert captures_path() == os.path.join(repo, "BENCH_CAPTURES.jsonl")
-    finally:
-        if old is not None:
-            os.environ["BENCH_CAPTURES_PATH"] = old
 
 
 def _profile_step():

@@ -1,7 +1,6 @@
 """PS at-scale micro-bench (VERDICT r4 #7): a >=1M-row sparse table
 sharded over TWO PSServer PROCESSES — pull and push throughput plus
-the geo-delta path — persisted to BENCH_CAPTURES.jsonl so the CTR
-config has a denominator beyond the single TPU window. (Reference
+the geo-delta path, each held to a sanity floor. (Reference
 operators/distributed/large_scale_kv.h — large-scale KV is exactly the
 capability this measures.)
 """
@@ -49,7 +48,6 @@ def two_server_procs():
 
 def test_million_row_sharded_pull_push_throughput(two_server_procs):
     from paddle_tpu.ps.service import PSClient
-    from tools._captures import persist_row
 
     client = PSClient(two_server_procs)
     ids_all = np.arange(ROWS, dtype=np.int64)
@@ -80,21 +78,12 @@ def test_million_row_sharded_pull_push_throughput(two_server_procs):
     # sanity floor: loopback TCP + native KV should stream well over
     # 100k rows/s; a 10x regression would trip this
     assert pull_tput > 5e4 and push_tput > 5e4, (pull_dt, push_dt)
-    for name, tput, dt in (("ps_pull", pull_tput, pull_dt),
-                           ("ps_push", push_tput, push_dt)):
-        persist_row({
-            "metric": f"{name}_rows_per_sec", "value": round(tput, 1),
-            "unit": "rows/s", "rows": ROWS, "dim": DIM, "batch": BATCH,
-            "servers": 2, "dt": round(dt, 3), "device_kind": "host-cpu",
-            "comparable": True,
-        }, kind="ps_bench")
 
 
 def test_geo_delta_throughput(two_server_procs):
     from paddle_tpu.ps.communicator import GeoCommunicator
     from paddle_tpu.ps.service import PSClient
     from paddle_tpu.ps.table import SparseTable
-    from tools._captures import persist_row
 
     client = PSClient(two_server_procs)
     local = SparseTable(dim=DIM, init_range=0.01, seed=2)
@@ -112,9 +101,3 @@ def test_geo_delta_throughput(two_server_procs):
     dt = time.perf_counter() - t0
     tput = n_rounds * ids_per_round / dt
     assert tput > 1e4, dt
-    persist_row({
-        "metric": "ps_geo_delta_rows_per_sec", "value": round(tput, 1),
-        "unit": "rows/s", "rounds": n_rounds, "ids_per_round":
-        ids_per_round, "k_steps": 2, "servers": 2, "dt": round(dt, 3),
-        "device_kind": "host-cpu", "comparable": True,
-    }, kind="ps_bench")

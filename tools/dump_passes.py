@@ -68,8 +68,7 @@ sys.path.insert(0, REPO)
 
 
 def _demo_program():
-    """A small training program with food for every pass (the same
-    shape bench.py's _static_pass_probe measures)."""
+    """A small training program with food for every pass."""
     import paddle_tpu.static as static
 
     main, startup = static.Program(), static.Program()

@@ -6,10 +6,10 @@ service rate and the measurement is a throughput/latency probe, not a
 queue-explosion test (open-loop overload is what the admission-control
 tests cover). Request CONTENT is deterministic: request ``i`` always
 carries the same rows (seeded by ``i``) and the same size from the
-``sizes`` cycle, whatever thread runs it — so a bench row or a chaos
+``sizes`` cycle, whatever thread runs it — so a test or a chaos
 drill replays identically.
 
-Library use (bench.py's serving probe)::
+Library use::
 
     from tools.load_gen import LoadGen
     summary = LoadGen(engine, total_requests=60, workers=4,
@@ -223,7 +223,7 @@ class DecodeLoadGen:
     next. Mixed lengths are deterministic per request index: request
     ``i`` draws ``prompt_len`` from ``prompt_lens``, ``max_new_tokens``
     from ``output_lens`` (cycled), and its token ids from
-    ``RandomState(i)`` — a bench row or drill replays identically.
+    ``RandomState(i)`` — a test or drill replays identically.
 
     ``run()`` returns (and stores as ``.summary``) the decode metrics:
     ``decode_tokens_per_sec`` (generated tokens / wall), client-side

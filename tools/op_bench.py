@@ -1,9 +1,9 @@
 """Op-level micro-benchmark harness (reference
 operators/benchmark/op_tester.cc + operators/jit/benchmark.cc): times the
 hot kernels — matmul, attention (XLA and Pallas flash), layernorm,
-embedding lookup, conv — on the current backend and appends one JSON
-line per op to a per-round history file so a single-kernel regression
-between rounds is visible without running a full model.
+embedding lookup, conv — on the current backend and prints one JSON
+line per op, so a single kernel can be timed without running a full
+model (fused xent's three kernels apart: ``--ops fused_xent``).
 
 Usage:
     python tools/op_bench.py                 # bench all ops, print rows
@@ -11,7 +11,7 @@ Usage:
     python tools/op_bench.py --append bench_ops.jsonl  # history file
 
 Each row: {"op", "shape", "ms", "gflops" (if meaningful), "backend",
-"device_kind", "round": $BENCH_ROUND}. Smoke shapes via BENCH_SMOKE=1.
+"device_kind"}. Smoke shapes via BENCH_SMOKE=1.
 """
 from __future__ import annotations
 
@@ -324,12 +324,10 @@ BENCHES = {
 }
 
 
-def run_benches(ops=None, smoke=None):
-    """Resolve the backend, run the named benches (default: all), return
-    the row dicts. Importable so the regression-gate test shares the
-    exact measurement path with the CLI."""
-    if smoke is None:
-        smoke = os.environ.get("BENCH_SMOKE") == "1"
+def run_benches(ops):
+    """Resolve the backend, run the named benches, return the row
+    dicts."""
+    smoke = os.environ.get("BENCH_SMOKE") == "1"
 
     global jax
     import jax
@@ -337,7 +335,7 @@ def run_benches(ops=None, smoke=None):
     backend = jax.default_backend()
     kind = jax.devices()[0].device_kind
     rows = []
-    for name in (ops or list(BENCHES)):
+    for name in ops:
         name = name.strip()
         if not name:
             continue
@@ -345,8 +343,7 @@ def run_benches(ops=None, smoke=None):
             row = BENCHES[name](smoke)
         except Exception as e:
             row = {"op": name, "error": f"{type(e).__name__}: {e}"}
-        row.update({"backend": backend, "device_kind": kind, "smoke": smoke,
-                    "round": os.environ.get("BENCH_ROUND", "")})
+        row.update({"backend": backend, "device_kind": kind, "smoke": smoke})
         if "ms" in row:
             row["ms"] = round(row["ms"], 4)
         for k in ("gflops", "gbps"):
@@ -363,11 +360,8 @@ def main():
                     help="JSONL history file to append rows to")
     args = ap.parse_args()
     rows = run_benches(args.ops.split(","))
-    from tools._captures import persist_row
-
     for row in rows:
         print(json.dumps(row), flush=True)
-        persist_row(row, kind="opbench")
     if args.append:
         with open(args.append, "a") as f:
             for row in rows:
