@@ -511,6 +511,13 @@ def _kernel_checks():
             flash_causal(f"grouped window={window}", (1, 8192, 8, 128),
                          (1, 8192, 2, 128), (1, 8192, 2, 128), window)))
 
+    # eight query heads on two key heads of 64 (PR 46): the stream
+    # kernels on 64-lane blocks, half a vreg
+    checks.append((
+        "flash stream grouped 8q/2kv (1, 8192, ., 64)",
+        flash_causal("grouped, heads of 64", (1, 8192, 8, 64),
+                     (1, 8192, 2, 64), (1, 8192, 2, 64))))
+
     def flash_masked(fails, shape=(8, 512, 12, 64)):
         b, l = shape[:2]    # ragged key-padding: row i keeps l - 37 i keys
         keep = jnp.arange(l)[None, :] < l - 37 * jnp.arange(b)[:, None]
@@ -968,6 +975,40 @@ def _kernel_checks():
 
     kda_stage("conv")
     kda_stage("gate_norm")
+
+    # -- the gated short convolution's fused stage against its float32
+    # formula, at the LFM2 cell's shapes -------------------------------------
+    def gated_conv_stage(b=2, t=8192, d=2048, taps=3, dtype=bf16):
+        name = f"gated_conv stage fwd + bwd ({b}, {t}, {3 * d}) " \
+               f"{jnp.dtype(dtype).name}"
+
+        def check(fails):
+            from paddle_tpu.ops.pallas import counters, gated_conv
+
+            args = (rnd(1, (b, t, 3 * d), dtype), rnd(2, (taps, d), f32, 0.5))
+            w = rnd(3, (b, t, d), f32)
+
+            def run(form):
+                def loss(*a):
+                    out = form(*a)
+                    return jnp.sum(out.astype(f32) * w), out
+                return jax.jit(jax.value_and_grad(
+                    loss, argnums=(0, 1), has_aux=True))(*args)
+
+            before = counters.snapshot()
+            (_, out), got = run(gated_conv.gated_conv)
+            if counters.delta(before) != {"gated_conv.fused": 1}:
+                fails.append(f"{name}: outside its gate")
+                return
+            (_, ref), want = run(gated_conv.gated_conv_xla)
+            if out.dtype != dtype:
+                fails.append(f"{name} out: {out.dtype}")
+            _close(f"{name} out", out, ref, tol_of(dtype), fails)
+            for g, r, nm in zip(got, want, ("proj", "taps")):
+                _close(f"{name} d{nm}", g, r, tol_of(dtype), fails)
+        checks.append((name, check))
+
+    gated_conv_stage()
 
     # -- the dropless expert layer, each rung against a dense loop -----------
     def experts(held, dtype, rungs, t=8192, d=2304, f=1024,

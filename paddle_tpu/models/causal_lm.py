@@ -2,7 +2,8 @@
 
 ``CausalLM.from_config(cfg)`` reads a Hugging Face style ``config.json``
 dict and builds: token embedding, ``num_hidden_layers`` residual blocks,
-a final RMSNorm and an untied head. A block is a token mixer AND a
+a final RMSNorm and a head (its own matrix, or the embedding's where the
+file says ``tie_word_embeddings``). A block is a token mixer AND a
 feed-forward (``x += Mix(RMSNorm(x))``, ``x += FFN(RMSNorm(x))``) or,
 for a config that lays its layers out by ``hybrid_override_pattern``, ONE
 sublayer behind one norm (``x += Sub(RMSNorm(x))``). Which kinds a block
@@ -24,7 +25,10 @@ kind registered there.
     mixer   ``mamba2`` -> ``nn.Mamba2Mixer`` (``mamba_num_heads`` heads
             of ``mamba_head_dim``, ``ssm_state_size``, ``n_groups``,
             ``conv_kernel``, ``time_step_*``)
-            with ``layer_types``: ``sliding_attention`` / ``full_attention``
+            with ``layer_types``: ``conv`` -> ``conv``
+            (``nn.GatedShortConv``: ``conv_L_cache`` taps; a file that
+            says ``conv_bias`` true is refused by that key);
+            ``sliding_attention`` / ``full_attention``
             -> ``gqa`` (``nn.GroupedQueryAttention``:
             ``num_key_value_heads`` key heads of ``head_dim``,
             ``sliding_window`` on the sliding layers, ``qk_norm``). Its
@@ -46,12 +50,14 @@ kind registered there.
             have to be null)
     ffn     with ``mlp_layer_types``: ``sparse`` -> ``moe``, ``dense`` ->
             ``dense``
-            else the first ``first_k_dense_replace`` layers -> ``dense``;
+            else the first ``first_k_dense_replace`` or
+            ``num_dense_layers`` layers -> ``dense``;
             the others -> ``moe`` where the file counts experts
             (``num_experts`` or ``n_routed_experts``) and ``dense``
             where it counts none
             ``dense``: ``nn.GatedFFN`` of ``intermediate_size``
-            (``hidden_act``), or for a config that says
+            (``hidden_act``, SiLU for a file without the key), or for a
+            config that says
             ``mlp_hidden_act`` (``nemotron_h``) ``nn.PlainFFN``
             ``moe``: ``nn.SparseMoELayer``: ``num_experts_per_token`` or
             ``num_experts_per_tok`` of the router's ``num_experts`` or
@@ -64,9 +70,19 @@ kind registered there.
             softmax for a config that has neither key and says
             ``norm_topk_prob``, which is then the renormalisation (a
             file that says ``norm_topk_prob``, scores by sigmoid and has
-            neither key has to gain one)
+            neither key has to gain one); ``use_expert_bias`` is the
+            layer's ``router_bias``
+            buffer, which every expert layer has, zero and outside the
+            gradient
+    head    ``tie_word_embeddings``: ONE ``(vocabulary, hidden)``
+            parameter, ``embed.weight``, read by the embedding's gather
+            and by the fused cross-entropy; its gradient is the sum of
+            both uses and the model has no ``head`` leaf (counter
+            ``causal_lm.tied_head`` once a build). Else a ``head`` matrix
+            of its own
 
-The norms' epsilon is ``rms_norm_eps`` or ``layer_norm_epsilon``.
+The norms' epsilon is ``rms_norm_eps``, ``layer_norm_epsilon`` or
+``norm_eps``; a file with none of the three is refused.
 A chip's share of an expert-parallel deployment is said with
 ``experts_held`` / ``expert_offset`` (the router keeps all its outputs).
 ``loss`` goes through the fused vocabulary cross-entropy, so the
@@ -117,8 +133,15 @@ def _first(cfg, *names, default=None):
     return default
 
 
+_EPS_KEYS = ("rms_norm_eps", "layer_norm_epsilon", "norm_eps")
+
+
 def _eps(cfg):
-    return _first(cfg, "rms_norm_eps", "layer_norm_epsilon")
+    eps = _first(cfg, *_EPS_KEYS)
+    if eps is None:
+        raise ValueError(f"a config with none of {_EPS_KEYS}: the norms' "
+                         "epsilon would be a guess")
+    return eps
 
 
 #: a ``hybrid_override_pattern`` character -> (mixer kind, ffn kind)
@@ -135,8 +158,9 @@ def _pattern_kinds(cfg, layer):
     return _PATTERN[char]
 
 
-#: ``layer_types`` entries that the ``gqa`` mixer builds
-_GQA_LAYER_TYPES = ("sliding_attention", "full_attention")
+#: a ``layer_types`` entry -> the mixer kind that builds it
+_LAYER_TYPES = {"conv": "conv", "sliding_attention": "gqa",
+                "full_attention": "gqa"}
 
 
 def _gqa_rope(cfg, kind):
@@ -179,6 +203,11 @@ def _mixer_gqa(cfg, layer):
         window=cfg["sliding_window"] if kind == "sliding_attention"
         else None,
         rope=rope, qk_norm=qk_norm, epsilon=_eps(cfg))
+
+
+def _mixer_conv(cfg, layer):
+    return nn.GatedShortConv(cfg["hidden_size"], taps=cfg["conv_L_cache"],
+                             bias=cfg.get("conv_bias", False))
 
 
 def _mixer_mamba2(cfg, layer):
@@ -230,7 +259,7 @@ def _ffn_dense(cfg):
         return nn.PlainFFN(cfg["hidden_size"], cfg["intermediate_size"],
                            activation=cfg["mlp_hidden_act"])
     return nn.GatedFFN(cfg["hidden_size"], cfg["intermediate_size"],
-                       activation=cfg["hidden_act"])
+                       activation=cfg.get("hidden_act", "silu"))
 
 
 def _ffn_moe(cfg):
@@ -268,8 +297,8 @@ def _ffn_moe(cfg):
         shared_width=shared or None, score_func=score, gated=not plain)
 
 
-MIXERS = {"gqa": _mixer_gqa, "kda": _mixer_kda, "mamba2": _mixer_mamba2,
-          "mla": _mixer_mla}
+MIXERS = {"conv": _mixer_conv, "gqa": _mixer_gqa, "kda": _mixer_kda,
+          "mamba2": _mixer_mamba2, "mla": _mixer_mla}
 FFNS = {"dense": _ffn_dense, "moe": _ffn_moe}
 
 
@@ -291,10 +320,11 @@ def mixer_kind(cfg, layer: int):
         return _pattern_kinds(cfg, layer)[0]
     if "layer_types" in cfg:
         kind = cfg["layer_types"][layer - 1]
-        if kind not in _GQA_LAYER_TYPES:
+        if kind not in _LAYER_TYPES:
             raise NotImplementedError(
-                f"layer_types entry {kind!r}: {_GQA_LAYER_TYPES} are built")
-        return "gqa"
+                f"layer_types entry {kind!r}: {sorted(_LAYER_TYPES)} are "
+                "built")
+        return _LAYER_TYPES[kind]
     lin = cfg.get("linear_attn_config") or {}
     return "kda" if layer in lin.get("kda_layers", ()) else "mla"
 
@@ -306,7 +336,8 @@ def ffn_kind(cfg, layer: int):
     if "mlp_layer_types" in cfg:
         return {"sparse": "moe", "dense": "dense"}[
             cfg["mlp_layer_types"][layer - 1]]
-    dense = layer <= cfg.get("first_k_dense_replace", 0) \
+    dense = layer <= _first(cfg, "first_k_dense_replace",
+                            "num_dense_layers", default=0) \
         or _first(cfg, "num_experts", "n_routed_experts") is None \
         or (layer - 1) % cfg.get("moe_layer_freq", 1) != 0
     return "dense" if dense else "moe"
@@ -370,9 +401,8 @@ class CausalLM(nn.Layer):
         super().__init__()
         self.config = dict(cfg)
         self.recompute = bool(recompute)
-        if cfg.get("tie_word_embeddings", False):
-            raise NotImplementedError("a head tied to the embedding")
         from ..nn.initializer import Normal
+        from ..ops.pallas.counters import bump
 
         init = nn.ParamAttr(initializer=Normal(
             0.0, cfg.get("initializer_range", 0.02)))
@@ -382,17 +412,20 @@ class CausalLM(nn.Layer):
             DecoderBlock(cfg, n + 1)
             for n in range(cfg["num_hidden_layers"])])
         self.final_norm = nn.RMSNorm(cfg["hidden_size"], epsilon=_eps(cfg))
-        # (vocabulary, hidden): the layout the fused cross-entropy streams
-        self.head = self.create_parameter(
-            [cfg["vocab_size"], cfg["hidden_size"]], attr=init)
+        # (vocabulary, hidden): the layout the fused cross-entropy
+        # streams, and the embedding's: a tied head IS ``embed.weight``
+        self.tied = bool(cfg.get("tie_word_embeddings", False))
+        if self.tied:
+            bump("causal_lm", "tied_head")
+        else:
+            self.head = self.create_parameter(
+                [cfg["vocab_size"], cfg["hidden_size"]], attr=init)
         # the fused cross-entropy takes a bias; this head has none
         self._no_bias = Tensor(jnp.zeros((cfg["vocab_size"],), jnp.float32))
         self.ut_steps = int(cfg.get("total_ut_steps", 1))
         if self.ut_steps < 1:
             raise ValueError(f"total_ut_steps {self.ut_steps}")
         if self.ut_steps > 1:
-            from ..ops.pallas.counters import bump
-
             # one gate for all passes; its logit decides a token's exit
             self.exit_gate = nn.Linear(cfg["hidden_size"], 1,
                                        weight_attr=init)
@@ -402,6 +435,12 @@ class CausalLM(nn.Layer):
     @classmethod
     def from_config(cls, cfg: dict, recompute: bool = False) -> "CausalLM":
         return cls(cfg, recompute=recompute)
+
+    @property
+    def head_weight(self):
+        """The head's (vocabulary, hidden) matrix: the embedding's where
+        the two are tied."""
+        return self.embed.weight if self.tied else self.head
 
     def _walk(self, x):
         """One pass: every block once, then the final norm. (normed
@@ -446,7 +485,7 @@ class CausalLM(nn.Layer):
         from .. import ops
 
         h, _ = self.hidden(input_ids)
-        return ops.matmul(h, self.head, transpose_y=True)
+        return ops.matmul(h, self.head_weight, transpose_y=True)
 
     def loss(self, input_ids, labels, ignore_index=-100,
              return_routing=False):
@@ -458,7 +497,7 @@ class CausalLM(nn.Layer):
         passes, routing = self.hidden_passes(input_ids)
         if self.ut_steps == 1:
             loss = F.fused_linear_cross_entropy(
-                passes[0], self.head, self._no_bias, labels,
+                passes[0], self.head_weight, self._no_bias, labels,
                 ignore_index=ignore_index)
         else:
             loss = self._expected_exit_loss(passes, labels, ignore_index)
@@ -475,7 +514,7 @@ class CausalLM(nn.Layer):
             stacked = ops.stack(passes, axis=0)
             tiled = ops.stack([labels] * self.ut_steps, axis=0)
             rows = F.fused_linear_cross_entropy(
-                stacked, self.head, self._no_bias, tiled,
+                stacked, self.head_weight, self._no_bias, tiled,
                 ignore_index=ignore_index, reduction="none")
             # the gate reads passes 1 .. T-1 (the last pass takes what is
             # left), in float32 whatever the autocast

@@ -51,6 +51,7 @@ from .moe import (  # noqa: F401
 from .linear_attention import KimiDeltaAttention  # noqa: F401
 from .grouped_query_attention import GroupedQueryAttention  # noqa: F401
 from .state_space import Mamba2Mixer  # noqa: F401
+from .gated_conv import GatedShortConv  # noqa: F401
 from .latent_attention import MLAttention  # noqa: F401
 from .crf import LinearChainCRF, crf_decoding, linear_chain_crf  # noqa: F401,E402
 

@@ -261,9 +261,7 @@ def conv_norm(q, k, v, q_taps, k_taps, v_taps, head):
     row's start: ``L2norm_head(SiLU(ShortConv(q))) / sqrt(head)``,
     ``L2norm_head(SiLU(ShortConv(k)))``, ``SiLU(ShortConv(v))``."""
     taps = q_taps.shape[0]
-    why = _ineligible(head)
-    if why is None and taps - 1 > shared.HALO // 2:
-        why = f"{taps} taps: at most {shared.HALO // 2 + 1}"
+    why = _ineligible(head) or shared._too_many_taps(taps)
     if why is not None:
         bump("kda_stage", "xla", f"convolution ineligible: {why}")
         return conv_norm_xla(q, k, v, q_taps, k_taps, v_taps, head)

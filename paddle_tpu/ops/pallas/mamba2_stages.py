@@ -40,6 +40,7 @@ as the path of the CPU and of any shape the stages do not take — counted
 from __future__ import annotations
 
 import functools
+import operator
 from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
@@ -72,10 +73,16 @@ class ConvForm(NamedTuple):
     shapes: the kernels' role name, the type the forward writes (the
     input's when None) and, behind SiLU, an L2 norm of each head —
     ``norm = (lanes of a head, scale, epsilon)``: ``y * scale *
-    rsqrt(sum_head(y^2) + epsilon)`` — or None."""
+    rsqrt(sum_head(y^2) + epsilon)`` — or None. Or, for a convolution
+    gated on both sides and with no SiLU, ``gate = (m, g)``: the
+    channels at offset ``m`` of the same array multiply the
+    convolution's input, those at ``g`` its output (``x_g *
+    ShortConv(x * x_m)``); the backward then returns the three
+    gradients."""
     role: str
     out_dtype: Optional[Any] = None
     norm: Optional[Tuple[int, float, float]] = None
+    gate: Optional[Tuple[int, int]] = None
 
     @property
     def head(self):
@@ -237,34 +244,60 @@ def _l2_norm_bwd(y, dn, norm):
     return per_head(one, head, y, dn)
 
 
-def _conv_fwd_kernel(prev_ref, x_ref, taps_ref, *refs, norm=None):
-    """refs: the bias' (1, C) row where there is one, then the result."""
+def _product(slabs):
+    """The convolution's input, float32: a form's own rows, times its
+    multiplier's where it has one."""
+    return functools.reduce(operator.mul, (s.astype(_F32) for s in slabs))
+
+
+def _conv_fwd_kernel(prev_ref, x_ref, *refs, norm=None, gated=False):
+    """refs: of a gated form the multiplier's HALO and block and the
+    output gate's block; the taps; the bias' (1, C) row where there is
+    one; then the result."""
     from jax.experimental import pallas as pl
 
-    *bias_ref, out_ref = refs
+    sources = [(prev_ref, x_ref)]
+    if gated:
+        sources.append(refs[:2])
+        gate_ref, *refs = refs[2:]
+    taps_ref, *bias_ref, out_ref = refs
     taps, bias = taps_ref[...], bias_ref[0][...] if bias_ref else None
     width = taps.shape[0]
 
-    def emit(r0, xe):
-        pre = _conv_pre(_shifted(xe.astype(_F32), width, HALO), taps, bias)
-        y = pre * jax.nn.sigmoid(pre)
+    def emit(r0, slabs):
+        pre = _conv_pre(_shifted(_product(slabs), width, HALO), taps, bias)
+        if gated:
+            y = pre * gate_ref[pl.ds(r0, SLAB), :].astype(_F32)
+        else:
+            y = pre * jax.nn.sigmoid(pre)
         if norm is not None:
             y = _l2_norm(y, norm)
         out_ref[pl.ds(r0, SLAB), :] = y.astype(out_ref.dtype)
 
-    emit(0, _first_slab(prev_ref, x_ref, pl.program_id(2) == 0))
-    _slabs(x_ref.shape[0], lambda r0: emit(
-        r0, x_ref[pl.ds(r0 - HALO, HALO + SLAB), :]), start=1)
+    first = pl.program_id(2) == 0
+    emit(0, [_first_slab(p, x, first) for p, x in sources])
+    _slabs(x_ref.shape[0], lambda r0: emit(r0, [
+        x[pl.ds(r0 - HALO, HALO + SLAB), :] for _, x in sources]), start=1)
 
 
-def _conv_bwd_kernel(prev_ref, x_ref, next_ref, dy_ref, dynext_ref, taps_ref,
-                     *refs, length, norm=None):
-    """refs: the bias' row where there is one, then dx, the partial sums
-    (dtaps' rows; behind them dbias' with a bias) and dp_ref: ``d_pre``
-    of the block's rows and of the HALO after them, float32 scratch."""
+def _conv_bwd_kernel(prev_ref, x_ref, next_ref, dy_ref, dynext_ref, *refs,
+                     length, norm=None, gated=False):
+    """refs: of a gated form the multiplier's HALO, block and next HALO
+    and the output gate's block and next HALO; the taps; the bias' row
+    where there is one; then dx, of a gated form the multiplier's and the
+    output gate's gradients, the partial sums (dtaps' rows; behind them
+    dbias' with a bias) and dp_ref: ``d_pre`` of the block's rows and of
+    the HALO after them, float32 scratch."""
     from jax.experimental import pallas as pl
 
-    *bias_ref, dx_ref, dw_ref, dp_ref = refs
+    sources = [(prev_ref, x_ref, next_ref)]
+    if gated:
+        sources.append(refs[:3])
+        gate_ref, gatenext_ref, *refs = refs[3:]
+        *refs, dm_ref, dg_ref, dw_ref, dp_ref = refs
+        taps_ref, *bias_ref, dx_ref = refs
+    else:
+        taps_ref, *bias_ref, dx_ref, dw_ref, dp_ref = refs
     rows = x_ref.shape[0]
     taps, bias = taps_ref[...], bias_ref[0][...] if bias_ref else None
     width = taps.shape[0]
@@ -275,21 +308,29 @@ def _conv_bwd_kernel(prev_ref, x_ref, next_ref, dy_ref, dynext_ref, taps_ref,
     def _start():
         dw_ref[...] = jnp.zeros_like(dw_ref)
 
-    def d_pre(r0, xe, dy, own):
+    def d_pre(r0, slabs, dy, own, gate=None):
         """``d_pre`` of rows [r0, r0 + n) into the scratch; with ``own``
         their part of dtaps and dbias (the rows after the block are the
-        next step's own)."""
+        next step's own) and, of a gated form, the output gate's
+        gradient."""
         n = dy.shape[0]
-        xe = xe.astype(_F32)
+        xe = _product(slabs)
         if ragged:          # what lies past the row's end is not data
             xe = jnp.where(_row_ids(i * rows + r0 - HALO, HALO + n) < length,
                            xe, 0.0)
         xs = _shifted(xe, width, HALO)
-        y, slope = _silu_grad(_conv_pre(xs, taps, bias))
-        dy = dy.astype(_F32)
-        if norm is not None:
-            dy = _l2_norm_bwd(y, dy, norm)
-        dp = dy * slope
+        pre = _conv_pre(xs, taps, bias)
+        if gated:
+            dy = dy.astype(_F32)
+            dp = dy * gate.astype(_F32)
+            if own:
+                dg_ref[pl.ds(r0, n), :] = (dy * pre).astype(dg_ref.dtype)
+        else:
+            y, slope = _silu_grad(pre)
+            dy = dy.astype(_F32)
+            if norm is not None:
+                dy = _l2_norm_bwd(y, dy, norm)
+            dp = dy * slope
         if ragged or not own:
             dp = jnp.where(_row_ids(i * rows + r0, n) < length, dp, 0.0)
         dp_ref[pl.ds(r0, n), :] = dp
@@ -299,17 +340,26 @@ def _conv_bwd_kernel(prev_ref, x_ref, next_ref, dy_ref, dynext_ref, taps_ref,
             if bias is not None:
                 dw_ref[width] += _fold(dp)
 
-    d_pre(0, _first_slab(prev_ref, x_ref, i == 0), dy_ref[0:SLAB, :], True)
+    d_pre(0, [_first_slab(p, x, i == 0) for p, x, _ in sources],
+          dy_ref[0:SLAB, :], True, gate_ref[0:SLAB, :] if gated else None)
     _slabs(rows, lambda r0: d_pre(
-        r0, x_ref[pl.ds(r0 - HALO, HALO + SLAB), :],
-        dy_ref[pl.ds(r0, SLAB), :], True), start=1)
-    d_pre(rows, jnp.concatenate([x_ref[rows - HALO:rows, :], next_ref[...]],
-                                axis=0), dynext_ref[...], False)
+        r0, [x[pl.ds(r0 - HALO, HALO + SLAB), :] for _, x, _ in sources],
+        dy_ref[pl.ds(r0, SLAB), :], True,
+        gate_ref[pl.ds(r0, SLAB), :] if gated else None), start=1)
+    d_pre(rows, [jnp.concatenate([x[rows - HALO:rows, :], after[...]], axis=0)
+                 for _, x, after in sources],
+          dynext_ref[...], False, gatenext_ref[...] if gated else None)
 
     def emit(r0):
         de = dp_ref[pl.ds(r0, SLAB + HALO), :]
         dx = sum(taps[j:j + 1] * _down(de, j + 1 - width)[:SLAB]
                  for j in range(width))
+        if gated:       # the input was x * m: each takes the other's rows
+            sl = pl.ds(r0, SLAB)
+            mult = sources[1][1]
+            dm_ref[sl, :] = (dx * x_ref[sl, :].astype(_F32)).astype(
+                dm_ref.dtype)
+            dx = dx * mult[sl, :].astype(_F32)
         dx_ref[pl.ds(r0, SLAB), :] = dx.astype(dx_ref.dtype)
 
     _slabs(rows, emit)
@@ -351,51 +401,70 @@ def _conv_vectors(taps, bias, lanes):
     return vectors, [_vector_spec(v.shape[0], lanes) for v in vectors]
 
 
+def _gate_specs(form, t, rows, lanes):
+    """Block specs of a gated form's two other column groups: (the
+    multiplier's own block and its HALOs before and after, the output
+    gate's the same way)."""
+    return tuple(_conv_specs(t, rows, lanes, o // lanes) for o in form.gate)
+
+
 @functools.partial(jax.jit, static_argnums=(3, 4))
 def _conv_part_fwd(proj, taps, bias, offset, form=ConvForm(ROLE_CONV)):
     """One part of xBC: channels [offset, offset + w) of the projection,
     w = taps.shape[1]; bias (1, w) or None."""
     b, t, _ = proj.shape
     width, w = taps.shape
-    lanes = _lanes(offset, w, head=form.head)
+    lanes = _lanes(offset, w, *(form.gate or ()), head=form.head)
     rows = _block_rows(t, lanes, "fwd")
     own, before, _ = _conv_specs(t, rows, lanes, offset // lanes)
     mine = _conv_specs(t, rows, lanes, 0)[0]
     vectors, vector_specs = _conv_vectors(taps, bias, lanes)
+    gates = []
+    if form.gate:
+        (m_own, m_before, _), (g_own, _, _) = _gate_specs(form, t, rows, lanes)
+        gates = [m_before, m_own, g_own]
     return kernel_call(
-        form.role, functools.partial(_conv_fwd_kernel, norm=form.norm),
+        form.role, functools.partial(_conv_fwd_kernel, norm=form.norm,
+                                     gated=bool(form.gate)),
         grid=(b, w // lanes, -(-t // rows)),
-        in_specs=[before, own, *vector_specs], out_specs=mine,
+        in_specs=[before, own, *gates, *vector_specs], out_specs=mine,
         out_shape=_sds((b, t, w), form.out_dtype or proj.dtype, proj),
         compiler_params=_compiler_params(),
-    )(proj, proj, *vectors)
+    )(proj, proj, *[proj] * len(gates), *vectors)
 
 
 @functools.partial(jax.jit, static_argnums=(3, 5))
 def _conv_part_bwd(proj, taps, bias, offset, dy, form=ConvForm(ROLE_CONV)):
     """(dx (B, T, w), partial sums (B, W + 1, 8, w) float32: dtaps' rows,
-    then dbias'; (B, W, 8, w) without a bias)."""
+    then dbias'; (B, W, 8, w) without a bias). Of a gated form (dx, the
+    multiplier's gradient, the output gate's, partial sums)."""
     from jax.experimental.pallas import tpu as pltpu
 
     b, t, _ = proj.shape
     width, w = taps.shape
-    lanes = _lanes(offset, w, head=form.head)
+    lanes = _lanes(offset, w, *(form.gate or ()), head=form.head)
     rows = _block_rows(t, lanes, "bwd")
     own, before, after = _conv_specs(t, rows, lanes, offset // lanes)
     mine, _, mine_after = _conv_specs(t, rows, lanes, 0)
     vectors, vector_specs = _conv_vectors(taps, bias, lanes)
     sums = width + len(vectors) - 1
+    gates = []
+    if form.gate:
+        (m_own, m_before, m_after), (g_own, _, g_after) = _gate_specs(
+            form, t, rows, lanes)
+        gates = [m_before, m_own, m_after, g_own, g_after]
+    grads = [_sds((b, t, w), proj.dtype, proj)] * (3 if form.gate else 1)
     return kernel_call(
         form.role, functools.partial(_conv_bwd_kernel, length=t,
-                                     norm=form.norm),
+                                     norm=form.norm, gated=bool(form.gate)),
         grid=(b, w // lanes, -(-t // rows)),
-        in_specs=[before, own, after, mine, mine_after, *vector_specs],
-        out_specs=[mine, _partial_spec(sums, lanes)],
-        out_shape=[_sds((b, t, w), proj.dtype, proj),
-                   _sds((b, sums, 8, w), _F32, proj)],
+        in_specs=[before, own, after, mine, mine_after, *gates,
+                  *vector_specs],
+        out_specs=[*[mine] * len(grads), _partial_spec(sums, lanes)],
+        out_shape=[*grads, _sds((b, sums, 8, w), _F32, proj)],
         scratch_shapes=[pltpu.VMEM((rows + HALO, lanes), _F32)],
         compiler_params=_compiler_params(),
-    )(proj, proj, proj, dy, dy, *vectors)
+    )(proj, proj, proj, dy, dy, *[proj] * len(gates), *vectors)
 
 
 def _conv_operands(taps, bias, lo, hi):
@@ -584,6 +653,14 @@ def _ineligible(*lanes_of):
     return None
 
 
+def _too_many_taps(width):
+    """Why the convolution's launches do not take ``width`` taps (the
+    earlier tokens come from half a HALO); None when they do."""
+    if width - 1 > HALO // 2:
+        return f"{width} taps: at most {HALO // 2 + 1}"
+    return None
+
+
 def conv_silu(proj, taps, bias, start, widths):
     """``SiLU(ShortConv(xBC) + bias)`` of the channels ``xBC = proj[...,
     start:start + sum(widths)]``, causal along T from zeros at each row's
@@ -592,9 +669,7 @@ def conv_silu(proj, taps, bias, start, widths):
     type."""
     b, t, _ = proj.shape
     width, channels = taps.shape
-    why = _ineligible(start, *widths)
-    if why is None and width - 1 > HALO // 2:
-        why = f"{width} taps: at most {HALO // 2 + 1}"
+    why = _ineligible(start, *widths) or _too_many_taps(width)
     if why is not None:
         bump("mamba2_stage", "xla", f"convolution ineligible: {why}")
         return conv_silu_xla(proj, taps, bias, start, widths)

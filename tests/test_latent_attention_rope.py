@@ -300,12 +300,21 @@ def test_a_file_without_mla_use_nope_rotates():
     ({"q_lora_rank": 24}, "MLA with a low-rank query projection"),
     ({"rope_scaling": {"type": "yarn", "factor": 4}}, "rope_scaling"),
     ({"n_group": 4, "topk_group": 2}, "group-limited routing"),
-    ({"tie_word_embeddings": True}, "tied"),
 ])
 def test_what_the_family_has_and_the_program_lacks_still_raises(change,
                                                                 message):
     with pytest.raises(NotImplementedError, match=message):
         CausalLM.from_config(dict(CFG, **change))
+
+
+def test_a_tied_head_of_this_family_is_the_embedding():
+    """Until PR 46 ``tie_word_embeddings`` raised here (the fourth case
+    above); since then the head IS ``embed.weight`` and the model has no
+    ``head`` leaf (``tests/test_causal_lm_lfm2.py`` holds the gradient)."""
+    model = CausalLM.from_config(dict(CFG, tie_word_embeddings=True))
+    names = [n for n, _ in model.named_parameters()]
+    assert "head" not in names and "embed.weight" in names
+    assert model.head_weight is model.embed.weight
 
 
 def test_loss_and_first_gradients_match_the_reference():

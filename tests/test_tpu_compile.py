@@ -463,3 +463,51 @@ def test_kda_stage_kernels_compile_at_the_kimi_cells_shapes(
                           text)) == launches
     assert text.count(role) >= launches
     assert f"[{b},{t},{h},{d}]" not in text
+
+
+@pytest.mark.parametrize("t,dtype", [(8192, BF16), (8192, F32), (8240, BF16)],
+                         ids=["bfloat16", "float32", "ragged_bfloat16"])
+def test_the_gated_conv_stage_compiles_at_the_lfm2_cells_shapes(
+        one_chip, monkeypatch, t, dtype):
+    """The gated short convolution on the in-projection of 2 x 8,192
+    tokens, three groups of 2,048 channels and 3 taps (PR 46): ONE launch
+    forward and ONE backward, the result in the projection's type, also
+    at a length that is no whole number of blocks; and no sliced copy of
+    a column group beside the launches."""
+    import paddle_tpu.framework.bringup as bringup
+    from paddle_tpu.ops.pallas import gated_conv
+
+    monkeypatch.setattr(bringup, "pallas_enabled", lambda: True)
+    b, d = 2, 2048
+
+    def loss(proj, taps):
+        out = gated_conv.gated_conv(proj, taps)
+        assert out.dtype == dtype
+        return jnp.sum(out.astype(F32))
+
+    out = _compile(jax.value_and_grad(loss, argnums=(0, 1)), one_chip,
+                   ((b, t, 3 * d), dtype), ((3, d), F32))
+    text = out.as_text()
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                          text)) == 2
+    assert text.count(gated_conv.ROLE) >= 2
+    assert not re.findall(rf"\[{b},{t},{d}\]\S* slice\(", text)
+
+
+@pytest.mark.parametrize("precision", [None, "highest"])
+def test_grouped_stream_flash_compiles_at_the_lfm2_cells_heads_of_64(
+        one_chip, precision):
+    """2 x 8,192 tokens, 32 query heads on 8 key/value heads of 64 (PR
+    46): the stream kernels on 64-lane blocks, half a vreg, which no cell
+    ran before (BERT's 64-wide heads take the short kernels)."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    def loss(q, k, v):
+        return jnp.sum(fa._flash_attention_pallas(
+            q, k, v, causal=True).astype(F32))
+
+    grads = jax.grad(loss, argnums=(0, 1, 2))
+    with jax.default_matmul_precision(precision or "default"):
+        out = _compile(grads, one_chip, ((2, 8192, 32, 64), BF16),
+                       ((2, 8192, 8, 64), BF16), ((2, 8192, 8, 64), BF16))
+    assert "flash_attention_grouped" in out.as_text()
