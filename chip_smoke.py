@@ -1013,20 +1013,24 @@ def _kernel_checks():
     # -- the dropless expert layer, each rung against a dense loop -----------
     def experts(held, dtype, rungs, t=8192, d=2304, f=1024,
                 num_experts=256, top_k=8, scaling=2.446,
-                score_func="sigmoid", plain=False):
+                score_func="sigmoid", plain=False, highest=False):
         """A cell's layer shapes (by default the Kimi cell's), one compiled
         layer through each of its rungs. ``rungs`` maps a number of held experts that the
         correction bias makes every token's picks to the rows that must
-        then run: the count of pairs decides the rung, ``ragged_dot`` on
-        a lower one, on the top one where more than twice as many experts
-        are held as a token picks, every token through every expert
-        (t x held rows) where not. ``plain`` experts are ``relu(x U)^2
-        D`` and get no gate matrix."""
+        then run: the count of pairs decides the rung, the grouped
+        kernels (``ops.pallas.grouped_ffn``) on a lower one, on the top
+        one where more than twice as many experts are held as a token
+        picks, every token through every expert (t x held rows) where
+        not. ``plain`` experts are ``relu(x U)^2 D`` and get no gate
+        matrix. ``highest``: bfloat16 operands under the phase's own
+        precision too (``ragged_dot`` refused them there; the kernels
+        take them)."""
         from paddle_tpu.nn.moe import SCORE_FUNCS, sparse_moe
 
         name = f"sparse experts {held} of {num_experts} held, " \
                f"{score_func}, {'plain' if plain else 'gated'}, {t} x {d} x {f}, " \
-               f"rungs {sorted(rungs.values())}, {jnp.dtype(dtype).name}"
+               f"rungs {sorted(rungs.values())}, {jnp.dtype(dtype).name}" \
+               + (" at highest" if highest else "")
 
         def layer(x, router, wg, wu, wd, bias, w):
             out, routing = sparse_moe.raw_fn(
@@ -1063,10 +1067,10 @@ def _kernel_checks():
             for forced, rows in rungs.items():
                 bias = jnp.zeros((num_experts,), f32).at[:forced].set(10.0)
                 args = (x, router, wg, wu, wd, bias, w)
-                # bfloat16 operands at the step's own precision: the
-                # TPU's ragged_dot refuses them under the phase's "highest"
+                # bfloat16 operands at the step's own precision, as a
+                # step runs them
                 with jax.default_matmul_precision(
-                        "highest" if dtype == f32 else "default"):
+                        "highest" if dtype == f32 or highest else "default"):
                     ((_, (got, routing)), dgot) = layer_(*args)
                     ((_, (want, _)), dwant) = loop_(*args)
                 pairs, ran = (int(v) for v in np.asarray(routing))
@@ -1082,10 +1086,13 @@ def _kernel_checks():
                            fails)
         checks.append((name, check))
 
-    # the Kimi cell's share in the step's type; a share that holds twice
-    # the experts a token picks (the Mellum cell's ratio), whose top rung
-    # is still every token through every expert; and one that holds
-    # three times as many, whose top rung is ragged_dot on every pair
+    # the Kimi cell's share in the step's type (``ragged_dot`` still: its
+    # own ladder serves it, and the kernels take the dense rung's work
+    # only); a share that holds twice the experts a token picks (the
+    # Mellum cell's ratio), whose top rung is still every token through
+    # every expert; and one that holds three times as many, whose rungs
+    # are the grouped kernels, the top one on every pair. The rungs are
+    # ``nn.moe._row_ladder``'s: eight times the even share
     experts(8, bf16, {0: 16384, 8: 65536})
     experts(16, f32, {0: 32768, 8: 131072})
     experts(24, f32, {0: 49152, 8: 65536})
@@ -1093,14 +1100,14 @@ def _kernel_checks():
     # (one gated FFN of width 16 x 896), whatever the routing
     experts(16, bf16, {0: 262144}, t=16384, f=896, num_experts=64,
             scaling=1.0, score_func="softmax")
-    # the Nemotron cell's share at its own shapes: plain relu^2 experts;
-    # one rung, the dense one (a sorted rung of 49,152 rows is over a
-    # third of its 131,072), whatever the routing; and the sorted rungs
-    # of plain experts at a router twice as wide
-    experts(8, bf16, {0: 131072}, t=16384, d=2688, f=1856,
+    # the Nemotron cell's share at its own shapes: plain relu^2 experts
+    # of a width that is no whole number of lanes; the grouped rung of
+    # 49,152 rows and the dense one above it, at the step's precision and
+    # once under this phase's (``ragged_dot`` refused bfloat16 there)
+    experts(8, bf16, {0: 49152, 6: 131072}, t=16384, d=2688, f=1856,
             num_experts=128, top_k=6, scaling=2.5, plain=True)
-    experts(8, bf16, {0: 24576, 6: 131072}, t=16384, d=2688, f=1856,
-            num_experts=256, top_k=6, scaling=2.5, plain=True)
+    experts(8, bf16, {0: 49152, 6: 131072}, t=16384, d=2688, f=1856,
+            num_experts=128, top_k=6, scaling=2.5, plain=True, highest=True)
 
     # -- fused embedding bag --------------------------------------------------
     def bag(vocab, d, b, s, dtype, combiner):

@@ -8,15 +8,21 @@
   (``experts_held`` from ``expert_offset``), routes over all of them and
   computes its own experts' part; no token is ever dropped and there is
   no capacity factor. Sorted (token, expert) pairs go through a grouped
-  matrix product (``jax.lax.ragged_dot``) on a rung of a ladder of row
-  capacities that the count of pairs picks. The top rung of a share that
+  matrix product on a rung of a ladder of row capacities that the count
+  of pairs picks: ``jax.lax.ragged_dot``, whose time follows the live
+  rows, or on a TPU the Pallas launches of ``ops.pallas.grouped_ffn``,
+  the same function on a static row count, which compute all of a rung's
+  rows whatever the routing. The top rung of a share that
   holds at most twice the experts a token picks is every token through
   every held expert, as ONE FFN of width held x F with the routing
   weights on its hidden activations (``_every_pair_ffn``): three large
   products for gated experts, two for plain ones, no loop over experts,
   a step's time independent of its routing. Under it a sorted rung
-  stays only at a third of its rows or fewer (``_row_ladder``): a share
-  whose lowest sorted rung would be wider runs the dense rung alone.
+  stays only where it costs less (``_row_ladder``, in dense rows from
+  the shapes): ``ragged_dot``'s at a third of the dense rows or fewer,
+  else the kernels' where their launches, gathers and way back come to
+  less than the dense rung; a share whose sorted rungs would cost more
+  runs the dense rung alone.
 - :class:`MoELayer` is the CAPACITY layer: GShard top-2 softmax gating
   into a static ``(tokens, experts, capacity)`` grid that drops what
   overflows, with the explicit expert-parallel exchange below.
@@ -67,6 +73,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import Mesh, PartitionSpec
 
@@ -396,27 +403,73 @@ class MoELayer(Layer):
 # ---------------------------------------------------------------------------
 # the dropless layer
 # ---------------------------------------------------------------------------
+#: the multiply-adds a v5e's MXU does, at the dense rung's rate, while XLA
+#: gathers one ROW, whatever its width (62 ns: 48 the gather, the rest
+#: the row's scalars), and while it sorts and indexes one (token, pick)
+#: SLOT of a share (40 ns): a device trace of one layer's step and the
+#: three cells that ran the kernels (PERF.md sections 5 and 6, PR 47;
+#: ``tools/op_bench.py --ops expert_ffn`` prints a rung's time beside
+#: what these state)
+GROUPED_GATHER, GROUPED_SLOT = 5e6, 3.2e6
+#: what the kernels' rungs call the sort of a layer's pairs, both ways
+KEPT = "sparse_moe_sort"
+
+
+def _grouped_cost(tokens: int, top_k: int, held: int, d: int, f: int,
+                  gated: bool):
+    """What a rung costs through ``ops.pallas.grouped_ffn`` on experts of
+    D x F, in dense rows (one token through one expert on the dense rung,
+    forward, recomputed and backward: seven products of D x F plain,
+    eleven gated): ``(a row's, a rung's beside its rows)``. A launched
+    row's products are a dense row's (the launches run at the MXU's rate,
+    as the dense rung's do); it is gathered three times, the token's row
+    forward and recomputed and its cotangent; and the way back, forward
+    and backward, is three passes of a one-hot product of BLOCK tokens
+    for every CHUNK of rows. Beside its rows a rung launches a tile more
+    for each expert, its way back visits a chunk more for every (block of
+    tokens, expert), and the sort, its inverse and the rows' layout walk
+    every slot of the share, whatever the rung holds. So narrow experts'
+    rungs are dear."""
+    from ..ops.pallas.grouped_ffn import BLOCK, CHUNK, TILE
+
+    unit = (11 if gated else 7) * d * f         # a dense row's multiply-adds
+    row = 1.0 + 3 * GROUPED_GATHER / unit + 2 * 3 * BLOCK * d / unit
+    visits = held * -(-tokens // BLOCK)
+    return row, (row * held * TILE + tokens * top_k * GROUPED_SLOT / unit
+                 + 2 * 3 * visits * CHUNK * BLOCK * d / unit)
+
+
 def _row_ladder(pairs: int, experts_held: int, num_experts: int,
-                dense_rows: int = 0) -> tuple:
+                dense_rows: int = 0, row_cost: float = 3,
+                skew: int = 8, rung_cost: float = 0) -> tuple:
     """The static row capacities the grouped product may run at,
     ascending, in whole 256-row blocks. The top rung holds every (token,
     expert) pair there can be, so nothing is ever dropped; the lowest is
-    eight times what even routing would send to the experts held, and
+    ``skew`` times what even routing would send to the experts held, and
     each rung is four times the one below: uneven routing (an expert's
-    load follows its tokens' frequencies) then moves the rung seldom,
+    load follows its tokens' frequencies, and a share's experts, the
+    only ones whose output reaches the loss, draw 5.4-5.7 times the even
+    share by the end of a benchmark window) then moves the rung seldom,
     and a step's time hardly depends on its data, at the price of rows
     that hold no pair. Where the top rung is the dense one, every token
-    through every held expert on ``dense_rows`` rows, a sorted rung
-    stays only at a third of those rows or fewer: a sorted row costs
-    what 2.5 to 3.3 dense rows cost (its gather, its selects and its
-    float32 scatter-add, PERF.md section 7.10) and its product takes as
-    long as its live rows, so above a third it is no cheaper than the
-    dense rung and makes a step's time follow its routing. All of it
-    follows from the shapes."""
-    top = -(-pairs // 256) * 256
-    rung = -(-8 * pairs * experts_held // (num_experts * 256)) * 256
+    through every held expert on ``dense_rows`` rows, a sorted rung stays
+    only where it costs no more than those: ``row_cost`` dense rows a row
+    and ``rung_cost`` beside them. The defaults are ``ragged_dot``'s: a
+    sorted row costs what 2.5 to 3.3 dense rows cost (its gather, its
+    selects and its float32 scatter-add, PERF.md section 7.10) and its
+    product takes as long as its live rows, so above a third it is no
+    cheaper than the dense rung and makes a step's time follow its
+    routing. The grouped kernels' are ``_grouped_cost``; under a dense
+    top they may keep a rung of every pair. All of it follows from the
+    shapes."""
+    def blocks(rows):
+        return -(-int(rows) // 256) * 256
+
+    top = blocks(pairs)
+    rung = blocks(-(-skew * pairs * experts_held // num_experts))
     rungs = []
-    while rung < top and (not dense_rows or 3 * rung <= dense_rows):
+    while (rung <= top and row_cost * rung + rung_cost <= dense_rows) \
+            if dense_rows else rung < top:
         rungs.append(rung)
         rung *= 4
     return tuple(rungs) + (top,)
@@ -508,6 +561,120 @@ SCORE_FUNCS = {"sigmoid": jax.nn.sigmoid,
                "softmax": lambda logits: jax.nn.softmax(logits, axis=-1)}
 
 
+def _routed(x, router_w, router_bias, w_gate, w_up, w_down, *, top_k,
+            expert_offset, scaling, renormalize, score_func, rungs,
+            dense_top, grouped, dtype, stored):
+    """``sparse_moe`` on the ladder it chose: ``rungs`` ascending, the top
+    one the dense rung (``dense_top``) or sorted as the others; the sorted
+    rungs through the kernels (``grouped``) or ``ragged_dot``; products in
+    ``dtype`` (None: the stacks' own); ``stored`` the layout the device
+    keeps the gate / up stacks in."""
+    from ..ops.pallas import grouped_ffn
+
+    t = x.shape[0]
+    held = w_up.shape[0]
+    scores = SCORE_FUNCS[score_func](jnp.matmul(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, picked = jax.lax.top_k(scores + router_bias, top_k)       # (T, k)
+    weight = jnp.take_along_axis(scores, picked, axis=1)
+    if renormalize:
+        weight = weight / nan_inf.probe(
+            "renorm_denominator", jnp.sum(weight, axis=1, keepdims=True),
+            smallest=True)
+    weight = (scaling * weight).reshape(-1)
+    local = picked.reshape(-1) - expert_offset
+    mine = (local >= 0) & (local < held)
+    # a pair's expert here, or ``held`` for an expert that is elsewhere
+    slot = jnp.where(mine, local, held)
+    # pairs on held experts first, expert by expert (a stable sort keeps
+    # the tokens of an expert in order)
+    order = jnp.argsort(slot, stable=True)
+    token = jnp.arange(t * top_k, dtype=jnp.int32) // top_k
+    # (a one-hot sum, not a bincount: a TPU scatter walks its updates)
+    one_hot = jax.nn.one_hot(slot, held + 1, dtype=jnp.int32)
+    sizes = jnp.sum(one_hot, axis=0)[:held]
+    count = jnp.sum(sizes)
+
+    def cast(*ws):          # (plain experts' absent gate stays None)
+        return tuple(w if dtype is None or w is None else w.astype(dtype)
+                     for w in ws)
+
+    if grouped:
+        # the sort the other way round, without a scatter: a pair's row is
+        # its expert's first row plus the earlier pairs on that expert
+        earlier = jnp.cumsum(one_hot, axis=0) - one_hot
+        first = jnp.cumsum(sizes) - sizes
+        row_of_slot = jnp.where(mine, jnp.sum(
+            one_hot[:, :held] * (earlier[:, :held] + first), axis=1), -1)
+        # (both ways kept across a block's recomputation, 0.8 MB a layer:
+        # ``optimizer.meta.recompute``; an identity anywhere else)
+        order, row_of_slot = (checkpoint_name(a, KEPT)
+                              for a in (order, row_of_slot))
+    else:
+        w_gate, w_up, w_down = cast(w_gate, w_up, w_down)
+
+    def at(rows):
+        def run(x, weight, w_gate, w_up, w_down):
+            take = order[:rows]
+            if grouped:     # the stacks as stored: it rounds them itself
+                # (a pair past the rung's rows reads as one with no row)
+                return grouped_ffn.grouped_ffn(
+                    x, weight.reshape(t, top_k), take,
+                    row_of_slot.reshape(t, top_k), sizes, w_gate, w_up,
+                    w_down, dtype=dtype, declared=True,
+                    up_minor_d=stored.major_to_minor == (0, 2, 1))
+            return _grouped_ffn(
+                x, token[take], jnp.where(mine[take], weight[take], 0.0),
+                sizes, w_gate, w_up, w_down, t)
+        return run
+
+    def every_pair(x, weight, w_gate, w_up, w_down):
+        # each token's weight on each held expert: its picks compared
+        # with the experts and summed (no scatter, see ``sizes``); a pick
+        # that is elsewhere (slot ``held``) matches none
+        by_expert = jnp.sum(jnp.where(
+            slot.reshape(t, top_k, 1) == jnp.arange(held),
+            weight.reshape(t, top_k, 1), 0.0), axis=1)
+        return _every_pair_ffn(x, by_expert, w_gate, w_up, w_down, stored)
+
+    top = every_pair if dense_top else at(rungs[-1])
+    if grouped and dense_top:
+        # the stacks reach the switch as stored; the dense rung rounds
+        # them first. Under grouped rungs it recomputes what it would keep
+        # for its backward (the gate and up results, T x H x F each):
+        # they would be residuals of EVERY branch of the switch, written
+        # as zeros by the grouped rung that runs instead (0.87 ms each at
+        # 16,384 x 8 x 1856, PERF.md section 6, PR 47); it keeps the
+        # rounded stacks
+        inner = nan_inf.checkpoint(every_pair)
+
+        def top(x, weight, w_gate, w_up, w_down):
+            return inner(x, weight, *cast(w_gate, w_up, w_down))
+    rung = jnp.sum(count > jnp.asarray(rungs[:-1], jnp.int32))
+    # (lax.switch; in a step built under FLAGS_check_nan_inf it also hands
+    # out the ``hidden`` row of the rung that ran)
+    out = nan_inf.probe("routed", nan_inf.switch(
+        rung, [at(r) for r in rungs[:-1]] + [top],
+        x, weight, w_gate, w_up, w_down))
+    # the dense top rung's rows, in the ladder's whole 256-row blocks
+    rows = rungs[:-1] + ((-(-t * held // 256) * 256,) if dense_top
+                         else rungs[-1:])
+    ran = jnp.asarray(rows, jnp.float32)[rung]
+    return out, jnp.stack([count.astype(jnp.float32), ran])
+
+
+#: ``_routed`` traced once for a step's expert layers, which have one shape:
+#: tracing and differentiating the kernels' rungs layer by layer (the padded
+#: layout's index arithmetic, two branches of a switch, a ``custom_vjp``)
+#: cost four expert layers 1.7 s of ``setup_s`` on the chip's host where
+#: the dense rung alone costs 0.4 (PERF.md section 6, PR 47); XLA inlines
+#: the call
+_routed_once = jax.jit(_routed, static_argnames=(
+    "top_k", "expert_offset", "scaling", "renormalize", "score_func",
+    "rungs", "dense_top", "grouped", "dtype", "stored"))
+
+
 @primitive("sparse_moe")
 def sparse_moe(x, router_w, router_bias, w_gate, w_up, w_down, top_k,
                expert_offset=0, scaling=1.0, renormalize=True,
@@ -528,57 +695,11 @@ def sparse_moe(x, router_w, router_bias, w_gate, w_up, w_down, top_k,
     whatever the autocast level (a rounded score flips picks); the
     experts' products run in the autocast type."""
     from ..amp import amp_dtype, amp_enabled
+    from ..ops.pallas import grouped_ffn
     from ..ops.pallas.counters import bump
 
-    t = x.shape[0]
-    num_experts, held = router_w.shape[1], w_up.shape[0]
-    scores = SCORE_FUNCS[score_func](jnp.matmul(
-        x.astype(jnp.float32), router_w.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
-    _, picked = jax.lax.top_k(scores + router_bias, top_k)       # (T, k)
-    weight = jnp.take_along_axis(scores, picked, axis=1)
-    if renormalize:
-        weight = weight / nan_inf.probe(
-            "renorm_denominator", jnp.sum(weight, axis=1, keepdims=True),
-            smallest=True)
-    weight = (scaling * weight).reshape(-1)
-    local = picked.reshape(-1) - expert_offset
-    mine = (local >= 0) & (local < held)
-    # a pair's expert here, or ``held`` for an expert that is elsewhere
-    slot = jnp.where(mine, local, held)
-    # pairs on held experts first, expert by expert (a stable sort keeps
-    # the tokens of an expert in order)
-    order = jnp.argsort(slot, stable=True)
-    token = jnp.arange(t * top_k, dtype=jnp.int32) // top_k
-    # (a one-hot sum, not a bincount: a TPU scatter walks its updates)
-    sizes = jnp.sum(jax.nn.one_hot(slot, held + 1, dtype=jnp.int32),
-                    axis=0)[:held]
-    count = jnp.sum(sizes)
-    dense_top = held <= 2 * top_k
-    rungs = _row_ladder(t * min(top_k, held), held, num_experts,
-                        t * held if dense_top else 0)
-    stored = _stored_layout(w_up)       # of the stack as it is kept
-    if amp_enabled():       # (plain experts' absent gate stays None)
-        w_gate, w_up, w_down = (w if w is None else w.astype(amp_dtype())
-                                for w in (w_gate, w_up, w_down))
-
-    def at(rows):
-        def run(x, weight, w_gate, w_up, w_down):
-            take = order[:rows]
-            return _grouped_ffn(
-                x, token[take], jnp.where(mine[take], weight[take], 0.0),
-                sizes, w_gate, w_up, w_down, t)
-        return run
-
-    def every_pair(x, weight, w_gate, w_up, w_down):
-        # each token's weight on each held expert: its picks compared
-        # with the experts and summed (no scatter, see ``sizes``); a pick
-        # that is elsewhere (slot ``held``) matches none
-        by_expert = jnp.sum(jnp.where(
-            slot.reshape(t, top_k, 1) == jnp.arange(held),
-            weight.reshape(t, top_k, 1), 0.0), axis=1)
-        return _every_pair_ffn(x, by_expert, w_gate, w_up, w_down, stored)
-
+    (t, d), (held, _, f) = x.shape, w_up.shape
+    num_experts = router_w.shape[1]
     # The top rung. With no more experts here than a token picks, every
     # pair is every token through every expert: no sort, no gathered copy
     # of the rows, one FFN of width H x F. Up to twice as many
@@ -592,20 +713,48 @@ def sparse_moe(x, router_w, router_bias, w_gate, w_up, w_down, top_k,
     # rung under the dense one only at a third of its rows or fewer
     # (19,385-20,097 tokens/s over twelve seeds of the Nemotron cell on
     # a sorted rung of 0.375 of them, section 6, PR 33).
-    top = every_pair if dense_top else at(rungs[-1])
+    dense_top = held <= 2 * top_k
+    pairs = t * min(top_k, held)
+    dense_rows = t * held if dense_top else 0
+    rungs = _row_ladder(pairs, held, num_experts, dense_rows)
+    # On a TPU the sorted rungs may be Pallas grouped products on a static
+    # row count, which compute all of a rung's rows whatever the routing
+    # and neither select nor scatter; ``ragged_dot`` is the same function's
+    # plain statement. Under a dense top they take the work the DENSE rung
+    # does today, where ``_row_ladder`` finds a rung of theirs that costs
+    # less than it (``_grouped_cost``, from the shapes: the wide experts'
+    # shares, Nemotron's and LFM2's, and not the narrow ones', Kanana's,
+    # which lost 1.5% on them, and Mellum's). A
+    # share that ``ragged_dot``'s own ladder serves keeps it: that rung is
+    # a third of the dense rows or fewer and mostly empty (1-3 thousand
+    # live pairs of 16,384 in the Kimi cell), and its time follows its
+    # live rows, which no static rung matches (-5.3% there on the kernels,
+    # PERF.md section 6, PR 47).
+    grouped = grouped_ffn.takes() and (len(rungs) == 1 or not dense_top)
+    if grouped and dense_top:
+        row, rung = _grouped_cost(t, top_k, held, d, f, w_gate is not None)
+        rungs = _row_ladder(pairs, held, num_experts, dense_rows, row,
+                            rung_cost=rung)
+        grouped = len(rungs) > 1
+    if grouped and dense_top and rungs[-2] >= rungs[-1]:
+        # the grouped rung holds every pair there can be: the dense rung
+        # above it could never run, and is not built
+        rungs, dense_top = rungs[:-1], False
+    dtype = amp_dtype() if amp_enabled() else None
     bump("sparse_moe", "every_pair" if dense_top else "sorted")
     bump("sparse_moe", "plain" if w_gate is None else "gated")
-    rung = jnp.sum(count > jnp.asarray(rungs[:-1], jnp.int32))
-    # (lax.switch; in a step built under FLAGS_check_nan_inf it also hands
-    # out the ``hidden`` row of the rung that ran)
-    out = nan_inf.probe("routed", nan_inf.switch(
-        rung, [at(r) for r in rungs[:-1]] + [top],
-        x, weight, w_gate, w_up, w_down))
-    # the dense top rung's rows, in the ladder's whole 256-row blocks
-    rows = rungs[:-1] + ((-(-t * held // 256) * 256,) if dense_top
-                         else rungs[-1:])
-    ran = jnp.asarray(rows, jnp.float32)[rung]
-    return out, jnp.stack([count.astype(jnp.float32), ran])
+    if grouped:
+        bump("sparse_moe", "grouped")
+        for rows in rungs[:len(rungs) - dense_top]:
+            grouped_ffn.declare(rows, t, held, d, f, w_gate is not None,
+                                w_up.dtype if dtype is None else dtype)
+    # (a step built under FLAGS_check_nan_inf leaves its rows as it traces)
+    run = _routed_once if grouped and nan_inf.record is None else _routed
+    return run(x, router_w, router_bias, w_gate, w_up, w_down, top_k=top_k,
+               expert_offset=expert_offset, scaling=scaling,
+               renormalize=renormalize, score_func=score_func, rungs=rungs,
+               dense_top=dense_top, grouped=grouped, dtype=dtype,
+               stored=_stored_layout(w_up))     # of the stack as it is kept
 
 
 class SparseMoELayer(Layer):

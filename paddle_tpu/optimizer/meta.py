@@ -93,14 +93,16 @@ def _segment_params(fn):
 @functools.cache
 def _kept_policy():
     """What a recomputed segment keeps beside its inputs: the values
-    named ``flash_attention.KEPT``. ONE object for every segment: jax
-    keys its partial-evaluation caches on the policy, and a policy a
-    block would part the jitted helpers the blocks share (``tril``,
-    ``silu``, ...) once a block."""
+    named ``flash_attention.KEPT`` and ``nn.moe.KEPT`` (the sort of a
+    dropless expert layer's pairs on its kernels' rungs). ONE object for
+    every segment: jax keys its partial-evaluation caches on the policy,
+    and a policy a block would part the jitted helpers the blocks share
+    (``tril``, ``silu``, ...) once a block."""
+    from ..nn import moe
     from ..ops.pallas import flash_attention
 
     return jax.checkpoint_policies.save_only_these_names(
-        flash_attention.KEPT)
+        flash_attention.KEPT, moe.KEPT)
 
 
 def recompute(function, *args, **kwargs):

@@ -15,7 +15,7 @@ import paddle_tpu.framework.bringup as bringup
 from benchmarks.reference import kimi_linear as ref
 from paddle_tpu import nn
 from paddle_tpu.models.causal_lm import CausalLM, ffn_kind, mixer_kind
-from paddle_tpu.nn.moe import _row_ladder, sparse_moe
+from paddle_tpu.nn.moe import _grouped_cost, _row_ladder, sparse_moe
 from paddle_tpu.ops.pallas import counters
 from paddle_tpu.ops.pallas import flash_attention as fa
 from paddle_tpu.ops.pallas import fused_xent as fx
@@ -179,6 +179,44 @@ def test_every_rung_of_the_row_ladder_gives_the_same_layer():
     assert rows == 1024 and pairs == 800
     want = ref.moe(routed, "f.", x, cfg, ref._dense, router_bias=bias)
     assert _rel(out, want) < 1e-5
+
+
+def test_the_kernels_ladders_follow_what_their_rungs_cost():
+    """At the grouped kernels' costs (``_grouped_cost``: a launched row's
+    products are a dense row's, its three gathers cost by the ROW, the
+    way back is three passes of a one-hot product, and the sort walks
+    every slot) the five MoE cells' shares under their dense rung: ONE
+    grouped rung at eight times the even share for the wide experts
+    (LFM2's is every pair), and none for the narrow ones: the Kanana
+    share's rung would cost 139 thousand dense rows where the dense rung
+    has 131,072, and the Mellum share's every pair is half its dense
+    rows."""
+    for tokens, d, f, top_k, held, experts, gated, rungs in (
+            (8192, 2304, 1024, 8, 8, 256, True, (16384,)),        # Kimi
+            (16384, 2688, 1856, 6, 8, 128, False, (49152,)),      # Nemotron
+            (16384, 2048, 768, 6, 8, 128, True, ()),              # Kanana
+            (16384, 2048, 1536, 4, 8, 64, True, (65536,)),        # LFM2
+            (16384, 2304, 896, 8, 16, 64, True, ())):             # Mellum
+        pairs = tokens * min(top_k, held)
+        row, rung = _grouped_cost(tokens, top_k, held, d, f, gated)
+        assert _row_ladder(pairs, held, experts, tokens * held, row,
+                           rung_cost=rung)[:-1] == rungs
+    # a launched row costs a dense row and a half at Nemotron's experts
+    # and two at Kanana's; beside its rows a Nemotron rung costs what
+    # 23 thousand dense rows do (8 tiles, 98,304 slots sorted, 512 visits
+    # of the way back): 99 thousand in all, as the cell measured (102)
+    row, rung = _grouped_cost(16384, 6, 8, 2688, 1856, False)
+    assert 1.5 < row < 1.6 and 22000 < rung < 24000
+    assert 98000 < row * 49152 + rung < 100000
+    row, rung = _grouped_cost(16384, 6, 8, 2048, 768, True)
+    assert 2.0 < row < 2.1 and 131072 < row * 49152 + rung < 142000
+    # under a dense top a rung of every pair stays where it is cheaper
+    assert _row_ladder(131072, 16, 64, 262144, row_cost=2.0,
+                       skew=4) == (131072, 131072)
+    assert _row_ladder(131072, 16, 64, 262144, row_cost=2.5,
+                       skew=4) == (131072,)
+    assert _row_ladder(131072, 16, 64, 262144, row_cost=1.5, skew=4,
+                       rung_cost=70000) == (131072,)
 
 
 def test_causal_lm_loss_and_gradients_match_the_reference():

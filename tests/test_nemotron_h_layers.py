@@ -176,6 +176,15 @@ def test_every_rung_of_the_ladder_runs_plain_experts():
     experts = 256
     rungs = _row_ladder(tokens * top_k, held, experts)
     assert rungs == (512, 2048)
+    # with the constants as arguments: half the skew is a rung lower, a
+    # dense top keeps the rungs that cost less than its rows, and a
+    # costlier row is a shorter ladder
+    assert _row_ladder(tokens * top_k, held, experts, row_cost=2.0,
+                       skew=4) == (256, 1024, 2048)
+    assert _row_ladder(tokens * top_k, held, experts, tokens * held,
+                       row_cost=2.0, skew=4) == (256, 1024, 2048)
+    assert _row_ladder(tokens * top_k, held, experts, tokens * held,
+                       row_cost=16, skew=4) == (256, 2048)
     x = jnp.asarray(rng.randn(tokens, d), jnp.float32)
     router = jnp.asarray(rng.randn(d, experts), jnp.float32)
     up, down = _experts(rng, held, d, f, gated=False)

@@ -346,6 +346,133 @@ def test_the_expert_layers_wide_rung_hands_its_gradients_over_as_stored(
         rf"\[{held},({d},{f}|{f},{d})\]\S* (copy|transpose)\(", text)
 
 
+#: the lowest rung the grouped kernels would run at in each MoE cell's
+#: expert layer (``nn.moe._row_ladder`` at eight times the even share);
+#: the layers' shapes are ``tools/op_bench.py``'s ``EXPERT_CELLS``
+GROUPED_RUNGS = {"kimi": 16384, "mellum": 131072, "nemotron": 49152,
+                 "kanana": 49152, "lfm2": 65536}
+
+
+def _expert_cell(cell):
+    """(tokens, D, F, held, experts, top_k, gated, scores, rung)."""
+    import sys
+
+    tools = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools")
+    if tools not in sys.path:
+        sys.path.insert(0, tools)
+    import op_bench
+
+    return op_bench.EXPERT_CELLS[cell] + (GROUPED_RUNGS[cell],)
+
+
+@pytest.mark.parametrize("cell", list(GROUPED_RUNGS))
+def test_the_expert_layers_grouped_rung_compiles_at_the_moe_cells_shapes(
+        one_chip, monkeypatch, cell):
+    """Each MoE cell's expert layer with the kernels' gate open, under
+    bfloat16 autocast and the block's recomputation, value and gradients.
+    The wide experts' shares (Nemotron's, LFM2's) run one grouped rung,
+    under the dense one or, LFM2's, of every pair: the forward's two
+    launches and its way back, the recomputed forward's one (its down
+    product feeds nothing) and the backward's four or five (a gated expert
+    has one more stack) and its way back, with no ``ragged-dot``, no
+    scatter of a row array (the router's scores' cotangent is the one
+    scatter, (T x E,) scalars, the parent's), and no float32 copy or
+    transpose of a stack: the gradients leave the launches in the layout
+    the chip keeps the stacks in (the Nemotron stack's as (held, F, D)
+    blocks), and what the rung keeps for its backward is the stacks as it
+    rounded them, not the switch's own operands. Nemotron's width 1856 is
+    14.5 lanes: the launches take it as one whole block. The other three
+    run what they ran (the Kanana and Mellum shares' narrow experts the
+    dense rung alone: ``nn.moe._grouped_cost``; the Kimi share
+    ``ragged_dot``, whose own ladder serves it), and their compiled
+    program is, to the letter, the one compiled with the gate shut: the
+    parent's."""
+    import functools
+
+    import paddle_tpu.framework.bringup as bringup
+    from paddle_tpu import amp
+    from paddle_tpu.nn import moe
+    from paddle_tpu.nn.moe import sparse_moe
+    from paddle_tpu.ops.pallas import counters
+
+    t, d, f, held, experts, top_k, gated, score, rung = _expert_cell(cell)
+    chip, = one_chip.device_set
+    monkeypatch.setattr(moe, "_stored_layout", functools.partial(
+        moe._stored_layout, device=chip))
+
+    def layer(x, router, up, down, gate=None):
+        with amp.auto_cast(level="O1", dtype="bfloat16"):
+            return sparse_moe.raw_fn(
+                x, router, jnp.zeros((experts,), F32), gate, up, down,
+                top_k=top_k, score_func=score)[0]
+
+    shapes = (((t, d), BF16), ((d, experts), F32), ((held, d, f), F32),
+              ((held, f, d), F32)) + ((((held, d, f), F32),) if gated else ())
+
+    def compiled(gate_open):
+        monkeypatch.setattr(bringup, "pallas_enabled", lambda: gate_open)
+        counters.reset()
+        text = _compile(
+            jax.value_and_grad(
+                lambda *a: jnp.sum(jax.checkpoint(layer)(*a)),
+                argnums=tuple(range(len(shapes)))),
+            one_chip, *shapes).as_text()
+        return text, counters.snapshot()
+
+    kernels = cell in ("nemotron", "lfm2")
+    # (one call site: the text names the lines it was traced from)
+    (text, counts), *shut = [compiled(gate_open) for gate_open in
+                             ([True] if kernels else [True, False])]
+    if not kernels:
+        assert "sparse_moe.grouped" not in counts
+        assert text == shut[0][0]
+        return
+    assert counts["sparse_moe.grouped"] == counts["moe_grouped.pallas"] == 1
+    assert ("sparse_moe.every_pair" in counts) == (cell == "nemotron")
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) \
+        == (10 if gated else 9)
+    assert not re.findall(r"\bragged-dot\(", text)
+    assert not re.findall(r"= \w+\[\d+,[\d,]+\]\S* scatter\(", text)
+    assert not re.findall(
+        rf"f32\[{held},({d},{f}|{f},{d})\]\S* (copy|transpose)\(", text)
+
+
+@pytest.mark.parametrize("cell", list(GROUPED_RUNGS))
+def test_the_grouped_rungs_kernels_compile_under_highest(one_chip, cell):
+    """The launches alone at each cell's rung under
+    ``jax_default_matmul_precision=highest`` (chip_smoke.py; S12(ii): the
+    TPU's ``ragged_dot`` refuses bfloat16 operands there): they name the
+    MXU's own precision for bfloat16 operands, and the scoped VMEM they
+    ask for (the compiler refuses a launch that needs more) holds a
+    stack's whole matrix twice beside the row tiles."""
+    import functools
+
+    from paddle_tpu.ops.pallas import grouped_ffn as gf
+
+    t, d, f, held, _, top_k, gated, _, rung = _expert_cell(cell)
+
+    def loss(x, weight, slot_of_row, row_of_slot, sizes, up, down, gate=None):
+        return jnp.sum(jax.checkpoint(functools.partial(
+            gf.grouped_ffn, dtype=BF16, up_minor_d=not gated))(
+                x, weight, slot_of_row, row_of_slot, sizes, gate, up, down))
+
+    shapes = (((t, d), BF16), ((t, top_k), F32), ((rung,), jnp.int32),
+              ((t, top_k), jnp.int32), ((held,), jnp.int32),
+              ((held, d, f), F32), ((held, f, d), F32)) \
+        + ((((held, d, f), F32),) if gated else ())
+    with jax.default_matmul_precision("highest"):
+        out = _compile(jax.grad(
+            loss, argnums=(0, 1, 5, 6) + ((7,) if gated else ())),
+            one_chip, *shapes)
+    text = out.as_text()
+    # no value asked for: the recomputed forward's one launch, the
+    # backward's four or five and its way back
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) \
+        == (7 if gated else 6)
+    assert gf.padded_rows(rung, held) == rung + held * gf.TILE
+
+
 @pytest.mark.parametrize("dtype,precision", [(BF16, None), (F32, "highest")],
                          ids=["bfloat16", "float32_highest"])
 def test_ssd_chunk_kernels_compile_at_the_nemotron_cells_shapes(

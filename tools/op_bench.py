@@ -3,7 +3,9 @@ operators/benchmark/op_tester.cc + operators/jit/benchmark.cc): times the
 hot kernels — matmul, attention (XLA and Pallas flash), layernorm,
 embedding lookup, conv — on the current backend and prints one JSON
 line per op, so a single kernel can be timed without running a full
-model (fused xent's three kernels apart: ``--ops fused_xent``).
+model (fused xent's three kernels apart: ``--ops fused_xent``; the
+dropless expert layer's rungs at the MoE cells' shapes: ``--ops
+expert_ffn``).
 
 Usage:
     python tools/op_bench.py                 # bench all ops, print rows
@@ -308,7 +310,85 @@ def bench_fused_xent(smoke):
             "kernels": [_xent_kernel_rows(*c) for c in cells]}
 
 
+#: the five MoE cells' expert layers: tokens a step, D, F, experts held
+#: of how many, picks a token, gated or plain, the router's scores
+EXPERT_CELLS = {
+    "nemotron": (16384, 2688, 1856, 8, 128, 6, False, "sigmoid"),
+    "lfm2": (16384, 2048, 1536, 8, 64, 4, True, "sigmoid"),
+    "kanana": (16384, 2048, 768, 8, 128, 6, True, "sigmoid"),
+    "mellum": (16384, 2304, 896, 16, 64, 8, True, "softmax"),
+    "kimi": (8192, 2304, 1024, 8, 256, 8, True, "sigmoid"),
+}
+
+
+def bench_expert_ffn(smoke, only=""):
+    """The dropless expert layer (``nn.moe.sparse_moe``: router, sort and
+    the rung that runs) at each MoE cell's shapes (``only``: some of them,
+    ``nemotron+lfm2``), forward + recomputed forward + backward under
+    bfloat16 autocast, once a count of pairs: a correction bias on the
+    first ``forced`` experts makes them every token's picks, 0 and 1 of
+    them for the lowest rung at two counts (a grouped rung's time should
+    not follow its count), ``top_k`` of them for the dense rung.
+    ``dense_rows`` is what the lowest rung took in the dense rung's rows
+    (the router, the sort and the ways back in both), and ``stated`` what
+    ``nn.moe._grouped_cost`` states for it where it is the kernels'."""
+    import jax.numpy as jnp
+
+    from paddle_tpu import amp
+    from paddle_tpu.nn.moe import _grouped_cost, sparse_moe
+    from paddle_tpu.ops.pallas import counters
+
+    cells = {"smoke": (256, 64, 32, 2, 16, 2, True, "sigmoid")} if smoke \
+        else {k: v for k, v in EXPERT_CELLS.items()
+              if not only or k in only.split("+")}
+    out = []
+    for name, (t, d, f, held, experts, top_k, gated, score) in cells.items():
+        def layer(x, router, bias, up, down, gate=None):
+            with amp.auto_cast(level="O1", dtype="bfloat16"):
+                return sparse_moe.raw_fn(
+                    x, router, bias, gate, up, down, top_k=top_k,
+                    score_func=score)
+
+        def loss(x, router, up, down, gate, bias):
+            routed, routing = jax.checkpoint(layer)(
+                x, router, bias, up, down, gate)
+            return jnp.sum(routed * routed), routing
+
+        keys = jax.random.split(jax.random.key(0), 5)
+        args = [jax.random.normal(keys[0], (t, d), jnp.bfloat16),
+                jax.random.normal(keys[1], (d, experts)) * d ** -0.5,
+                jax.random.normal(keys[2], (held, d, f)) * d ** -0.5,
+                jax.random.normal(keys[3], (held, f, d)) * f ** -0.5,
+                jax.random.normal(keys[4], (held, d, f)) * d ** -0.5
+                if gated else None]
+        step = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3) + ((4,) if gated else ()),
+            has_aux=True))
+        before = counters.snapshot()
+        rungs = []
+        for forced in (0, 1, top_k):
+            bias = jnp.zeros((experts,), jnp.float32).at[:forced].set(10.0)
+            (_, routing), _ = step(*args, bias)
+            pairs, rows = (int(v) for v in np.asarray(routing))
+            ms = _timeit(step, *args, bias, iters=10)
+            rungs.append({"forced": forced, "pairs": pairs, "rows": rows,
+                          "ms": round(ms, 4),
+                          "us_per_row": round(1e3 * ms / rows, 4)})
+        row = {"cell": name, "rungs": rungs}
+        # (no ratio where the ladder is one rung: every count ran on it)
+        if rungs[0]["rows"] != rungs[-1]["rows"]:
+            row["dense_rows"] = round(
+                rungs[0]["ms"] / rungs[-1]["ms"] * rungs[-1]["rows"])
+            if "sparse_moe.grouped" in counters.delta(before):
+                a_row, a_rung = _grouped_cost(t, top_k, held, d, f, gated)
+                row["stated"] = round(a_row * rungs[0]["rows"] + a_rung)
+        out.append(row)
+    return {"op": "expert_ffn", "ms": out[0]["rungs"][0]["ms"],
+            "cells": out}
+
+
 BENCHES = {
+    "expert_ffn": bench_expert_ffn,
     "matmul": bench_matmul,
     "attention": bench_attention,
     "flash_attention": bench_flash_attention,
@@ -339,8 +419,10 @@ def run_benches(ops):
         name = name.strip()
         if not name:
             continue
+        # (``expert_ffn=nemotron+lfm2``: a bench that takes a choice)
+        name, _, choice = name.partition("=")
         try:
-            row = BENCHES[name](smoke)
+            row = BENCHES[name](smoke, *([choice] if choice else []))
         except Exception as e:
             row = {"op": name, "error": f"{type(e).__name__}: {e}"}
         row.update({"backend": backend, "device_kind": kind, "smoke": smoke})
