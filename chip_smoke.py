@@ -976,6 +976,72 @@ def _kernel_checks():
     kda_stage("conv")
     kda_stage("gate_norm")
 
+    # -- a KDA mixer that ``recompute`` runs again against the mixer as it
+    # is, at the Kimi cell's shapes: the segment keeps the chunk kernel's o
+    # and states (PR 48), so its traced gradient holds ONE chunk forward
+    # and its numbers are the unrecomputed block's ---------------------------
+    def kda_block_recomputed(b=1, t=8192, hidden=2304, heads=32, d=128):
+        name = f"kda block recomputed ({b}, {t}, {hidden}), " \
+               f"{heads} heads of {d}"
+
+        def check(fails):
+            import paddle_tpu as paddle
+            from paddle_tpu import nn
+            from paddle_tpu.framework import tape
+            from paddle_tpu.framework.tensor import Tensor
+            from paddle_tpu.ops.pallas import counters
+            from paddle_tpu.optimizer.meta import recompute
+
+            paddle.seed(0)
+            mixer = nn.KimiDeltaAttention(hidden, heads, d)
+            params = list(mixer.parameters())
+            values = [p.value for p in params]
+            x, w = rnd(1, (b, t, hidden)), rnd(2, (b, t, hidden))
+
+            def grads(again):
+                def loss(xv, pv):
+                    # the parameters hold tracers, the tape off: how
+                    # TrainStep traces a model
+                    try:
+                        for p, v in zip(params, pv):
+                            p._value = v
+                        with tape.no_grad():
+                            h = Tensor(xv)
+                            y = recompute(mixer, h) if again else mixer(h)
+                    finally:
+                        for p, v in zip(params, values):
+                            p._value = v
+                    return jnp.sum((xv + y.value) * w)
+                return jax.value_and_grad(loss, argnums=(0, 1))
+
+            before = counters.snapshot()
+            text = str(jax.make_jaxpr(grads(True))(x, values))
+            seen = counters.delta(before)
+            if seen.get("kda_chunk.kept_across_recompute") != 1 \
+                    or seen.get("kda_chunk.pallas") != 1:
+                fails.append(f"{name}: outside its gate ({seen})")
+                return
+            if text.count("name=kda_chunk_fwd") != 1 \
+                    or text.count("name=kda_chunk_bwd") != 1:
+                fails.append(
+                    f"{name}: {text.count('name=kda_chunk_fwd')} forward, "
+                    f"{text.count('name=kda_chunk_bwd')} backward launches")
+            (lk, got), (lx, want) = (jax.jit(grads(a))(x, values)
+                                     for a in (True, False))
+            _close(f"{name} loss", lk, lx, tol_of(f32), fails)
+            leaves = list(zip(
+                jax.tree_util.tree_leaves(got),
+                jax.tree_util.tree_leaves(want),
+                ["x"] + [n for n, _ in mixer.named_parameters()]))
+            for a, r, nm in leaves:
+                _close(f"{name} d{nm}", a, r, tol_of(f32), fails)
+            same = sum(bool(jnp.all(a == r)) for a, r, _ in leaves)
+            log(f"       {name}: {same} of {len(leaves)} gradients equal "
+                "bit for bit")
+        checks.append((name, check))
+
+    kda_block_recomputed()
+
     # -- the gated short convolution's fused stage against its float32
     # formula, at the LFM2 cell's shapes -------------------------------------
     def gated_conv_stage(b=2, t=8192, d=2048, taps=3, dtype=bf16):

@@ -61,9 +61,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ...framework import nan_inf
-from .counters import bump, kernel_call
+from .counters import bump, in_recomputed, kernel_call
 from .flash_attention import _sds
 
 _F32 = jnp.float32
@@ -73,6 +74,15 @@ CHUNK = 64
 _SUB = 16
 #: exponents of decays that a mask discards anyway are held under this
 _EXP_CAP = 80.0
+#: what the forward rule calls the two arrays its Pallas launch wrote, o
+#: and the states the chunks started from: ``optimizer.meta.recompute``
+#: keeps the values of this name across a recomputed segment, so the
+#: segment's second run brings q, k, v, g, beta back from the projections
+#: and does not launch the O(T) recurrence again to write the same two
+#: arrays (48 KB a token and layer at 32 heads of 128 x 128). An identity
+#: outside a checkpoint with a policy: it lowers to nothing
+KEPT = "kda_chunk_out_states"
+_kept = functools.partial(checkpoint_name, name=KEPT)
 
 
 def _dot(a, b, trans_b=False):
@@ -491,10 +501,12 @@ def _chunk_kda(q, k, v, g, beta, chunk, kernel, record=False):
 def _chunk_kda_fwd(q, k, v, g, beta, chunk, kernel, record):
     """``record`` (a step built under FLAGS_check_nan_inf): the result
     is (o, the ``nan_inf.row`` of the states the chunks started from),
-    which no probe outside this rule can reach."""
+    which no probe outside this rule can reach. What the kernel wrote is
+    named :data:`KEPT` (before the record reads it); the XLA form names
+    nothing, and ``gc`` is a segment's to compute again."""
     gc = _cumulate(g, chunk)
-    o, states = (_pallas_fwd if kernel else _xla_fwd)(q, k, v, gc, beta,
-                                                      chunk)
+    o, states = _kept(_pallas_fwd(q, k, v, gc, beta, chunk)) if kernel \
+        else _xla_fwd(q, k, v, gc, beta, chunk)
     return ((o, nan_inf.row(states)) if record else o,
             (q, k, v, gc, beta, states))
 
@@ -544,6 +556,8 @@ def chunk_kda_flat(q, k, v, g, beta, chunk=CHUNK):
     if kernel:
         bump("kda_chunk", "pallas", **kda_work(b, t + pad, h, kd, vd))
         bump("kda_chunk", f"heads{_heads_a_step(h, kd, vd, chunk)}")
+        if in_recomputed():
+            bump("kda_chunk", "kept_across_recompute")
     else:
         bump("kda_chunk", "xla",
              f"dispatch ineligible ({h} heads of {kd} x {vd}, chunk {chunk}"

@@ -93,16 +93,18 @@ def _segment_params(fn):
 @functools.cache
 def _kept_policy():
     """What a recomputed segment keeps beside its inputs: the values
-    named ``flash_attention.KEPT`` and ``nn.moe.KEPT`` (the sort of a
-    dropless expert layer's pairs on its kernels' rungs). ONE object for
-    every segment: jax keys its partial-evaluation caches on the policy,
-    and a policy a block would part the jitted helpers the blocks share
-    (``tril``, ``silu``, ...) once a block."""
+    named ``flash_attention.KEPT`` (the flash kernels' output and
+    logsumexp), ``kda.KEPT`` (the KDA chunk kernel's output and chunk
+    states) and ``nn.moe.KEPT`` (the sort of a dropless expert layer's
+    pairs on its kernels' rungs). ONE object for every segment: jax keys
+    its partial-evaluation caches on the policy, and a policy a block
+    would part the jitted helpers the blocks share (``tril``, ``silu``,
+    ...) once a block."""
     from ..nn import moe
-    from ..ops.pallas import flash_attention
+    from ..ops.pallas import flash_attention, kda
 
     return jax.checkpoint_policies.save_only_these_names(
-        flash_attention.KEPT, moe.KEPT)
+        flash_attention.KEPT, kda.KEPT, moe.KEPT)
 
 
 def recompute(function, *args, **kwargs):
@@ -127,7 +129,13 @@ def recompute(function, *args, **kwargs):
     for, and would launch the O(T^2) forward kernel only to write the
     same two arrays again. There is no length the kernels accept at
     which that launch is cheaper than the bytes, so it is no option.
-    The dispatch counts ``flash_attention.kept_across_recompute``.
+    The dispatch counts ``flash_attention.kept_across_recompute``. The
+    same holds for the KDA chunk kernel (``kda.KEPT``: its float32
+    output and the state every 64-token chunk started from, 4 H (V +
+    V K / 64) bytes a token; ``kda_chunk.kept_across_recompute``): the
+    second run brings q, k, v, g, beta back and does not walk the
+    sequence again. The Mamba-2 scan's outputs are not kept: its second
+    run is 1.3% of that cell's step for the same bytes (ROADMAP S19(c)).
 
     A segment may be called several times in one step on the SAME
     parameters (a looped model walks its blocks ``total_ut_steps``

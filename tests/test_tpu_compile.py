@@ -8,6 +8,7 @@ PR 26).
 The topology is described inside a fixture, never at import: only one
 process may load the TPU's library, and every xdist worker imports every
 test file. All such tests live in this one file."""
+import collections
 import os
 import re
 
@@ -39,18 +40,20 @@ F32, BF16 = jnp.float32, jnp.bfloat16
 KDA = (1, 8192, 32, 128)
 
 
-def _pallas_calls(fn, *shapes):
-    """The parameters of every ``pallas_call`` ``fn`` traces, nested jits
+def _pallas_calls_in(jaxpr):
+    """The parameters of every ``pallas_call`` of a jaxpr, nested jits
     included."""
-    def walk(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                yield eqn.params
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                yield from walk(sub)
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn.params
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls_in(sub)
 
+
+def _pallas_calls(fn, *shapes):
+    """The parameters of every ``pallas_call`` ``fn`` traces."""
     args = [jax.ShapeDtypeStruct(s, d) for s, d in shapes]
-    return list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+    return list(_pallas_calls_in(jax.make_jaxpr(fn)(*args).jaxpr))
 
 
 def _pallas_grids(fn, *shapes):
@@ -638,3 +641,98 @@ def test_grouped_stream_flash_compiles_at_the_lfm2_cells_heads_of_64(
         out = _compile(grads, one_chip, ((2, 8192, 32, 64), BF16),
                        ((2, 8192, 8, 64), BF16), ((2, 8192, 8, 64), BF16))
     assert "flash_attention_grouped" in out.as_text()
+
+
+# ---------------------------------------------------------------------------
+# a cell's whole TrainStep, as its benchmark driver builds it
+# ---------------------------------------------------------------------------
+def _cell_step(cell_name, one_chip, monkeypatch):
+    """(the jitted step, its arguments as shapes on the described chip)
+    of a training cell at its real size, built by the cell's own driver
+    from abstract parameters and an abstract optimizer state: nothing as
+    large as the model is ever made here."""
+    import numpy as np
+
+    import paddle_tpu as paddle
+    import paddle_tpu.framework.bringup as bringup
+    from benchmarks import harness
+    from paddle_tpu.ops.pallas import counters
+
+    monkeypatch.setattr(bringup, "pallas_enabled", lambda: True)
+    cell, cfg = harness.load_cell(cell_name)
+    driver = harness.load_driver(cfg)
+    params = {k: jax.ShapeDtypeStruct(s, F32) for k, s in
+              driver.param_shapes(driver.model_config(cfg)).items()}
+    step = driver.Loop(cfg, cell, params, 1).step
+    step._opt_state = jax.eval_shape(lambda: step.optimizer.init_state(
+        params, dict(step.model.named_parameters())))
+    ids = paddle.to_tensor(np.zeros(
+        (int(cell["traffic"]["batch"]), int(cell["traffic"]["seq"])),
+        "int32"))
+    counters.reset()
+    weights, buffers, lr, batch = step._inputs((ids, ids))
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (weights, buffers, step._opt_state, lr, batch))
+    return step._compiled, args
+
+
+def test_the_kimi_cells_step_launches_the_kda_chunk_forward_once_a_layer(
+        one_chip, monkeypatch):
+    """1 x 8,192 tokens through the cell's five blocks under per-block
+    recomputation: the four KDA layers' chunk forward is FOUR custom
+    calls of the compiled step (eight until PR 48: the backward's second
+    run of a block launched the recurrence again to write the same ``o``
+    and states), every one in the forward pass and none in a
+    ``rematted_computation``; four backward launches; the two stages
+    either side still run again (they bring q, k, v and the gate back),
+    and the whole step fits the chip with the 1.6 GB it now keeps."""
+    from paddle_tpu.ops.pallas import counters
+
+    step, args = _cell_step("kimi-linear-48b-a3b.pretrain-seq8k", one_chip,
+                            monkeypatch)
+    out = step.lower(*args).compile()
+    snap = counters.snapshot()
+    assert snap["kda_chunk.kept_across_recompute"] \
+        == snap["kda_chunk.pallas"] == 4
+    assert snap["flash_attention.kept_across_recompute"] == 1
+    calls = [line for line in out.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    where = {role: [re.search(r'op_name="([^"]*)"', c).group(1)
+                    for c in calls if f"/pallas/{role}/pallas_call" in c]
+             for role in ("kda_chunk_fwd", "kda_chunk_bwd", "kda_conv",
+                          "kda_gate_norm", "flash_attention_stream_fwd")}
+    assert len(where["kda_chunk_fwd"]) == len(where["kda_chunk_bwd"]) == 4
+    assert not any("rematted_computation" in w
+                   for w in where["kda_chunk_fwd"])
+    assert len(where["flash_attention_stream_fwd"]) == 1
+    # three projections a layer: forward, run again, backward; one gate
+    assert len(where["kda_conv"]) == 36 and len(where["kda_gate_norm"]) == 12
+    assert sum("rematted_computation" in w for w in where["kda_conv"]) == 12
+    mem = out.memory_analysis()
+    held = mem.argument_size_in_bytes + mem.output_size_in_bytes \
+        - mem.alias_size_in_bytes + mem.temp_size_in_bytes
+    assert held < 16 * 2 ** 30, mem
+
+
+@pytest.mark.parametrize("cell, want", [
+    ("nemotron-3-nano-30b-a3b.pretrain-seq8k",
+     {"ssd_chunk": 12, "mamba2_conv": 36, "mamba2_gate_norm": 12,
+      "flash_attention_grouped": 2}),
+    ("lfm2-24b-a2b.pretrain-seq8k",
+     {"gated_conv": 15, "flash_attention_grouped": 2}),
+], ids=["nemotron", "lfm2"])
+def test_a_scan_or_convolution_cells_step_launches_what_its_parent_did(
+        one_chip, monkeypatch, cell, want):
+    """The one policy gained a name that no segment of these steps holds:
+    their traced steps launch the state-space scan (forward, run again,
+    backward: its outputs are NOT kept, ROADMAP S19(c)) and the gated
+    convolution as often as at PR 47, and count nothing kept for KDA."""
+    from paddle_tpu.ops.pallas import counters
+
+    step, args = _cell_step(cell, one_chip, monkeypatch)
+    calls = collections.Counter(
+        p["name"] for p in _pallas_calls_in(step.trace(*args).jaxpr))
+    assert {role: calls[role] for role in want} == want
+    assert "kda_chunk_fwd" not in calls
+    assert not any(k.startswith("kda_chunk") for k in counters.snapshot())
