@@ -518,6 +518,36 @@ def _kernel_checks():
         flash_causal("grouped, heads of 64", (1, 8192, 8, 64),
                      (1, 8192, 2, 64), (1, 8192, 2, 64))))
 
+    # sixteen query heads on two key heads of 256 (PR 49): the widest
+    # head the dispatch admits. The XLA reference keeps (heads, 8192,
+    # 8192) scores, so it runs a key head's group at a time
+    def flash_heads_of_256(fails, label="grouped 16q/2kv, heads of 256"):
+        q, k, v = (rnd(s, (1, 8192, h, 256), bf16)
+                   for s, h in ((1, 16), (2, 2), (3, 2)))
+        w = rnd(4, (1, 8192, 16, 256), f32)
+        if not fa._pallas_ok(q, k, True, v=v):
+            fails.append(f"flash stream {label}: outside its gate")
+            return
+
+        def run(f, q, k, v, w):
+            out, grads = jax.jit(jax.value_and_grad(
+                lambda q, k, v: (lambda o: (jnp.sum(o.astype(f32) * w), o))(
+                    f(q, k, v)), argnums=(0, 1, 2), has_aux=True))(q, k, v)
+            return (out[1],) + grads
+
+        got = run(lambda q, k, v: fa._flash_attention_pallas(
+            q, k, v, causal=True), q, k, v, w)
+        halves = [run(lambda q, k, v: fa._xla_attention(
+            q, k, v, None, 0.0, True, None), q[:, :, 8 * n:8 * n + 8],
+            k[:, :, n:n + 1], v[:, :, n:n + 1], w[:, :, 8 * n:8 * n + 8])
+            for n in range(2)]
+        for g, nm, parts in zip(got, ("out", "dq", "dk", "dv"),
+                                zip(*halves)):
+            _close(f"flash stream {label} {nm}", g,
+                   jnp.concatenate(parts, axis=2), tol_of(bf16), fails)
+    checks.append(("flash stream grouped 16q/2kv (1, 8192, ., 256)",
+                   flash_heads_of_256))
+
     def flash_masked(fails, shape=(8, 512, 12, 64)):
         b, l = shape[:2]    # ragged key-padding: row i keeps l - 37 i keys
         keep = jnp.arange(l)[None, :] < l - 37 * jnp.arange(b)[:, None]
@@ -1042,6 +1072,72 @@ def _kernel_checks():
 
     kda_block_recomputed()
 
+    # -- a Gated DeltaNet mixer at the Qwen3-Next cell's shapes (PR 49): the
+    # convolution stage on 16 key heads, the chunk kernels' scalar-decay
+    # form under 32 value heads, the gated norm with SiLU, against the
+    # same layer on the XLA forms; decays from the family's start (up to
+    # 21 a token) ------------------------------------------------------------
+    def gdn_mixer(b=1, t=8192, hidden=2048, hk=16, hv=32, d=128):
+        name = f"gdn mixer ({b}, {t}, {hidden}), {hk}k/{hv}v heads of {d}"
+
+        def check(fails):
+            import paddle_tpu as paddle
+            import paddle_tpu.framework.bringup as bringup
+            from paddle_tpu import nn
+            from paddle_tpu.framework import tape
+            from paddle_tpu.framework.tensor import Tensor
+            from paddle_tpu.ops.pallas import counters
+
+            paddle.seed(0)
+            mixer = nn.GatedDeltaNet(hidden, hk, hv, d, d)
+            params = list(mixer.parameters())
+            values = [p.value for p in params]
+            x, w = rnd(1, (b, t, hidden)), rnd(2, (b, t, hidden))
+
+            def loss(xv, pv):
+                try:
+                    for p, v in zip(params, pv):
+                        p._value = v
+                    with tape.no_grad():
+                        y = mixer(Tensor(xv))
+                finally:
+                    for p, v in zip(params, values):
+                        p._value = v
+                return jnp.sum(y.value * w)
+
+            def run():
+                return jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(
+                    x, values)
+
+            before = counters.snapshot()
+            lk, got = run()
+            seen = counters.delta(before)
+            if seen != {"kda_stage.fused": 2, "gdn.scalar_decay": 1,
+                        "kda_chunk.pallas": 1,
+                        f"kda_chunk.heads{_heads(hv, d)}": 1}:
+                fails.append(f"{name}: outside its gate ({seen})")
+                return
+            gate = bringup.pallas_enabled
+            bringup.pallas_enabled = lambda: False
+            try:
+                lx, want = run()
+            finally:
+                bringup.pallas_enabled = gate
+            _close(f"{name} loss", lk, lx, tol_of(f32), fails)
+            for a, r, nm in zip(
+                    jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want),
+                    ["x"] + [n for n, _ in mixer.named_parameters()]):
+                _close(f"{name} d{nm}", a, r, tol_of(f32), fails)
+        checks.append((name, check))
+
+    def _heads(h, d):
+        from paddle_tpu.ops.pallas import kda
+
+        return kda._heads_a_step(h, d, d, kda.CHUNK)
+
+    gdn_mixer()
+
     # -- the gated short convolution's fused stage against its float32
     # formula, at the LFM2 cell's shapes -------------------------------------
     def gated_conv_stage(b=2, t=8192, d=2048, taps=3, dtype=bf16):
@@ -1174,6 +1270,12 @@ def _kernel_checks():
             num_experts=128, top_k=6, scaling=2.5, plain=True)
     experts(8, bf16, {0: 49152, 6: 131072}, t=16384, d=2688, f=1856,
             num_experts=128, top_k=6, scaling=2.5, plain=True, highest=True)
+    # the Qwen3-Next cell's share at its own shapes (PR 49): 32 held of
+    # top 10 in 512 is more than twice the picks, so there is no dense
+    # rung: both sorted rungs, 40,960 and 81,920 rows, on the grouped
+    # kernels, 32 groups of width 512
+    experts(32, bf16, {0: 40960, 10: 81920}, d=2048, f=512,
+            num_experts=512, top_k=10, scaling=1.0, score_func="softmax")
 
     # -- fused embedding bag --------------------------------------------------
     def bag(vocab, d, b, s, dtype, combiner):

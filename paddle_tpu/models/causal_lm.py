@@ -41,6 +41,19 @@ kind registered there.
             scale (``log_scaling_alpha``) is refused by that key.
             A pattern's ``*`` is a full layer with no per-head norms and
             no positions unless the file gives a ``rope`` entry
+            with ``full_attention_interval`` n (and the five
+            ``linear_*`` keys; refused without ``linear_num_value_heads``):
+            every n-th layer -> ``gqa``, the others -> ``gdn``
+            (``nn.GatedDeltaNet``: ``linear_num_key_heads`` heads of
+            ``linear_key_head_dim`` under ``linear_num_value_heads`` of
+            ``linear_value_head_dim``, ``linear_conv_kernel_dim`` taps).
+            Such a file's attention is a full layer with its positions
+            from the top-level ``rope_theta``, on the first
+            ``partial_rotary_factor`` of each head only where it has
+            that key (a pattern's file keeps the key read by nothing:
+            its attention takes ``rope`` alone), and with
+            ``attn_output_gate`` a sigmoid gate from a doubled ``q_proj``
+            on the attention's output
             else ``linear_attn_config.kda_layers`` (1-based) -> ``kda``
             (``nn.KimiDeltaAttention``); every other layer -> ``mla``
             (``nn.MLAttention``: NoPE where the file says
@@ -54,7 +67,9 @@ kind registered there.
             ``num_dense_layers`` layers -> ``dense``;
             the others -> ``moe`` where the file counts experts
             (``num_experts`` or ``n_routed_experts``) and ``dense``
-            where it counts none
+            where it counts none; a file with ``mlp_only_layers``
+            (0-based) or ``decoder_sparse_step`` s: ``dense`` on the
+            listed layers and on every layer that is no s-th one
             ``dense``: ``nn.GatedFFN`` of ``intermediate_size``
             (``hidden_act``, SiLU for a file without the key), or for a
             config that says
@@ -64,7 +79,10 @@ kind registered there.
             ``n_routed_experts``; ``num_shared_experts`` or
             ``n_shared_experts`` shared (of
             ``moe_shared_expert_intermediate_size`` where the file says
-            it); experts gated, or plain relu^2 ones for a config that
+            it), or for a file with ``shared_expert_intermediate_size``
+            ONE shared expert of that width under its sigmoid gate
+            (``shared_gate``); experts gated, or plain relu^2 ones for
+            a config that
             says ``mlp_hidden_act``; scores by
             ``moe_router_activation_func`` or ``scoring_func``, or
             softmax for a config that has neither key and says
@@ -82,7 +100,11 @@ kind registered there.
             of its own
 
 The norms' epsilon is ``rms_norm_eps``, ``layer_norm_epsilon`` or
-``norm_eps``; a file with none of the three is refused.
+``norm_eps``; a file with none of the three is refused. With
+``zero_centered_norm`` every block norm, the final norm and the
+attention's per-head norms scale by ``1 + w``, ``w`` started at zero
+(``nn.RMSNorm(zero_centered=True)``; a linear mixer's output norm keeps
+the ordinary scale).
 A chip's share of an expert-parallel deployment is said with
 ``experts_held`` / ``expert_offset`` (the router keeps all its outputs).
 ``loss`` goes through the fused vocabulary cross-entropy, so the
@@ -136,6 +158,10 @@ def _first(cfg, *names, default=None):
 _EPS_KEYS = ("rms_norm_eps", "layer_norm_epsilon", "norm_eps")
 
 
+def _zero_centered(cfg):
+    return bool(cfg.get("zero_centered_norm", False))
+
+
 def _eps(cfg):
     eps = _first(cfg, *_EPS_KEYS)
     if eps is None:
@@ -187,22 +213,34 @@ def _gqa_rope(cfg, kind):
 
 
 def _mixer_gqa(cfg, layer):
+    heads = cfg["num_attention_heads"]
+    head_dim = cfg.get("head_dim", cfg["hidden_size"] // heads)
+    rotary_dim = None
     if "hybrid_override_pattern" in cfg:
         # nemotron_h's attention: full, no per-head norms, and no
         # positions (the state-space layers carry them) unless the file
-        # gives a ``rope`` entry
+        # gives a ``rope`` entry; its ``partial_rotary_factor`` is read
+        # by nothing
         kind, rope, qk_norm = ("full_attention", cfg.get("rope"),
                                cfg.get("qk_norm", False))
     else:
-        kind = cfg["layer_types"][layer - 1]
+        kind = "full_attention" if "full_attention_interval" in cfg \
+            else cfg["layer_types"][layer - 1]
         rope, qk_norm = _gqa_rope(cfg, kind), cfg.get("qk_norm", True)
-    heads = cfg["num_attention_heads"]
+        if "partial_rotary_factor" in cfg:
+            if "rope_parameters" in cfg:
+                raise NotImplementedError(
+                    "partial_rotary_factor beside rope_parameters: which "
+                    "of the two states the rotated part is not read")
+            rotary_dim = int(cfg["partial_rotary_factor"] * head_dim)
     return nn.GroupedQueryAttention(
         cfg["hidden_size"], heads, cfg.get("num_key_value_heads", heads),
-        cfg.get("head_dim", cfg["hidden_size"] // heads),
+        head_dim,
         window=cfg["sliding_window"] if kind == "sliding_attention"
         else None,
-        rope=rope, qk_norm=qk_norm, epsilon=_eps(cfg))
+        rope=rope, qk_norm=qk_norm, epsilon=_eps(cfg),
+        output_gate=bool(cfg.get("attn_output_gate", False)),
+        rotary_dim=rotary_dim, zero_centered_norm=_zero_centered(cfg))
 
 
 def _mixer_conv(cfg, layer):
@@ -225,6 +263,14 @@ def _mixer_mamba2(cfg, layer):
         time_step=(cfg.get("time_step_min", 1e-3),
                    cfg.get("time_step_max", 1e-1)),
         time_step_floor=cfg.get("time_step_floor", 1e-4))
+
+
+def _mixer_gdn(cfg, layer):
+    return nn.GatedDeltaNet(
+        cfg["hidden_size"], cfg["linear_num_key_heads"],
+        cfg["linear_num_value_heads"], cfg["linear_key_head_dim"],
+        cfg["linear_value_head_dim"],
+        conv_size=cfg["linear_conv_kernel_dim"], epsilon=_eps(cfg))
 
 
 def _mixer_kda(cfg, layer):
@@ -281,6 +327,15 @@ def _ffn_moe(cfg):
     shared = _first(cfg, "num_shared_experts", "n_shared_experts",
                     default=0) * cfg.get(
         "moe_shared_expert_intermediate_size", width)
+    # a file with this key has ONE shared expert of that width, and a
+    # sigmoid gate on its output
+    shared_gate = "shared_expert_intermediate_size" in cfg
+    if shared_gate:
+        if shared:
+            raise NotImplementedError(
+                "shared_expert_intermediate_size beside a count of shared "
+                "experts")
+        shared = cfg["shared_expert_intermediate_size"]
     # a file with ``mlp_hidden_act`` has plain experts, relu(x U)^2 D
     plain = "mlp_hidden_act" in cfg
     if plain and cfg["mlp_hidden_act"] != "relu2":
@@ -294,11 +349,12 @@ def _ffn_moe(cfg):
         scaling=cfg.get("routed_scaling_factor", 1.0),
         renormalize=cfg.get("moe_renormalize",
                             cfg.get("norm_topk_prob", True)),
-        shared_width=shared or None, score_func=score, gated=not plain)
+        shared_width=shared or None, score_func=score, gated=not plain,
+        shared_gate=shared_gate)
 
 
-MIXERS = {"conv": _mixer_conv, "gqa": _mixer_gqa, "kda": _mixer_kda,
-          "mamba2": _mixer_mamba2, "mla": _mixer_mla}
+MIXERS = {"conv": _mixer_conv, "gdn": _mixer_gdn, "gqa": _mixer_gqa,
+          "kda": _mixer_kda, "mamba2": _mixer_mamba2, "mla": _mixer_mla}
 FFNS = {"dense": _ffn_dense, "moe": _ffn_moe}
 
 
@@ -325,6 +381,12 @@ def mixer_kind(cfg, layer: int):
                 f"layer_types entry {kind!r}: {sorted(_LAYER_TYPES)} are "
                 "built")
         return _LAYER_TYPES[kind]
+    if "full_attention_interval" in cfg:
+        if "linear_num_value_heads" not in cfg:
+            raise NotImplementedError(
+                "full_attention_interval without linear_num_value_heads: "
+                "which mixer the other layers have is not said")
+        return "gdn" if layer % cfg["full_attention_interval"] else "gqa"
     lin = cfg.get("linear_attn_config") or {}
     return "kda" if layer in lin.get("kda_layers", ()) else "mla"
 
@@ -339,7 +401,9 @@ def ffn_kind(cfg, layer: int):
     dense = layer <= _first(cfg, "first_k_dense_replace",
                             "num_dense_layers", default=0) \
         or _first(cfg, "num_experts", "n_routed_experts") is None \
-        or (layer - 1) % cfg.get("moe_layer_freq", 1) != 0
+        or (layer - 1) % cfg.get("moe_layer_freq", 1) != 0 \
+        or layer - 1 in cfg.get("mlp_only_layers", ()) \
+        or layer % cfg.get("decoder_sparse_step", 1) != 0
     return "dense" if dense else "moe"
 
 
@@ -351,24 +415,29 @@ class DecoderBlock(nn.Layer):
 
     def __init__(self, cfg, layer: int):
         super().__init__()
-        eps, hidden = _eps(cfg), cfg["hidden_size"]
+        hidden = cfg["hidden_size"]
+
+        def norm():
+            return nn.RMSNorm(hidden, epsilon=_eps(cfg),
+                              zero_centered=_zero_centered(cfg))
+
         self.mixer_kind, self.ffn_kind = (mixer_kind(cfg, layer),
                                           ffn_kind(cfg, layer))
         self.sandwich = bool(cfg.get("sandwich_norm", False))
         if self.mixer_kind and self.ffn_kind:
-            self.input_norm = nn.RMSNorm(hidden, epsilon=eps)
+            self.input_norm = norm()
             self.mixer = MIXERS[self.mixer_kind](cfg, layer)
             if self.sandwich:
-                self.mixer_out_norm = nn.RMSNorm(hidden, epsilon=eps)
-            self.post_norm = nn.RMSNorm(hidden, epsilon=eps)
+                self.mixer_out_norm = norm()
+            self.post_norm = norm()
             self.ffn = FFNS[self.ffn_kind](cfg)
             if self.sandwich:
-                self.ffn_out_norm = nn.RMSNorm(hidden, epsilon=eps)
+                self.ffn_out_norm = norm()
         else:
             if self.sandwich:
                 raise NotImplementedError(
                     "sandwich_norm in a block of one sublayer")
-            self.norm = nn.RMSNorm(hidden, epsilon=eps)
+            self.norm = norm()
             if self.mixer_kind:
                 self.mixer = MIXERS[self.mixer_kind](cfg, layer)
             else:
@@ -411,7 +480,8 @@ class CausalLM(nn.Layer):
         self.layers = nn.LayerList([
             DecoderBlock(cfg, n + 1)
             for n in range(cfg["num_hidden_layers"])])
-        self.final_norm = nn.RMSNorm(cfg["hidden_size"], epsilon=_eps(cfg))
+        self.final_norm = nn.RMSNorm(cfg["hidden_size"], epsilon=_eps(cfg),
+                                     zero_centered=_zero_centered(cfg))
         # (vocabulary, hidden): the layout the fused cross-entropy
         # streams, and the embedding's: a tied head IS ``embed.weight``
         self.tied = bool(cfg.get("tie_word_embeddings", False))

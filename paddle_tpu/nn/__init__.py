@@ -48,7 +48,7 @@ from .clip import ClipGradByValue, ClipGradByNorm, ClipGradByGlobalNorm  # noqa:
 from .moe import (  # noqa: F401
     MoELayer, SparseMoELayer, moe_apply_ep, MOE_EP_RULES,
 )
-from .linear_attention import KimiDeltaAttention  # noqa: F401
+from .linear_attention import GatedDeltaNet, KimiDeltaAttention  # noqa: F401
 from .grouped_query_attention import GroupedQueryAttention  # noqa: F401
 from .state_space import Mamba2Mixer  # noqa: F401
 from .gated_conv import GatedShortConv  # noqa: F401

@@ -764,7 +764,10 @@ class SparseMoELayer(Layer):
     ``expert_offset``; with all of them held this is the whole layer. A
     share's output leaves out what the absent experts would have added;
     ``shared_width`` adds one always-on expert, of that width and the
-    routed experts' form, that every share computes alike. ``gated``
+    routed experts' form, that every share computes alike; with
+    ``shared_gate`` its output is multiplied by ``sigmoid(x w_s)``, ``w_s``
+    a ``(d_model, 1)`` leaf without bias (counter ``moe.shared_gate`` once
+    a build). ``gated``
     experts are ``(silu(x G) * x U) D`` (``nn.GatedFFN``), the others
     plain, ``relu(x U)^2 D`` (``nn.PlainFFN``) with no ``experts_gate``.
     ``forward`` keeps ``[pairs on
@@ -774,8 +777,9 @@ class SparseMoELayer(Layer):
     def __init__(self, d_model, d_expert, num_experts, top_k,
                  experts_held=None, expert_offset=0, scaling=1.0,
                  renormalize=True, shared_width=None, score_func="sigmoid",
-                 gated=True):
+                 gated=True, shared_gate=False):
         super().__init__()
+        from ..ops.pallas.counters import bump
         from .common import GatedFFN, Linear, PlainFFN
 
         held = num_experts if experts_held is None else int(experts_held)
@@ -800,6 +804,12 @@ class SparseMoELayer(Layer):
         self.experts_down = self.create_parameter([held, d_expert, d_model])
         self.shared = (GatedFFN if gated else PlainFFN)(
             d_model, shared_width) if shared_width else None
+        if shared_gate and self.shared is None:
+            raise ValueError("shared_gate on a layer with no shared expert")
+        self.shared_gate = Linear(d_model, 1, bias_attr=False) \
+            if shared_gate else None
+        if shared_gate:
+            bump("moe", "shared_gate")
         self.last_routing = None
 
     def forward(self, x):
@@ -813,4 +823,11 @@ class SparseMoELayer(Layer):
             expert_offset=self.expert_offset, scaling=self.scaling,
             renormalize=self.renormalize, score_func=self.score_func)
         out = ops.reshape(routed, list(shape))
-        return out if self.shared is None else out + self.shared(x)
+        if self.shared is None:
+            return out
+        shared = self.shared(x)
+        if self.shared_gate is not None:
+            from . import functional as F
+
+            shared = F.sigmoid(self.shared_gate(x)) * shared
+        return out + shared

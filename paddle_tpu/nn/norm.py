@@ -146,18 +146,24 @@ class LayerNorm(Layer):
 
 
 class RMSNorm(Layer):
-    """x / rms(x) * weight over the last axis; no mean, no bias."""
+    """x / rms(x) * weight over the last axis; no mean, no bias. With
+    ``zero_centered`` the scale is ``1 + weight`` and ``weight`` starts
+    at zero: weight decay then pulls the scale to 1 and not to 0 (a
+    different trained model, not another spelling of the same one)."""
 
     def __init__(self, hidden_size, epsilon=1e-6, weight_attr=None,
-                 name=None):
+                 name=None, zero_centered=False):
         super().__init__()
         self._epsilon = epsilon
+        self._zero_centered = bool(zero_centered)
         self.weight = self.create_parameter(
             [int(hidden_size)], attr=weight_attr,
-            default_initializer=I.Constant(1.0))
+            default_initializer=I.Constant(
+                0.0 if self._zero_centered else 1.0))
 
     def forward(self, x):
-        return F.rms_norm(x, self.weight, self._epsilon)
+        scale = self.weight + 1.0 if self._zero_centered else self.weight
+        return F.rms_norm(x, scale, self._epsilon)
 
 
 class GroupNorm(Layer):

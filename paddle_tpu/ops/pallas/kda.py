@@ -27,6 +27,14 @@ overflows under strong decay: rows are taken ``_SUB`` at a time, each
 group against the cumulative decay just before its first row, so every
 exponent is either non-positive or spans less than one group.
 
+A decay that is one SCALAR a head (the same in all K channels: Gated
+DeltaNet's) is a second static form of the same body (``scalar=True``):
+then ``e^(G_r - G_i)`` is one C x C matrix a head and is taken as it
+stands, every exponent non-positive, so a decay of any strength is exact
+(the groups' reference decay holds only while a group's span stays under
+e^80, 5 a token; a scalar decay started at A = 16, dt = softplus(1)
+falls by 21 a token). Everything else of a chunk is the same lines.
+
 The backward walks the chunks in reverse with the state's cotangent as
 its carry; it reads the state each chunk started from (saved by the
 forward, ``C`` times smaller than per-token states) and recomputes the
@@ -159,10 +167,28 @@ def _intra(q, k, gc):
     return p, m, parts
 
 
-def _chunk_fwd(q, k, v, gc, beta, st):
+def _intra_scalar(q, k, gc):
+    """:func:`_intra` for a decay that is the same in every channel of
+    the head (``gc``'s columns are equal): P and M from ONE product and
+    the C x C matrix e^(G_r - G_i), no exponent above zero. Returns (p,
+    m, (a_p, a_m, d)): the undecayed products and the decays, for the
+    backward."""
+    c = q.shape[0]
+    span = jnp.minimum(gc[:, :1] - gc.T[:1], 0.0)             # (C, C)
+    d = jnp.exp(span)
+    a = _dot(jnp.concatenate([q, k], axis=0), k, trans_b=True)  # (2C, C)
+    r, i = _iota((c, c), 0), _iota((c, c), 1)
+    p = jnp.where(r >= i, a[:c] * d, 0.0)
+    m = jnp.where(r > i, a[c:] * d, 0.0)
+    return p, m, (a[:c], a[c:], d)
+
+
+def _chunk_fwd(q, k, v, gc, beta, st, scalar=False):
     """One chunk of one head. q, k, gc: (C, K); v: (C, V); beta: (1, C);
-    st: the state TRANSPOSED, (V, K). Returns (o (C, V), next state)."""
-    p, m, _ = _intra(q, k, gc)
+    st: the state TRANSPOSED, (V, K). Returns (o (C, V), next state).
+    ``scalar``: the decay is one scalar a token (``gc``'s columns are
+    equal)."""
+    p, m, _ = (_intra_scalar if scalar else _intra)(q, k, gc)
     gam = jnp.exp(gc)
     u = _dot(_unit_lower_inverse(m * beta),
              v - _dot(k * gam, st, trans_b=True))
@@ -172,10 +198,12 @@ def _chunk_fwd(q, k, v, gc, beta, st):
     return o, st_next
 
 
-def _chunk_bwd(q, k, v, gc, beta, st, do, dst_next):
-    """Cotangents of one chunk: (dq, dk, dv, dgc, dbeta (1, C), dst)."""
+def _chunk_bwd(q, k, v, gc, beta, st, do, dst_next, scalar=False):
+    """Cotangents of one chunk: (dq, dk, dv, dgc, dbeta (1, C), dst).
+    With ``scalar`` the decay's cotangent is the sum of ``dgc`` over the
+    head's channels (how it is spread over them is no one's to read)."""
     c = q.shape[0]
-    p, m, parts = _intra(q, k, gc)
+    p, m, parts = (_intra_scalar if scalar else _intra)(q, k, gc)
     gam = jnp.exp(gc)
     qt, kt = q * gam, k * gam
     inv = _unit_lower_inverse(m * beta)
@@ -212,8 +240,24 @@ def _chunk_bwd(q, k, v, gc, beta, st, do, dst_next):
     dlast = dlast + jnp.sum(dkbar_g, axis=0, keepdims=True)
     row = _iota((c, 1), 0)
     dgc = dgc + jnp.where(row == c - 1, dlast, 0.0)
-    # P and M, a group of rows at a time
     dp, dm = dpb * beta, dmb * beta
+    if scalar:
+        # P = a_p d and M = a_m d (under their masks, which dp and dm
+        # already carry), d = e^(G_r - G_i)
+        a_p, a_m, d = parts
+        both = jnp.concatenate([q, k], axis=0)
+        da = jnp.concatenate([dp * d, dm * d], axis=0)         # (2C, C)
+        dboth = _dot(da, k)
+        dq = dq + dboth[:c]
+        dk = dk + dboth[c:] + _dot(da.T, both)
+        # G_r takes its row's sum of d's cotangent, G_i gives its
+        # column's: as products with ones, which leave each sum in every
+        # channel of the head; a K-th of it each
+        dspan = (dp * a_p + dm * a_m) * d
+        ones = jnp.full_like(gc, 1.0 / gc.shape[1])
+        dgc = dgc + _dot(dspan, ones) - _dot(dspan.T, ones)
+        return dq, dk, dr, dgc, dbeta, dst
+    # P and M, a group of rows at a time
     dq_rows, dk_rows, dg_rows = [], [], []
     for lo, hi, e, f, both in parts:
         da = jnp.concatenate([dp[lo:hi], dm[lo:hi]], axis=0)   # (2 sub, C)
@@ -268,10 +312,15 @@ def _beta_from_rows(rows):
 _heads = functools.partial(jax.vmap, in_axes=0)
 
 
-def _xla_fwd(q, k, v, gc, beta, chunk):
+def _form(fn, scalar):
+    """The chunk formula ``fn`` in its scalar-decay form, or as it is."""
+    return functools.partial(fn, scalar=True) if scalar else fn
+
+
+def _xla_fwd(q, k, v, gc, beta, chunk, scalar=False):
     b, _, h = beta.shape
     kd, vd = q.shape[-1] // h, v.shape[-1] // h
-    step = _heads(_heads(_chunk_fwd))
+    step = _heads(_heads(_form(_chunk_fwd, scalar)))
 
     def body(st, xs):
         o, st_next = step(*xs, st)
@@ -283,10 +332,10 @@ def _xla_fwd(q, k, v, gc, beta, chunk):
     return _from_chunks(o), states
 
 
-def _xla_bwd(q, k, v, gc, beta, states, do, chunk):
+def _xla_bwd(q, k, v, gc, beta, states, do, chunk, scalar=False):
     b, _, h = beta.shape
     kd, vd = q.shape[-1] // h, v.shape[-1] // h
-    step = _heads(_heads(_chunk_bwd))
+    step = _heads(_heads(_form(_chunk_bwd, scalar)))
 
     def body(dst, xs):
         dq, dk, dv, dgc, dbeta, dst = step(*xs, dst)
@@ -341,7 +390,8 @@ def _to_heads(ref, x):
         ref[:, n * d:(n + 1) * d] = x[n]
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, st_ref, carry):
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, st_ref, carry,
+                *, scalar=False):
     from jax.experimental import pallas as pl
 
     @pl.when(pl.program_id(2) == 0)
@@ -353,7 +403,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, st_ref, carry):
     st_ref[...] = st
     # the chunk formulas on a leading axis of G heads, as the XLA form
     # runs them: every product is G independent ones, back to back
-    o, st_next = _heads(_chunk_fwd)(
+    o, st_next = _heads(_form(_chunk_fwd, scalar))(
         _heads_of(q_ref, heads), _heads_of(k_ref, heads),
         _heads_of(v_ref, heads), _heads_of(g_ref, heads), b_ref[...], st)
     _to_heads(o_ref, o)
@@ -361,7 +411,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, st_ref, carry):
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, st_ref, do_ref,
-                dq_ref, dk_ref, dv_ref, dg_ref, db_ref, carry):
+                dq_ref, dk_ref, dv_ref, dg_ref, db_ref, carry, *,
+                scalar=False):
     from jax.experimental import pallas as pl
 
     @pl.when(pl.program_id(2) == 0)
@@ -369,7 +420,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, st_ref, do_ref,
         carry[...] = jnp.zeros_like(carry)
 
     heads = carry.shape[0]
-    dq, dk, dv, dgc, dbeta, dst = _heads(_chunk_bwd)(
+    dq, dk, dv, dgc, dbeta, dst = _heads(_form(_chunk_bwd, scalar))(
         _heads_of(q_ref, heads), _heads_of(k_ref, heads),
         _heads_of(v_ref, heads), _heads_of(g_ref, heads), b_ref[...],
         st_ref[...], _heads_of(do_ref, heads), carry[...])
@@ -412,8 +463,8 @@ def _compiler_params():
 # on the arrays as the kernel takes them: with the reshapes around it
 # inside too, XLA formed other fusions round the kernels in a recomputed
 # block and the Kimi step's other operations cost 10 ms more (PR 38)
-@jax.jit
-def _launch_fwd(q, k, v, gc, rows):
+@functools.partial(jax.jit, static_argnames=("scalar",))
+def _launch_fwd(q, k, v, gc, rows, scalar=False):
     from jax.experimental.pallas import tpu as pltpu
 
     b, t, _ = q.shape
@@ -422,7 +473,8 @@ def _launch_fwd(q, k, v, gc, rows):
     heads = _heads_a_step(h, kd, vd, chunk)
     keys, vals, row, state = _specs(heads, kd, vd, chunk, nc, reverse=False)
     return kernel_call(
-        "kda_chunk_fwd", _fwd_kernel, grid=(b, h // heads, nc),
+        "kda_chunk_fwd", _form(_fwd_kernel, scalar),
+        grid=(b, h // heads, nc),
         in_specs=[keys, keys, vals, keys, row],
         out_specs=[vals, state],
         out_shape=[_sds((b, t, h * vd), _F32, q),
@@ -432,8 +484,8 @@ def _launch_fwd(q, k, v, gc, rows):
     )(q, k, v, gc, rows)
 
 
-@jax.jit
-def _launch_bwd(q, k, v, gc, rows, states, do):
+@functools.partial(jax.jit, static_argnames=("scalar",))
+def _launch_bwd(q, k, v, gc, rows, states, do, scalar=False):
     from jax.experimental.pallas import tpu as pltpu
 
     b, t, _ = q.shape
@@ -442,7 +494,8 @@ def _launch_bwd(q, k, v, gc, rows, states, do):
     heads = _heads_a_step(h, kd, vd, chunk)
     keys, vals, row, state = _specs(heads, kd, vd, chunk, nc, reverse=True)
     return kernel_call(
-        "kda_chunk_bwd", _bwd_kernel, grid=(b, h // heads, nc),
+        "kda_chunk_bwd", _form(_bwd_kernel, scalar),
+        grid=(b, h // heads, nc),
         in_specs=[keys, keys, vals, keys, row, state, vals],
         out_specs=[keys, keys, vals, keys, row],
         out_shape=[_sds((b, t, h * kd), _F32, q),
@@ -455,13 +508,13 @@ def _launch_bwd(q, k, v, gc, rows, states, do):
     )(q, k, v, gc, rows, states, do)
 
 
-def _pallas_fwd(q, k, v, gc, beta, chunk):
-    return _launch_fwd(q, k, v, gc, _beta_rows(beta, chunk))
+def _pallas_fwd(q, k, v, gc, beta, chunk, scalar=False):
+    return _launch_fwd(q, k, v, gc, _beta_rows(beta, chunk), scalar=scalar)
 
 
-def _pallas_bwd(q, k, v, gc, beta, states, do, chunk):
+def _pallas_bwd(q, k, v, gc, beta, states, do, chunk, scalar=False):
     *tokens, dbeta = _launch_bwd(q, k, v, gc, _beta_rows(beta, chunk),
-                                 states, do)
+                                 states, do, scalar=scalar)
     return (*tokens, _beta_from_rows(dbeta))
 
 
@@ -493,68 +546,103 @@ def _cumulate(g, chunk, reverse=False):
                       preferred_element_type=_F32).reshape(g.shape)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _chunk_kda(q, k, v, g, beta, chunk, kernel, record=False):
-    return _chunk_kda_fwd(q, k, v, g, beta, chunk, kernel, record)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _chunk_kda(q, k, v, g, beta, chunk, kernel, record=False, scalar=False):
+    return _chunk_kda_fwd(q, k, v, g, beta, chunk, kernel, record,
+                          scalar)[0]
 
 
-def _chunk_kda_fwd(q, k, v, g, beta, chunk, kernel, record):
+def _chunk_kda_fwd(q, k, v, g, beta, chunk, kernel, record, scalar):
     """``record`` (a step built under FLAGS_check_nan_inf): the result
     is (o, the ``nan_inf.row`` of the states the chunks started from),
     which no probe outside this rule can reach. What the kernel wrote is
     named :data:`KEPT` (before the record reads it); the XLA form names
     nothing, and ``gc`` is a segment's to compute again."""
     gc = _cumulate(g, chunk)
-    o, states = _kept(_pallas_fwd(q, k, v, gc, beta, chunk)) if kernel \
-        else _xla_fwd(q, k, v, gc, beta, chunk)
+    o, states = _kept(_pallas_fwd(q, k, v, gc, beta, chunk, scalar)) \
+        if kernel else _xla_fwd(q, k, v, gc, beta, chunk, scalar)
     return ((o, nan_inf.row(states)) if record else o,
             (q, k, v, gc, beta, states))
 
 
-def _chunk_kda_bwd(chunk, kernel, record, res, do):
+def _chunk_kda_bwd(chunk, kernel, record, scalar, res, do):
     if record:
         do, _ = do
     q, k, v, gc, beta, states = res
     dq, dk, dv, dgc, dbeta = (_pallas_bwd if kernel else _xla_bwd)(
-        q, k, v, gc, beta, states, do, chunk)
+        q, k, v, gc, beta, states, do, chunk, scalar)
     return dq, dk, dv, _cumulate(dgc, chunk, reverse=True), dbeta
 
 
 _chunk_kda.defvjp(_chunk_kda_fwd, _chunk_kda_bwd)
 
 
-def kda_work(b, t, h, kd, vd):
+def kda_work(b, t, h, kd, vd, key_heads=None, scalar_decay=False):
     """``work=`` / ``grad_work=`` of one call: the recurrence's own
     operations (decay, read, rank-one write and query of a K x V state:
     6 K V a token and head; twice that backward) and the bytes it cannot
     avoid — q, k, v, g (float32) and beta read and o written once; the
-    same again plus o's cotangent read and five cotangents written."""
-    tokens = b * t * h
-    moved = 4 * tokens * (3 * kd + 2 * vd + 1)
+    same again plus o's cotangent read and five cotangents written. q and
+    k count once a KEY head (``key_heads``, where fewer than ``h``) and a
+    scalar decay as one float a token and head: the recurrence's work,
+    whatever the launch is handed."""
+    tokens = b * t
+    kh = h if key_heads is None else key_heads
+    moved = 4 * tokens * (2 * kh * kd + h * (1 if scalar_decay else kd)
+                          + h * (2 * vd + 1))
+    flops = 6.0 * tokens * h * kd * vd
     return {
-        "work": {"kda_chunk_fwd": (6.0 * tokens * kd * vd, moved)},
-        "grad_work": {"kda_chunk_bwd": (
-            12.0 * tokens * kd * vd,
-            2 * moved)}}
+        "work": {"kda_chunk_fwd": (flops, moved)},
+        "grad_work": {"kda_chunk_bwd": (2 * flops, 2 * moved)}}
 
 
-def chunk_kda_flat(q, k, v, g, beta, chunk=CHUNK):
+def _to_value_heads(x, key_heads, heads):
+    """(B, T, key_heads * K) -> (B, T, heads * K): value head j reads
+    key head j // (heads / key_heads)."""
+    b, t, width = x.shape
+    kd = width // key_heads
+    return jnp.broadcast_to(
+        x.reshape(b, t, key_heads, 1, kd),
+        (b, t, key_heads, heads // key_heads, kd)).reshape(b, t, heads * kd)
+
+
+def chunk_kda_flat(q, k, v, g, beta, chunk=CHUNK, key_heads=None):
     """Gated delta rule over each row of a batch from a zero state, on
     the projections' own layout: q, k, g (B, T, H * K); v (B, T, H * V);
     beta (B, T, H), which says how many heads the channels are; ``g`` is
     the per-token, per-channel LOG decay (<= 0). Returns o (B, T, H * V),
     float32. A length that is no multiple of ``chunk`` is padded with
-    tokens that neither write nor decay."""
+    tokens that neither write nor decay.
+
+    Two static forms of the one call. A decay of ``beta``'s shape (B, T,
+    H) is one SCALAR a head (the same in all K channels of it; counted
+    ``gdn.scalar_decay``; the chunk formulas' ``scalar`` form, exact at
+    any strength), and ``key_heads`` says that q and k hold that many
+    heads, each read by ``H / key_heads`` value heads. Both are made the
+    launch's own shapes out here, as broadcasts whose transposes sum the
+    cotangents (``dg`` over a head's channels, ``dq`` and ``dk`` over a
+    key head's readers); the work declared is the recurrence's, with q,
+    k and g at their own sizes."""
     q, k, v, g, beta = (a.astype(_F32) for a in (q, k, v, g, beta))
     b, t, h = beta.shape
-    kd, vd = q.shape[-1] // h, v.shape[-1] // h
+    kh = h if key_heads is None else int(key_heads)
+    if h % kh:
+        raise ValueError(f"{h} value heads are no multiple of {kh} key heads")
+    kd, vd = q.shape[-1] // kh, v.shape[-1] // h
+    scalar_decay = g.shape == beta.shape and kd != 1
+    if scalar_decay:
+        bump("gdn", "scalar_decay")
+        g = jnp.repeat(g, kd, axis=-1)
+    if kh != h:
+        q, k = (_to_value_heads(a, kh, h) for a in (q, k))
     pad = (-t) % chunk
     if pad:
         q, k, v, g, beta = (jnp.pad(a, ((0, 0), (0, pad), (0, 0)))
                             for a in (q, k, v, g, beta))
     kernel = _kernel_takes(kd, vd, chunk)
     if kernel:
-        bump("kda_chunk", "pallas", **kda_work(b, t + pad, h, kd, vd))
+        bump("kda_chunk", "pallas", **kda_work(
+            b, t + pad, h, kd, vd, kh, scalar_decay))
         bump("kda_chunk", f"heads{_heads_a_step(h, kd, vd, chunk)}")
         if in_recomputed():
             bump("kda_chunk", "kept_across_recompute")
@@ -563,7 +651,7 @@ def chunk_kda_flat(q, k, v, g, beta, chunk=CHUNK):
              f"dispatch ineligible ({h} heads of {kd} x {vd}, chunk {chunk}"
              "; backend or 128-lane heads)")
     o = _chunk_kda(q, k, v, g, beta, chunk, kernel,
-                   nan_inf.record is not None)
+                   nan_inf.record is not None, scalar_decay)
     if nan_inf.record is not None:
         o, states_row = o
         nan_inf.probe_row("kda_states", states_row)

@@ -6,7 +6,8 @@ float32 on the chip only.
     before the recurrence   q = L2norm_head(SiLU(ShortConv(x W_q))) / sqrt(D)
                             k = L2norm_head(SiLU(ShortConv(x W_k)))
                             v = SiLU(ShortConv(x W_v))
-    after it                out = RMSNorm_head(o) * weight * sigmoid(gate)
+    after it                out = RMSNorm_head(o) * weight * act(gate)
+                            (act: sigmoid, or SiLU for a mixer that says so)
 
 *Before.* The convolution is ``mamba2_stages``' (its halo, slab and block
 logic; no bias here), told to write float32 — the recurrence's operands
@@ -45,6 +46,9 @@ ROLE_CONV = "kda_conv"
 ROLE_NORM = "kda_gate_norm"
 #: added to the squared length under the L2 normalisation of q and k
 L2_EPS = 1e-6
+#: what the stage after the recurrence may put on its gate: a static
+#: argument of the ONE stage
+GATES = {"sigmoid": jax.nn.sigmoid, "silu": jax.nn.silu}
 
 
 # ---------------------------------------------------------------------------
@@ -81,8 +85,9 @@ def conv_norm_xla(q, k, v, q_taps, k_taps, v_taps, head):
         (q, k, v), (q_taps, k_taps, v_taps), _scales(head)))
 
 
-def norm_gate_xla(o, gate, weight, epsilon):
+def norm_gate_xla(o, gate, weight, epsilon, gate_fn="sigmoid"):
     head = weight.shape[0]
+    act = GATES[gate_fn]
 
     @jax.checkpoint
     def gated(o, gate, weight):
@@ -90,7 +95,7 @@ def norm_gate_xla(o, gate, weight, epsilon):
         o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
                               + epsilon)
         return (o * weight.astype(_F32)).reshape(gate.shape) \
-            * jax.nn.sigmoid(gate.astype(_F32))
+            * act(gate.astype(_F32))
 
     return gated(o, gate, weight)
 
@@ -124,34 +129,44 @@ _conv_fused.defvjp(_conv_fused_fwd, _conv_fused_bwd)
 
 
 # ---------------------------------------------------------------------------
-# RMSNorm of each head x weight x sigmoid(gate)
+# RMSNorm of each head x weight x act(gate)
 # ---------------------------------------------------------------------------
 def _rms(o, epsilon):
     r = jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + epsilon)
     return o * r, r
 
 
-def _norm_fwd_kernel(o_ref, g_ref, w_ref, out_ref, *, head, epsilon):
+def _gate_and_slope(g, gate_fn):
+    """(act(g), act'(g)) of a gate's float32 block."""
+    s = jax.nn.sigmoid(g)
+    if gate_fn == "sigmoid":
+        return s, s * (1.0 - s)
+    return g * s, s * (1.0 + g * (1.0 - s))
+
+
+def _norm_fwd_kernel(o_ref, g_ref, w_ref, out_ref, *, head, epsilon,
+                     gate_fn):
     from jax.experimental import pallas as pl
 
     w = w_ref[...]
+    act = GATES[gate_fn]
 
     def emit(r0):
         r = pl.ds(r0, shared.SLAB)
         n = shared.per_head(lambda o: _rms(o, epsilon)[0], head,
                             o_ref[r, :].astype(_F32))
-        out_ref[r, :] = (n * w * jax.nn.sigmoid(
+        out_ref[r, :] = (n * w * act(
             g_ref[r, :].astype(_F32))).astype(out_ref.dtype)
 
     shared._slabs(o_ref.shape[0], emit)
 
 
 def _norm_bwd_kernel(o_ref, g_ref, dout_ref, w_ref, do_ref, dg_ref, dw_ref,
-                     *, head, epsilon, length):
-    """With r = rsqrt(mean o^2 + eps), n = o r, s = sigmoid(gate) and
-    out = n w s:  dn = dout w s;  do = r (dn - n mean(dn n));
-    dgate = dout n w s (1 - s);  dweight = sum dout n s (over the tokens
-    and the heads)."""
+                     *, head, epsilon, length, gate_fn):
+    """With r = rsqrt(mean o^2 + eps), n = o r, a = act(gate) and
+    out = n w a:  dn = dout w a;  do = r (dn - n mean(dn n));
+    dgate = dout n w act'(gate);  dweight = sum dout n a (over the
+    tokens and the heads)."""
     from jax.experimental import pallas as pl
 
     rows = o_ref.shape[0]
@@ -168,14 +183,14 @@ def _norm_bwd_kernel(o_ref, g_ref, dout_ref, w_ref, do_ref, dg_ref, dw_ref,
 
     def emit(r0):
         sl = pl.ds(r0, shared.SLAB)
-        s = jax.nn.sigmoid(g_ref[sl, :].astype(_F32))
+        a, slope = _gate_and_slope(g_ref[sl, :].astype(_F32), gate_fn)
         dout = dout_ref[sl, :].astype(_F32)
-        dws = dout * s
         n, do = shared.per_head(one, head, o_ref[sl, :].astype(_F32),
-                                dws * w)
+                                dout * a * w)
         do_ref[sl, :] = do.astype(do_ref.dtype)
-        dweight = dws * n
-        dg_ref[sl, :] = (dweight * w * (1.0 - s)).astype(dg_ref.dtype)
+        dout_n = dout * n
+        dweight = dout_n * a
+        dg_ref[sl, :] = (dout_n * w * slope).astype(dg_ref.dtype)
         if length % rows:       # what lies past the row's end is not data
             dweight = jnp.where(
                 shared._row_ids(i * rows + r0, shared.SLAB) < length,
@@ -196,37 +211,38 @@ def _norm_blocks(o, weight, direction):
             jnp.tile(weight.astype(_F32), width // head)[None])
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-@functools.partial(jax.jit, static_argnums=(3,))
-def _norm_fused(o, gate, weight, epsilon):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _norm_fused(o, gate, weight, epsilon, gate_fn):
     lanes, rows, grid, row = _norm_blocks(o, weight, "fwd")
     tokens = shared._norm_specs(rows, lanes)
     return kernel_call(
         ROLE_NORM, functools.partial(_norm_fwd_kernel, head=weight.shape[0],
-                                     epsilon=epsilon),
+                                     epsilon=epsilon, gate_fn=gate_fn),
         grid=grid, in_specs=[tokens, tokens, shared._vector_spec(1, lanes)],
         out_specs=tokens, out_shape=_sds(o.shape, _F32, o),
         compiler_params=shared._compiler_params(),
     )(o, gate, row)
 
 
-def _norm_fused_fwd(o, gate, weight, epsilon):
-    return _norm_fused(o, gate, weight, epsilon), (o, gate, weight)
+def _norm_fused_fwd(o, gate, weight, epsilon, gate_fn):
+    return _norm_fused(o, gate, weight, epsilon, gate_fn), (o, gate, weight)
 
 
-def _norm_fused_bwd(epsilon, res, dout):
-    return _norm_bwd(*res, dout, epsilon)
+def _norm_fused_bwd(epsilon, gate_fn, res, dout):
+    return _norm_bwd(*res, dout, epsilon, gate_fn)
 
 
-@functools.partial(jax.jit, static_argnums=(4,))
-def _norm_bwd(o, gate, weight, dout, epsilon):
+@functools.partial(jax.jit, static_argnums=(4, 5))
+def _norm_bwd(o, gate, weight, dout, epsilon, gate_fn):
     b, _, width = o.shape
     head = weight.shape[0]
     lanes, rows, grid, row = _norm_blocks(o, weight, "bwd")
     tokens = shared._norm_specs(rows, lanes)
     do, dgate, partial = kernel_call(
         ROLE_NORM, functools.partial(_norm_bwd_kernel, head=head,
-                                     epsilon=epsilon, length=o.shape[1]),
+                                     epsilon=epsilon, length=o.shape[1],
+                                     gate_fn=gate_fn),
         grid=grid,
         in_specs=[tokens, tokens, tokens, shared._vector_spec(1, lanes)],
         out_specs=[tokens, tokens, shared._partial_spec(1, lanes)],
@@ -273,15 +289,18 @@ def conv_norm(q, k, v, q_taps, k_taps, v_taps, head):
         (q, k, v), (q_taps, k_taps, v_taps), _scales(head)))
 
 
-def norm_gate(o, gate, weight, epsilon):
-    """``RMSNorm_head(o) * weight * sigmoid(gate)``, float32: o (B, T,
+def norm_gate(o, gate, weight, epsilon, gate_fn="sigmoid"):
+    """``RMSNorm_head(o) * weight * act(gate)``, float32: o (B, T,
     H * head) float32, the recurrence's output; gate the same shape in
-    the projections' type; weight (head,)."""
+    the projections' type; weight (head,); ``gate_fn`` names ``act``
+    (:data:`GATES`)."""
+    if gate_fn not in GATES:
+        raise ValueError(f"gate {gate_fn!r}: {sorted(GATES)} are built")
     why = _ineligible(weight.shape[0])
     if why is not None:
         bump("kda_stage", "xla", f"gated norm ineligible: {why}")
-        return norm_gate_xla(o, gate, weight, epsilon)
+        return norm_gate_xla(o, gate, weight, epsilon, gate_fn)
     moved = nbytes(o, gate) + 4 * o.size
     bump("kda_stage", "fused", work={ROLE_NORM: (0.0, float(moved))},
          grad_work={ROLE_NORM: (0.0, float(moved + nbytes(o, gate)))})
-    return _norm_fused(o, gate, weight, float(epsilon))
+    return _norm_fused(o, gate, weight, float(epsilon), gate_fn)

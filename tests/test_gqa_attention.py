@@ -440,3 +440,148 @@ def test_mla_rotates_with_the_same_op_and_refuses_a_scaled_rotation():
     with pytest.raises(NotImplementedError, match="rope_inv_freq"):
         nn.MLAttention(64, 4, 16, 8, 16, 32,
                        rope={"rope_theta": 1e4, "rope_type": "yarn"})
+
+
+# ---------------------------------------------------------------------------
+# an output gate from a doubled q_proj, and rotary positions on a part of
+# each head (PR 49): against hand-written formulas
+# ---------------------------------------------------------------------------
+def _rotate_by_hand(x, rotary_dim, theta):
+    """x (T, H, D) numpy: pair i of the first ``rotary_dim`` entries is
+    (x_i, x_{i + R/2}), turned by position * theta^(-2i/R); the rest of
+    the head is left as it is."""
+    t, half = x.shape[0], rotary_dim // 2
+    out = x.copy()
+    for i in range(half):
+        angle = np.arange(t) * theta ** (-2.0 * i / rotary_dim)
+        c, s = np.cos(angle)[:, None], np.sin(angle)[:, None]
+        a, b = x[..., i], x[..., i + half]
+        out[..., i], out[..., i + half] = a * c - b * s, b * c + a * s
+    return out
+
+
+def _gated_attention_by_hand(p, x, heads, kv_heads, d, rotary_dim, theta,
+                             gate, zero_centered):
+    """float64 numpy, one row x (T, hidden)."""
+    t = x.shape[0]
+    qg = (x @ p["q_proj.weight"]).reshape(t, heads, (2 if gate else 1) * d)
+    q, g = qg[..., :d], qg[..., d:].reshape(t, -1)
+    k = (x @ p["k_proj.weight"]).reshape(t, kv_heads, d)
+    v = (x @ p["v_proj.weight"]).reshape(t, kv_heads, d)
+
+    def norm(a, w):
+        a = a / np.sqrt((a * a).mean(-1, keepdims=True) + 1e-6)
+        return a * ((1.0 + w) if zero_centered else w)
+
+    q = _rotate_by_hand(norm(q, p["q_norm.weight"]), rotary_dim, theta)
+    k = _rotate_by_hand(norm(k, p["k_norm.weight"]), rotary_dim, theta)
+    out = np.zeros((t, heads, d))
+    for h in range(heads):
+        kv = h // (heads // kv_heads)
+        s = q[:, h] @ k[:, kv].T / math.sqrt(d)
+        s = np.where(np.tril(np.ones((t, t), bool)), s, -np.inf)
+        w = np.exp(s - s.max(-1, keepdims=True))
+        out[:, h] = (w / w.sum(-1, keepdims=True)) @ v[:, kv]
+    out = out.reshape(t, heads * d)
+    if gate:
+        out = out / (1.0 + np.exp(-g))
+    return out @ p["o_proj.weight"]
+
+
+@pytest.mark.parametrize("gate,rotary_dim,zero_centered", [
+    (True, 8, True), (True, None, False), (False, 8, False)],
+    ids=["gate+partial+1w", "gate", "partial"])
+def test_output_gate_and_partial_rotary_against_a_hand_written_layer(
+        gate, rotary_dim, zero_centered):
+    """16 query heads on 2 key heads would be the cell's; here 4 on 2,
+    heads of 32, a quarter of each head rotated (``rotary_dim`` 8: the
+    inverse frequencies of a head of 8), the gate a head's second half of
+    the doubled ``q_proj``."""
+    hidden, t, heads, kv, d, theta = 24, 12, 4, 2, 32, 1e7
+    paddle.seed(3)
+    counters.reset()
+    layer = nn.GroupedQueryAttention(
+        hidden, heads, kv, d, rope={"rope_type": "default",
+                                    "rope_theta": theta},
+        output_gate=gate, rotary_dim=rotary_dim,
+        zero_centered_norm=zero_centered)
+    snap = counters.snapshot()
+    assert snap.get("gqa.output_gate", 0) == int(gate)
+    assert snap.get("gqa.partial_rotary", 0) == int(rotary_dim is not None)
+    shapes = {k: tuple(p.shape) for k, p in layer.named_parameters()}
+    assert shapes["q_proj.weight"] == (hidden, (2 if gate else 1) * heads * d)
+    rng = np.random.RandomState(4)
+    for name, p in layer.named_parameters():
+        if name.endswith("norm.weight"):
+            assert float(jnp.max(jnp.abs(p.value))) == (
+                0.0 if zero_centered else 1.0)
+            p._value = jnp.asarray(0.3 * rng.randn(*p.shape)
+                                   + (0.0 if zero_centered else 1.0),
+                                   jnp.float32)
+    x = rng.randn(t, hidden)
+    params = {k: np.asarray(p.value, np.float64)
+              for k, p in layer.named_parameters()}
+    want = _gated_attention_by_hand(params, x, heads, kv, d,
+                                    rotary_dim or d, theta, gate,
+                                    zero_centered)
+    with jax.default_matmul_precision("highest"):
+        got = layer(paddle.to_tensor(x[None].astype(np.float32))).numpy()[0]
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+    with pytest.raises(ValueError, match="rotary_dim"):
+        nn.GroupedQueryAttention(hidden, heads, kv, d, rotary_dim=7)
+
+
+def test_the_gate_has_its_scope_and_type_and_absent_arguments_change_nothing():
+    """``gated_attn`` is in the HLO round the gate's product, which runs
+    in the attention output's (autocast) type; a layer built without the
+    three new arguments lowers, differentiated, to the text it lowered to
+    before they existed (sha256 from the parent commit, 2c186de)."""
+    import hashlib
+
+    from paddle_tpu import amp
+
+    paddle.seed(0)
+    layer = nn.GroupedQueryAttention(32, 4, 2, 16, rope=PLAIN,
+                                     output_gate=True, rotary_dim=4)
+
+    def run(a):
+        with amp.auto_cast(level="O1", dtype="bfloat16"):
+            return layer(paddle.to_tensor(a)).value
+
+    text = jax.jit(run).lower(jnp.zeros((1, 16, 32), jnp.float32)).as_text(
+        debug_info=True)
+    lines = text.splitlines()
+    names = [ln.split(" = ")[0] for ln in lines
+             if ln.startswith("#loc") and "gated_attn" in ln
+             and ln.split('"')[1].endswith("/mul")]
+    gate = [ln for ln in lines if "stablehlo.multiply" in ln
+            and any(ln.rstrip().endswith(f"loc({n})") for n in names)]
+    assert gate and all("bf16" in ln and "f32" not in ln for ln in gate), \
+        (names, gate)
+    paddle.seed(0)
+    plain = nn.GroupedQueryAttention(32, 4, 2, 16, window=8, rope=PLAIN)
+
+    def loss(a):
+        return plain(paddle.to_tensor(a)).value.sum()
+
+    text = jax.jit(jax.grad(loss)).lower(
+        jnp.zeros((1, 16, 32), jnp.float32)).as_text()
+    assert "gated_attn" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "875e50c14b473cdf59832e5efd3a745bb5d2ceee33b5fc7486f2e2733b6f6a60")
+
+
+def test_gated_heads_of_256_dispatch_to_the_grouped_kernels(on_chip_gate,
+                                                            interpret):
+    """K and V stay 2 heads wide to the kernel at heads of 256: the
+    dispatch counts ``flash_attention.grouped`` and no ``xla``."""
+    paddle.seed(1)
+    layer = nn.GroupedQueryAttention(64, 4, 2, 256, rope=PLAIN,
+                                     output_gate=True, rotary_dim=64)
+    counters.reset()
+    x = np.random.RandomState(2).randn(1, 256, 64).astype(np.float32)
+    got = layer(paddle.to_tensor(x)).numpy()
+    snap = counters.snapshot()
+    assert snap.get("flash_attention.grouped") == 1, snap
+    assert "flash_attention.xla" not in snap, snap
+    assert np.isfinite(got).all()

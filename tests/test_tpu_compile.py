@@ -736,3 +736,170 @@ def test_a_scan_or_convolution_cells_step_launches_what_its_parent_did(
     assert {role: calls[role] for role in want} == want
     assert "kda_chunk_fwd" not in calls
     assert not any(k.startswith("kda_chunk") for k in counters.snapshot())
+
+
+# ---------------------------------------------------------------------------
+# the Qwen3-Next cell (PR 49): the chunk body's scalar form, heads of 256,
+# a share of sorted rungs alone, and the whole step
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("which", ["fwd", "bwd"])
+def test_kda_chunk_kernels_compile_in_the_scalar_decay_form(one_chip, which):
+    """The same two launches, at the same 32 heads of 128 x 128 and the
+    same heads a grid step, with the chunk formulas' ``scalar`` form (the
+    C x C matrix of decays from one column of a head's block, its
+    transposes and its row and column sums)."""
+    from paddle_tpu.ops.pallas import kda
+
+    b, t, h, d = KDA
+    heads = kda._heads_a_step(h, d, d, kda.CHUNK)
+    launch = kda._pallas_fwd if which == "fwd" else kda._pallas_bwd
+    seq = [((b, t, h * d), F32)] * 4 + [(KDA[:3], F32)]
+    if which == "bwd":
+        seq += [((b, h, t // kda.CHUNK, d, d), F32), ((b, t, h * d), F32)]
+
+    def fn(*a):
+        return launch(*a, kda.CHUNK, True)
+
+    assert _pallas_grids(fn, *seq) == [(b, h // heads, t // kda.CHUNK)]
+    out = _compile(fn, one_chip, *seq)
+    assert f"kda_chunk_{which}" in out.as_text()
+
+
+@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bfloat16", "float32"])
+def test_the_gdn_mixer_compiles_at_the_cells_shapes(one_chip, monkeypatch,
+                                                    dtype):
+    """``gdn_mix`` on the projections of 1 x 8,192 tokens, 16 key heads
+    under 32 value heads of 128: the convolution stage on the 16 key
+    heads' width (and not on 32 copies), the two chunk launches, the gated
+    norm with SiLU; value and every gradient."""
+    import paddle_tpu.framework.bringup as bringup
+    from paddle_tpu.nn.linear_attention import gdn_mix
+    from paddle_tpu.ops.pallas import counters
+
+    monkeypatch.setattr(bringup, "pallas_enabled", lambda: True)
+    b, t, hk, hv, d = 1, 8192, 16, 32, 128
+    shapes = [((b, t, 2 * hk * d + 2 * hv * d), dtype),
+              ((b, t, 2 * hv), dtype), ((4, 2 * hk * d + hv * d), F32),
+              ((hv,), F32), ((hv,), F32), ((d,), F32)]
+
+    def loss(*a):
+        return jnp.sum(gdn_mix.raw_fn(*a, num_key_heads=hk,
+                                      num_value_heads=hv, key_dim=d,
+                                      value_dim=d))
+
+    counters.reset()
+    text = _compile(jax.value_and_grad(
+        loss, argnums=tuple(range(len(shapes)))), one_chip,
+        *shapes).as_text()
+    snap = counters.snapshot()
+    assert snap["gdn.scalar_decay"] == snap["kda_chunk.pallas"] == 1
+    assert snap["kda_stage.fused"] == 2 and "kda_stage.xla" not in snap
+    # conv x 3 forward and backward, the chunk pair, the gated norm pair
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"',
+                          text)) == 10
+    for scope in ("gdn_before", "gdn_after"):
+        assert scope in text
+
+
+@pytest.mark.parametrize("precision", [None, "highest"])
+def test_grouped_stream_flash_compiles_at_heads_of_256(one_chip, precision):
+    """1 x 8,192 tokens, 16 query heads on 2 key/value heads of 256: the
+    widest head the dispatch admits, which no cell ran before (the widest
+    accepted are the Kanana cell's 192 / 128)."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    def loss(q, k, v):
+        return jnp.sum(fa._flash_attention_pallas(
+            q, k, v, causal=True).astype(F32))
+
+    grads = jax.grad(loss, argnums=(0, 1, 2))
+    with jax.default_matmul_precision(precision or "default"):
+        out = _compile(grads, one_chip, ((1, 8192, 16, 256), BF16),
+                       ((1, 8192, 2, 256), BF16), ((1, 8192, 2, 256), BF16))
+    assert "flash_attention_grouped" in out.as_text()
+
+
+def test_a_share_of_sorted_rungs_alone_compiles_on_the_grouped_kernels(
+        one_chip, monkeypatch):
+    """32 held of 512 experts of 2048 x 512, top 10, softmax, 8,192 tokens:
+    more than twice the picks are held, so the ladder has no dense rung,
+    only the sorted ones of 40,960 and 81,920 rows, both through
+    ``ops.pallas.grouped_ffn`` (32 groups of width 512); value and
+    gradients under the block's recomputation."""
+    import functools
+
+    import paddle_tpu.framework.bringup as bringup
+    from paddle_tpu import amp
+    from paddle_tpu.nn import moe
+    from paddle_tpu.nn.moe import _row_ladder, sparse_moe
+    from paddle_tpu.ops.pallas import counters
+
+    t, d, f, held, experts, top_k = 8192, 2048, 512, 32, 512, 10
+    assert _row_ladder(t * top_k, held, experts) == (40960, 81920)
+    chip, = one_chip.device_set
+    monkeypatch.setattr(moe, "_stored_layout", functools.partial(
+        moe._stored_layout, device=chip))
+    monkeypatch.setattr(bringup, "pallas_enabled", lambda: True)
+
+    def layer(x, router, up, down, gate):
+        with amp.auto_cast(level="O1", dtype="bfloat16"):
+            return sparse_moe.raw_fn(
+                x, router, jnp.zeros((experts,), F32), gate, up, down,
+                top_k=top_k, score_func="softmax")[0]
+
+    shapes = (((t, d), BF16), ((d, experts), F32), ((held, d, f), F32),
+              ((held, f, d), F32), ((held, d, f), F32))
+    counters.reset()
+    text = _compile(
+        jax.value_and_grad(lambda *a: jnp.sum(jax.checkpoint(layer)(*a)),
+                           argnums=tuple(range(len(shapes)))),
+        one_chip, *shapes).as_text()
+    snap = counters.snapshot()
+    assert snap["sparse_moe.sorted"] == snap["sparse_moe.grouped"] == 1
+    assert "sparse_moe.every_pair" not in snap
+    assert not re.findall(r"\bragged-dot\(", text)
+    assert "moe_grouped_up" in text and "moe_grouped_combine" in text
+
+
+def test_the_qwen3_next_cells_step_launches_the_recurrence_once_a_layer(
+        one_chip, monkeypatch):
+    """1 x 8,192 tokens through the cell's four blocks under per-block
+    recomputation: THREE ``kda_chunk_fwd`` custom calls, none in a
+    ``rematted_computation``, three backward ones; the attention layer's
+    flash forward once; the spans ``gdn_before``, ``gdn_after`` and
+    ``gated_attn`` in the compiled text; the counters of the new paths;
+    and the whole step fits the chip."""
+    from paddle_tpu.ops.pallas import counters
+
+    step, args = _cell_step("qwen3-next-80b-a3b.pretrain-seq8k", one_chip,
+                            monkeypatch)
+    out = step.lower(*args).compile()
+    snap = counters.snapshot()
+    assert snap["kda_chunk.kept_across_recompute"] \
+        == snap["kda_chunk.pallas"] == snap["gdn.scalar_decay"] == 3
+    assert snap["flash_attention.grouped"] == 1
+    assert snap["flash_attention.kept_across_recompute"] == 1
+    assert snap["sparse_moe.grouped"] == snap["sparse_moe.sorted"] == 4
+    # (the build's counters, ``gqa.output_gate``, ``gqa.partial_rotary``
+    # and ``moe.shared_gate``, were counted before ``_cell_step`` reset
+    # the table: ``tests/test_causal_lm_qwen3_next.py`` holds them)
+    assert not [k for k in snap if k.endswith(".xla")], snap
+    text = out.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    where = {role: [re.search(r'op_name="([^"]*)"', c).group(1)
+                    for c in calls if f"/pallas/{role}/pallas_call" in c]
+             for role in ("kda_chunk_fwd", "kda_chunk_bwd", "kda_conv",
+                          "kda_gate_norm", "flash_attention_grouped")}
+    assert len(where["kda_chunk_fwd"]) == len(where["kda_chunk_bwd"]) == 3
+    assert not any("rematted_computation" in w
+                   for w in where["kda_chunk_fwd"])
+    assert len(where["kda_conv"]) == 27 and len(where["kda_gate_norm"]) == 9
+    assert 2 <= len(where["flash_attention_grouped"]) <= 3
+    for scope in ("gdn_before", "gdn_after", "gated_attn"):
+        assert scope in text, scope
+    mem = out.memory_analysis()
+    held = mem.argument_size_in_bytes + mem.output_size_in_bytes \
+        - mem.alias_size_in_bytes + mem.temp_size_in_bytes
+    print(f"memory_analysis: {held / 1e9:.3f} GB held, {mem}")
+    assert held < 16 * 2 ** 30, mem
