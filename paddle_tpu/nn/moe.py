@@ -11,8 +11,8 @@
   matrix product on a rung of a ladder of row capacities that the count
   of pairs picks: ``jax.lax.ragged_dot``, whose time follows the live
   rows, or on a TPU the Pallas launches of ``ops.pallas.grouped_ffn``,
-  the same function on a static row count, which compute all of a rung's
-  rows whatever the routing. The top rung of a share that
+  the same function on a static row count, which compute the 512-row
+  tiles of a rung that hold a pair. The top rung of a share that
   holds at most twice the experts a token picks is every token through
   every held expert, as ONE FFN of width held x F with the routing
   weights on its hidden activations (``_every_pair_ffn``): three large
@@ -422,14 +422,18 @@ def _grouped_cost(tokens: int, top_k: int, held: int, d: int, f: int,
     forward, recomputed and backward: seven products of D x F plain,
     eleven gated): ``(a row's, a rung's beside its rows)``. A launched
     row's products are a dense row's (the launches run at the MXU's rate,
-    as the dense rung's do); it is gathered three times, the token's row
-    forward and recomputed and its cotangent; and the way back, forward
-    and backward, is three passes of a one-hot product of BLOCK tokens
-    for every CHUNK of rows. Beside its rows a rung launches a tile more
-    for each expert, its way back visits a chunk more for every (block of
-    tokens, expert), and the sort, its inverse and the rows' layout walk
-    every slot of the share, whatever the rung holds. So narrow experts'
-    rungs are dear."""
+    as the dense rung's do) and are paid by the LIVE tile only: the
+    launches stop at their last live tile, so this term states a rung
+    that is full, and overstates one that is not (a corrected model may
+    admit the narrow experts' shares: ROADMAP S12); it is gathered three
+    times, the token's row forward and recomputed and its cotangent,
+    whether it holds a pair or not; and the way back, forward and
+    backward, is three passes of a one-hot product of BLOCK tokens for
+    every CHUNK of rows that holds a pair. Beside its rows a rung
+    launches a tile more for each expert, its way back visits a chunk
+    more for every (block of tokens, expert), and the sort, its inverse
+    and the rows' layout walk every slot of the share, whatever the rung
+    holds. So narrow experts' rungs are dear."""
     from ..ops.pallas.grouped_ffn import BLOCK, CHUNK, TILE
 
     unit = (11 if gated else 7) * d * f         # a dense row's multiply-adds
@@ -450,18 +454,20 @@ def _row_ladder(pairs: int, experts_held: int, num_experts: int,
     load follows its tokens' frequencies, and a share's experts, the
     only ones whose output reaches the loss, draw 5.4-5.7 times the even
     share by the end of a benchmark window) then moves the rung seldom,
-    and a step's time hardly depends on its data, at the price of rows
-    that hold no pair. Where the top rung is the dense one, every token
-    through every held expert on ``dense_rows`` rows, a sorted rung stays
-    only where it costs no more than those: ``row_cost`` dense rows a row
-    and ``rung_cost`` beside them. The defaults are ``ragged_dot``'s: a
+    at the price of rows that hold no pair: ``ragged_dot``'s products
+    and the kernels' take as long as the live rows, a tile of 512 at a
+    time in the kernels, so a step's time follows its routing smoothly
+    (1% of a rung a tile) and jumps only where a count crosses a rung;
+    the gathers, the sort and the grids' steps are paid by the rung.
+    Where the top rung is the dense one, every token through every held
+    expert on ``dense_rows`` rows, a sorted rung stays only where it
+    costs no more than those: ``row_cost`` dense rows a row and
+    ``rung_cost`` beside them. The defaults are ``ragged_dot``'s: a
     sorted row costs what 2.5 to 3.3 dense rows cost (its gather, its
-    selects and its float32 scatter-add, PERF.md section 7.10) and its
-    product takes as long as its live rows, so above a third it is no
-    cheaper than the dense rung and makes a step's time follow its
-    routing. The grouped kernels' are ``_grouped_cost``; under a dense
-    top they may keep a rung of every pair. All of it follows from the
-    shapes."""
+    selects and its float32 scatter-add, PERF.md section 7.10), so above
+    a third it is no cheaper than the dense rung. The grouped kernels'
+    are ``_grouped_cost``; under a dense top they may keep a rung of
+    every pair. All of it follows from the shapes."""
     def blocks(rows):
         return -(-int(rows) // 256) * 256
 
@@ -718,8 +724,8 @@ def sparse_moe(x, router_w, router_bias, w_gate, w_up, w_down, top_k,
     dense_rows = t * held if dense_top else 0
     rungs = _row_ladder(pairs, held, num_experts, dense_rows)
     # On a TPU the sorted rungs may be Pallas grouped products on a static
-    # row count, which compute all of a rung's rows whatever the routing
-    # and neither select nor scatter; ``ragged_dot`` is the same function's
+    # row count, which compute the tiles of a rung that hold a pair and
+    # neither select nor scatter; ``ragged_dot`` is the same function's
     # plain statement. Under a dense top they take the work the DENSE rung
     # does today, where ``_row_ladder`` finds a rung of theirs that costs
     # less than it (``_grouped_cost``, from the shapes: the wide experts'

@@ -1,6 +1,7 @@
 """The dropless expert layer's grouped rung: each expert's FFN on its own
 run of (token, expert) pairs sorted by expert, at a STATIC row capacity R,
-as Pallas grouped products whose time does not follow the routing.
+as Pallas grouped products on static grids whose steps do a product only
+where a pair stands: a launch's time follows the pairs it was sent.
 
     rows   = x[token of each sorted row]                      a gather
     gate, up = rows G[e], rows U[e]        e the row's expert, by tile
@@ -11,16 +12,32 @@ as Pallas grouped products whose time does not follow the routing.
 for plain ones (no ``w_gate``). Products take operands of ``dtype`` (the
 autocast type) and accumulate in float32.
 
-*A static grid.* Inside, every expert's run is padded to whole tiles of
-``TILE`` rows, at least one, and the tiles past the last run are given to
-the last expert: R + held x TILE rows in all, whatever the group sizes. A
-padded row carries token 0 and weight 0: its result is multiplied by 0 in
-float32, the way back matches it to no token, its cotangents are 0 and it
-adds exact zeros to its expert's weight gradients. So a tile belongs to ONE expert (its
-weights are picked through scalar-prefetched ``group_of_tile``), nothing
-is masked, no row is unspecified, and a launch does the same work for any
-routing. (``megablox.gmm`` sizes its grid by the live tiles, which is
-what makes a step's time follow its data.)
+*A static grid, and the live tiles.* Inside, every expert's run is padded
+to whole tiles of ``TILE`` rows, at least one: the LIVE tiles, whose count
+follows the routing. The tiles past them are given to the last expert:
+R + held x TILE rows in all, whatever the group sizes, so the shapes and
+the grids are static. A padded row of a live tile carries token 0 and
+weight 0: its result is multiplied by 0 in float32, the way back matches
+it to no token, its cotangents are 0 and it adds exact zeros to its
+expert's weight gradients. So a tile belongs to ONE expert (its weights
+are picked through scalar-prefetched ``group_of_tile``, whose last element
+is the count of live tiles) and nothing is masked. A grid step past the
+live tiles does NO product and fetches nothing (its index maps stay on
+the last live tile's blocks, which are in VMEM), as a visit of the way
+back that matches no token does none. (``megablox.gmm`` sizes its grid by
+the live tiles; here the grid stays the rung's and a step past them
+costs under a microsecond: PERF.md section 7.10, PR 50.)
+
+*The contract of the rows past the live tiles*: no launch writes them
+and no reader reads them as numbers. Every (R', width) result of
+``_row_products`` holds what the device's memory held there (NaN in
+interpret mode). Its readers are the next launches, which stop at the
+same tile; ``_combine``, whose visits that match no token read no row;
+the routing weights' cotangent, a gather over ``padded_of_slot``, which
+names live rows only; and the ``FLAGS_check_nan_inf`` row of the hidden
+activations, which is taken of the live tiles' rows. (Zeros there cost
+their bytes for no reader: 1.2 to 3.2 ms a layer and step on a v5e at
+the three cells' shapes, PERF.md section 5, PR 50.)
 
 *No scatter, and no gather on the way back.* The sort is a permutation,
 known both ways: the rows go out by a gather over ``slot_of_row`` and come
@@ -146,6 +163,18 @@ def _kept(results):
     return ([None] + [a.astype(_F32) for a in results])[-2:]
 
 
+def _live_tiles(group_of_tile):
+    """The count of live tiles, ``group_of_tile``'s last element
+    (``_layout``): of the array, or of its scalar-prefetched ref."""
+    return group_of_tile[group_of_tile.shape[0] - 1]
+
+
+def _live_tile(i, group_of_tile):
+    """The tile whose blocks step ``i`` is on: its own, or past the live
+    tiles the last live one, which is in VMEM already."""
+    return jnp.minimum(i, _live_tiles(group_of_tile) - 1)
+
+
 def _slabs(width, one):
     """``one(start, size)`` over ``width`` columns (or rows) in slabs of
     SLAB, as a ROLLED loop and a static tail. Mosaic unrolls a product
@@ -231,17 +260,24 @@ def _row_products(role, group_of_tile, tiles, weights, outs, dtype, tile,
     section 7.20): ``_BODIES[role]`` on the VMEM blocks of a tile of each
     array in ``tiles`` (R', W), of the whole matrix of the tile's expert
     from each stack in ``weights``, and of the (R', width) results, whose
-    (width, dtype) are ``outs``."""
+    (width, dtype) are ``outs``. Only on the live tiles (``_layout``): a
+    step past them stays on the last live tile's blocks, fetches nothing
+    and does no product, and the results' rows past the live tiles are
+    never written."""
     n_rows = tiles[0].shape[0]
     body = _BODIES[role]
 
-    def kernel(_, *refs):
+    def kernel(g, *refs):
         ins = len(tiles) + len(weights)
-        body(refs[:len(tiles)], refs[len(tiles):ins], refs[ins:], dtype,
-             minor_d)
+
+        @pl.when(pl.program_id(0) < _live_tiles(g))
+        def _():
+            body(refs[:len(tiles)], refs[len(tiles):ins], refs[ins:], dtype,
+                 minor_d)
 
     def by_row(width):
-        return pl.BlockSpec((tile, width), lambda i, g: (i, 0))
+        return pl.BlockSpec((tile, width),
+                            lambda i, g: (_live_tile(i, g), 0))
 
     need = 0
     for a in tiles:
@@ -271,8 +307,9 @@ def _row_products(role, group_of_tile, tiles, weights, outs, dtype, tile,
 def _weight_grad(group_of_tile, a, b, held, tile):
     """``a^T b`` over each expert's tiles: (held, a's width, b's width)
     float32. An expert's block stays in VMEM while its tiles pass; every
-    expert has a tile, so every block is written. In a ``jax.jit`` of its
-    own, as ``_row_products``."""
+    expert has a live tile, so every block is written; the steps past the
+    live tiles (the last expert's, ``_layout``) fetch nothing and add
+    nothing. In a ``jax.jit`` of its own, as ``_row_products``."""
     n_rows, ka = a.shape
     n = b.shape[1]
     # the widest whole-lane divisor of ``n`` whose float32 block fits
@@ -282,16 +319,19 @@ def _weight_grad(group_of_tile, a, b, held, tile):
 
     def kernel(g, a_ref, b_ref, out_ref):
         i = pl.program_id(1)
-        first = jnp.logical_or(i == 0, g[i] != g[jnp.maximum(i - 1, 0)])
-        b = b_ref[...]
 
-        def one(at, n):
-            part = _dot(a_ref[:, pl.ds(at, n)], b, _TN)
-            # (a select: what the block held before its expert's first
-            # tile is not read as a number)
-            out_ref[pl.ds(at, n), :] = part + jnp.where(
-                first, 0.0, out_ref[pl.ds(at, n), :])
-        _slabs(ka, one)
+        @pl.when(i < _live_tiles(g))
+        def _():
+            first = jnp.logical_or(i == 0, g[i] != g[jnp.maximum(i - 1, 0)])
+            b = b_ref[...]
+
+            def one(at, n):
+                part = _dot(a_ref[:, pl.ds(at, n)], b, _TN)
+                # (a select: what the block held before its expert's first
+                # tile is not read as a number)
+                out_ref[pl.ds(at, n), :] = part + jnp.where(
+                    first, 0.0, out_ref[pl.ds(at, n), :])
+            _slabs(ka, one)
 
     need = (2 * tile * (ka * a.dtype.itemsize + tn * b.dtype.itemsize)
             + 3 * ka * tn * 4)
@@ -299,8 +339,10 @@ def _weight_grad(group_of_tile, a, b, held, tile):
         ROLE_DW, kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(n // tn, n_rows // tile),
-            in_specs=[pl.BlockSpec((tile, ka), lambda j, i, g: (i, 0)),
-                      pl.BlockSpec((tile, tn), lambda j, i, g: (i, j))],
+            in_specs=[pl.BlockSpec((tile, ka),
+                                   lambda j, i, g: (_live_tile(i, g), 0)),
+                      pl.BlockSpec((tile, tn),
+                                   lambda j, i, g: (_live_tile(i, g), j))],
             out_specs=pl.BlockSpec((None, ka, tn),
                                    lambda j, i, g: (g[i], 0, j))),
         out_shape=jax.ShapeDtypeStruct((held, ka, n), _F32),
@@ -315,9 +357,12 @@ def _weight_grad(group_of_tile, a, b, held, tile):
 # ---------------------------------------------------------------------------
 def _layout(sizes, slot_of_row, row_of_slot, tile, block, chunk):
     """Where the sorted rows stand once every group is whole tiles:
-    ``group_of_tile`` (tiles,), each padded row's slot and whether it
-    holds a pair, each slot's padded row (the padded row count where
-    it has none), and the way back's ``_visits``."""
+    ``group_of_tile`` (tiles + 1,), each tile's expert and, last, the
+    count of LIVE tiles (every expert's run in whole tiles, at least one
+    each: the tiles past them, the last expert's, hold no pair), each
+    padded row's slot and whether it holds a pair, each slot's padded row
+    (the padded row count where it has none), and the way back's
+    ``_visits``."""
     held, rows = sizes.shape[0], slot_of_row.shape[0]
     tiles = -(-rows // tile) + held
     # (of the pairs that have a row: the first ``rows`` of them)
@@ -343,7 +388,8 @@ def _layout(sizes, slot_of_row, row_of_slot, tile, block, chunk):
         has_row, first_row[group] + row_of_slot - offset[group], tiles * tile)
     visits = _visits(jnp.where(has_row, group, held), first_row,
                      tiles * tile, block, chunk)
-    return group_of_tile, slot_of_padded, live, padded_of_slot, visits
+    return (jnp.concatenate([group_of_tile, tile_end[-1:].astype(jnp.int32)]),
+            slot_of_padded, live, padded_of_slot, visits)
 
 
 def _visits(group_of_slot, first_row, rows, block, chunk):
@@ -352,11 +398,15 @@ def _visits(group_of_slot, first_row, rows, block, chunk):
     expert on ONE block of ``block`` tokens are a run of at most ``block``
     rows, in one or two (``block`` / ``chunk`` + 1 at most) chunks of
     ``chunk`` rows. A visit is a (token block, chunk) pair whose rows are
-    summed into the block; every (block, expert) gets one visit at least,
-    so every block is written. There are at most ``rows / chunk + held x
-    blocks`` of them (a chunk more only where an expert's rows pass a
-    chunk's end), and the grid is that many, whatever the routing: the
-    visits past the last are given to the last block and match no token.
+    summed into the block; every (block, expert) is owed one visit at
+    least, so every block is written. There are at most ``rows / chunk +
+    held x blocks`` of them (a chunk more only where an expert's rows pass
+    a chunk's end), and the grid is that many, whatever the routing; what
+    follows the routing is how many of them do a product: a visit that
+    matches no token (the one an empty (block, expert) is owed, and the
+    visits past the last, given to the last block) stays on the chunk of
+    the last visit that matched, so it fetches nothing, and ``_combine``
+    does no product on it.
     ``group_of_slot`` (T, top_k) is each slot's expert here, or ``held``
     for a slot with no row. Returns, a visit: its block, its chunk, the
     first token it looks for (below every token where it is to match
@@ -377,8 +427,9 @@ def _visits(group_of_slot, first_row, rows, block, chunk):
         visit[:, None] >= end[None, :], axis=1, dtype=jnp.int32))
     nth = visit - (end - jnp.maximum(chunks, 1))[pair]
     matches = nth < chunks[pair]        # (false past the last visit too)
+    stay = jax.lax.cummax(jnp.where(matches, visit, 0))
     return (pair // held,
-            jnp.clip(chunk_lo[pair] + nth, 0, rows // chunk - 1),
+            jnp.clip(chunk_lo[pair] + nth, 0, rows // chunk - 1)[stay],
             jnp.where(matches, pair // held * block, -block - 2),
             ((nth == 0) & (pair % held == 0)).astype(jnp.int32))
 
@@ -387,11 +438,15 @@ def _visits(group_of_slot, first_row, rows, block, chunk):
 def _combine(rows, token_of_row, visits, tokens, block, chunk):
     """Each token's rows of ``rows`` (R', W) float32, summed: (tokens, W).
     ``token_of_row`` (R' / chunk, 1, chunk) is a row's token, -1 for a
-    row that holds no pair. No gather and no scatter: a visit (``_visits``) takes a chunk of
-    rows and adds, to its block of tokens, the one-hot product that picks
-    each token's row out of it, on the MXU, the float32 rows as three
-    bfloat16 pieces (exact: a token has one row an expert at most, so a
-    product's sum has one term, and three pieces hold float32's 24 bits).
+    row that holds no pair. No gather and no scatter: a visit
+    (``_visits``) takes a chunk of rows and adds, to its block of tokens,
+    the one-hot product that picks each token's row out of it, on the
+    MXU, the float32 rows as three bfloat16 pieces (exact: a token has
+    one row an expert at most, so a product's sum has one term, and three
+    pieces hold float32's 24 bits).
+    A visit that matches no token does no product and reads no row (its
+    chunk may be one that no launch wrote); where it is its block's first
+    it writes the zeros the block is owed.
     XLA's way back is a gather for each of a token's ``top_k`` slots,
     about 55 ns a ROW whatever its width and whether the slot has a row
     here: 7.8 ms where this takes 3 at 16,384 x 6 slots of 2688 on a v5e
@@ -402,24 +457,32 @@ def _combine(rows, token_of_row, visits, tokens, block, chunk):
     def kernel(_, __, look_ref, first_ref, rows_ref, token_ref, out_ref):
         visit = pl.program_id(0)
         first = first_ref[visit] == 1
-        want = look_ref[visit] + jax.lax.broadcasted_iota(
-            jnp.int32, (block, chunk), 0)
-        pick = jnp.where(token_ref[...] == want, 1.0, 0.0).astype(
-            jnp.bfloat16)                           # (block, chunk)
+        matches = look_ref[visit] >= 0
 
-        def one(at, n):
-            left = rows_ref[:, pl.ds(at, n)]
-            total = None
-            for _ in range(3):
-                piece = left.astype(jnp.bfloat16)
-                part = _dot(pick, piece)
-                total = part if total is None else total + part
-                left = left - piece.astype(_F32)
-            # (a select: what the block held before its first visit is
-            # not read as a number)
-            out_ref[:, pl.ds(at, n)] = total + jnp.where(
-                first, 0.0, out_ref[:, pl.ds(at, n)])
-        _slabs(width, one)
+        @pl.when(matches)
+        def _():
+            want = look_ref[visit] + jax.lax.broadcasted_iota(
+                jnp.int32, (block, chunk), 0)
+            pick = jnp.where(token_ref[...] == want, 1.0, 0.0).astype(
+                jnp.bfloat16)                       # (block, chunk)
+
+            def one(at, n):
+                left = rows_ref[:, pl.ds(at, n)]
+                total = None
+                for _ in range(3):
+                    piece = left.astype(jnp.bfloat16)
+                    part = _dot(pick, piece)
+                    total = part if total is None else total + part
+                    left = left - piece.astype(_F32)
+                # (a select: what the block held before its first visit
+                # is not read as a number)
+                out_ref[:, pl.ds(at, n)] = total + jnp.where(
+                    first, 0.0, out_ref[:, pl.ds(at, n)])
+            _slabs(width, one)
+
+        @pl.when(jnp.logical_and(first, jnp.logical_not(matches)))
+        def _():
+            out_ref[...] = jnp.zeros_like(out_ref)
 
     need = 2 * (chunk + block) * width * 4 + 3 * chunk * width * 2
     out = kernel_call(
@@ -522,7 +585,12 @@ def _grouped_fwd(x, weight, slot_of_row, row_of_slot, sizes, w_gate, w_up,
     out, res = _forward(x, weight, slot_of_row, row_of_slot, sizes, w_gate,
                         w_up, w_down, dtype, tiling, up_minor_d)
     if record:
-        out = out, nan_inf.row(_hidden(*_kept(res[1])).astype(dtype))
+        # (of the rows the launches wrote: the live tiles')
+        kept, group_of_tile = res[1], res[4]
+        hidden = _hidden(*_kept(kept)).astype(dtype)
+        written = jnp.arange(hidden.shape[0]) \
+            < _live_tiles(group_of_tile) * tiling[0]
+        out = out, nan_inf.row(jnp.where(written[:, None], hidden, 0))
     # (and the types the cotangents leave in)
     return out, (res, [jnp.zeros((0,), a.dtype)
                        for a in (x, w_gate, w_up, w_down) if a is not None])
@@ -546,7 +614,11 @@ def work(rows, d, f, gated, itemsize, tokens=0, held=0):
     each product's 2 x rows x D x F, and the bytes of the row arrays it
     reads and writes once (the stacks, read once an expert, are small
     beside them); the way back's one-hot products as launched, three
-    bfloat16 passes a visit, and the chunks it reads."""
+    bfloat16 passes a visit, and the chunks it reads. AS LAUNCHED: the
+    work of the whole static grid, an upper bound of what a step does
+    (the tiles and visits that hold no pair do none of it, and how many
+    those are follows the routing, which no shape tells); no roofline
+    metric reads these roles."""
     unit = 2.0 * rows * d * f
     ups = 2 if gated else 1
     wide, narrow = rows * d * itemsize, rows * f * itemsize
@@ -567,12 +639,14 @@ def work(rows, d, f, gated, itemsize, tokens=0, held=0):
 
 def declare(rows, tokens, held, d, f, gated, dtype):
     """Count one dispatch of a rung of ``rows`` rows and declare its
-    launches' work. ``grouped_ffn`` does, unless told that its caller
-    has: ``nn.moe.sparse_moe`` traces its rungs once for a step's layers
-    and counts each layer's."""
+    launches' work, AS LAUNCHED (:func:`work`); ``live_tiles_only`` says
+    that the launches do a part of it, the live tiles'. ``grouped_ffn``
+    does, unless told that its caller has: ``nn.moe.sparse_moe`` traces
+    its rungs once for a step's layers and counts each layer's."""
     bump("moe_grouped", "pallas", **work(
         padded_rows(rows, held), d, f, gated, jnp.dtype(dtype).itemsize,
         tokens, held))
+    bump("moe_grouped", "live_tiles_only")
 
 
 def grouped_ffn(x, weight, slot_of_row, row_of_slot, group_sizes, w_gate,

@@ -107,6 +107,8 @@ def _picks(case, t, k, held, experts, rng):
     elif case == "empty_groups":    # experts 0 and 2 get nothing
         picked = np.where((picked == 0) | (picked == 2), held + picked,
                           picked)
+    elif case == "nothing":         # no pair on a held expert
+        picked = picked % (experts - held) + held
     return picked
 
 
@@ -174,7 +176,8 @@ def test_the_grouped_rung_is_the_per_expert_loop(interp, case, gated, dtype):
     for name, a, b in zip(names, got[1], want[1]):
         assert a.dtype == b.dtype and a.shape == b.shape, name
         assert _rel(a, b) < tol, (name, _rel(a, b))
-    assert counters.snapshot() == {"moe_grouped.pallas": 1}
+    assert counters.snapshot() == {"moe_grouped.pallas": 1,
+                                   "moe_grouped.live_tiles_only": 1}
 
 
 @pytest.mark.parametrize("gated", [True, False], ids=["gated", "plain"])
@@ -215,8 +218,6 @@ def test_the_way_back_sums_each_tokens_rows(interp, case):
     held, k, experts, width = 3, 2, 6, 24
     rng = np.random.RandomState(5)
     picked = _picks(picks, t, k, held, experts, rng)
-    if picks == "nothing":
-        picked = picked % (experts - held) + held
     slot_of_row, row_of_slot, sizes, count = _sorted(picked, held, rows)
     *_, live, padded_of_slot, visits = gf._layout(
         sizes, slot_of_row, row_of_slot, TILE, gf.BLOCK, gf.CHUNK)
@@ -229,6 +230,18 @@ def test_the_way_back_sums_each_tokens_rows(interp, case):
         sizes, slot_of_row, row_of_slot, TILE, gf.BLOCK, gf.CHUNK)[1] // k,
         -1).reshape(-1, 1, gf.CHUNK)
     got = gf._combine(values, token, visits, t, gf.BLOCK, gf.CHUNK)
+    # a visit that matches no token does no product: NaN in every chunk
+    # that holds no pair (a one-hot 0 x NaN is NaN) reaches no block
+    holds = np.asarray(token).reshape(-1, gf.CHUNK).max(axis=1) >= 0
+    assert not holds.all()
+    unread = jnp.where(jnp.repeat(jnp.asarray(holds), gf.CHUNK)[:, None],
+                       values, jnp.nan)
+    assert np.array_equal(np.asarray(got), np.asarray(gf._combine(
+        unread, token, visits, t, gf.BLOCK, gf.CHUNK)))
+    # and it stays on the chunk of the last visit that matched
+    block_of, chunk_of, look, _ = (np.asarray(v) for v in visits)
+    assert np.all(holds[chunk_of[look >= 0]])
+    assert np.all(chunk_of[1:][look[1:] < 0] == chunk_of[:-1][look[1:] < 0])
     at = np.asarray(padded_of_slot)
     padded = np.concatenate([np.asarray(values, np.float64),
                              np.zeros((1, width))])
@@ -237,6 +250,103 @@ def test_the_way_back_sums_each_tokens_rows(interp, case):
     assert int((at < launched).sum()) == min(count, rows)
     np.testing.assert_allclose(np.asarray(got, np.float64), want,
                                rtol=3e-7, atol=0)
+
+
+# ---------------------------------------------------------------------------
+# the launches stop at the last live tile
+# ---------------------------------------------------------------------------
+#: case -> (picks, capacity; None: the count, "no_tail": a hand-made table)
+TAILS = {
+    "wide_tail": ("mixed", 48 + 4 * TILE),
+    "no_pair_here": ("nothing", 48),        # ``held`` live tiles, no live row
+    "count_is_capacity": ("mixed", None),   # the narrowest tail a rung has
+    "no_tail": (None, "no_tail"),           # the gate cuts no live tile
+}
+
+
+def _tiles(case):
+    """(``group_of_tile`` with the live count last, tiles, live tiles)."""
+    picks, rows = TAILS[case]
+    held = 3
+    if rows == "no_tail":       # (``_layout`` always leaves a tile over)
+        table = jnp.asarray([0, 0, 1, 2, 2, 5], jnp.int32)
+    else:
+        picked = _picks(picks, 40, 2, held, 6, np.random.RandomState(7))
+        if rows is None:
+            rows = _sorted(picked, held, 80)[3]
+        slot_of_row, row_of_slot, sizes, _ = _sorted(picked, held, rows)
+        table = gf._layout(sizes, slot_of_row, row_of_slot, TILE, gf.BLOCK,
+                           gf.CHUNK)[0]
+        assert table.shape == (-(-rows // TILE) + held + 1,)
+    tiles, live = table.shape[0] - 1, int(table[-1])
+    assert held <= live <= tiles and (live == tiles) == (case == "no_tail")
+    assert (live == held) == (case == "no_pair_here")
+    # every tile past the live ones is the last expert's
+    assert np.all(np.asarray(table[live:tiles]) == held - 1)
+    return table, tiles, live
+
+
+def _planted(a, live_rows):
+    """``a`` with NaN in every row from ``live_rows`` on."""
+    return a.at[live_rows:].set(jnp.nan)
+
+
+#: role -> (widths of the row arrays it reads, of the stacks' matrices,
+#: the results' (width, type)), at D = 32, F = 24, gated
+ROLE_SHAPES = {
+    gf.ROLE_UP: ([32], [(32, 24)] * 2, ((24, BF16),) * 2),
+    gf.ROLE_DOWN: ([24, 24, 1], [(24, 32)], ((32, F32),)),
+    gf.ROLE_DHIDDEN: ([32, 24, 24, 1], [(24, 32)],
+                      ((24, BF16),) * 3 + ((1, F32),)),
+    gf.ROLE_DX: ([24, 24], [(32, 24)] * 2, ((32, F32),)),
+}
+
+
+@pytest.mark.parametrize("case", list(TAILS))
+@pytest.mark.parametrize("role", list(ROLE_SHAPES))
+def test_a_launch_does_no_product_on_a_tile_past_the_live_ones(
+        interp, role, case):
+    """NaN in every input row of the tiles past the live ones: the live
+    rows of every result are the clean call's, bit for bit and finite.
+    The rows past them are NOT WRITTEN (the module's contract; interpret
+    mode marks what no step wrote with NaN, whatever the inputs held)."""
+    table, tiles, live = _tiles(case)
+    rng = np.random.RandomState(8)
+    row_widths, matrices, outs = ROLE_SHAPES[role]
+    arrays = [jnp.asarray(rng.randn(tiles * TILE, w), F32 if w == 1 else BF16)
+              for w in row_widths]
+    stacks = [jnp.asarray(rng.randn(3, *m) * 0.2, BF16) for m in matrices]
+    clean = gf._row_products(role, table, arrays, stacks, outs, BF16, TILE)
+    got = gf._row_products(role, table,
+                           [_planted(a, live * TILE) for a in arrays],
+                           stacks, outs, BF16, TILE)
+    for a, b in zip(got, clean):
+        a, b = (np.asarray(v, np.float32) for v in (a, b))
+        assert np.all(np.isfinite(a[:live * TILE]))
+        assert np.array_equal(a[:live * TILE], b[:live * TILE])
+        assert np.all(np.isnan(b[live * TILE:]))
+
+
+@pytest.mark.parametrize("case", list(TAILS))
+def test_a_weight_gradient_sums_the_live_tiles_alone(interp, case):
+    """``_weight_grad`` with NaN in both operands' rows past the live
+    tiles: every expert's block is the clean call's and finite, and the
+    clean call's is the per-expert sum over its own tiles."""
+    table, tiles, live = _tiles(case)
+    rng = np.random.RandomState(9)
+    a = jnp.asarray(rng.randn(tiles * TILE, 32), BF16)
+    b = jnp.asarray(rng.randn(tiles * TILE, 24), BF16)
+    clean = gf._weight_grad(table, a, b, 3, TILE)
+    got = gf._weight_grad(table, _planted(a, live * TILE),
+                          _planted(b, live * TILE), 3, TILE)
+    assert got.shape == (3, 32, 24) and np.all(np.isfinite(np.asarray(got)))
+    assert np.array_equal(np.asarray(got), np.asarray(clean))
+    group = np.repeat(np.asarray(table[:live]), TILE)
+    a64, b64 = (np.asarray(v, np.float64)[:live * TILE] for v in (a, b))
+    for e in range(3):
+        want = a64[group == e].T @ b64[group == e]
+        np.testing.assert_allclose(np.asarray(clean[e]), want, rtol=1e-5,
+                                   atol=1e-5)
 
 
 def test_what_a_rung_launches_on():
@@ -374,4 +484,36 @@ def test_the_record_carries_the_hidden_row_of_the_rung_that_ran(
     assert int(routing[1]) == (768 if rung == "grouped" else 256 * 8)
     hidden, routed = np.asarray(rows)[1:]
     assert hidden[0] == 0 and hidden[1] > 0 and routed[1] > 0
+    assert all(np.all(np.isfinite(np.asarray(g))) for g in grads)
+
+
+def test_the_record_of_a_rung_with_a_wide_tail_stays_clean(interp):
+    """Under ``FLAGS_check_nan_inf`` the ``hidden`` row is of the rows the
+    launches wrote: six tiles past the live ones, which no step writes
+    (NaN in interpret mode), leave it with no non-finite element."""
+    inp = _inputs(True)
+    picked = _picks("mixed", 40, 2, 3, 6, np.random.RandomState(3))
+    slot_of_row, row_of_slot, sizes, _ = _sorted(picked, 3, 48 + 4 * TILE)
+    table = gf._layout(sizes, slot_of_row, row_of_slot, TILE, gf.BLOCK,
+                       gf.CHUNK)[0]
+    assert table.shape[0] - 1 - int(table[-1]) >= 4
+
+    class Holder:
+        def named_sublayers(self):
+            return []
+
+    def loss(x, up):
+        with nan_inf.recording(Holder()) as rec:
+            out = gf.grouped_ffn(x, inp["weight"], slot_of_row, row_of_slot,
+                                 sizes, inp["w_gate"], up, inp["w_down"],
+                                 dtype=BF16)
+            keys = [k for k, _, _ in rec.frames[0].entries]
+            rows = rec.frames[0].stacked()
+        return jnp.sum(out * inp["cot"]), (keys, rows)
+
+    (_, (keys, rows)), grads = jax.value_and_grad(
+        loss, argnums=(0, 1), has_aux=True)(inp["x"], inp["w_up"])
+    assert keys == ["hidden"]
+    nonfinite, largest, _ = np.asarray(rows)[0]
+    assert nonfinite == 0 and 0 < largest < 100
     assert all(np.all(np.isfinite(np.asarray(g))) for g in grads)

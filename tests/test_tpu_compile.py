@@ -431,7 +431,8 @@ def test_the_expert_layers_grouped_rung_compiles_at_the_moe_cells_shapes(
         assert "sparse_moe.grouped" not in counts
         assert text == shut[0][0]
         return
-    assert counts["sparse_moe.grouped"] == counts["moe_grouped.pallas"] == 1
+    assert counts["sparse_moe.grouped"] == counts["moe_grouped.pallas"] \
+        == counts["moe_grouped.live_tiles_only"] == 1
     assert ("sparse_moe.every_pair" in counts) == (cell == "nemotron")
     assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) \
         == (10 if gated else 9)
@@ -718,24 +719,38 @@ def test_the_kimi_cells_step_launches_the_kda_chunk_forward_once_a_layer(
 @pytest.mark.parametrize("cell, want", [
     ("nemotron-3-nano-30b-a3b.pretrain-seq8k",
      {"ssd_chunk": 12, "mamba2_conv": 36, "mamba2_gate_norm": 12,
-      "flash_attention_grouped": 2}),
+      "flash_attention_grouped": 2, "moe_grouped_dw": 8}),
     ("lfm2-24b-a2b.pretrain-seq8k",
-     {"gated_conv": 15, "flash_attention_grouped": 2}),
-], ids=["nemotron", "lfm2"])
+     {"gated_conv": 15, "flash_attention_grouped": 2, "moe_grouped_dw": 12}),
+    ("qwen3-next-80b-a3b.pretrain-seq8k", {"moe_grouped_dw": 24}),
+], ids=["nemotron", "lfm2", "qwen3-next"])
 def test_a_scan_or_convolution_cells_step_launches_what_its_parent_did(
         one_chip, monkeypatch, cell, want):
     """The one policy gained a name that no segment of these steps holds:
     their traced steps launch the state-space scan (forward, run again,
     backward: its outputs are NOT kept, ROADMAP S19(c)) and the gated
-    convolution as often as at PR 47, and count nothing kept for KDA."""
+    convolution as often as at PR 47, and count nothing kept for KDA.
+    And the grouped rung's launches that stop at their last live tile
+    (PR 50) are the launches it had: four rungs' in the Nemotron and LFM2
+    steps, eight in the Qwen3-Next step (two sorted rungs a layer), each
+    counted ``moe_grouped.live_tiles_only`` once."""
     from paddle_tpu.ops.pallas import counters
 
     step, args = _cell_step(cell, one_chip, monkeypatch)
     calls = collections.Counter(
         p["name"] for p in _pallas_calls_in(step.trace(*args).jaxpr))
+    rungs = 8 if cell.startswith("qwen3") else 4
+    want = dict(want, moe_grouped_up=2 * rungs, moe_grouped_down=rungs,
+                moe_grouped_dhidden=rungs, moe_grouped_dx=rungs,
+                moe_grouped_combine=2 * rungs)
     assert {role: calls[role] for role in want} == want
+    snap = counters.snapshot()
+    assert snap["moe_grouped.live_tiles_only"] \
+        == snap["moe_grouped.pallas"] == rungs
+    if cell.startswith("qwen3"):
+        return      # (its KDA launches: the test of its own step, below)
     assert "kda_chunk_fwd" not in calls
-    assert not any(k.startswith("kda_chunk") for k in counters.snapshot())
+    assert not any(k.startswith("kda_chunk") for k in snap)
 
 
 # ---------------------------------------------------------------------------

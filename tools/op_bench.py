@@ -310,7 +310,7 @@ def bench_fused_xent(smoke):
             "kernels": [_xent_kernel_rows(*c) for c in cells]}
 
 
-#: the five MoE cells' expert layers: tokens a step, D, F, experts held
+#: the six MoE cells' expert layers: tokens a step, D, F, experts held
 #: of how many, picks a token, gated or plain, the router's scores
 EXPERT_CELLS = {
     "nemotron": (16384, 2688, 1856, 8, 128, 6, False, "sigmoid"),
@@ -318,6 +318,7 @@ EXPERT_CELLS = {
     "kanana": (16384, 2048, 768, 8, 128, 6, True, "sigmoid"),
     "mellum": (16384, 2304, 896, 16, 64, 8, True, "softmax"),
     "kimi": (8192, 2304, 1024, 8, 256, 8, True, "sigmoid"),
+    "qwen3next": (8192, 2048, 512, 32, 512, 10, True, "softmax"),
 }
 
 
@@ -327,16 +328,19 @@ def bench_expert_ffn(smoke, only=""):
     ``nemotron+lfm2``), forward + recomputed forward + backward under
     bfloat16 autocast, once a count of pairs: a correction bias on the
     first ``forced`` experts makes them every token's picks, 0 and 1 of
-    them for the lowest rung at two counts (a grouped rung's time should
-    not follow its count), ``top_k`` of them for the dense rung.
+    them for the lowest rung at two counts (a grouped rung's launches
+    stop at its last live tile, so its time follows its count:
+    ``live_tiles`` of ``tiles`` a run, from the same scores as the
+    layer's), ``top_k`` of them for the dense rung.
     ``dense_rows`` is what the lowest rung took in the dense rung's rows
     (the router, the sort and the ways back in both), and ``stated`` what
     ``nn.moe._grouped_cost`` states for it where it is the kernels'."""
     import jax.numpy as jnp
 
     from paddle_tpu import amp
-    from paddle_tpu.nn.moe import _grouped_cost, sparse_moe
+    from paddle_tpu.nn.moe import SCORE_FUNCS, _grouped_cost, sparse_moe
     from paddle_tpu.ops.pallas import counters
+    from paddle_tpu.ops.pallas.grouped_ffn import TILE, padded_rows
 
     cells = {"smoke": (256, 64, 32, 2, 16, 2, True, "sigmoid")} if smoke \
         else {k: v for k, v in EXPERT_CELLS.items()
@@ -364,6 +368,16 @@ def bench_expert_ffn(smoke, only=""):
         step = jax.jit(jax.value_and_grad(
             loss, argnums=(0, 1, 2, 3) + ((4,) if gated else ()),
             has_aux=True))
+
+        @jax.jit
+        def held_sizes(x, router, bias):
+            """Pairs on each held expert, by the layer's own routing."""
+            scores = SCORE_FUNCS[score](jnp.matmul(
+                x.astype(jnp.float32), router,
+                precision=jax.lax.Precision.HIGHEST))
+            _, picked = jax.lax.top_k(scores + bias, top_k)
+            return jnp.sum(picked.reshape(-1, 1) == jnp.arange(held), axis=0)
+
         before = counters.snapshot()
         rungs = []
         for forced in (0, 1, top_k):
@@ -374,6 +388,12 @@ def bench_expert_ffn(smoke, only=""):
             rungs.append({"forced": forced, "pairs": pairs, "rows": rows,
                           "ms": round(ms, 4),
                           "us_per_row": round(1e3 * ms / rows, 4)})
+            if rows < t * held and "sparse_moe.grouped" in counters.delta(
+                    before):        # a grouped rung ran, not the dense one
+                sizes = np.asarray(held_sizes(args[0], args[1], bias))
+                rungs[-1].update(
+                    live_tiles=int(np.maximum(1, -(-sizes // TILE)).sum()),
+                    tiles=padded_rows(rows, held) // TILE)
         row = {"cell": name, "rungs": rungs}
         # (no ratio where the ladder is one rung: every count ran on it)
         if rungs[0]["rows"] != rungs[-1]["rows"]:
